@@ -1,0 +1,460 @@
+#!/usr/bin/env python
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process that owns the chip drives the main path once, through the
+entry points a user calls, and checks every result bit for bit against a
+plain numpy reference computed on the host from the same ``--seed``:
+
+  1. *Device.* ``jax.devices()`` must be a TPU, else exit non-zero.
+  2. *Library door.* ``run_ranks(8, app, device_mesh=True)`` — eight
+     thread-ranks bound to the one chip (``HBMSlotChannel``) holding
+     device-resident ``jax.Array`` buffers, 64 MiB f32 per rank:
+     allreduce sum (the Pallas slot kernel), allreduce max, bcast,
+     reduce_scatter_block at 64 MiB; allgather and alltoall at 8 MiB;
+     allreduce sum at 4 B / 4 KiB / 1 MiB; and allreduce at 1 MiB on
+     host numpy buffers under ``MV2T_ALLREDUCE_ALGO=device`` (staging).
+  3. *Launcher door.* ``mvapich2_tpu.run --vpod -np 8
+     benchmarks/osu_allreduce.py -m 67108864 -i 3 -x 1`` in this same
+     process (the launcher runs rank threads in-process on the real
+     device), to the port's own ``No Errors``.
+  4. *Proof the chip did the work.* ``coll_level_chip`` rose by exactly
+     the device collectives issued, every ``dev_coll_fallback_*`` pvar
+     is 0, and the lowered text of the slot program that ran holds a
+     ``tpu_custom_call``.
+
+``--chips 4`` runs the four-chip phase instead, and nothing else:
+``run_ranks(4, app, device_mesh=True)`` (1:1 ``DeviceCollChannel``, the
+Pallas ICI ring kernels) compared with numpy AND with the stock XLA
+lowering (``lax.psum`` / ``all_gather`` / ``all_to_all``).
+
+Any failed phase raises: the exit code is non-zero and no result line is
+printed. Timings are host-clock smoke timings around
+``block_until_ready`` — not metrics. The last line of stdout is
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import json
+import os
+import statistics
+import sys
+import time
+
+import numpy as np
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, REPO)
+
+MiB = 1 << 20
+NRANKS = 8
+STEADY_CALLS = 3
+OSU_ARGS = ["-m", str(64 * MiB), "-i", "3", "-x", "1"]
+
+
+def say(msg: str) -> None:
+    print(msg, flush=True)
+
+
+def rank_data(seed: int, tag: int, rank: int, nelems: int) -> np.ndarray:
+    """Rank ``rank``'s f32 buffer for phase ``tag``: small-integer
+    values, so f32 sums over 8 ranks are exact in any order."""
+    rng = np.random.default_rng([seed, tag, rank])
+    return rng.integers(-8, 9, nelems).astype(np.float32)
+
+
+def fallback_pvars() -> dict:
+    from mvapich2_tpu import mpit
+    return {n: mpit.pvar(n).read()
+            for n in (mpit.pvar_get_info(i)["name"]
+                      for i in range(mpit.pvar_get_num()))
+            if n.startswith("dev_coll_fallback_")}
+
+
+@contextlib.contextmanager
+def forced_device_allreduce():
+    """``MV2T_ALLREDUCE_ALGO=device`` for the duration: host numpy
+    buffers then take the device whatever crossover is compiled in."""
+    from mvapich2_tpu.utils.config import get_config
+    cfg = get_config()
+    os.environ["MV2T_ALLREDUCE_ALGO"] = "device"
+    cfg.reload()
+    try:
+        yield
+    finally:
+        del os.environ["MV2T_ALLREDUCE_ALGO"]
+        cfg.reload()
+
+
+class _Phase:
+    """One collective at one size: inputs, the call, the reference."""
+
+    def __init__(self, tag, label, nelems, call, ref, host=False):
+        self.tag, self.label, self.nelems = tag, label, nelems
+        self.call, self.ref, self.host = call, ref, host
+
+
+def _library_phases(nranks: int, big: int, mid: int):
+    """The library door's calls. ``big``/``mid``: elements per rank at
+    the 64 MiB / 8 MiB points (cut for the CPU rehearsal)."""
+    from mvapich2_tpu.core import op as opmod
+    root = 3 % nranks
+
+    def rsb_ref(xs):
+        return np.sum(xs, axis=0).reshape(nranks, -1)
+
+    def a2a_ref(xs):
+        c = xs[0].size // nranks
+        return np.stack([np.concatenate(
+            [xs[s][r * c:(r + 1) * c] for s in range(nranks)])
+            for r in range(nranks)])
+
+    P = _Phase
+    return [
+        P(1, "allreduce sum", big, lambda c, x: c.allreduce(x),
+          lambda xs: np.sum(xs, axis=0)),
+        P(1, "allreduce max", big,
+          lambda c, x: c.allreduce(x, op=opmod.MAX),
+          lambda xs: np.max(xs, axis=0)),
+        P(1, "bcast", big, lambda c, x: c.bcast(x, root=root),
+          lambda xs: xs[root]),
+        P(1, "reduce_scatter_block", big,
+          lambda c, x: c.reduce_scatter_block(x), rsb_ref),
+        P(2, "allgather", mid, lambda c, x: c.allgather(x),
+          lambda xs: np.concatenate(xs)),
+        P(2, "alltoall", mid, lambda c, x: c.alltoall(x), a2a_ref),
+        P(3, "allreduce sum", 1, lambda c, x: c.allreduce(x),
+          lambda xs: np.sum(xs, axis=0)),
+        P(4, "allreduce sum", 1024, lambda c, x: c.allreduce(x),
+          lambda xs: np.sum(xs, axis=0)),
+        P(5, "allreduce sum", MiB // 4, lambda c, x: c.allreduce(x),
+          lambda xs: np.sum(xs, axis=0)),
+        P(6, "allreduce sum [host buffers, MV2T_ALLREDUCE_ALGO=device]",
+          MiB // 4, lambda c, x: c.allreduce(x),
+          lambda xs: np.sum(xs, axis=0), host=True),
+    ]
+
+
+def _expect(ref: np.ndarray, label: str, rank: int) -> np.ndarray:
+    """This rank's slice of the reference (rank-indexed for the
+    scattering collectives, shared otherwise)."""
+    if label in ("reduce_scatter_block", "alltoall"):
+        return ref[rank]
+    return ref
+
+
+def _peak_bytes(device) -> int:
+    stats = device.memory_stats() or {}
+    return int(stats.get("peak_bytes_in_use", -1))
+
+
+def library_door(seed: int, nranks: int = NRANKS, big: int = 16 * MiB,
+                 mid: int = 2 * MiB, device_mesh=True,
+                 channel: str = "HBMSlotChannel",
+                 expect_kernel: bool = True) -> int:
+    """Door 1: MPI calls on thread-ranks bound to the device. Returns
+    the number of device collectives issued (per rank)."""
+    import jax
+
+    from mvapich2_tpu import run_ranks
+
+    phases = _library_phases(nranks, big, mid)
+    data, refs = {}, {}
+    for ph in phases:       # inputs + references: host, outside timing
+        if ph.tag not in data:
+            data[ph.tag] = [rank_data(seed, ph.tag, r, ph.nelems)
+                            for r in range(nranks)]
+        refs[id(ph)] = ph.ref(data[ph.tag])
+    report = [None] * len(phases)
+
+    def app(comm):
+        ch = comm.device_channel
+        assert type(ch).__name__ == channel, type(ch).__name__
+        dev = ch.device
+        for i, ph in enumerate(phases):
+            x = data[ph.tag][comm.rank]
+            if not ph.host:
+                x = jax.device_put(x, dev)
+            want = _expect(refs[id(ph)], ph.label, comm.rank)
+            times = []
+            for _ in range(1 + STEADY_CALLS):
+                comm.barrier()
+                t0 = time.perf_counter()
+                out = jax.block_until_ready(ph.call(comm, x))
+                times.append(time.perf_counter() - t0)
+                got = np.asarray(out)           # outside the timing
+                if not ph.host:
+                    assert out.devices() == {dev}, (ph.label, out.devices())
+                if got.shape != want.shape or not np.array_equal(got, want):
+                    raise AssertionError(
+                        f"rank {comm.rank}: {ph.label} at "
+                        f"{ph.nelems * 4} B differs from the numpy "
+                        f"reference")
+                del out, got
+            if comm.rank == 0:
+                report[i] = (times[0], statistics.median(times[1:]),
+                             _peak_bytes(dev))
+        if comm.rank == 0 and expect_kernel:
+            # proof from the program, not from the rule: the slot
+            # program rank 0 (the leader) built and ran for the 64 MiB
+            # sum lowers to a Mosaic kernel
+            prog = ch._programs[("allreduce", big, "float32", "sum", 0,
+                                 None)]
+            prog = getattr(prog, "fn", prog)
+            text = prog.lower(jax.ShapeDtypeStruct(
+                (nranks, big), np.float32)).as_text()
+            assert "tpu_custom_call" in text, \
+                "the slot program holds no Pallas kernel"
+            say("library door: tpu_custom_call present in the lowered "
+                "slot program (allreduce sum, "
+                f"{nranks} x {big * 4} B)")
+
+    with forced_device_allreduce():                # host-buffer phase
+        run_ranks(nranks, app, device_mesh=device_mesh, timeout=900.0)
+    for ph, (first, steady, peak) in zip(phases, report):
+        say(f"library door: {ph.label:<22} {ph.nelems * 4:>10} B/rank  "
+            f"bit-equal to numpy on {nranks} ranks | first call "
+            f"{first:.3f} s (compile), steady {steady * 1e3:.3f} ms/call "
+            f"(smoke timing) | peak_bytes_in_use {peak}")
+    return len(phases) * (1 + STEADY_CALLS)
+
+
+class _Tee:
+    """Pass-through stdout that keeps what went by."""
+
+    def __init__(self, inner):
+        self.inner, self.kept = inner, []
+
+    def write(self, s):
+        self.kept.append(s)
+        return self.inner.write(s)
+
+    def flush(self):
+        self.inner.flush()
+
+
+def launcher_door(nranks: int = NRANKS, osu_args=OSU_ARGS) -> int:
+    """Door 2: the mpirun front door, in-process on the real device.
+    Returns the number of device collectives issued (per rank)."""
+    from types import SimpleNamespace
+
+    from mvapich2_tpu.bench import osu_util
+    from mvapich2_tpu.runtime import launcher
+
+    argv = ["--vpod", "-np", str(nranks),
+            os.path.join(REPO, "benchmarks", "osu_allreduce.py"), *osu_args]
+    say("launcher door: python -m mvapich2_tpu.run " + " ".join(argv)
+        + "   [MV2T_ALLREDUCE_ALGO=device]")
+    tee = sys.stdout = _Tee(sys.stdout)
+    t0 = time.perf_counter()
+    try:
+        with forced_device_allreduce():   # the port allocates host buffers
+            rc = launcher.main(argv)
+    finally:
+        sys.stdout = tee.inner
+    out = "".join(tee.kept)
+    if rc != 0 or "No Errors" not in out:
+        raise AssertionError(f"launcher door failed: rc={rc}, "
+                             f"'No Errors' {'in' if 'No Errors' in out else 'not in'} output")
+    # the size table the port walked: skip + iters calls per size, plus
+    # the int32 error-count allreduce of finalize_ok (the f64 latency
+    # statistics do not lower with x64 off and keep the host path)
+    it = iter(osu_args)
+    o = dict(zip(it, it))
+    opts = SimpleNamespace(min_size=4, max_size=int(o["-m"]),
+                           iterations=int(o["-i"]), skip=int(o["-x"]))
+    calls = sum(opts.skip + osu_util.scale_iters(opts, s)
+                for s in osu_util.sizes(opts)) + 1
+    say(f"launcher door: No Errors; {calls} device allreduces per rank "
+        f"up to {opts.max_size} B in {time.perf_counter() - t0:.1f} s "
+        f"(smoke timing)")
+    return calls
+
+
+def one_chip(seed: int) -> None:
+    import jax
+
+    from mvapich2_tpu import mpit
+    from mvapich2_tpu.transport import shm
+    say("native helpers: " + ("libshmring.so built on demand and loaded"
+                              if shm._load_native() is not None
+                              else "not built; python fallback"))
+    chip0, fb0 = mpit.pvar("coll_level_chip").read(), fallback_pvars()
+    calls = library_door(seed)
+    calls += launcher_door()
+    rose = mpit.pvar("coll_level_chip").read() - chip0
+    fb = {n: v - fb0[n] for n, v in fallback_pvars().items()}
+    say(f"proof: coll_level_chip rose by {rose} = {NRANKS} ranks x {calls} "
+        f"device collectives issued; fallbacks {fb}")
+    assert rose == NRANKS * calls, (rose, NRANKS * calls)
+    assert fb and not any(fb.values()), fb
+    say(f"proof: peak_bytes_in_use {_peak_bytes(jax.devices()[0])}")
+
+
+# ---------------------------------------------------------------------------
+# --chips 4: the ICI ring kernels against numpy and the stock lowering
+# ---------------------------------------------------------------------------
+
+def _stock(mesh, name: str, op: str, xs):
+    """The stock XLA lowering of one collective over the same mesh, on
+    the same data: [p] per-rank results as numpy."""
+    import jax
+    from jax import lax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    p = len(xs)
+    body = {
+        ("allreduce", "sum"): lambda x: lax.psum(x, "x"),
+        ("allreduce", "max"): lambda x: lax.pmax(x, "x"),
+        ("allgather", None): lambda x: lax.all_gather(
+            x.reshape(-1), "x", tiled=True).reshape(1, -1),
+        ("alltoall", None): lambda x: lax.all_to_all(
+            x.reshape(p, -1), "x", 0, 0).reshape(1, -1),
+    }[(name, op)]
+    g = jax.device_put(np.stack(xs), NamedSharding(mesh, P("x", None)))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("x", None),),
+                              out_specs=P("x", None), check_vma=False))
+    return np.asarray(f(g))
+
+
+def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
+    """``scale`` divides the element counts (CPU rehearsal only)."""
+    import jax
+
+    from mvapich2_tpu import mpit, run_ranks
+    from mvapich2_tpu.core import op as opmod
+    from mvapich2_tpu.parallel.mesh import make_mesh
+
+    devs = jax.devices()[:nranks]
+    assert len(set(devs)) == nranks, f"need {nranks} devices, have {devs}"
+    mesh = make_mesh((nranks,), ("x",), devs)
+    K = 1024
+    cases = [   # (tag, name, op, bytes per rank)
+        (1, "allreduce", "sum", 4 * K), (2, "allreduce", "sum", MiB),
+        (3, "allreduce", "sum", 64 * MiB), (4, "allgather", None, 16 * MiB),
+        (5, "alltoall", None, 16 * MiB), (6, "allreduce", "max", MiB),
+    ]
+    cases = [(t, n, o, max(nranks * 128 * 4, b // scale))
+             for t, n, o, b in cases]
+    data = {t: [rank_data(seed, 100 + t, r, b // 4) for r in range(nranks)]
+            for t, _n, _o, b in cases}
+    results = {t: [None] * nranks for t, *_ in cases}
+    homes = [None] * nranks
+    report = {}
+
+    def app(comm):
+        ch = comm.device_channel
+        assert type(ch).__name__ == "DeviceCollChannel", type(ch).__name__
+        dev = ch.device
+        homes[comm.rank] = dev
+        for t, name, op, nbytes in cases:
+            x = jax.device_put(data[t][comm.rank], dev)
+            assert x.sharding.device_set == {dev}
+            call = {"allreduce": lambda: comm.allreduce(
+                        x, op=opmod.MAX if op == "max" else opmod.SUM),
+                    "allgather": lambda: comm.allgather(x),
+                    "alltoall": lambda: comm.alltoall(x)}[name]
+            times = []
+            for _ in range(1 + STEADY_CALLS):
+                comm.barrier()
+                t0 = time.perf_counter()
+                out = jax.block_until_ready(call())
+                times.append(time.perf_counter() - t0)
+                assert out.sharding.device_set == {dev}, \
+                    (name, comm.rank, out.sharding.device_set)
+            results[t][comm.rank] = np.asarray(out)
+            if comm.rank == 0:
+                report[t] = (times[0], statistics.median(times[1:]))
+                say(f"four chips: ran {name} {op or ''} {nbytes} B/rank "
+                    f"x {1 + STEADY_CALLS}")
+
+    before = {n: mpit.pvar(n).read()
+              for n in ("dev_coll_tier_vmem", "dev_coll_tier_hbm",
+                        "coll_level_ici")}
+    fb0 = fallback_pvars()
+    run_ranks(nranks, app, device_mesh=mesh, timeout=900.0)
+    assert len(set(homes)) == nranks, \
+        f"the {nranks} shards do not live on {nranks} devices: {homes}"
+    say(f"four chips: shards on {sorted(str(d) for d in homes)}")
+
+    for t, name, op, nbytes in cases:
+        xs = data[t]
+        c = xs[0].size // nranks
+        ref = {"allreduce": lambda: [np.sum(xs, axis=0) if op == "sum"
+                                     else np.max(xs, axis=0)] * nranks,
+               "allgather": lambda: [np.concatenate(xs)] * nranks,
+               "alltoall": lambda: [np.concatenate(
+                   [xs[s][r * c:(r + 1) * c] for s in range(nranks)])
+                   for r in range(nranks)]}[name]()
+        stock = _stock(mesh, name, op, xs)
+        for r in range(nranks):
+            got = results[t][r]
+            if not np.array_equal(got, ref[r]):
+                raise AssertionError(f"{name} {op} {nbytes} B: rank {r} "
+                                     f"differs from the numpy reference")
+            if not np.array_equal(got, stock[r].reshape(got.shape)):
+                raise AssertionError(f"{name} {op} {nbytes} B: rank {r} "
+                                     f"differs from the stock lowering")
+        first, steady = report[t]
+        say(f"four chips: {name} {op or '':<3} {nbytes:>9} B/rank  "
+            f"bit-equal to numpy and to the stock XLA lowering on "
+            f"{nranks} ranks | first call {first:.3f} s (compile), steady "
+            f"{steady * 1e3:.3f} ms/call (smoke timing)")
+    rose = {n: mpit.pvar(n).read() - v for n, v in before.items()}
+    fb = {n: v - fb0[n] for n, v in fallback_pvars().items()}
+    say(f"proof: {rose}; fallbacks {fb}")
+    assert rose["dev_coll_tier_vmem"] > 0 and rose["dev_coll_tier_hbm"] > 0
+    assert rose["coll_level_ici"] == nranks * len(cases) * (1 + STEADY_CALLS)
+    assert fb and not any(fb.values()), fb
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4 = the four-chip ICI phase and nothing else")
+    args = ap.parse_args(argv)
+
+    import jax
+    import jaxlib
+
+    from mvapich2_tpu.utils.compile_cache import (cache_entries,
+                                                  ensure_compile_cache)
+    cache_dir = ensure_compile_cache()
+    devs = jax.devices()
+    d0 = devs[0]
+    libtpu = "n/a"
+    if d0.platform == "tpu":
+        libtpu = getattr(d0.client, "platform_version", "?").replace(
+            "\n", " ")
+    n0 = cache_entries(cache_dir)
+    say(f"device: platform={d0.platform} kind={d0.device_kind} "
+        f"count={len(devs)} | jax {jax.__version__} jaxlib "
+        f"{jaxlib.__version__} | libtpu {libtpu}")
+    say(f"compile cache: {cache_dir or '(none placed: CPU asked for)'} "
+        f"({n0} entries before)")
+    if d0.platform != "tpu":
+        say("chip_smoke: jax found no TPU; nothing was checked")
+        return 1
+    if len(devs) != args.chips:
+        say(f"chip_smoke: --chips {args.chips} but jax reports "
+            f"{len(devs)} devices")
+        return 1
+
+    t0 = time.perf_counter()
+    if args.chips == 4:
+        four_chips(args.seed)
+    else:
+        one_chip(args.seed)
+    say(f"compile cache: {cache_dir} ({cache_entries(cache_dir)} entries "
+        f"after, {n0} before)")
+    say(f"chip_smoke: all phases passed in {time.perf_counter() - t0:.1f} s")
+    print(json.dumps({"ok": True, "device": {
+        "platform": d0.platform, "kind": d0.device_kind,
+        "count": len(devs)}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

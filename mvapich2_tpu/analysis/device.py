@@ -5,8 +5,7 @@ The host shm protocol earned its verification net in PR 7 (the
 kernel modules (ops/pallas_ici.py, ops/pallas_ring.py, rma/device.py)
 drive raw Mosaic DMA: every ``make_async_copy``/``make_async_remote_copy``
 is a contract with the hardware — an unawaited handle is a use-after-free
-of a VMEM slot, an unpaired credit semaphore is the 64 MiB deadlock the
-interpreter can never reproduce (jax<0.5 interpret mode is creditless).
+of a VMEM slot, an unpaired credit semaphore is the 64 MiB deadlock.
 Five invariant families, all syntactic:
 
   * **copy/wait pairing** — a handle bound from ``make_async_*copy`` and
@@ -25,13 +24,17 @@ Five invariant families, all syntactic:
     that are ``semaphore_signal``ed must equal the set that is
     ``semaphore_wait``ed (a signal-only sem leaks credits; a wait-only
     sem is a guaranteed hang).
-  * **interpret gates** — every credit-semaphore op must sit behind an
-    explicit creditless gate (an ``if`` on a ``credits``-ish flag or a
-    ``sem is None`` check), and the gate (or its def) must be annotated
-    ``# device: hw-only`` so hardware-only code is marked in source —
-    the 0.4.x interpreter cannot execute remote signals, so unmarked
-    credit code is exactly the code no CI run has ever executed.
-  * **VMEM budget** — scratch ``pltpu.VMEM((ndir, depth, chunk), ...)``
+  * **credit gates** — where a credit-semaphore op sits behind a
+    creditless gate (an ``if`` on a ``credits``-ish flag or a
+    ``sem is None`` check), the gate (or its def) must be annotated
+    ``# device: hw-only``. The mark names the code a ``credits=False``
+    run skips: the credit handshake, which runs on hardware and — since
+    the TPU interpreter executes remote signals — in every CPU test, and
+    is off only in a schedule experiment that asks for it. (The name is
+    from the days when no interpreter could run it.) Ungated credit ops
+    are fine: they always run.
+  * **VMEM budget** — scratch ``pltpu.VMEM((ndir, depth, chunk, 128),
+    ...)``
     allocations are evaluated against every committed configuration
     (the ICI_CHUNK_BYTES / ICI_PIPELINE_DEPTH cvar defaults parsed from
     mpit.py, plus each committed tuning profile's ici_chunk_bytes):
@@ -497,19 +500,13 @@ class DevicePass(LintPass):
                                  "guaranteed hang")
                 if f is not None:
                     out.append(f)
-        # every credit op behind an annotated creditless gate
+        # every creditless gate around a credit op is annotated
         parents = parent_map(mod.tree)
         seen_gates: Set[Tuple[int, str]] = set()
         for node, op, sem in self._sem_sites(mod):
             gate_line = self._gate_line(node, parents)
             if gate_line is None:
-                f = self.finding(mod, node.lineno,
-                                 f"credit-semaphore op on '{sem}' has "
-                                 "no creditless gate — interpret mode "
-                                 "(jax<0.5) cannot execute it")
-                if f is not None:
-                    out.append(f)
-                continue
+                continue        # ungated: runs wherever the kernel runs
             if (gate_line, sem) in seen_gates:
                 continue
             seen_gates.add((gate_line, sem))
@@ -521,7 +518,7 @@ class DevicePass(LintPass):
                 f = self.finding(mod, gate_line,
                                  f"creditless gate for '{sem}' is not "
                                  "annotated '# device: hw-only' — "
-                                 "hardware-only code must be marked")
+                                 "credit-gated code must be marked")
                 if f is not None:
                     out.append(f)
 
@@ -624,11 +621,17 @@ class DevicePass(LintPass):
             total = 0
             for _line, dims in bufs:
                 size = _BUDGET_ITEMSIZE
+                # a trailing lanes dim means 'chunk' counts rows of it
+                lanes = 128 if any(isinstance(d, str) and "LANES" in d
+                                   for d in dims) else 1
                 for d in dims:
                     if isinstance(d, int):
                         size *= d
+                    elif "LANES" in d:
+                        size *= lanes
                     elif "chunk" in d:
-                        size *= max(1, chunk_bytes // _BUDGET_ITEMSIZE)
+                        size *= max(1, chunk_bytes // _BUDGET_ITEMSIZE
+                                    // lanes)
                     elif "depth" in d:
                         size *= depth
                     elif "ndir" in d or "dir" in d:

@@ -18,8 +18,8 @@ interleaving the memory model allows:
 
 The device lane rides the same net: ``ici.build_ring`` models the
 chunk-credit flow control of the HBM-streaming remote-DMA engine
-(ops/pallas_ici.py) — the handshake the jax<0.5 interpreter can never
-execute — proving no-slot-collision, no-lost-credit, agreement and
+(ops/pallas_ici.py) — exhaustively, where an interpreter or chip run
+sees one interleaving — proving no-slot-collision, no-lost-credit, agreement and
 deadlock freedom for uni- and bidirectional rings under the
 global-chunk-counter slot schedule. Its ``quant=True`` variant models
 the block-quantized wire (ops/pallas_quant.py: scale word + packed

@@ -1,10 +1,9 @@
 """Chunk-credit model of the HBM-streaming ICI ring (ops/pallas_ici.py).
 
-The credit handshake of the chunked remote-DMA engine has NEVER
-executed: the jax<0.5 interpreter is creditless (no remote semaphore
-signal), so every interpreter run since PR 8 validated the data
-schedule but not the flow control. This model is the handshake's
-verification net before the first TPU host run — the device analog of
+The credit handshake of the chunked remote-DMA engine runs under the
+TPU interpreter and on the chip (PR 22), but each such run sees one
+interleaving. This model is the handshake's exhaustive verification
+net — the device analog of
 the seqlock/doorbell/lease models PR 7 built for the host shm
 protocols.
 
@@ -41,9 +40,10 @@ bidirectional):
   * **no-deadlock** — the wave always completes (explorer built-in).
 
 What it cannot prove: the VPU fold arithmetic and the multi-round
-reduce-scatter block rotation (interpreter-proven: the 0.4.x emulator
-is deterministic dataflow), and Mosaic's lowering of the semaphore ops
-themselves — those wait for the first TPU host (ROADMAP item 1).
+reduce-scatter block rotation (interpreter-proven, and bit-exact on
+four v5e chips — chip_smoke.py --chips 4), and Mosaic's lowering of the
+semaphore ops themselves (tests/test_chip_compile.py asks the chip's
+compiler).
 
 Mutations (tests/test_modelcheck.py asserts every one is caught by a
 named invariant):

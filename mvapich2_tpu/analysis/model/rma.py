@@ -3,9 +3,9 @@
 
 The lock/flush/unlock grammar and the target-side fold pipeline of the
 device RMA lane have never run against an adversarial interleaving:
-the jax<0.5 interpreter is synchronous dataflow (creditless, one
-program order), so interpreter runs validate the data schedule but not
-the sync grammar the hardware path depends on. This model is that
+an interpreter (or chip) run executes the credit handshake but sees one
+interleaving of it, so such runs validate the data schedule, not every
+order of the sync grammar the hardware path depends on. This model is that
 grammar's verification net — the one-sided sibling of the ici
 chunk-credit model.
 

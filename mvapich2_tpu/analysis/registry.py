@@ -41,8 +41,7 @@ INTERNAL_ENV: Set[str] = {
     "MV2T_RANK", "MV2T_SIZE", "MV2T_KVS", "MV2T_FAKE_NODE", "MV2T_FT",
     "MV2T_WORLD_BASE", "MV2T_SPAWN_CTX", "MV2T_APPNUM",
     "MV2T_PARENT_RANKS", "MV2T_RANK_PLATFORM", "MV2T_PLATFORM_EXPLICIT",
-    "MV2T_VPOD_CHILD", "MV2T_VPOD_REAL", "MV2T_TEST_ON_TPU",
-    "MV2T_TEST_FULL", "MV2T_FT_WATCHER",
+    "MV2T_VPOD_CHILD", "MV2T_TEST_FULL", "MV2T_FT_WATCHER",
     # sanitizer-lane plumbing (bin/runtests --tsan): points every ring
     # consumer in the job at one instrumented variant .so — a build
     # coordinate, not a tunable
@@ -54,7 +53,7 @@ INTERNAL_ENV: Set[str] = {
 # MV2T_MET_*: the metrics-segment layout #define namespace
 # (native/shm_layout.h, doc-referenced) — cross-language constants
 # pinned by the layout doctor, not env tunables
-INTERNAL_PREFIXES = ("MV2T_DEBUG_", "MV2T_STASH_", "MV2T_MET_")
+INTERNAL_PREFIXES = ("MV2T_DEBUG_", "MV2T_MET_")
 
 # env-drift doctor: the committed non-python surfaces scanned by
 # default (native getenv reads; MV2T_* tokens in bin/ and the README)

@@ -93,6 +93,16 @@ def collective_latency(comm, title: str, run_one: Callable[[int], None],
         comm.barrier()
 
 
-def finalize_ok(comm) -> None:
+def finalize_ok(comm, errs: int = 0) -> None:
+    """Close a benchmark the way the conformance corpus closes a test:
+    sum every rank's validation-error count and have rank 0 print
+    ``No Errors`` (or the count) as the last line; a job with errors
+    exits non-zero."""
+    total = int(comm.allreduce(np.array([errs], np.int32))[0])
+    if comm.rank == 0:
+        print("No Errors" if total == 0 else f"Found {total} errors")
+        sys.stdout.flush()
     comm.barrier()
     mpi.Finalize()
+    if total:
+        sys.exit(1)

@@ -1068,6 +1068,14 @@ class DeviceCollChannel:
             return False
 
 
+def _slot_kernel_op(op: str) -> bool:
+    """The fused Pallas slot kernel (ops/pallas_hbm) carries the sum;
+    other ops take the XLA reduction over the same slot array. A kernel
+    that fails to compile or run fails the collective — there is no
+    second lowering to hide behind."""
+    return op == "sum"
+
+
 class HBMSlotChannel(DeviceCollChannel):
     """All bound ranks share ONE device: collectives run through an HBM
     slot segment — the device-side analog of the reference's slotted
@@ -1103,14 +1111,6 @@ class HBMSlotChannel(DeviceCollChannel):
         self.size = size
         self._programs: Dict = {}
         self._nb_seq = 0
-        # flipped (shared via the rendezvous, since each rank holds its
-        # own channel object) when Mosaic rejects the fused kernel on
-        # this TPU generation: reductions fall back to the XLA path
-        self.rv.no_pallas = getattr(self.rv, "no_pallas", False)
-
-    def _use_pallas(self, op: str) -> bool:
-        from ..ops import pallas_hbm as ph
-        return op == "sum" and ph.HAVE_PALLAS and not self.rv.no_pallas
 
     def _chan_desc(self) -> str:
         return f"slot{self.size}x{self.device.platform}"
@@ -1125,7 +1125,7 @@ class HBMSlotChannel(DeviceCollChannel):
                "prod": jnp.prod}[op or "sum"]
 
         if name in ("allreduce", "reduce"):
-            if self._use_pallas(op):
+            if _slot_kernel_op(op):
                 def f(x):
                     return ph.hbm_slot_allreduce(x)
             else:
@@ -1143,7 +1143,7 @@ class HBMSlotChannel(DeviceCollChannel):
             def f(x):                       # [R, n] -> [R, R, c] transpose
                 return jnp.transpose(x.reshape(R, R, c), (1, 0, 2))
         elif name == "reduce_scatter_block":
-            if self._use_pallas(op):
+            if _slot_kernel_op(op):
                 def f(x):
                     return ph.hbm_slot_allreduce(x)
             else:
@@ -1176,21 +1176,8 @@ class HBMSlotChannel(DeviceCollChannel):
             x = jax.device_put(
                 np.stack([np.asarray(s).reshape(n)
                           for s in rv.slots]), self.device)
-        prog = self._program(name, n, str(dtype), op, root)
-        try:
-            out = jax.block_until_ready(prog(x))
-        except Exception:
-            if not self._use_pallas(op):
-                raise
-            # Mosaic rejected the fused kernel on this TPU generation
-            # (bench/autotune catch the same failure mode): fall back to
-            # the XLA reduction for the life of this binding
-            log.warn("pallas slot kernel failed for %s; falling back to "
-                     "the XLA reduction path", name)
-            self.rv.no_pallas = True
-            self._programs.clear()
-            prog = self._program(name, n, str(dtype), op, root)
-            out = jax.block_until_ready(prog(x))
+        out = jax.block_until_ready(
+            self._program(name, n, str(dtype), op, root)(x))
         if name == "alltoall":
             return [out[r] for r in range(R)]
         if name == "reduce_scatter_block":
@@ -1252,10 +1239,6 @@ class DeviceFoldChannel(DeviceCollChannel):
         self._mesh_devices = mesh_devs
         self._programs: Dict = {}
         self._nb_seq = 0
-        # shared via the rendezvous, like the slot channel: Mosaic
-        # rejecting the fused fold kernel demotes every chip's fold to
-        # the XLA reduction for the life of the binding
-        self.rv.no_pallas = getattr(self.rv, "no_pallas", False)
 
     def _mesh_extent(self) -> int:
         return self.ndev
@@ -1266,13 +1249,9 @@ class DeviceFoldChannel(DeviceCollChannel):
     def nonblocking(self, comm, name: str, *a, plan: bool = False):
         return None     # host NBC schedule (fold has no DAG segments yet)
 
-    def _use_pallas(self, op: str) -> bool:
-        from ..ops import pallas_hbm as ph
-        return op == "sum" and ph.HAVE_PALLAS and not self.rv.no_pallas
-
     def _fold_prog(self, op: str):
-        """Per-chip fold program: the HBM fused slot-reduce when it
-        lowers, the XLA reduction otherwise (cached like any program)."""
+        """Per-chip fold program: the HBM fused slot-reduce for sum, the
+        XLA reduction for the other ops (cached like any program)."""
         key = ("chipfold", 0, "", op, 0, None)
         got = self._programs.get(key)
         if got is None:
@@ -1282,7 +1261,7 @@ class DeviceFoldChannel(DeviceCollChannel):
             from ..ops import pallas_hbm as ph
             red = {"sum": jnp.sum, "max": jnp.max, "min": jnp.min,
                    "prod": jnp.prod}[op or "sum"]
-            if self._use_pallas(op):
+            if _slot_kernel_op(op):
                 def f(x):
                     return ph.hbm_slot_allreduce(x)
             else:
@@ -1313,17 +1292,7 @@ class DeviceFoldChannel(DeviceCollChannel):
                 return s.reshape(n)
             return jax.device_put(np.asarray(s).reshape(n),
                                   self._mesh_devices[j])
-        x = self._chip_stack(j, n, dtype)
-        try:
-            return self._fold_prog(op)(x)
-        except Exception:
-            if not self._use_pallas(op):
-                raise
-            log.warn("pallas chip-fold kernel failed; falling back to "
-                     "the XLA reduction path")
-            self.rv.no_pallas = True
-            self._programs.pop(("chipfold", 0, "", op, 0, None), None)
-            return self._fold_prog(op)(x)
+        return self._fold_prog(op)(self._chip_stack(j, n, dtype))
 
     def _leader(self, name: str, op: str, root: int) -> List:
         """Leader compute: fold per chip, run the mesh program over the
@@ -1464,6 +1433,9 @@ _CVAR_OF = {"allreduce": "ALLREDUCE", "bcast": "BCAST",
             "reduce": "REDUCE", "reduce_scatter_block": "REDUCE_SCATTER"}
 
 
+_warned_no_lower: set = set()
+
+
 def _select_transport(comm, name: str, nbytes: int, op, buf) -> str:
     """'device' or 'host' for this call — step 2 of the tuning order
     (coll/tuning.py docstring). Note: the decision must be identical on
@@ -1476,8 +1448,10 @@ def _select_transport(comm, name: str, nbytes: int, op, buf) -> str:
               and _dtype_ok(buf))
     if forced == "device":
         if not lowers:
-            log.warn("%s forced to device but op/dtype does not lower; "
-                     "using host path", name)
+            if name not in _warned_no_lower:    # once per collective,
+                _warned_no_lower.add(name)      # not once per call
+                log.warn("%s forced to device but op/dtype does not "
+                         "lower; using host path", name)
             return "host"
         return "device"
     if forced:
@@ -1672,6 +1646,8 @@ def bind_universes(universes, mesh=None, axis=None) -> bool:
     """
     import jax
 
+    from ..utils.compile_cache import ensure_compile_cache
+    ensure_compile_cache()
     n = len(universes)
     slot_device = None
     fold = False

@@ -421,6 +421,10 @@ def select_algorithm(comm, name: str, nbytes: int, op=None) -> Callable:
     cfg = get_config()
     # 1. env override
     forced = cfg.get(f"{name.upper()}_ALGO", "")
+    if forced == "device":
+        # names the transport, not a host algorithm: coll/device.py
+        # handed this call back (op/dtype does not lower) and said so
+        forced = ""
     if forced:
         fn = ALGOS[name].get(forced)
         if fn is None:

@@ -1,93 +1,99 @@
-"""Pallas API compatibility + shared fallback accounting for ops/.
+"""The one seam between ops/ and the installed jax (0.9.0), plus the
+shared fallback accounting.
 
-The pallas TPU surface moved between jax releases (``pltpu.CompilerParams``
-was ``TPUCompilerParams``; ``InterpretParams`` — the race-detecting
-interpreter config — does not exist before jax 0.5): the kernels in this
-package run against whichever spelling the installed jax provides, so the
-device path cannot be broken by a version skew the way the r6 seed was
-(every pallas test failed with AttributeError on 0.4.x).
+Everything here is written for the single jax this repo runs on: no
+version probing, no alternate spellings. Three things live here:
 
-Also home of ``note_fallback`` — the observability hook for the
-VMEM-cap / shape / dtype rejections that used to be silent (the invisible
-4 MiB cliff of ops/pallas_ring.py): every rejection bumps one of the
-``dev_coll_fallback_{size,dtype,shape,platform}`` pvars declared in
-mpit.py. Kernel wrappers call it at trace time (once per compiled shape);
-the per-call accounting for the MPI path lives in coll/device.py.
+``compiler_params`` / ``interpret_params`` / ``resolve_interpret`` — how
+every kernel wrapper in ops/ builds its ``pallas_call`` arguments. The
+interpret rule is ONE rule for the whole package (``resolve_interpret``):
+a TPU backend never interprets, whatever was asked; off the TPU a kernel
+interprets when the caller or the MV2T_ICI_INTERPRET cvar asks for it,
+and local (no remote DMA) kernels interpret by default so the CPU suite
+runs them. Interpreted kernels always get ``pltpu.InterpretParams`` (the
+threaded TPU interpreter: remote DMAs, remote semaphore signals and the
+barrier semaphore all work), so the credit handshake and entry barrier
+the chip runs are the ones CPU tests run.
+
+``serialize_executable`` / ``deserialize_executable`` — the daemon
+exec-cache seam over ``jax.export``.
+
+``note_fallback`` — the observability hook for the VMEM-cap / shape /
+dtype rejections that used to be silent: every rejection bumps one of
+the ``dev_coll_fallback_{size,dtype,shape,platform}`` pvars declared in
+mpit.py. Kernel wrappers call it at trace time (once per compiled
+shape); the per-call accounting for the MPI path lives in
+coll/device.py.
 """
 
 from __future__ import annotations
 
-import dataclasses
 from typing import Optional
+
+import jax
+from jax.experimental.pallas import tpu as pltpu
 
 from ..utils.mlog import get_logger
 
 log = get_logger("pallas")
 
-try:
-    from jax.experimental import pallas as pl          # noqa: F401
-    from jax.experimental.pallas import tpu as pltpu
-    HAVE_PALLAS = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    HAVE_PALLAS = False
-
 
 def compiler_params(**kw):
-    """A pltpu compiler-params object for this jax version; keyword
-    arguments the local dataclass does not know are dropped (they are
-    scheduling hints, never correctness)."""
-    cp = getattr(pltpu, "CompilerParams", None)
-    if cp is None:
-        cp = pltpu.TPUCompilerParams
-    allowed = {f.name for f in dataclasses.fields(cp)}
-    return cp(**{k: v for k, v in kw.items() if k in allowed})
+    """``pltpu.CompilerParams`` — one spelling, unknown keywords are a
+    TypeError (a misspelt scheduling hint must not vanish silently)."""
+    return pltpu.CompilerParams(**kw)
 
 
 def interpret_params(**kw):
-    """The richest interpreter config this jax supports: the
-    race-detecting ``InterpretParams`` when present, else plain
-    ``interpret=True`` (the 0.4.x emulator is deterministic dataflow —
-    DMA discharge in program order — so the sweep still validates the
-    schedule, just not slot races)."""
-    ip = getattr(pltpu, "InterpretParams", None)
-    if ip is None:
-        return True
-    try:
-        return ip(**kw)
-    except TypeError:   # a field moved; the bare config still interprets
-        return ip()
+    """The TPU interpreter config every interpreted kernel runs under."""
+    return pltpu.InterpretParams(**kw)
 
 
-def have_remote_signal() -> bool:             # device: hw-only
-    """True when remote ``semaphore_signal`` works under the active
-    execution mode — the credit handshake needs it. The 0.4.x
-    interpreter raises NotImplementedError for remote signals, so
-    interpret-mode callers must run creditless (safe there: the
-    emulator is synchronous dataflow, flow control is moot). Code
-    gated on this (or on the resolved ``credits`` flag) is exactly the
-    code no interpreter run executes — the mv2tlint ``device`` pass
-    requires every such gate to carry the ``# device: hw-only`` mark."""
-    return getattr(pltpu, "InterpretParams", None) is not None
+def on_tpu() -> bool:
+    """True when the process' default backend is a TPU. Initializes the
+    backend (as any jit call would) and lets its failure propagate: a
+    JAX that cannot start is an error, never 'not a TPU'."""
+    return jax.default_backend() == "tpu"
+
+
+def resolve_interpret(interpret=None, *, local: bool = False):
+    """The ``interpret=`` argument for a ``pallas_call`` — the single
+    rule of ops/. ``interpret``: None (ask the MV2T_ICI_INTERPRET cvar;
+    ``local`` kernels — no cross-device traffic — additionally default
+    to the interpreter off the TPU), False, True, or a ready
+    ``InterpretParams``. Returns False or an ``InterpretParams``.
+
+    A TPU backend never interprets: the answer there is False whatever
+    was asked, so no flag or leftover environment can put the
+    interpreter between an MPI call and the chip."""
+    if interpret is None:
+        from ..utils.config import get_config
+        interpret = bool(get_config()["ICI_INTERPRET"]) or \
+            (local and not on_tpu())
+    if not interpret:
+        return False
+    if on_tpu():
+        log.warn("pallas interpret mode requested on a TPU backend; "
+                 "ignored (kernels always compile on the chip)")
+        return False
+    if interpret is True:
+        return interpret_params()
+    return interpret
 
 
 # -- device-executable export/import seam (the daemon exec cache) ------
 # jax.export serializes a traced+lowered program (StableHLO + the
 # already-compiled Mosaic payloads of any pallas custom calls) to
 # portable bytes; deserializing skips jax tracing and lowering — the
-# dominant cold-start cost of a device job's first collective. The API
-# appeared around jax 0.4.30 and moved (jax.experimental.export before
-# that): both helpers return None when THIS jax cannot, so callers
-# no-op cleanly — the cache degrades to per-process builds, it never
-# breaks the collective. Interpreter-mode kernels that resist export
-# (host callbacks) land in the same None path.
+# dominant cold-start cost of a device job's first collective. Both
+# helpers return None when the program resists export (interpreter-mode
+# kernels carry host callbacks), so callers no-op cleanly — the cache
+# degrades to per-process builds, it never breaks the collective.
 
 def exec_fingerprint() -> str:
     """The environment half of the executable-cache key: an artifact is
     only valid under the jax/backend/precision/tuning-profile that
     built it. Cheap string compare, never a version parse."""
-    import jax
-
     from ..utils.config import get_config
     prof = str(get_config().get("TUNING_PROFILE", "") or "")
     return (f"jax{jax.__version__}|{jax.default_backend()}"
@@ -96,14 +102,9 @@ def exec_fingerprint() -> str:
 
 def serialize_executable(fn, *args) -> Optional[bytes]:
     """Serialize ``fn`` (a jax.jit-wrapped callable) traced at the
-    shapes/dtypes of ``args``. None = this jax has no export API or the
-    program resists export — the caller skips caching."""
+    shapes/dtypes of ``args``. None = the program resists export — the caller skips caching."""
+    from jax import export as jexp
     try:
-        from jax import export as jexp
-    except ImportError:   # pre-export jax: the cache no-ops
-        return None
-    try:
-        import jax
         specs = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args]
         return jexp.export(fn)(*specs).serialize()
     except Exception as e:   # noqa: BLE001 — caching is best-effort
@@ -113,13 +114,9 @@ def serialize_executable(fn, *args) -> Optional[bytes]:
 
 def deserialize_executable(blob: bytes):
     """Rehydrate a serialized executable as a jitted callable, or None
-    when this jax cannot (the caller rebuilds from source)."""
+    when the blob does not load (the caller rebuilds from source)."""
+    from jax import export as jexp
     try:
-        from jax import export as jexp
-    except ImportError:
-        return None
-    try:
-        import jax
         return jax.jit(jexp.deserialize(blob).call)
     except Exception as e:   # noqa: BLE001
         log.dbg(1, "executable import failed (%r); rebuilding", e)

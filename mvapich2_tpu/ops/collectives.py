@@ -32,12 +32,8 @@ AxisName = Union[str, Tuple[str, ...]]
 
 
 def axis_size(axis: AxisName) -> int:
-    """Static size of the bound axis (MPI_Comm_size analog). jax < 0.5
-    has no lax.axis_size; psum of a literal 1 constant-folds to the
-    same concrete value there."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
+    """Static size of the bound axis (MPI_Comm_size analog)."""
+    return lax.axis_size(axis)
 
 
 def axis_rank(axis: AxisName):

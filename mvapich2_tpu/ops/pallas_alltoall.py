@@ -24,24 +24,28 @@ slot/credit streaming engine as ops/pallas_ici.py:
     into it; the receiver re-grants per consumed chunk; at step exit
     the sender fences on its credit balance returning to ``depth``
     (its receiver consumed everything), which is exactly the condition
-    that makes the next step's writes land in free slots. Creditless
-    under the 0.4.x interpreter, like every other lane.
+    that makes the next step's writes land in free slots. The kernel
+    opens with an all-peers entry barrier (every shard is written by
+    every other before it ends, and the first writer differs per
+    lane).
   * **alltoallv** — per-peer counts/displs are static at build time
-    (the mesh channel knows the full count matrix). The wire program
-    (remote DMAs, credit waves, fences) stays a single rank-symmetric
-    op sequence with traced peer indices — paired shards must meet at
-    the SAME op instance, so nothing that rendezvouses may live under
-    a rank conditional; only the local HBM<->VMEM staging, whose
-    offsets and valid prefixes are compile-time constants per rank, is
-    lowered under per-rank ``pl.when(my == r)`` branches. Wire chunks
-    are padded to the step-wide maximum
-    (``W_s = max_r nchunks(counts[r][(r+s)%p])``) and always travel at
-    full chunk size so the DMA byte counts — and therefore the
-    send/recv semaphore pairing — stay uniform along the whole
-    permutation even when the counts are skewed; a pair with fewer (or
-    zero) valid chunks pads with discarded slots but still runs the
-    full credit wave, so no credit leaks on a zero-count peer (the
-    model variant in analysis/model/ici.py seeds exactly that bug).
+    (the mesh channel knows the full count matrix). The wrapper packs
+    each rank's sends into uniform whole-tile per-peer blocks
+    ``(p, block_rows, 128)`` on the XLA side (one dynamic slice per
+    peer) and scatters the received blocks' valid prefixes to their
+    displacements afterwards, so the kernel is the uniform one: a
+    single rank-symmetric op sequence with traced peer indices —
+    paired shards must meet at the SAME op instance, so nothing that
+    rendezvouses may live under a rank conditional. The count matrix
+    only sets how many rows each permutation step moves: the step-wide
+    maximum (``rows_s = max_r tile_rows(counts[r][(r+s)%p])``), so the
+    DMA byte counts — and therefore the send/recv semaphore pairing —
+    stay uniform along the whole permutation even when the counts are
+    skewed; a step nobody has payload for is skipped mesh-wide, and a
+    pair with fewer (or zero) valid elements moves pad rows but still
+    runs the full credit wave, so no credit leaks on a zero-count peer
+    (the model variant in analysis/model/ici.py seeds exactly that
+    bug).
   * **Bidirectional** on >2-shard axes: the step list splits across
     two lanes with disjoint slot arrays (steps 1..ceil((p-1)/2) travel
     "rightward", the rest "leftward"), both pipelines in flight at
@@ -66,22 +70,23 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ._compat import HAVE_PALLAS, compiler_params, note_fallback
-from .pallas_ici import (_RingStreamer, _cfg_chunk_elems, _cfg_depth,
-                         _chunks, _resolve_flags, _resolve_ndir,
-                         _trace_entry, planned_tier)
+from ._compat import compiler_params, note_fallback
+from .pallas_ici import (_LANES, _RingStreamer, _as_blocks,
+                         _cfg_chunk_rows, _cfg_depth, _chunks, _copy,
+                         _entry_barrier, _from_blocks, _resolve_flags,
+                         _resolve_ndir, _tile_rows, _trace_entry,
+                         planned_tier)
 
-if HAVE_PALLAS:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # cvar/pvar declarations (ICI_* knobs are shared with the ring engine)
 from .. import mpit  # noqa: F401,E402
 
-# distinct Mosaic collective ids (pallas_ring owns 7/8, pallas_ici
-# 9-11, pallas_quant 12, pallas_rma 13-16)
-_CID_ALLTOALL = 17
-_CID_ALLTOALLV = 18
+# Mosaic collective id (pallas_ring owns 0/1, pallas_ici 2-5,
+# pallas_quant 6, pallas_rma 7-10); alltoall and alltoallv are one
+# kernel
+_CID_ALLTOALL = 11
 
 
 # ---------------------------------------------------------------------------
@@ -139,38 +144,24 @@ class _A2AStreamer(_RingStreamer):
             return
         pltpu.semaphore_wait(self.cap_sem.at[d], self.depth)
 
-    def free_slot(self, d):
-        """The slot the next wire chunk will stream through, with its
-        previous outbound DMA retired (send slot free for reload). A
-        shared op — every rank waits on the same handle instance."""
+    def issue_a2a(self, d, x_hbm, off, sz):
+        """Front half: retire the slot's previous outbound DMA, load
+        rows [off, off+sz) of the block bound for this step's peer into
+        the send slot, then launch the remote DMA — the one op both
+        sides of the pair rendezvous on, traced once for all ranks
+        (the peer index stays traced arithmetic)."""
         slot = self.gc[d] % self.depth
         prev = self.pending_send.pop((d, slot), None)
         if prev is not None:
             prev.wait_send()
-        return slot
-
-    def load_chunk(self, d, x_hbm, src_off, valid):
-        """Local staging (branchable — HBM->VMEM only, no rendezvous):
-        load the valid prefix of the upcoming chunk into its send
-        slot."""
-        slot = self.gc[d] % self.depth
-        ld = pltpu.make_async_copy(
-            x_hbm.at[pl.ds(src_off, valid)],
-            self.send_buf.at[d, slot, pl.ds(0, valid)],
-            self.in_sem.at[d, slot])
-        ld.start()
-        ld.wait()
-
-    def issue_wire(self, d, wire):
-        """Launch the remote DMA at the uniform wire size — the one op
-        both sides of the pair rendezvous on, so it must be traced once
-        for all ranks (peer index stays traced arithmetic)."""
-        slot = self.gc[d] % self.depth
-        self._take_credit(d)
         dst = self.step_dst[d]
+        _copy(x_hbm.at[dst, pl.ds(off, sz)],
+              self.send_buf.at[d, slot, pl.ds(0, sz)],
+              self.in_sem.at[d, slot])
+        self._take_credit(d)
         rdma = pltpu.make_async_remote_copy(
-            src_ref=self.send_buf.at[d, slot, pl.ds(0, wire)],
-            dst_ref=self.recv_buf.at[d, slot, pl.ds(0, wire)],
+            src_ref=self.send_buf.at[d, slot, pl.ds(0, sz)],
+            dst_ref=self.recv_buf.at[d, slot, pl.ds(0, sz)],
             send_sem=self.send_sem.at[d, slot],
             recv_sem=self.recv_sem.at[d, slot],
             device_id=self._dev(dst),
@@ -180,38 +171,15 @@ class _A2AStreamer(_RingStreamer):
         self.gc[d] += 1
         return slot
 
-    def drain_wire(self, d, slot):
-        """The chunk from this step's writer has landed — shared wait
-        on the recv semaphore."""
-        self.pending_send[(d, slot)].wait_recv()
-
-    def store_chunk(self, d, slot, o_hbm, dst_off, valid):
-        """Local staging (branchable): store the landed chunk's valid
-        prefix to its output displacement. The wait keeps the slot's
-        payload live until it is out — the caller re-grants after."""
-        st = pltpu.make_async_copy(
-            self.recv_buf.at[d, slot, pl.ds(0, valid)],
-            o_hbm.at[pl.ds(dst_off, valid)],
-            self.st_sem.at[d, slot])
-        st.start()
-        st.wait()
-
-    def issue_a2a(self, d, x_hbm, src_off, valid, wire):
-        """Front half: load the valid prefix of the chunk from the send
-        buffer (padding chunks skip the load), then launch the remote
-        DMA at the uniform wire size."""
-        self.free_slot(d)
-        if valid > 0:
-            self.load_chunk(d, x_hbm, src_off, valid)
-        return self.issue_wire(d, wire)
-
-    def drain_a2a(self, d, slot, o_hbm, dst_off, valid):
+    def drain_a2a(self, d, slot, o_hbm, off, sz):
         """Back half: the chunk from this step's writer has landed —
-        store the valid prefix to its output displacement (padding
-        chunks store nothing) and re-grant the slot."""
-        self.drain_wire(d, slot)
-        if valid > 0:
-            self.store_chunk(d, slot, o_hbm, dst_off, valid)
+        store it to rows [off, off+sz) of that writer's output block
+        and re-grant the slot. The store's wait keeps the slot's
+        payload live until it is out."""
+        self.pending_send[(d, slot)].wait_recv()
+        _copy(self.recv_buf.at[d, slot, pl.ds(0, sz)],
+              o_hbm.at[self.step_up[d], pl.ds(off, sz)],
+              self.st_sem.at[d, slot])
         self._grant(d)
 
     def finish(self):
@@ -235,8 +203,8 @@ def _mk_a2a_streamer(p, ndir, depth, credits, scratch):
 
 def _a2a_scratch_shapes(ndir: int, depth: int, chunk: int, dtype):
     return [
-        pltpu.VMEM((ndir, depth, chunk), dtype),    # send slots
-        pltpu.VMEM((ndir, depth, chunk), dtype),    # recv slots
+        pltpu.VMEM((ndir, depth, chunk, _LANES), dtype),   # send slots
+        pltpu.VMEM((ndir, depth, chunk, _LANES), dtype),   # recv slots
         pltpu.SemaphoreType.DMA((ndir, depth)),     # send-chunk loads
         pltpu.SemaphoreType.DMA((ndir, depth)),     # stores
         pltpu.SemaphoreType.DMA((ndir, depth)),     # remote send
@@ -261,130 +229,49 @@ def _lane_steps(p: int, ndir: int) -> List[List[int]]:
 def _a2a_wave(st, x_hbm, o_hbm, lanes):
     """One permutation step across the active lanes: grant the step's
     credits, pipeline issue-chunk-c / drain-chunk-(c-1) per lane, then
-    fence. ``lanes``: (d, dst, upstream, issues, drains) with
-    issues[k] = (src_off, valid, wire) and drains[k] = (dst_off,
-    valid)."""
-    for d, dst, up, _i, _dr in lanes:
+    fence. ``lanes``: (d, dst, upstream, chunks) with chunks the static
+    (row offset, rows) list the step moves."""
+    for d, dst, up, _ch in lanes:
         st.set_step(d, dst, up)
         st.grant_step_credits(d)
-    cmax = max(len(i) for _d, _t, _u, i, _dr in lanes)
-    slots = {d: [None] * len(i) for d, _t, _u, i, _dr in lanes}
+    cmax = max(len(ch) for _d, _t, _u, ch in lanes)
+    slots = {d: [None] * len(ch) for d, _t, _u, ch in lanes}
     for c in range(cmax + 1):
-        for d, _t, _u, issues, _dr in lanes:
-            if c < len(issues):
-                src_off, valid, wire = issues[c]
-                slots[d][c] = st.issue_a2a(d, x_hbm, src_off, valid,
-                                           wire)
-        for d, _t, _u, issues, drains in lanes:
-            if 1 <= c and c - 1 < len(drains):
-                dst_off, valid = drains[c - 1]
-                st.drain_a2a(d, slots[d][c - 1], o_hbm, dst_off, valid)
-    for d, _t, _u, _i, _dr in lanes:
+        for d, _t, _u, chunks in lanes:
+            if c < len(chunks):
+                slots[d][c] = st.issue_a2a(d, x_hbm, *chunks[c])
+        for d, _t, _u, chunks in lanes:
+            if 1 <= c <= len(chunks):
+                st.drain_a2a(d, slots[d][c - 1], o_hbm, *chunks[c - 1])
+    for d, _t, _u, _ch in lanes:
         st.step_fence(d)
 
 
 # ---------------------------------------------------------------------------
-# kernels
+# kernel
 # ---------------------------------------------------------------------------
 
-def _hbm_alltoall_kernel(axis_name, p, nblk, chunk, depth, ndir,
+def _hbm_alltoall_kernel(axis_name, p, step_rows, chunk, depth, ndir,
                          credits, x_hbm, o_hbm, *scratch):
-    """Uniform alltoall: input [p*nblk] (block j -> shard j), output
-    [p*nblk] (block j from shard j). The chunk schedule is globally
-    uniform, so the whole program is symmetric — every shard's k-th
-    outgoing handle pairs with its k-th arrival and the peer indices
-    stay traced arithmetic."""
+    """Alltoall(v): input (p, block_rows, 128) — block j is the payload
+    for shard j — output the same shape with block j received from
+    shard j. ``step_rows[s]`` is the static row count permutation step
+    ``s`` moves (s=0: the local block); uniform alltoall moves whole
+    blocks, the v-variant the step-wide maximum. The chunk schedule is
+    globally uniform, so the whole program is symmetric — every shard's
+    k-th outgoing handle pairs with its k-th arrival and the peer
+    indices stay traced arithmetic."""
     my = lax.axis_index(axis_name)
-    init_sem = scratch[-1]
     st = _mk_a2a_streamer(p, ndir, depth, credits, scratch[:-1])
+
+    # every shard writes into every other before the kernel ends, and
+    # the first writer differs per lane: barrier with all peers
+    _entry_barrier([lax.rem(my + s, p) for s in range(1, p)])
 
     # local block: one HBM-to-HBM DMA, no wire
-    cp = pltpu.make_async_copy(x_hbm.at[pl.ds(my * nblk, nblk)],
-                               o_hbm.at[pl.ds(my * nblk, nblk)],
-                               init_sem)
-    cp.start()
-    cp.wait()
-
-    spans = _chunks(0, nblk, chunk)
-    steps = _lane_steps(p, ndir)
-    for q in range(max(len(ls) for ls in steps)):
-        lanes = []
-        for d in range(ndir):
-            if q >= len(steps[d]):
-                continue
-            s = steps[d][q]
-            dst = lax.rem(my + s, p)
-            up = lax.rem(my - s + p, p)
-            lanes.append((d, dst, up,
-                          [(dst * nblk + off, sz, sz)
-                           for off, sz in spans],
-                          [(up * nblk + off, sz) for off, sz in spans]))
-        _a2a_wave(st, x_hbm, o_hbm, lanes)
-    st.finish()
-
-
-def _step_wire(counts: Sequence[Sequence[int]], s: int,
-               chunk: int) -> int:
-    """Wire chunks at permutation step ``s``: the step-wide maximum
-    over every (r -> (r+s)%p) pair — skewed pairs pad up to it so the
-    DMA schedule stays uniform along the permutation."""
-    p = len(counts)
-    return max(-(-counts[r][(r + s) % p] // chunk) for r in range(p))
-
-
-def _hbm_alltoallv_kernel(axis_name, p, chunk, depth, ndir, credits,
-                          counts, sdispls, rdispls, x_hbm, o_hbm,
-                          *scratch):
-    """Variable-count alltoall. Everything that rendezvouses — the
-    remote chunk DMAs, credit signals, fences — is ONE rank-symmetric
-    op sequence with traced peer indices, exactly like the uniform
-    kernel: a pair must meet at the same op instance, so per-rank
-    branches around wire ops would deadlock (each branch would trace
-    its own instance and rank r's op could never pair with rank r+s's).
-    The count matrix only shapes the local staging: per-rank offsets
-    and valid prefixes are compile-time constants lowered under
-    ``pl.when(my == r)``, loads/stores HBM<->VMEM with no cross-device
-    traffic. Every rank runs the full step-wide chunk schedule ``W_s``
-    (skewed pairs pad with discarded slots at the uniform wire size)."""
-    my = lax.axis_index(axis_name)
-    init_sem = scratch[-1]
-    st = _mk_a2a_streamer(p, ndir, depth, credits, scratch[:-1])
-
-    # local block: one HBM-to-HBM DMA per rank, no wire — branch-safe
-    for r in range(p):
-        cloc = counts[r][r]
-        if cloc > 0:
-            @pl.when(my == r)
-            def _local(r=r, cloc=cloc):
-                cp = pltpu.make_async_copy(
-                    x_hbm.at[pl.ds(sdispls[r][r], cloc)],
-                    o_hbm.at[pl.ds(rdispls[r][r], cloc)], init_sem)
-                cp.start()
-                cp.wait()
-
-    def load_branches(d, s, k):
-        """Stage chunk k of the step-s outbound block: each rank's
-        static valid prefix, one local-DMA branch per rank that has
-        payload left at this chunk offset."""
-        off = k * chunk
-        for r in range(p):
-            sv = min(chunk, max(0, counts[r][(r + s) % p] - off))
-            if sv > 0:
-                @pl.when(my == r)
-                def _ld(r=r, sv=sv, off=off):
-                    st.load_chunk(d, x_hbm,
-                                  sdispls[r][(r + s) % p] + off, sv)
-
-    def store_branches(d, slot, s, k):
-        off = k * chunk
-        for r in range(p):
-            up = (r - s) % p
-            rv = min(chunk, max(0, counts[up][r] - off))
-            if rv > 0:
-                @pl.when(my == r)
-                def _st(r=r, up=up, rv=rv, off=off):
-                    st.store_chunk(d, slot, o_hbm,
-                                   rdispls[r][up] + off, rv)
+    if step_rows[0] > 0:
+        _copy(x_hbm.at[my, pl.ds(0, step_rows[0])],
+              o_hbm.at[my, pl.ds(0, step_rows[0])], scratch[-1])
 
     steps = _lane_steps(p, ndir)
     for q in range(max(len(ls) for ls in steps)):
@@ -393,33 +280,40 @@ def _hbm_alltoallv_kernel(axis_name, p, chunk, depth, ndir, credits,
             if q >= len(steps[d]):
                 continue
             s = steps[d][q]
-            W = _step_wire(counts, s, chunk)
-            if W == 0:
+            if step_rows[s] == 0:
                 continue                # whole step is empty mesh-wide
-            st.set_step(d, lax.rem(my + s, p), lax.rem(my - s + p, p))
-            st.grant_step_credits(d)
-            lanes.append((d, s, W))
-        cmax = max((W for _d, _s, W in lanes), default=0)
-        slots = {d: [None] * W for d, _s, W in lanes}
-        for c in range(cmax + 1):
-            for d, s, W in lanes:
-                if c < W:
-                    st.free_slot(d)
-                    load_branches(d, s, c)
-                    slots[d][c] = st.issue_wire(d, chunk)
-            for d, s, W in lanes:
-                if 1 <= c <= W:
-                    st.drain_wire(d, slots[d][c - 1])
-                    store_branches(d, slots[d][c - 1], s, c - 1)
-                    st._grant(d)
-        for d, _s, _W in lanes:
-            st.step_fence(d)
+            lanes.append((d, lax.rem(my + s, p), lax.rem(my - s + p, p),
+                          _chunks(0, step_rows[s], chunk)))
+        if lanes:
+            _a2a_wave(st, x_hbm, o_hbm, lanes)
     st.finish()
 
 
 # ---------------------------------------------------------------------------
 # wrappers
 # ---------------------------------------------------------------------------
+
+def _a2a_call(blocks: jax.Array, axis_name: str, p: int, step_rows,
+              chunk_bytes, depth, bidirectional, credits, interpret):
+    """Launch the streaming kernel over (p, block_rows, 128) blocks."""
+    interpret, credits = _resolve_flags(interpret, credits)
+    rows = blocks.shape[1]
+    chunk = min(_cfg_chunk_rows(blocks.dtype, chunk_bytes), rows)
+    d = _cfg_depth(depth)
+    ndir = _resolve_ndir(p, bidirectional)
+    kernel = functools.partial(_hbm_alltoall_kernel, axis_name, p,
+                               tuple(step_rows), chunk, d, ndir, credits)
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(blocks.shape, blocks.dtype),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=_a2a_scratch_shapes(ndir, d, chunk, blocks.dtype),
+        compiler_params=compiler_params(collective_id=_CID_ALLTOALL,
+                                        has_side_effects=True),
+        interpret=interpret,
+    )(blocks)
+
 
 def hbm_alltoall(x: jax.Array, axis_name: str, num_devices: int, *,
                  chunk_bytes: Optional[int] = None,
@@ -437,28 +331,12 @@ def hbm_alltoall(x: jax.Array, axis_name: str, num_devices: int, *,
     if x.size % p:
         raise ValueError(f"alltoall shard size {x.size} not divisible "
                          f"by {p}")
-    if not HAVE_PALLAS:
-        from .collectives import all_to_all
-        c = x.size // p
-        return all_to_all(x.reshape(p, c), axis_name, split_axis=0,
-                          concat_axis=0).reshape(-1)
-    interpret, credits = _resolve_flags(interpret, credits)
-    nblk = x.size // p
-    chunk = min(_cfg_chunk_elems(x.dtype, chunk_bytes), nblk)
-    d = _cfg_depth(depth)
-    ndir = _resolve_ndir(p, bidirectional)
-    kernel = functools.partial(_hbm_alltoall_kernel, axis_name, p,
-                               nblk, chunk, d, ndir, credits)
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((x.size,), x.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        scratch_shapes=_a2a_scratch_shapes(ndir, d, chunk, x.dtype),
-        compiler_params=compiler_params(collective_id=_CID_ALLTOALL,
-                                        has_side_effects=True),
-        interpret=interpret,
-    )(x)
+    c = x.size // p
+    rows = _tile_rows(c, x.dtype)
+    out = _a2a_call(_as_blocks(x.reshape(-1), p, rows), axis_name, p,
+                    (rows,) * p, chunk_bytes, depth, bidirectional,
+                    credits, interpret)
+    return _from_blocks(out, c)
 
 
 def packed_displs(counts: Sequence[Sequence[int]]
@@ -510,60 +388,62 @@ def hbm_alltoallv(x: jax.Array, axis_name: str, num_devices: int,
     if p == 1:
         return x[:out_len]
     total = sum(sum(row) for row in counts)
-    if not HAVE_PALLAS or total == 0:
+    if total == 0:
         return _xla_alltoallv(x, axis_name, p, counts, sdispls, rdispls,
                               out_len)
-    interpret, credits = _resolve_flags(interpret, credits)
-    cmax = max(max(row) for row in counts)
-    chunk = min(_cfg_chunk_elems(x.dtype, chunk_bytes), max(1, cmax))
-    d = _cfg_depth(depth)
-    ndir = _resolve_ndir(p, bidirectional)
-    counts = tuple(tuple(row) for row in counts)
-    sdispls = tuple(tuple(row) for row in sdispls)
-    rdispls = tuple(tuple(row) for row in rdispls)
-    kernel = functools.partial(_hbm_alltoallv_kernel, axis_name, p,
-                               chunk, d, ndir, credits, counts,
-                               sdispls, rdispls)
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((out_len,), x.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        scratch_shapes=_a2a_scratch_shapes(ndir, d, chunk, x.dtype),
-        compiler_params=compiler_params(collective_id=_CID_ALLTOALLV,
-                                        has_side_effects=True),
-        interpret=interpret,
-    )(x)
+    my = lax.axis_index(axis_name)
+    rows = _tile_rows(max(max(row) for row in counts), x.dtype)
+    step_rows = [_tile_rows(max(counts[r][(r + s) % p]
+                                for r in range(p)), x.dtype)
+                 if any(counts[r][(r + s) % p] for r in range(p)) else 0
+                 for s in range(p)]
+    recv = _a2a_call(
+        _pack_blocks(x, my, sdispls, rows * _LANES).reshape(
+            p, rows, _LANES),
+        axis_name, p, step_rows, chunk_bytes, depth, bidirectional,
+        credits, interpret)
+    return _unpack_blocks(recv.reshape(p, rows * _LANES), my, counts,
+                          rdispls, out_len)
+
+
+def _pack_blocks(x, my, sdispls, width: int):
+    """[in_len] -> [p, width]: row j = the ``width`` elements at this
+    rank's send displacement for peer j (the valid prefix is
+    counts[my][j]; the tail is whatever follows — dropped again by
+    ``_unpack_blocks``)."""
+    sd = jnp.asarray(np.asarray(sdispls, dtype=np.int32))
+    xp = jnp.pad(x, (0, width))          # slack: a slice never clamps
+    return jnp.stack([lax.dynamic_slice(xp, (sd[my, j],), (width,))
+                      for j in range(len(sdispls))])
+
+
+def _unpack_blocks(recv, my, counts, rdispls, out_len: int):
+    """[p, width] received blocks -> [out_len]: scatter block j's valid
+    prefix (counts[j][my]) to this rank's receive displacement for peer
+    j; out-of-range lanes drop."""
+    p, width = recv.shape
+    c_arr = jnp.asarray(np.asarray(counts, dtype=np.int32))
+    rd = jnp.asarray(np.asarray(rdispls, dtype=np.int32))
+    lanes = jnp.arange(width, dtype=jnp.int32)
+    out = jnp.zeros((out_len,), recv.dtype)
+    for j in range(p):
+        idx = jnp.where(lanes < c_arr[j, my], rd[my, j] + lanes, out_len)
+        out = out.at[idx].set(recv[j], mode="drop")
+    return out
 
 
 def _xla_alltoallv(x, axis_name, p, counts, sdispls, rdispls, out_len):
     """Bit-exact XLA emulation of the v-variant: pad every pair to the
     matrix maximum, run the uniform lax.all_to_all, then scatter each
-    received block's valid prefix to its displacement (out-of-range
-    lanes drop). The padded wire is O(p * cmax) — the streaming kernel
-    exists precisely to beat this."""
+    received block's valid prefix to its displacement. The padded wire
+    is O(p * cmax) — the streaming kernel exists precisely to beat
+    this."""
     my = lax.axis_index(axis_name)
     cmax = max(1, max(max(row) for row in counts))
-    c_arr = jnp.asarray(np.asarray(counts, dtype=np.int32))
-    sd_arr = jnp.asarray(np.asarray(sdispls, dtype=np.int32))
-    rd_arr = jnp.asarray(np.asarray(rdispls, dtype=np.int32))
-    lanes = jnp.arange(cmax, dtype=jnp.int32)
-    xp = jnp.pad(x, (0, cmax))          # safe gather slack
-    blocks = []
-    for j in range(p):                  # pack block j for shard j
-        src = sd_arr[my, j] + lanes
-        seg = jnp.where(lanes < c_arr[my, j], xp[src],
-                        jnp.zeros((), x.dtype))
-        blocks.append(seg)
-    sent = jnp.stack(blocks)            # [p, cmax]
+    sent = _pack_blocks(x, my, sdispls, cmax)            # [p, cmax]
     recv = lax.all_to_all(sent, axis_name, split_axis=0, concat_axis=0)
-    recv = recv.reshape(p, cmax)
-    out = jnp.zeros((out_len,), x.dtype)
-    for j in range(p):                  # unpack block j from shard j
-        cnt = c_arr[j, my]
-        idx = jnp.where(lanes < cnt, rd_arr[my, j] + lanes, out_len)
-        out = out.at[idx].set(recv[j], mode="drop")
-    return out
+    return _unpack_blocks(recv.reshape(p, cmax), my, counts, rdispls,
+                          out_len)
 
 
 # ---------------------------------------------------------------------------

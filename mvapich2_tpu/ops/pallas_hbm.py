@@ -46,11 +46,9 @@ from typing import Callable, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ._compat import HAVE_PALLAS, compiler_params
+from ._compat import compiler_params, resolve_interpret
 
-if HAVE_PALLAS:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental import pallas as pl
 
 # Measured-best defaults on TPU v5e (64 MiB/rank, 8 ranks); a committed
 # tuning profile overrides them via the kernel-param keys below.
@@ -71,24 +69,21 @@ def _pick_block(M: int, bm: int) -> int:
     return bm
 
 
-def _interpret() -> bool:
-    return jax.devices()[0].platform != "tpu"
-
-
 def fused_reduce_to_slot(x: jax.Array, *, layout: str = "planar",
                          block_m: Optional[int] = None,
                          mean: bool = False,
-                         side_effects: bool = False) -> jax.Array:
+                         side_effects: bool = False,
+                         interpret=None) -> jax.Array:
     """Reduce ``R`` co-resident rank slots into one ``(M, 128)`` result
     slot in a single fused HBM pass (read ``R*m``, write ``m``).
 
     ``x`` is ``(R, M, 128)`` planar or ``(M, R, 128)`` interleaved.
     ``side_effects`` marks the call effectful so repeated identical
     calls inside one program are not CSE'd away (benchmark harnesses
-    that time K back-to-back executions).
+    that time K back-to-back executions). ``interpret``: see
+    _compat.resolve_interpret (None = compiled on a TPU, interpreted
+    anywhere else).
     """
-    if not HAVE_PALLAS:  # pragma: no cover
-        raise RuntimeError("pallas unavailable")
     if layout == "planar":
         R, M, L = x.shape
         axis = 0
@@ -117,21 +112,19 @@ def fused_reduce_to_slot(x: jax.Array, *, layout: str = "planar",
         compiler_params=compiler_params(
             dimension_semantics=("arbitrary",),
             has_side_effects=side_effects),
-        interpret=_interpret(),
+        interpret=resolve_interpret(interpret, local=True),
     )(x)
 
 
 def fused_allreduce(x: jax.Array, *, block_m: Optional[int] = None,
                     mean: bool = False, donate: bool = False,
-                    parallel: bool = True) -> jax.Array:
+                    parallel: bool = True, interpret=None) -> jax.Array:
     """Materialized allreduce over interleaved ``(M, R, 128)`` slots:
     sum across the rank axis and write the broadcast rows back into
     every rank's rows from registers, one fused pass (``2*R*m``
     traffic; the reduced row is never re-read — XLA's fused
     sum+broadcast re-reads it per output row and measures ~15% slower).
     """
-    if not HAVE_PALLAS:  # pragma: no cover
-        raise RuntimeError("pallas unavailable")
     M, R, L = x.shape
     bm = _pick_block(M, block_m or _tuned_default(
         "hbm_fused_block_m", DEFAULT_FUSED_BLOCK_M))
@@ -151,7 +144,7 @@ def fused_allreduce(x: jax.Array, *, block_m: Optional[int] = None,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         compiler_params=compiler_params(
             dimension_semantics=("parallel" if parallel else "arbitrary",)),
-        interpret=_interpret(),
+        interpret=resolve_interpret(interpret, local=True),
         **kw,
     )(x)
 
@@ -169,7 +162,8 @@ def _pad_to_lanes(bufs: jax.Array) -> Tuple[jax.Array, int]:
 
 
 def hbm_slot_allreduce(bufs: jax.Array, *, mean: bool = False,
-                       block_m: Optional[int] = None) -> jax.Array:
+                       block_m: Optional[int] = None,
+                       interpret=None) -> jax.Array:
     """Allreduce ``(R, n)`` co-resident rank buffers through the HBM
     slot segment; returns the single shared ``(n,)`` result (the
     zero-copy broadcast — hand every rank this same array)."""
@@ -177,7 +171,7 @@ def hbm_slot_allreduce(bufs: jax.Array, *, mean: bool = False,
     R, npad = bufs.shape
     out = fused_reduce_to_slot(bufs.reshape(R, npad // 128, 128),
                                layout="planar", mean=mean,
-                               block_m=block_m)
+                               block_m=block_m, interpret=interpret)
     return out.reshape(npad)[:n]
 
 
@@ -210,8 +204,6 @@ def bench_candidates(M: int, R: int, L: int = 128) -> List[
     are marked effectful so repeated calls are not CSE'd."""
     m = M * L * 4
     cands: List[Tuple[str, Callable, int, bool]] = []
-    if not HAVE_PALLAS:
-        return cands
     for bm in (512, 1024):
         if M % bm == 0:
             cands.append((

@@ -5,7 +5,7 @@ ops/pallas_ring.py are VMEM-resident (shard + 2 comm slots must fit in
 ~16 MiB; the wrapper refuses past ``VMEM_LIMIT_BYTES``), which capped
 every device perf round since r3 at the XLA lowering's plateau. These
 kernels lift the cap the way the reference lifts the eager->rendezvous
-crossover: inputs and outputs stay in HBM (``TPUMemorySpace.ANY``) and
+crossover: inputs and outputs stay in HBM (``pl.ANY``) and
 the kernel streams fixed-size chunks through double-buffered VMEM
 scratch slots —
 
@@ -26,9 +26,24 @@ with ``depth`` credits (one per VMEM slot) and the receiver re-grants a
 credit as it consumes a slot, so a sender can run at most ``depth``
 chunks ahead — slot reuse is race-free because the slot sequence is a
 single global chunk counter per direction (write *k+D* lands in the slot
-freed by consume *k*). Under the 0.4.x interpreter remote semaphore
-signals are unavailable and unnecessary (the emulator is synchronous
-dataflow), so interpret-mode runs are creditless.
+freed by consume *k*). The TPU interpreter (pltpu.InterpretParams)
+executes remote signals, so CPU tests run the same handshake.
+
+Every kernel opens with a neighbour barrier on the Mosaic barrier
+semaphore (``_entry_barrier``): a remote DMA or a remote semaphore
+signal may only target a chip that has entered the kernel — before
+that its scratch semaphores and VMEM slots belong to whatever ran
+there last. The barrier semaphore is the one object that outlives a
+kernel, which is what ``collective_id`` names.
+
+Layout (what Mosaic's tiling demands): data moves as ``(rows, 128)``
+tiles with ``rows`` a multiple of the dtype's sublane tile (8 for
+4-byte types, 16 for 2-byte, 32 for 1-byte); slot, direction and ring
+block indices sit on leading, untiled dimensions, so every slice of a
+tiled dimension is a static, tile-aligned range and the only traced
+indices are leading ones. Wrappers pad each ring block to a whole
+number of tiles with the op identity and reshape the HBM operands to
+``(p, block_rows, 128)`` before the ``pallas_call``.
 
 Tier selection (``planned_tier``) is data driven: coll/tuning.py's
 ``device_tier`` maps shard bytes to vmem (pallas_ring) / hbm (here) /
@@ -54,14 +69,13 @@ from jax import lax
 
 from ..utils.config import get_config
 from ..utils.mlog import get_logger
-from ._compat import (HAVE_PALLAS, compiler_params, have_remote_signal,
-                      note_fallback)
+from ._compat import (compiler_params, note_fallback, on_tpu,
+                      resolve_interpret)
 
 log = get_logger("pallas_ici")
 
-if HAVE_PALLAS:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # cvars ICI_CHUNK_BYTES / ICI_PIPELINE_DEPTH / ICI_BIDIR / ICI_INTERPRET
 # are predeclared in mpit.py (the MPI_T surface enumerates them before
@@ -71,11 +85,15 @@ from .. import mpit  # noqa: F401,E402  — cvar/pvar declarations
 
 _SUPPORTED_OPS = ("sum", "max", "min", "prod")
 
-# distinct Mosaic collective ids (pallas_ring owns 7/8)
-_CID_ALLREDUCE = 9
-_CID_ALLGATHER = 10
-_CID_SENDRECV = 11
-_CID_REDUCE_SCATTER = 19
+# Mosaic collective ids: each names the barrier semaphore its kernel's
+# entry barrier signals (pallas_ring owns 0/1, pallas_quant 6,
+# pallas_rma 7-10, pallas_alltoall 11)
+_CID_ALLREDUCE = 2
+_CID_ALLGATHER = 3
+_CID_SENDRECV = 4
+_CID_REDUCE_SCATTER = 5
+
+_LANES = 128
 
 
 def _cfg_chunk_elems(dtype, chunk_bytes: Optional[int]) -> int:
@@ -84,6 +102,62 @@ def _cfg_chunk_elems(dtype, chunk_bytes: Optional[int]) -> int:
         chunk_bytes = kernel_param_cv("ici_chunk_bytes",
                                       "ICI_CHUNK_BYTES")
     return max(1, int(chunk_bytes) // np.dtype(dtype).itemsize)
+
+
+def _sublanes(dtype) -> int:
+    """Rows of one VMEM tile of ``dtype``: (8, 128) for 4-byte types,
+    (16, 128) for 2-byte, (32, 128) for 1-byte."""
+    return max(8, 32 // np.dtype(dtype).itemsize)
+
+
+def _tile_rows(nelems: int, dtype) -> int:
+    """Rows of the smallest whole-tile ``(rows, 128)`` slab holding
+    ``nelems`` elements."""
+    t = _sublanes(dtype)
+    return -(-(-(-int(nelems) // _LANES)) // t) * t
+
+
+def _cfg_chunk_rows(dtype, chunk_bytes: Optional[int]) -> int:
+    """The streaming chunk in rows: ICI_CHUNK_BYTES (or the explicit
+    override) rounded down to whole tiles, at least one tile."""
+    t = _sublanes(dtype)
+    rows = _cfg_chunk_elems(dtype, chunk_bytes) // _LANES
+    return max(t, rows // t * t)
+
+
+def _as_blocks(flat: jax.Array, nblocks: int, block_rows: int,
+               fill=0) -> jax.Array:
+    """``[nblocks * c]`` -> ``(nblocks, block_rows, 128)``: each of the
+    ``nblocks`` equal runs padded with ``fill`` to a whole-tile slab."""
+    c = flat.size // nblocks
+    width = block_rows * _LANES
+    x = flat.reshape(nblocks, c)
+    if width > c:
+        x = jnp.pad(x, ((0, 0), (0, width - c)), constant_values=fill)
+    return x.reshape(nblocks, block_rows, _LANES)
+
+
+def _from_blocks(blocks: jax.Array, c: int) -> jax.Array:
+    """Inverse of :func:`_as_blocks`: drop each block's pad, flatten."""
+    nb = blocks.shape[0]
+    x = blocks.reshape(nb, -1)
+    if x.shape[1] > c:
+        x = x[:, :c]
+    return x.reshape(nb * c)
+
+
+def _entry_barrier(peers) -> None:
+    """Kernel-entry barrier on the Mosaic barrier semaphore: signal
+    every device in ``peers`` (traced LOGICAL ids; the relation must be
+    symmetric — ring neighbours, an exchange partner, all peers) and
+    wait for as many signals. Past it, every device that will write
+    into this one's slots or semaphores knows this one is inside the
+    kernel, and vice versa."""
+    sem = pltpu.get_barrier_semaphore()
+    for dev in peers:
+        pltpu.semaphore_signal(sem, inc=1, device_id=dev,
+                               device_id_type=pltpu.DeviceIdType.LOGICAL)
+    pltpu.semaphore_wait(sem, len(peers))
 
 
 def _cfg_depth(depth: Optional[int]) -> int:
@@ -162,6 +236,12 @@ class _RingStreamer:
         # axis' row-major stride — see _dev_layout)
         return self.dev_base + idx * self.dev_stride
 
+    def enter(self):
+        """Kernel entry: barrier with both ring neighbours, then hand
+        out the initial slot credits."""
+        _entry_barrier([self._dev(self.left), self._dev(self.right)])
+        self.grant_initial_credits()
+
     def grant_initial_credits(self):          # device: hw-only
         """Each direction starts with ``depth`` slot credits granted to
         the upstream neighbor (the rank that remote-writes into us)."""
@@ -181,11 +261,13 @@ class _RingStreamer:
             h.wait()
             del self.pending_store[key]
 
-    def issue(self, d, sb_off, off, sz, with_acc, rb_off):
+    def issue(self, d, sb, off, sz, with_acc, rb):
         """Front half of the chunk pipeline: load the send chunk (and,
         for the reduce phase, prefetch the local accumulator chunk),
         then launch the remote DMA — it flies while the previous
-        chunk's reduce runs."""
+        chunk's reduce runs. ``sb``/``rb``: traced ring-block indices
+        (leading dim of the HBM operand); ``off``/``sz``: static,
+        tile-aligned row range inside the block."""
         slot = self.gc[d] % self.depth
         prev = self.pending_send.pop((d, slot), None)
         if prev is not None:
@@ -194,13 +276,13 @@ class _RingStreamer:
         if prev_st is not None:
             prev_st.wait()             # acc slot's last store landed
         ld = pltpu.make_async_copy(
-            self.o_hbm.at[pl.ds(sb_off + off, sz)],
+            self.o_hbm.at[sb, pl.ds(off, sz)],
             self.send_buf.at[d, slot, pl.ds(0, sz)],
             self.in_sem.at[d, slot])
         ld.start()
         if with_acc:
             la = pltpu.make_async_copy(
-                self.o_hbm.at[pl.ds(rb_off + off, sz)],
+                self.o_hbm.at[rb, pl.ds(off, sz)],
                 self.acc_buf.at[d, slot, pl.ds(0, sz)],
                 self.acc_sem.at[d, slot])
             la.start()
@@ -220,7 +302,7 @@ class _RingStreamer:
         self.gc[d] += 1
         return slot
 
-    def drain(self, d, slot, rb_off, off, sz, red):
+    def drain(self, d, slot, rb, off, sz, red):
         """Back half: the chunk from upstream has (or is about to have)
         landed — reduce it into the accumulator chunk (or store it
         verbatim for the gather phase) and free the slot."""
@@ -233,14 +315,14 @@ class _RingStreamer:
             self._grant(d)
             st = pltpu.make_async_copy(
                 self.acc_buf.at[d, slot, pl.ds(0, sz)],
-                self.o_hbm.at[pl.ds(rb_off + off, sz)],
+                self.o_hbm.at[rb, pl.ds(off, sz)],
                 self.st_sem.at[d, slot])
             st.start()
             self.pending_store[(d, slot)] = st
         else:
             st = pltpu.make_async_copy(
                 self.recv_buf.at[d, slot, pl.ds(0, sz)],
-                self.o_hbm.at[pl.ds(rb_off + off, sz)],
+                self.o_hbm.at[rb, pl.ds(off, sz)],
                 self.st_sem.at[d, slot])
             st.start()
             st.wait()                  # slot must land before re-grant
@@ -277,7 +359,11 @@ class _RingStreamer:
 
     def stream_step(self, spans_chunks, sb_offs, rb_offs, red):
         """One ring step: pipeline every chunk of every direction —
-        issue chunk c, then drain chunk c-1 while c is on the wire."""
+        issue chunk c, then drain chunk c-1 while c is on the wire.
+        ``sb_offs``/``rb_offs`` address the send/receive ring block per
+        direction in whatever unit ``issue``/``drain`` take (block
+        indices here; the quantized streamer still passes flat element
+        offsets) — this loop only hands them through."""
         ndir = self.ndir
         cmax = max(len(c) for c in spans_chunks)
         live: List[List[Optional[int]]] = [[None] * len(spans_chunks[d])
@@ -329,10 +415,12 @@ def _dev_layout(mesh_ctx, axis_name):
 
 
 def _scratch_shapes(ndir: int, depth: int, chunk: int, dtype):
+    """``chunk`` in rows. Slot and direction lead; the tiled trailing
+    (chunk, 128) pair is only ever sliced on whole tiles."""
     return [
-        pltpu.VMEM((ndir, depth, chunk), dtype),    # send slots
-        pltpu.VMEM((ndir, depth, chunk), dtype),    # recv slots
-        pltpu.VMEM((ndir, depth, chunk), dtype),    # accumulator slots
+        pltpu.VMEM((ndir, depth, chunk, _LANES), dtype),   # send slots
+        pltpu.VMEM((ndir, depth, chunk, _LANES), dtype),   # recv slots
+        pltpu.VMEM((ndir, depth, chunk, _LANES), dtype),   # accumulators
         pltpu.SemaphoreType.DMA((ndir, depth)),     # send-chunk loads
         pltpu.SemaphoreType.DMA((ndir, depth)),     # acc-chunk loads
         pltpu.SemaphoreType.DMA((ndir, depth)),     # stores
@@ -347,116 +435,90 @@ def _scratch_shapes(ndir: int, depth: int, chunk: int, dtype):
 # kernels
 # ---------------------------------------------------------------------------
 
-def _block_spans(nblk: int, ndir: int) -> List[Tuple[int, int]]:
-    """Element ranges of a block per direction: the clockwise lane
-    carries the first half, counter-clockwise the second."""
+def _block_spans(nblk: int, ndir: int,
+                 tile: int = 1) -> List[Tuple[int, int]]:
+    """Row ranges of a block per direction: the clockwise lane carries
+    the first half, counter-clockwise the second, cut on a ``tile``
+    boundary (a one-tile block leaves the second lane empty)."""
     if ndir == 1:
         return [(0, nblk)]
-    h = (nblk + 1) // 2
+    h = -(-nblk // (2 * tile)) * tile
     return [(0, h), (h, nblk)]
 
 
-def _hbm_all_reduce_kernel(axis_name, p, op, nblk, chunk, depth, ndir,
-                           credits, mesh_ctx, x_hbm, o_hbm, *scratch):
+def _ring_neighbours(axis_name, p):
     my = lax.axis_index(axis_name)
-    right = lax.rem(my + 1, p)
-    left = lax.rem(my - 1 + p, p)
-    red = _reducer(op)
-    init_sem = scratch[-1]
-    st = _mk_streamer(p, ndir, depth, credits, left, right, o_hbm,
-                      scratch[:-1], mesh_ctx, axis_name)
+    return my, lax.rem(my - 1 + p, p), lax.rem(my + 1, p)
 
-    cp = pltpu.make_async_copy(x_hbm, o_hbm, init_sem)
+
+def _copy(src, dst, sem):
+    cp = pltpu.make_async_copy(src, dst, sem)
     cp.start()
     cp.wait()
-    st.grant_initial_credits()
 
-    spans = _block_spans(nblk, ndir)
-    spans_chunks = [_chunks(lo, hi, chunk) for lo, hi in spans]
 
-    # Phase 1: reduce-scatter — cw round s passes the partial of block
-    # (my-s-1) rightward and folds the arrival into block (my-s-2); the
-    # ccw lane mirrors with +. After p-1 rounds block ``my`` is fully
-    # reduced on both lanes (same convention as pallas_ring.py).
+def _rs_rounds(st, my, p, ndir, spans_chunks, red):
+    """Reduce-scatter: cw round s passes the partial of block (my-s-1)
+    rightward and folds the arrival into block (my-s-2); the ccw lane
+    mirrors with +. After p-1 rounds block ``my`` is fully reduced on
+    both lanes (same convention as pallas_ring.py)."""
     for s in range(p - 1):
         sb = [lax.rem(my - s - 1 + 2 * p, p), lax.rem(my + s + 1, p)]
         rb = [lax.rem(my - s - 2 + 2 * p, p), lax.rem(my + s + 2, p)]
-        st.stream_step(spans_chunks,
-                       [sb[d] * nblk for d in range(ndir)],
-                       [rb[d] * nblk for d in range(ndir)], red)
+        st.stream_step(spans_chunks, sb[:ndir], rb[:ndir], red)
 
-    # Phase 2: all-gather — cw round s passes block (my-s) rightward,
-    # receives (my-s-1); ccw mirrors.
+
+def _ag_rounds(st, my, p, ndir, spans_chunks):
+    """All-gather: cw round s passes block (my-s) rightward, receives
+    (my-s-1); ccw mirrors."""
     for s in range(p - 1):
         sb = [lax.rem(my - s + 2 * p, p), lax.rem(my + s, p)]
         rb = [lax.rem(my - s - 1 + 2 * p, p), lax.rem(my + s + 1, p)]
-        st.stream_step(spans_chunks,
-                       [sb[d] * nblk for d in range(ndir)],
-                       [rb[d] * nblk for d in range(ndir)], None)
+        st.stream_step(spans_chunks, sb[:ndir], rb[:ndir], None)
+
+
+def _hbm_all_reduce_kernel(axis_name, p, op, spans_chunks, depth, ndir,
+                           credits, mesh_ctx, x_hbm, o_hbm, *scratch):
+    """x/o: (p, block_rows, 128) in HBM."""
+    my, left, right = _ring_neighbours(axis_name, p)
+    st = _mk_streamer(p, ndir, depth, credits, left, right, o_hbm,
+                      scratch[:-1], mesh_ctx, axis_name)
+    _copy(x_hbm, o_hbm, scratch[-1])
+    st.enter()
+    _rs_rounds(st, my, p, ndir, spans_chunks, _reducer(op))
+    _ag_rounds(st, my, p, ndir, spans_chunks)
     st.finish()
 
 
-def _hbm_reduce_scatter_kernel(axis_name, p, op, nblk, chunk, depth,
+def _hbm_reduce_scatter_kernel(axis_name, p, op, spans_chunks, depth,
                                ndir, credits, mesh_ctx, x_hbm, w_hbm,
                                o_hbm, *scratch):
     """The reduce-scatter phase of the allreduce ring alone — the
     per-axis primitive of the multi-axis mesh decomposition. Streams
     the same p-1 fold rounds over the chunk-credit slot schedule into
-    the working buffer ``w_hbm``; after them block ``my`` is fully
-    reduced and lands in the [nblk] output."""
-    my = lax.axis_index(axis_name)
-    right = lax.rem(my + 1, p)
-    left = lax.rem(my - 1 + p, p)
-    red = _reducer(op)
-    init_sem = scratch[-1]
+    the working buffer ``w_hbm`` (p, block_rows, 128); after them block
+    ``my`` is fully reduced and lands in the (block_rows, 128)
+    output."""
+    my, left, right = _ring_neighbours(axis_name, p)
     st = _mk_streamer(p, ndir, depth, credits, left, right, w_hbm,
                       scratch[:-1], mesh_ctx, axis_name)
-
-    cp = pltpu.make_async_copy(x_hbm, w_hbm, init_sem)
-    cp.start()
-    cp.wait()
-    st.grant_initial_credits()
-
-    spans = _block_spans(nblk, ndir)
-    spans_chunks = [_chunks(lo, hi, chunk) for lo, hi in spans]
-    for s in range(p - 1):
-        sb = [lax.rem(my - s - 1 + 2 * p, p), lax.rem(my + s + 1, p)]
-        rb = [lax.rem(my - s - 2 + 2 * p, p), lax.rem(my + s + 2, p)]
-        st.stream_step(spans_chunks,
-                       [sb[d] * nblk for d in range(ndir)],
-                       [rb[d] * nblk for d in range(ndir)], red)
+    _copy(x_hbm, w_hbm, scratch[-1])
+    st.enter()
+    _rs_rounds(st, my, p, ndir, spans_chunks, _reducer(op))
     st.finish()
-
-    out = pltpu.make_async_copy(w_hbm.at[pl.ds(my * nblk, nblk)], o_hbm,
-                                init_sem)
-    out.start()
-    out.wait()
+    _copy(w_hbm.at[my], o_hbm, scratch[-1])
 
 
-def _hbm_all_gather_kernel(axis_name, p, nblk, chunk, depth, ndir,
+def _hbm_all_gather_kernel(axis_name, p, spans_chunks, depth, ndir,
                            credits, mesh_ctx, x_hbm, o_hbm, *scratch):
-    my = lax.axis_index(axis_name)
-    right = lax.rem(my + 1, p)
-    left = lax.rem(my - 1 + p, p)
-    init_sem = scratch[-1]
+    """x: (block_rows, 128); o: (p, block_rows, 128)."""
+    my, left, right = _ring_neighbours(axis_name, p)
     st = _mk_streamer(p, ndir, depth, credits, left, right, o_hbm,
                       scratch[:-1], mesh_ctx, axis_name)
-
     # my shard lands in block ``my`` of the output
-    cp = pltpu.make_async_copy(x_hbm, o_hbm.at[pl.ds(my * nblk, nblk)],
-                               init_sem)
-    cp.start()
-    cp.wait()
-    st.grant_initial_credits()
-
-    spans = _block_spans(nblk, ndir)
-    spans_chunks = [_chunks(lo, hi, chunk) for lo, hi in spans]
-    for s in range(p - 1):
-        sb = [lax.rem(my - s + 2 * p, p), lax.rem(my + s, p)]
-        rb = [lax.rem(my - s - 1 + 2 * p, p), lax.rem(my + s + 1, p)]
-        st.stream_step(spans_chunks,
-                       [sb[d] * nblk for d in range(ndir)],
-                       [rb[d] * nblk for d in range(ndir)], None)
+    _copy(x_hbm, o_hbm.at[my], scratch[-1])
+    st.enter()
+    _ag_rounds(st, my, p, ndir, spans_chunks)
     st.finish()
 
 
@@ -470,6 +532,7 @@ def _sendrecv_kernel(axis_name, p, src, dst, x_hbm, o_hbm, send_sem,
     other shard self-copies. One wait pair consumes both semaphores."""
     my = lax.axis_index(axis_name)
     partner = jnp.where(my == src, dst, jnp.where(my == dst, src, my))
+    _entry_barrier([partner])
     rdma = pltpu.make_async_remote_copy(
         src_ref=x_hbm, dst_ref=o_hbm, send_sem=send_sem,
         recv_sem=recv_sem, device_id=partner,
@@ -483,19 +546,48 @@ def _sendrecv_kernel(axis_name, p, src, dst, x_hbm, o_hbm, send_sem,
 # ---------------------------------------------------------------------------
 
 def _resolve_flags(interpret, credits):
-    if interpret is None:
-        interpret = bool(get_config()["ICI_INTERPRET"])
-    if credits is None:
-        # hardware always runs the credit handshake; the 0.4.x
-        # interpreter cannot (no remote signal) and does not need to
-        credits = (not interpret) or have_remote_signal()  # device: hw-only
-    return interpret, credits
+    """(``interpret=`` for the pallas_call, credits on/off). The
+    interpret half is the package-wide rule (_compat.resolve_interpret:
+    never on a TPU backend, InterpretParams otherwise); the credit
+    handshake is on wherever the kernel runs — the TPU interpreter
+    executes remote signals, so CPU tests run the schedule the chip
+    runs. ``credits=False`` remains for schedule experiments only."""
+    return resolve_interpret(interpret), \
+        (True if credits is None else bool(credits))
 
 
 def _resolve_ndir(num_devices: int, bidirectional) -> int:
     if bidirectional is None:
         bidirectional = bool(get_config()["ICI_BIDIR"])
     return 2 if (bidirectional and num_devices > 2) else 1
+
+
+def _ring_call(kernel_fn, static, block_rows: int, dtype, cid: int,
+               out_shape, interpret, credits, chunk_bytes, depth,
+               num_devices: int, bidirectional, mesh_ctx, operand):
+    """The pallas_call every streaming ring shares: resolve the chunk
+    geometry in rows, bind the static schedule into ``kernel_fn`` and
+    launch with all operands left in HBM."""
+    interpret, credits = _resolve_flags(interpret, credits)
+    chunk = min(_cfg_chunk_rows(dtype, chunk_bytes), block_rows)
+    d = _cfg_depth(depth)
+    ndir = _resolve_ndir(num_devices, bidirectional)
+    spans_chunks = [_chunks(lo, hi, chunk) for lo, hi in
+                    _block_spans(block_rows, ndir, _sublanes(dtype))]
+    kernel = functools.partial(kernel_fn, *static, spans_chunks, d, ndir,
+                               credits, mesh_ctx)
+    multi = isinstance(out_shape, tuple)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        kernel,
+        out_shape=out_shape,
+        in_specs=[hbm],
+        out_specs=(hbm,) * len(out_shape) if multi else hbm,
+        scratch_shapes=_scratch_shapes(ndir, d, chunk, dtype),
+        compiler_params=compiler_params(collective_id=cid,
+                                        has_side_effects=True),
+        interpret=interpret,
+    )(operand)
 
 
 def hbm_ring_all_reduce(x: jax.Array, axis_name: str, num_devices: int,
@@ -507,39 +599,31 @@ def hbm_ring_all_reduce(x: jax.Array, axis_name: str, num_devices: int,
                         interpret=None, mesh_ctx=None) -> jax.Array:
     """Allreduce along ``axis_name`` via the chunked HBM-streaming ring
     (pipelined reduce-scatter + all-gather). Any shape/size: the shard
-    is flattened and padded to ``p`` blocks with the op identity.
-    ``mesh_ctx``: the surrounding mesh's full ordered (axis, size)
-    tuple when the ring is one phase of a multi-axis decomposition —
-    device ids walk that axis' row-major id line instead of 0..p-1."""
+    is flattened and padded to ``p`` whole-tile blocks with the op
+    identity. ``mesh_ctx``: the surrounding mesh's full ordered
+    (axis, size) tuple when the ring is one phase of a multi-axis
+    decomposition — device ids walk that axis' row-major id line
+    instead of 0..p-1."""
     p = num_devices
-    if not HAVE_PALLAS or p == 1:
+    if p == 1:
         from .collectives import allreduce
         return allreduce(x, axis_name, op)
-    interpret, credits = _resolve_flags(interpret, credits)
     shape = x.shape
     n = int(np.prod(shape)) if shape else 1
+    rows = _tile_rows(-(-n // p), x.dtype)
+    n_pad = p * rows * _LANES
     flat = x.reshape(n)
-    nblk = -(-n // p)
-    n_pad = nblk * p
     if n_pad > n:
         flat = jnp.pad(flat, (0, n_pad - n),
                        constant_values=_pad_identity(x.dtype, op))
-    chunk = min(_cfg_chunk_elems(x.dtype, chunk_bytes), nblk)
-    d = _cfg_depth(depth)
-    ndir = _resolve_ndir(p, bidirectional)
-    kernel = functools.partial(_hbm_all_reduce_kernel, axis_name, p, op,
-                               nblk, chunk, d, ndir, credits, mesh_ctx)
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((n_pad,), x.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        scratch_shapes=_scratch_shapes(ndir, d, chunk, x.dtype),
-        compiler_params=compiler_params(collective_id=_CID_ALLREDUCE,
-                                        has_side_effects=True),
-        interpret=interpret,
-    )(flat)
-    return out[:n].reshape(shape)
+    out = _ring_call(
+        _hbm_all_reduce_kernel, (axis_name, p, op), rows, x.dtype,
+        _CID_ALLREDUCE,
+        jax.ShapeDtypeStruct((p, rows, _LANES), x.dtype),
+        interpret, credits, chunk_bytes, depth, p, bidirectional,
+        mesh_ctx, flat.reshape(p, rows, _LANES))
+    out = out.reshape(n_pad)
+    return (out[:n] if n_pad > n else out).reshape(shape)
 
 
 def hbm_ring_all_gather(x: jax.Array, axis_name: str, num_devices: int,
@@ -552,29 +636,19 @@ def hbm_ring_all_gather(x: jax.Array, axis_name: str, num_devices: int,
     ring. ``x``: this shard's block [m, ...]; returns [p*m, ...]
     (tiled, like lax.all_gather(tiled=True))."""
     p = num_devices
-    if not HAVE_PALLAS or p == 1:
+    if p == 1:
         return lax.all_gather(x, axis_name, tiled=True)
-    interpret, credits = _resolve_flags(interpret, credits)
     shape = x.shape
     m = int(np.prod(shape)) if shape else 1
-    flat = x.reshape(m)
-    chunk = min(_cfg_chunk_elems(x.dtype, chunk_bytes), m)
-    d = _cfg_depth(depth)
-    ndir = _resolve_ndir(p, bidirectional)
-    kernel = functools.partial(_hbm_all_gather_kernel, axis_name, p, m,
-                               chunk, d, ndir, credits, mesh_ctx)
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((p * m,), x.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        scratch_shapes=_scratch_shapes(ndir, d, chunk, x.dtype),
-        compiler_params=compiler_params(collective_id=_CID_ALLGATHER,
-                                        has_side_effects=True),
-        interpret=interpret,
-    )(flat)
-    return out.reshape((p * shape[0],) + shape[1:]) if shape \
-        else out
+    rows = _tile_rows(m, x.dtype)
+    out = _ring_call(
+        _hbm_all_gather_kernel, (axis_name, p), rows, x.dtype,
+        _CID_ALLGATHER,
+        jax.ShapeDtypeStruct((p, rows, _LANES), x.dtype),
+        interpret, credits, chunk_bytes, depth, p, bidirectional,
+        mesh_ctx, _as_blocks(x.reshape(m), 1, rows)[0])
+    out = _from_blocks(out, m)
+    return out.reshape((p * shape[0],) + shape[1:]) if shape else out
 
 
 def hbm_ring_reduce_scatter(x: jax.Array, axis_name: str,
@@ -590,35 +664,23 @@ def hbm_ring_reduce_scatter(x: jax.Array, axis_name: str,
     array, [ceil(n/p)] (tiled; the tail blocks carry op-identity pad
     when p does not divide n)."""
     p = num_devices
-    if not HAVE_PALLAS or p == 1:
+    if p == 1:
         return _xla_reduce_scatter(x, axis_name, p, op)
-    interpret, credits = _resolve_flags(interpret, credits)
     n = int(x.size)
     flat = x.reshape(n)
     nblk = -(-n // p)
-    n_pad = nblk * p
-    if n_pad > n:
-        flat = jnp.pad(flat, (0, n_pad - n),
-                       constant_values=_pad_identity(x.dtype, op))
-    chunk = min(_cfg_chunk_elems(x.dtype, chunk_bytes), nblk)
-    d = _cfg_depth(depth)
-    ndir = _resolve_ndir(p, bidirectional)
-    kernel = functools.partial(_hbm_reduce_scatter_kernel, axis_name, p,
-                               op, nblk, chunk, d, ndir, credits,
-                               mesh_ctx)
-    _, out = pl.pallas_call(
-        kernel,
-        out_shape=(jax.ShapeDtypeStruct((n_pad,), x.dtype),
-                   jax.ShapeDtypeStruct((nblk,), x.dtype)),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=(pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY)),
-        scratch_shapes=_scratch_shapes(ndir, d, chunk, x.dtype),
-        compiler_params=compiler_params(
-            collective_id=_CID_REDUCE_SCATTER, has_side_effects=True),
-        interpret=interpret,
-    )(flat)
-    return out
+    ident = _pad_identity(x.dtype, op)
+    if nblk * p > n:
+        flat = jnp.pad(flat, (0, nblk * p - n), constant_values=ident)
+    rows = _tile_rows(nblk, x.dtype)
+    _, out = _ring_call(
+        _hbm_reduce_scatter_kernel, (axis_name, p, op), rows, x.dtype,
+        _CID_REDUCE_SCATTER,
+        (jax.ShapeDtypeStruct((p, rows, _LANES), x.dtype),
+         jax.ShapeDtypeStruct((rows, _LANES), x.dtype)),
+        interpret, credits, chunk_bytes, depth, p, bidirectional,
+        mesh_ctx, _as_blocks(flat, p, rows, ident))
+    return _from_blocks(out[None], nblk)
 
 
 def _xla_reduce_scatter(x: jax.Array, axis_name: str, p: int,
@@ -647,17 +709,17 @@ def remote_sendrecv(x: jax.Array, axis_name: str, num_devices: int,
     vice versa; every other shard returns its own ``x`` unchanged.
     MPI_Sendrecv exchange semantics, not ppermute's zero fill."""
     p = num_devices
-    if not HAVE_PALLAS or p == 1 or src == dst:
+    if p == 1 or src == dst:
         return x
-    interpret, _ = _resolve_flags(interpret, None)
+    interpret = resolve_interpret(interpret)
     _trace_entry("sendrecv", "hbm", x.size * x.dtype.itemsize,
                  src=src, dst=dst)
     kernel = functools.partial(_sendrecv_kernel, axis_name, p, src, dst)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA(()),
                         pltpu.SemaphoreType.DMA(())],
         compiler_params=compiler_params(collective_id=_CID_SENDRECV,
@@ -671,16 +733,15 @@ def remote_sendrecv(x: jax.Array, axis_name: str, num_devices: int,
 # ---------------------------------------------------------------------------
 
 def _kernels_runnable(interpret: Optional[bool]) -> bool:
-    """Compiled pallas needs a TPU; anywhere else the kernels run only
-    under the interpreter (tests, the CPU mesh CI)."""
+    """On a TPU backend the kernels compile, always; anywhere else they
+    run only under the interpreter (tests, the CPU mesh CI). A backend
+    that fails to initialize raises here — it is never read as 'no
+    TPU'."""
+    if on_tpu():
+        return True
     if interpret is None:
         interpret = bool(get_config()["ICI_INTERPRET"])
-    if interpret:
-        return True
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:   # uninitialized backend — resolve at trace time
-        return False
+    return bool(interpret)
 
 
 def planned_tier(name: str, shard_nbytes: int, dtype, op: Optional[str],
@@ -692,11 +753,11 @@ def planned_tier(name: str, shard_nbytes: int, dtype, op: Optional[str],
     lowering was taken, in which case it names the dev_coll_fallback_*
     pvar bucket: size (past the measured XLA crossover), dtype (op/
     dtype the kernels cannot reduce), shape (degenerate extent),
-    platform (no pallas / not a TPU and not interpreting). A 'quant'
+    platform (not a TPU and not interpreting). A 'quant'
     bin the call cannot actually quantize (non-sum op, int dtype,
     budget below the declared bound for ``num_devices``) degrades to
     the exact 'hbm' tier — a bit-exact fallback, not an XLA take."""
-    if not HAVE_PALLAS or not _kernels_runnable(interpret):
+    if not _kernels_runnable(interpret):
         return "xla", "platform"
     if op is not None and op not in _SUPPORTED_OPS:
         return "xla", "dtype"
@@ -744,6 +805,8 @@ def _mesh_mode(mesh_ctx, interpret) -> str:
     which is what the CPU sweep pins)."""
     if not mesh_ctx or len(mesh_ctx) <= 1:
         return "1d"
+    if on_tpu():
+        return "hw"         # a TPU backend never interprets
     if interpret is None:
         interpret = bool(get_config()["ICI_INTERPRET"])
     return "xla" if interpret else "hw"
@@ -776,14 +839,10 @@ def ici_all_reduce(x: jax.Array, axis_name: str, num_devices: int,
                                                   interpret=interpret)
     if tier == "vmem":
         from . import pallas_ring
-        if x.ndim >= 1 and x.shape[0] % p == 0 and op == "sum":
-            ip = True if (interpret is None
-                          and bool(get_config()["ICI_INTERPRET"])) \
-                else (interpret or False)
+        if op == "sum":
             return pallas_ring.ring_all_reduce(x, axis_name, p,
-                                               interpret=ip)
-        # shapes/ops the flat kernel cannot take stream instead (the
-        # chunked engine pads; no fallback)
+                                               interpret=interpret)
+        # ops the flat kernel cannot take stream instead (no fallback)
         tier = "hbm"
     if tier == "hbm":
         return hbm_ring_all_reduce(x, axis_name, p, op,
@@ -814,10 +873,8 @@ def ici_all_gather(x: jax.Array, axis_name: str, num_devices: int,
     _trace_entry("allgather", tier, out_nbytes)
     if tier == "vmem":
         from . import pallas_ring
-        ip = True if (interpret is None
-                      and bool(get_config()["ICI_INTERPRET"])) \
-            else (interpret or False)
-        return pallas_ring.ring_all_gather(x, axis_name, p, interpret=ip)
+        return pallas_ring.ring_all_gather(x, axis_name, p,
+                                           interpret=interpret)
     if tier == "hbm":
         return hbm_ring_all_gather(x, axis_name, p, interpret=interpret,
                                    mesh_ctx=mesh_ctx)

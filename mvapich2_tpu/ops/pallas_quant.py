@@ -58,13 +58,12 @@ import numpy as np
 from jax import lax
 
 from ..utils.mlog import get_logger
-from ._compat import HAVE_PALLAS, compiler_params
+from ._compat import compiler_params
 
 log = get_logger("pallas_quant")
 
-if HAVE_PALLAS:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # cvars QUANT_COLL / QUANT_BLOCK are predeclared in mpit.py (the MPI_T
 # surface enumerates them before this module is imported), same
@@ -78,8 +77,8 @@ WIRE_FORMATS = ("q8", "fp8")
 _Q8_MAX = 127.0
 _FP8_MAX = 448.0          # float8_e4m3fn finite max
 
-# distinct Mosaic collective id (pallas_ring owns 7/8, pallas_ici 9-11)
-_CID_QUANT_RS = 12
+# Mosaic collective id (pallas_ring owns 0/1, pallas_ici 2-5)
+_CID_QUANT_RS = 6
 
 
 # ---------------------------------------------------------------------------
@@ -330,7 +329,7 @@ def _quant_rs_kernel(axis_name, p, nblk, chunk, depth, ndir, credits,
     cp = pltpu.make_async_copy(x_hbm, o_hbm, init_sem)
     cp.start()
     cp.wait()
-    st.grant_initial_credits()
+    st.enter()
 
     spans = _quant_spans(nblk, ndir, block)
     spans_chunks = [_chunks(lo, hi, chunk) for lo, hi in spans]
@@ -397,7 +396,7 @@ def quant_ring_all_reduce(x: jax.Array, axis_name: str,
             x, axis_name, p, op, chunk_bytes=chunk_bytes, depth=depth,
             bidirectional=bidirectional, credits=credits,
             interpret=interpret)
-    if not HAVE_PALLAS or p == 1:
+    if p == 1:
         from .collectives import allreduce
         return allreduce(x, axis_name, op)
     if wire is None:
@@ -427,9 +426,9 @@ def quant_ring_all_reduce(x: jax.Array, axis_name: str,
         kernel,
         out_shape=[jax.ShapeDtypeStruct((n_pad,), jnp.float32),
                    jax.ShapeDtypeStruct((wblk,), jnp.int32)],
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)],
         scratch_shapes=_quant_scratch(ndir, d, chunk, wchunk),
         compiler_params=compiler_params(collective_id=_CID_QUANT_RS,
                                         has_side_effects=True),

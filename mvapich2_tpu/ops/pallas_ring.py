@@ -9,15 +9,23 @@ per-direction credit handshake (the vbuf credit-return of ibv_send.c:
 320-360): each round a shard grants one credit to each neighbor and
 consumes one from each, bounding ring skew to ±1 round so double buffering
 is race-free (verified with the pallas interpret-mode race detector).
+Every kernel opens with the neighbour barrier of ops/pallas_ici.py
+(``_entry_barrier``): no signal or DMA targets a chip that has not
+entered the kernel.
 
 They exist (1) as the explicit, schedulable form of the ring collectives
 for cases XLA's fused lowering can't express — fusing the reduction into
 the transfer loop, custom communication/compute interleaving — and (2) as
 the skeleton the ring-attention kernel in models/ follows.
 
-Both kernels are VMEM-resident (shard + 2 comm slots must fit in ~16 MiB);
-callers fall back to lax.psum / lax.all_gather beyond that — the
+Both kernels are VMEM-resident (shard + out + 2 comm slots must fit in
+~16 MiB); callers fall back to lax.psum / lax.all_gather beyond that — the
 eager->rendezvous style crossover, chosen by the tuning layer.
+
+Layout: the shard is flattened and padded to ``p`` whole-tile blocks,
+``(p, block_rows, 128)`` with ``block_rows`` a multiple of the dtype's
+sublane tile; the ring block and the comm slot are leading, untiled
+indices, so the only traced indices Mosaic sees are leading ones.
 
 Usage: inside shard_map over a 1-D mesh axis.
 """
@@ -25,139 +33,139 @@ Usage: inside shard_map over a 1-D mesh axis.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..utils.mlog import get_logger
-from ._compat import (HAVE_PALLAS, compiler_params, have_remote_signal,
-                      note_fallback)
+from ._compat import compiler_params, note_fallback, resolve_interpret
+from .pallas_ici import (_LANES, _as_blocks, _entry_barrier, _from_blocks,
+                         _tile_rows)
 
 log = get_logger("pallas")
 
-if HAVE_PALLAS:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
 # VMEM budget guard: shard + out + 2 slots, leave headroom
 VMEM_LIMIT_BYTES = 4 * 1024 * 1024
+
+# Mosaic collective ids (the barrier semaphore of each kernel)
+_CID_ALLGATHER = 0
+_CID_ALLREDUCE = 1
 
 FROM_LEFT = 0   # credit slots, indexed by which neighbor granted it
 FROM_RIGHT = 1
 
 
-def _grant_credits(cap_sem, left, right):     # device: hw-only
+def _grant_credits(cap_sem, left, right):
     """Grant one slot-credit to each neighbor (I am my left neighbor's
-    RIGHT, so I bump its FROM_RIGHT slot, and vice versa). cap_sem=None
-    disables the handshake — required under the jax<0.5 interpreter
-    (no remote signal) and safe there: the emulator is synchronous
-    dataflow, so flow control is moot."""
-    if cap_sem is None:
-        return
+    RIGHT, so I bump its FROM_RIGHT slot, and vice versa)."""
     pltpu.semaphore_signal(cap_sem.at[FROM_RIGHT], inc=1, device_id=left,
                            device_id_type=pltpu.DeviceIdType.LOGICAL)
     pltpu.semaphore_signal(cap_sem.at[FROM_LEFT], inc=1, device_id=right,
                            device_id_type=pltpu.DeviceIdType.LOGICAL)
 
 
-def _take_credits(cap_sem):                   # device: hw-only
+def _take_credits(cap_sem):
     """Consume one credit from each direction — blocks until both
     neighbors granted this round's slot."""
-    if cap_sem is None:
-        return
     pltpu.semaphore_wait(cap_sem.at[FROM_LEFT], 1)
     pltpu.semaphore_wait(cap_sem.at[FROM_RIGHT], 1)
 
 
-def _creditless(interpret) -> bool:
-    return bool(interpret) and not have_remote_signal()
+def _ring_step(comm_buf, send_sem, recv_sem, step, right):
+    """One ring hop: remote-DMA slot ``step % 2`` into the right
+    neighbor's other slot; returns the slot the left neighbor's block
+    landed in."""
+    send_slot = step % 2
+    recv_slot = (step + 1) % 2
+    rdma = pltpu.make_async_remote_copy(
+        src_ref=comm_buf.at[send_slot],
+        dst_ref=comm_buf.at[recv_slot],
+        send_sem=send_sem.at[send_slot],
+        recv_sem=recv_sem.at[recv_slot],
+        device_id=right,
+        device_id_type=pltpu.DeviceIdType.LOGICAL,
+    )
+    rdma.start()
+    rdma.wait()
+    return recv_slot
 
 
-def _ring_all_gather_kernel(axis_name, num_devices, creditless, x_ref,
-                            out_ref, comm_buf, send_sem, recv_sem,
-                            cap_sem):
+def _ring_all_gather_kernel(axis_name, p, x_ref, out_ref, comm_buf,
+                            send_sem, recv_sem, cap_sem):
+    """x: (rows, 128); out: (p, rows, 128); comm_buf: (2, rows, 128)."""
     my_id = lax.axis_index(axis_name)
-    if creditless:
-        cap_sem = None
-    right = lax.rem(my_id + 1, num_devices)
-    left = lax.rem(my_id - 1 + num_devices, num_devices)
-    chunk = x_ref.shape[0]
+    right = lax.rem(my_id + 1, p)
+    left = lax.rem(my_id - 1 + p, p)
 
+    _entry_barrier([left, right])
     _grant_credits(cap_sem, left, right)   # initial slot availability
-    out_ref[pl.ds(my_id * chunk, chunk)] = x_ref[...]
+    out_ref[my_id] = x_ref[...]
     comm_buf[0] = x_ref[...]
 
-    for step in range(num_devices - 1):
-        send_slot = step % 2
-        recv_slot = (step + 1) % 2
+    for step in range(p - 1):
         _take_credits(cap_sem)
-        rdma = pltpu.make_async_remote_copy(
-            src_ref=comm_buf.at[send_slot],
-            dst_ref=comm_buf.at[recv_slot],
-            send_sem=send_sem.at[send_slot],
-            recv_sem=recv_sem.at[recv_slot],
-            device_id=right,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
-        )
-        rdma.start()
-        rdma.wait()
-        src_dev = lax.rem(my_id - step - 1 + num_devices, num_devices)
-        out_ref[pl.ds(src_dev * chunk, chunk)] = comm_buf[recv_slot]
+        recv_slot = _ring_step(comm_buf, send_sem, recv_sem, step, right)
+        src_dev = lax.rem(my_id - step - 1 + p, p)
+        out_ref[src_dev] = comm_buf[recv_slot]
         _grant_credits(cap_sem, left, right)   # slot consumed: return credit
     # consume the final grants: also a completion barrier so no neighbor
     # still has an in-flight write into our buffers at kernel exit
     _take_credits(cap_sem)
 
 
+def _ring_scratch(rows: int, dtype):
+    return [
+        pltpu.VMEM((2, rows, _LANES), dtype),
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.SemaphoreType.REGULAR((2,)),
+    ]
+
+
 def ring_all_gather(x: jax.Array, axis_name: str, num_devices: int,
-                    interpret=False) -> jax.Array:
+                    interpret=None) -> jax.Array:
     """All-gather along ``axis_name`` via an explicit RDMA ring.
     ``x``: this shard's block [chunk, ...]; returns [p*chunk, ...]."""
-    if not HAVE_PALLAS or num_devices == 1:
+    p = num_devices
+    if p == 1:
         return lax.all_gather(x, axis_name, tiled=True)
-    if num_devices * x.nbytes > VMEM_LIMIT_BYTES:
+    if p * x.nbytes > VMEM_LIMIT_BYTES:
         # the gathered output + comm slots must be VMEM-resident; larger
         # buffers belong to the HBM-streaming tier (ops/pallas_ici) —
         # counted, never silent (the r5 4 MiB cliff lesson)
-        note_fallback("allgather", "size", num_devices * x.nbytes, x.dtype)
+        note_fallback("allgather", "size", p * x.nbytes, x.dtype)
         return lax.all_gather(x, axis_name, tiled=True)
-    chunk = x.shape[0]
-    out_shape = jax.ShapeDtypeStruct((num_devices * chunk,) + x.shape[1:],
-                                     x.dtype)
-    kernel = functools.partial(_ring_all_gather_kernel, axis_name,
-                               num_devices, _creditless(interpret))
-    return pl.pallas_call(
-        kernel,
-        out_shape=out_shape,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((2, chunk) + x.shape[1:], x.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.REGULAR((2,)),
-        ],
-        compiler_params=compiler_params(collective_id=7),
-        interpret=interpret,
-    )(x)
+    shape = x.shape
+    m = int(np.prod(shape)) if shape else 1
+    rows = _tile_rows(m, x.dtype)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        functools.partial(_ring_all_gather_kernel, axis_name, p),
+        out_shape=jax.ShapeDtypeStruct((p, rows, _LANES), x.dtype),
+        in_specs=[vmem],
+        out_specs=vmem,
+        scratch_shapes=_ring_scratch(rows, x.dtype),
+        compiler_params=compiler_params(collective_id=_CID_ALLGATHER),
+        interpret=resolve_interpret(interpret),
+    )(_as_blocks(x.reshape(m), 1, rows)[0])
+    out = _from_blocks(out, m)
+    return out.reshape((p * shape[0],) + shape[1:]) if shape else out
 
 
-def _ring_all_reduce_kernel(axis_name, num_devices, creditless, x_ref,
-                            out_ref, comm_buf, send_sem, recv_sem,
-                            cap_sem):
+def _ring_all_reduce_kernel(axis_name, p, x_ref, out_ref, comm_buf,
+                            send_sem, recv_sem, cap_sem):
     """Reduce-scatter ring + all-gather ring with the reduction fused into
-    the receive path (the SHARP-style in-transit reduce, done in VMEM)."""
+    the receive path (the SHARP-style in-transit reduce, done in VMEM).
+    x/out: (p, rows, 128); comm_buf: (2, rows, 128)."""
     my_id = lax.axis_index(axis_name)
-    if creditless:
-        cap_sem = None
-    right = lax.rem(my_id + 1, num_devices)
-    left = lax.rem(my_id - 1 + num_devices, num_devices)
-    p = num_devices
-    n = x_ref.shape[0]
-    blk = n // p  # caller guarantees divisibility
+    right = lax.rem(my_id + 1, p)
+    left = lax.rem(my_id - 1 + p, p)
 
+    _entry_barrier([left, right])
     _grant_credits(cap_sem, left, right)
     out_ref[...] = x_ref[...]
 
@@ -168,22 +176,10 @@ def _ring_all_reduce_kernel(axis_name, num_devices, creditless, x_ref,
     for step in range(p - 1):
         send_blk = lax.rem(my_id - step - 1 + 2 * p, p)
         recv_blk = lax.rem(my_id - step - 2 + 2 * p, p)
-        send_slot = step % 2
-        recv_slot = (step + 1) % 2
         _take_credits(cap_sem)
-        comm_buf[send_slot] = out_ref[pl.ds(send_blk * blk, blk)]
-        rdma = pltpu.make_async_remote_copy(
-            src_ref=comm_buf.at[send_slot],
-            dst_ref=comm_buf.at[recv_slot],
-            send_sem=send_sem.at[send_slot],
-            recv_sem=recv_sem.at[recv_slot],
-            device_id=right,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
-        )
-        rdma.start()
-        rdma.wait()
-        out_ref[pl.ds(recv_blk * blk, blk)] = (
-            out_ref[pl.ds(recv_blk * blk, blk)] + comm_buf[recv_slot])
+        comm_buf[step % 2] = out_ref[send_blk]
+        recv_slot = _ring_step(comm_buf, send_sem, recv_sem, step, right)
+        out_ref[recv_blk] = out_ref[recv_blk] + comm_buf[recv_slot]
         _grant_credits(cap_sem, left, right)
 
     # Phase 2 (rounds p-1..2p-3): all-gather — round s passes block (my-s)
@@ -192,56 +188,47 @@ def _ring_all_reduce_kernel(axis_name, num_devices, creditless, x_ref,
     for step in range(p - 1):
         send_blk = lax.rem(my_id - step + 2 * p, p)
         recv_blk = lax.rem(my_id - step - 1 + 2 * p, p)
-        send_slot = (p - 1 + step) % 2
-        recv_slot = (p + step) % 2
         _take_credits(cap_sem)
-        comm_buf[send_slot] = out_ref[pl.ds(send_blk * blk, blk)]
-        rdma = pltpu.make_async_remote_copy(
-            src_ref=comm_buf.at[send_slot],
-            dst_ref=comm_buf.at[recv_slot],
-            send_sem=send_sem.at[send_slot],
-            recv_sem=recv_sem.at[recv_slot],
-            device_id=right,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
-        )
-        rdma.start()
-        rdma.wait()
-        out_ref[pl.ds(recv_blk * blk, blk)] = comm_buf[recv_slot]
+        comm_buf[(p - 1 + step) % 2] = out_ref[send_blk]
+        recv_slot = _ring_step(comm_buf, send_sem, recv_sem,
+                               p - 1 + step, right)
+        out_ref[recv_blk] = comm_buf[recv_slot]
         _grant_credits(cap_sem, left, right)
     _take_credits(cap_sem)   # drain final grants; exit-time completion barrier
 
 
 def ring_all_reduce(x: jax.Array, axis_name: str, num_devices: int,
-                    interpret=False) -> jax.Array:
+                    interpret=None) -> jax.Array:
     """Sum-allreduce along ``axis_name`` via an explicit fused ring.
-    Requires x.shape[0] % num_devices == 0 and VMEM-resident sizes;
-    callers fall back to lax.psum otherwise (the tuning-layer crossover)."""
-    if not HAVE_PALLAS or num_devices == 1:
-        return lax.psum(x, axis_name)
+    Any shape (flattened, zero-padded to p whole-tile blocks) up to the
+    VMEM-resident size; larger shards fall back to lax.psum (the
+    tuning-layer crossover), counted."""
     p = num_devices
-    if x.shape[0] % p != 0 or x.nbytes > VMEM_LIMIT_BYTES:
+    if p == 1:
+        return lax.psum(x, axis_name)
+    if x.nbytes > VMEM_LIMIT_BYTES:
         # observable, not silent: the tuning layer's tier dispatch
         # (ops/pallas_ici.ici_all_reduce) streams these through HBM
         # instead; a direct caller landing here is counted per traced
         # shape via the dev_coll_fallback_* family
-        note_fallback("allreduce",
-                      "shape" if x.shape[0] % p else "size",
-                      x.nbytes, x.dtype)
+        note_fallback("allreduce", "size", x.nbytes, x.dtype)
         return lax.psum(x, axis_name)
-    blk = x.shape[0] // p
-    kernel = functools.partial(_ring_all_reduce_kernel, axis_name, p,
-                               _creditless(interpret))
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((2, blk) + x.shape[1:], x.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.REGULAR((2,)),
-        ],
-        compiler_params=compiler_params(collective_id=8),
-        interpret=interpret,
-    )(x)
+    shape = x.shape
+    n = int(np.prod(shape)) if shape else 1
+    rows = _tile_rows(-(-n // p), x.dtype)
+    n_pad = p * rows * _LANES
+    flat = x.reshape(n)
+    if n_pad > n:
+        flat = jnp.pad(flat, (0, n_pad - n))     # 0 = the sum identity
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        functools.partial(_ring_all_reduce_kernel, axis_name, p),
+        out_shape=jax.ShapeDtypeStruct((p, rows, _LANES), x.dtype),
+        in_specs=[vmem],
+        out_specs=vmem,
+        scratch_shapes=_ring_scratch(rows, x.dtype),
+        compiler_params=compiler_params(collective_id=_CID_ALLREDUCE),
+        interpret=resolve_interpret(interpret),
+    )(flat.reshape(p, rows, _LANES))
+    out = out.reshape(n_pad)
+    return (out[:n] if n_pad > n else out).reshape(shape)

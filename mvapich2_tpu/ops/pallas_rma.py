@@ -8,14 +8,15 @@ ops become three chunked remote-DMA kernels:
 
 * **Put** — ``remote_sendrecv`` (ops/pallas_ici.py) generalized to an
   arbitrary target offset: each chunk of the origin's source buffer is
-  one ``make_async_remote_copy`` into a VMEM landing slot on the
-  target, which alone commits it into its window shard at
-  ``disp + off`` (the vbuf staging model — a direct copy into the
-  window cannot work because every device must run the same remote DMA
-  and the non-target self-copies would clobber their windows).
+  one ``make_async_remote_copy`` into a VMEM landing slot on its
+  partner, committed to a landing *segment*; the wrapper writes the
+  target's landing segment into its window shard at ``disp`` (the vbuf
+  staging model — a direct copy into the window cannot work because
+  every device must run the same remote DMA and the non-target
+  self-copies would clobber their windows).
 * **Get** — the reversed copy: every device stages its OWN window
-  chunk, the symmetric permutation swaps origin<->target, and the
-  origin alone commits the landed chunk into its result buffer.
+  segment, the symmetric permutation swaps origin<->target, and the
+  wrapper keeps the origin's landing.
 * **Accumulate** — streams chunks through the PR 8 slot/credit
   schedule (``_RmaStreamer`` below, the partner-pair form of
   ``_RingStreamer``) with a VPU fold at the target: non-origin devices
@@ -33,10 +34,17 @@ landing slot, so an origin runs at most ``depth`` chunks ahead of the
 target's folds. Passive-target sync in rma/device.py (lock/unlock,
 flush, flush_local) rides exactly these DMA semaphores — a flush is
 complete when every pending handle in the streamer has been waited and
-the credit balance is back to ``depth``. Under the jax<0.5 interpreter
-remote semaphore signals are unavailable and unnecessary (synchronous
-dataflow), so interpret-mode runs are creditless, following the
-``# device: hw-only`` convention.
+the credit balance is back to ``depth``. The TPU interpreter executes
+remote signals, so CPU tests run the same handshake.
+
+Layout: the kernels never see the element displacement. The wrappers
+cut the ``n``-element window segment at ``disp`` out on the XLA side
+(and write it back), padded to whole ``(rows, 128)`` tiles, so every
+DMA slice inside a kernel is a static, tile-aligned row range with the
+slot index on a leading, untiled dimension — what Mosaic's tiling
+demands, as in ops/pallas_ici.py. The quantized accumulate keeps the
+flat 1-D form its codec is written for (refused by the chip's compiler
+with pallas_quant, ROADMAP A3).
 
 Tier selection lives in ``planned_rma_tier``: contiguous ops at or
 above the ``dev_rma_rdma_min`` edge run these kernels ('rdma', or
@@ -56,26 +64,27 @@ import numpy as np
 from jax import lax
 
 from ..utils.mlog import get_logger
-from ._compat import HAVE_PALLAS, compiler_params
+from ._compat import compiler_params
 
 log = get_logger("pallas_rma")
 
-if HAVE_PALLAS:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # cvar RMA_CHUNK_BYTES and the dev_rma_* pvar family are predeclared in
 # mpit.py (the MPI_T surface enumerates them before this module is
 # imported), same early-declaration contract as the ICI_* knobs.
 from .. import mpit  # noqa: F401,E402  — cvar/pvar declarations
-from .pallas_ici import _chunks, _resolve_flags  # noqa: E402
+from .pallas_ici import (_LANES, _as_blocks, _chunks,  # noqa: E402
+                         _entry_barrier, _from_blocks, _resolve_flags,
+                         _sublanes, _tile_rows)
 
-# distinct Mosaic collective ids (pallas_ring owns 7/8, pallas_ici
-# 9-11, pallas_quant 12)
-_CID_PUT = 13
-_CID_GET = 14
-_CID_ACC = 15
-_CID_ACC_QUANT = 16
+# Mosaic collective ids — the barrier semaphore of each kernel's entry
+# barrier (pallas_ring owns 0/1, pallas_ici 2-5, pallas_quant 6)
+_CID_PUT = 7
+_CID_GET = 8
+_CID_ACC = 9
+_CID_ACC_QUANT = 10
 
 
 def _cfg_chunk_elems(dtype, chunk_bytes: Optional[int]) -> int:
@@ -123,6 +132,13 @@ class _RmaStreamer:
         self.pending_send: Dict = {}           # slot -> remote handle
         self.pending_fold: Dict = {}           # slot -> window-chunk load
         self.pending_store: Dict = {}          # slot -> commit store
+
+    def enter(self):
+        """Kernel entry: barrier with the partner (no signal or DMA may
+        reach a device that has not entered the kernel), then the
+        initial credits."""
+        _entry_barrier([self.partner])
+        self.grant_initial_credits()
 
     def grant_initial_credits(self):          # device: hw-only
         """Grant the partner (the device whose remote DMAs land in our
@@ -212,17 +228,20 @@ class _RmaStreamer:
             pltpu.semaphore_wait(self.cap_sem, self.depth)
 
 
-def _rma_scratch_shapes(depth: int, chunk: int, dtype, wire_chunk=None):
-    """Stage/landing/fold VMEM slots + the semaphore set. With a
-    quantized wire the stage/landing slots carry int32 wire words
-    (``wire_chunk`` per slot) while the fold slot stays the window
-    dtype."""
+def _rma_scratch_shapes(depth: int, chunk: int, dtype, wire_chunk=None,
+                        lanes=()):
+    """Stage/landing/fold VMEM slots + the semaphore set. ``chunk`` is
+    in rows of ``lanes=(128,)`` for the tiled (exact) kernels — the slot
+    index leads, the tiled (chunk, 128) pair is only sliced on whole
+    tiles — and in elements with ``lanes=()`` for the quantized wire,
+    whose stage/landing slots carry int32 wire words (``wire_chunk`` per
+    slot) while the fold slot stays the window dtype."""
     wdt = jnp.int32 if wire_chunk is not None else dtype
     wck = wire_chunk if wire_chunk is not None else chunk
     return [
-        pltpu.VMEM((depth, wck), wdt),        # stage slots
-        pltpu.VMEM((depth, wck), wdt),        # landing slots
-        pltpu.VMEM((depth, chunk), dtype),    # fold slots
+        pltpu.VMEM((depth, wck, *lanes), wdt),        # stage slots
+        pltpu.VMEM((depth, wck, *lanes), wdt),        # landing slots
+        pltpu.VMEM((depth, chunk, *lanes), dtype),    # fold slots
         pltpu.SemaphoreType.DMA((depth,)),    # stage loads
         pltpu.SemaphoreType.DMA((depth,)),    # fold-operand loads
         pltpu.SemaphoreType.DMA((depth,)),    # commit stores
@@ -250,150 +269,193 @@ def _partner(me, origin, target):
 
 # ---------------------------------------------------------------------------
 # kernels
+#
+# Every kernel works on a SEGMENT: the n elements of the window at the
+# op's displacement, cut out (and put back) on the XLA side by the
+# wrappers, so the kernel never sees an unaligned element offset. Exact
+# ops get the segment as whole (rows, 128) tiles; the quantized
+# accumulate keeps the flat 1-D form its codec is written for. The
+# bodies are layout-agnostic: ``chunks`` are (offset, size) along the
+# leading dim in either form.
 # ---------------------------------------------------------------------------
 
-def _put_kernel(axis, origin, target, disp, chunks, depth, credits,
-                src_hbm, win_hbm, out_hbm, *scratch):
+def _stream(st, chunks, fill, consume, fload=None, commit=None):
+    """The chunk pipeline every one-sided op shares: issue chunk c,
+    then drain chunk c-1 while c is on the wire."""
+    live: List[Optional[int]] = [None] * len(chunks)
+    for c in range(len(chunks) + 1):
+        if c < len(chunks):
+            off, sz = chunks[c]
+            live[c] = st.issue(
+                functools.partial(fill, off=off, sz=sz),
+                functools.partial(fload, off=off, sz=sz)
+                if fload is not None else None)
+        if c >= 1:
+            off, sz = chunks[c - 1]
+            st.drain(live[c - 1],
+                     functools.partial(consume, off=off, sz=sz),
+                     functools.partial(commit, off=off, sz=sz)
+                     if commit is not None else None)
+    st.finish()
+
+
+def _put_kernel(axis, origin, target, chunks, depth, credits,
+                src_hbm, out_hbm, *scratch):
     """Chunked one-sided put: per chunk one remote DMA of the origin's
-    stage slot into the target's landing slot; the target alone commits
-    landings into its window shard at ``disp + off``."""
+    stage slot into the partner's landing slot, committed to the
+    landing segment ``out_hbm`` (the target's copy of it is what the
+    wrapper writes into the window; everyone else's is their own
+    zeros)."""
     me = lax.axis_index(axis)
-    out_hbm[...] = win_hbm[...]
     st = _mk_streamer(_partner(me, origin, target), depth, credits,
                       scratch)
-    st.grant_initial_credits()
-    live: List[Optional[int]] = [None] * len(chunks)
-    for c in range(len(chunks) + 1):
-        if c < len(chunks):
-            off, sz = chunks[c]
+    st.enter()
 
-            def fill(slot, off=off, sz=sz):
-                @pl.when(me == origin)
-                def _():
-                    st.stage_buf[slot, :sz] = src_hbm[pl.ds(off, sz)]
+    def fill(slot, off, sz):
+        @pl.when(me == origin)
+        def _():
+            pltpu.sync_copy(src_hbm.at[pl.ds(off, sz)],
+                            st.stage_buf.at[slot, pl.ds(0, sz)])
 
-                @pl.when(me != origin)
-                def _():
-                    st.stage_buf[slot, :sz] = jnp.zeros(
-                        (sz,), st.stage_buf.dtype)
+        @pl.when(me != origin)
+        def _():
+            st.stage_buf[slot, :sz] = jnp.zeros_like(
+                st.stage_buf[slot, :sz])
 
-            live[c] = st.issue(fill, None)
-        if c >= 1:
-            off, sz = chunks[c - 1]
+    def consume(slot, off, sz):
+        # landing -> segment commit: one local DMA, waited before the
+        # slot's credit goes back
+        pltpu.sync_copy(st.landing_buf.at[slot, pl.ds(0, sz)],
+                        out_hbm.at[pl.ds(off, sz)])
 
-            def consume(slot, off=off, sz=sz):
-                # direct landing->window commit (repo pallas_put idiom)
-                @pl.when(me == target)
-                def _():
-                    out_hbm[pl.ds(disp + off, sz)] = \
-                        st.landing_buf[slot, :sz]
-
-            st.drain(live[c - 1], consume, None)
-    st.finish()
+    _stream(st, chunks, fill, consume)
 
 
-def _get_kernel(axis, origin, target, disp, chunks, depth, credits,
-                win_hbm, out_hbm, *scratch):
+def _get_kernel(axis, origin, target, chunks, depth, credits,
+                seg_hbm, out_hbm, *scratch):
     """Chunked one-sided get — the reversed put: every device stages
-    its OWN window chunk at ``disp + off`` (so the non-pair self-copies
-    and the origin->target lane carry harmless data), and the origin
-    alone commits what lands from the target."""
+    its OWN window segment (so the non-pair self-copies and the
+    origin->target lane carry harmless data) and commits what lands;
+    the origin's landing is the target's segment (the wrapper keeps
+    only that one)."""
     me = lax.axis_index(axis)
-    n = out_hbm.shape[0]
-    out_hbm[...] = jnp.zeros((n,), out_hbm.dtype)
     st = _mk_streamer(_partner(me, origin, target), depth, credits,
                       scratch)
-    st.grant_initial_credits()
-    live: List[Optional[int]] = [None] * len(chunks)
-    for c in range(len(chunks) + 1):
-        if c < len(chunks):
-            off, sz = chunks[c]
+    st.enter()
 
-            def fill(slot, off=off, sz=sz):
-                st.stage_buf[slot, :sz] = win_hbm[pl.ds(disp + off, sz)]
+    def fill(slot, off, sz):
+        pltpu.sync_copy(seg_hbm.at[pl.ds(off, sz)],
+                        st.stage_buf.at[slot, pl.ds(0, sz)])
 
-            live[c] = st.issue(fill, None)
-        if c >= 1:
-            off, sz = chunks[c - 1]
+    def consume(slot, off, sz):
+        pltpu.sync_copy(st.landing_buf.at[slot, pl.ds(0, sz)],
+                        out_hbm.at[pl.ds(off, sz)])
 
-            def consume(slot, off=off, sz=sz):
-                @pl.when(me == origin)
-                def _():
-                    out_hbm[pl.ds(off, sz)] = st.landing_buf[slot, :sz]
-
-            st.drain(live[c - 1], consume, lambda slot: None)
-    st.finish()
+    _stream(st, chunks, fill, consume)
 
 
-def _acc_kernel(axis, origin, target, disp, chunks, depth, credits,
-                quant_block, wire, src_hbm, win_hbm, out_hbm, *scratch):
+def _acc_kernel(axis, origin, target, chunks, depth, credits,
+                quant_block, wire, src_hbm, seg_hbm, out_hbm, *scratch):
     """Chunked one-sided accumulate (MPI_SUM): the origin streams
     source chunks through the slot/credit schedule; every device folds
-    what lands into its own window chunk (the fold is uniform — only
+    what lands into its own window segment (the fold is uniform — only
     the target receives nonzero data, everyone else folds the identity
     it was sent), so no device diverges on the collective DMA sequence.
     With ``quant_block`` set the stage slot carries the pallas_quant
     block-scaled int32 wire (encode fused here, decode fused into the
     fold) under the same declared_bound contract."""
     me = lax.axis_index(axis)
-    out_hbm[...] = win_hbm[...]
+    del seg_hbm     # aliased to out_hbm: the segment is folded in place
     st = _mk_streamer(_partner(me, origin, target), depth, credits,
                       scratch)
-    st.grant_initial_credits()
+    st.enter()
     if quant_block is not None:
         from .pallas_quant import _decode_f32, _encode_f32
 
         def _ww(sz):
             # int32 wire words for a block-multiple chunk of sz elems
             return (sz // quant_block) * (1 + quant_block // 4)
-    live: List[Optional[int]] = [None] * len(chunks)
-    for c in range(len(chunks) + 1):
-        if c < len(chunks):
-            off, sz = chunks[c]
 
-            def fill(slot, off=off, sz=sz):
-                val = jnp.where(me == origin, src_hbm[pl.ds(off, sz)],
-                                jnp.zeros((sz,), src_hbm.dtype))
-                if quant_block is not None:
-                    st.stage_buf[slot, :_ww(sz)] = _encode_f32(
-                        val, quant_block, wire)
-                else:
-                    st.stage_buf[slot, :sz] = val
+    def fill(slot, off, sz):
+        # the fold slot is free here (its last commit was waited in
+        # issue, its next segment prefetch starts after this): borrow
+        # it to bring the source chunk into VMEM
+        pltpu.sync_copy(src_hbm.at[pl.ds(off, sz)],
+                        st.fold_buf.at[slot, pl.ds(0, sz)])
+        val = st.fold_buf[slot, :sz]
+        val = jnp.where(me == origin, val, jnp.zeros_like(val))
+        if quant_block is not None:
+            st.stage_buf[slot, :_ww(sz)] = _encode_f32(
+                val, quant_block, wire)
+        else:
+            st.stage_buf[slot, :sz] = val
 
-            def fload(slot, off=off, sz=sz):
-                ld = pltpu.make_async_copy(
-                    out_hbm.at[pl.ds(disp + off, sz)],
-                    st.fold_buf.at[slot, pl.ds(0, sz)],
-                    st.fold_sem.at[slot])
-                ld.start()
-                st.pending_fold[slot] = ld
+    def fload(slot, off, sz):
+        ld = pltpu.make_async_copy(
+            out_hbm.at[pl.ds(off, sz)],
+            st.fold_buf.at[slot, pl.ds(0, sz)],
+            st.fold_sem.at[slot])
+        ld.start()
+        st.pending_fold[slot] = ld
 
-            live[c] = st.issue(fill, fload)
-        if c >= 1:
-            off, sz = chunks[c - 1]
+    def consume(slot, off, sz):
+        if quant_block is not None:
+            add = _decode_f32(st.landing_buf[slot, :_ww(sz)],
+                              quant_block, wire)
+        else:
+            add = st.landing_buf[slot, :sz]
+        st.fold_buf[slot, :sz] = st.fold_buf[slot, :sz] + add
 
-            def consume(slot, sz=sz):
-                if quant_block is not None:
-                    add = _decode_f32(st.landing_buf[slot, :_ww(sz)],
-                                      quant_block, wire)
-                else:
-                    add = st.landing_buf[slot, :sz]
-                st.fold_buf[slot, :sz] = st.fold_buf[slot, :sz] + add
+    def commit(slot, off, sz):
+        w = pltpu.make_async_copy(
+            st.fold_buf.at[slot, pl.ds(0, sz)],
+            out_hbm.at[pl.ds(off, sz)],
+            st.st_sem.at[slot])
+        w.start()
+        st.pending_store[slot] = w
 
-            def commit(slot, off=off, sz=sz):
-                w = pltpu.make_async_copy(
-                    st.fold_buf.at[slot, pl.ds(0, sz)],
-                    out_hbm.at[pl.ds(disp + off, sz)],
-                    st.st_sem.at[slot])
-                w.start()
-                st.pending_store[slot] = w
-            st.drain(live[c - 1], consume, commit)
-    st.finish()
+    _stream(st, chunks, fill, consume, fload, commit)
 
 
 # ---------------------------------------------------------------------------
 # wrappers (call inside shard_map over the window's mesh axis)
 # ---------------------------------------------------------------------------
+
+def _tiles(flat, rows: int):
+    """[n] -> (rows, 128), zero-padded."""
+    return _as_blocks(flat, 1, rows)[0]
+
+
+def _untile(tiles, n: int):
+    return _from_blocks(tiles[None], n)
+
+
+def _rma_call(kern_fn, static, cid: int, operands, out_like, aliases,
+              rows: int, dtype, chunk_bytes, depth, credits, interpret,
+              tail=()):
+    """Launch one exact (tiled) one-sided kernel over (rows, 128)
+    segments: chunk geometry in rows, all operands left in HBM."""
+    interpret, credits = _resolve_flags(interpret, credits)
+    t = _sublanes(dtype)
+    chunk = min(max(t, _cfg_chunk_elems(dtype, chunk_bytes)
+                    // _LANES // t * t), rows)
+    d = _cfg_depth(depth)
+    kern = functools.partial(kern_fn, *static, _chunks(0, rows, chunk), d,
+                             credits, *tail)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        kern,
+        in_specs=[hbm] * len(operands),
+        out_specs=hbm,
+        out_shape=jax.ShapeDtypeStruct(out_like.shape, out_like.dtype),
+        scratch_shapes=_rma_scratch_shapes(d, chunk, dtype,
+                                           lanes=(_LANES,)),
+        input_output_aliases=aliases,
+        compiler_params=compiler_params(collective_id=cid,
+                                        has_side_effects=True),
+        interpret=interpret,
+    )(*operands)
+
 
 def rma_put(src, win_shard, axis: str, num_devices: int, origin: int,
             target: int, disp: int = 0, *,
@@ -402,28 +464,18 @@ def rma_put(src, win_shard, axis: str, num_devices: int, origin: int,
             credits: Optional[bool] = None, interpret=None):
     """One-sided contiguous put over remote DMA: origin pushes ``src``
     into the target's window shard at element offset ``disp``. Returns
-    the updated shard (in-place on the target via aliasing)."""
-    if not HAVE_PALLAS:
-        raise RuntimeError("pallas unavailable")
-    interpret, credits = _resolve_flags(interpret, credits)
+    the updated shard (the target's segment replaced, every other
+    shard as it was)."""
     n = src.shape[0]
-    chunk = min(_cfg_chunk_elems(src.dtype, chunk_bytes), n)
-    d = _cfg_depth(depth)
-    chunks = _chunks(0, n, chunk)
-    kern = functools.partial(_put_kernel, axis, origin, target, disp,
-                             chunks, d, credits)
-    return pl.pallas_call(
-        kern,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        out_shape=jax.ShapeDtypeStruct(win_shard.shape, win_shard.dtype),
-        scratch_shapes=_rma_scratch_shapes(d, chunk, src.dtype),
-        input_output_aliases={1: 0},
-        compiler_params=compiler_params(collective_id=_CID_PUT,
-                                        has_side_effects=True),
-        interpret=interpret,
-    )(src, win_shard)
+    rows = _tile_rows(n, src.dtype)
+    s_t = _tiles(src, rows)
+    landed = _rma_call(_put_kernel, (axis, origin, target), _CID_PUT,
+                       (s_t,), s_t, {}, rows, src.dtype, chunk_bytes,
+                       depth, credits, interpret)
+    me = lax.axis_index(axis)
+    seg = jnp.where(me == target, _untile(landed, n),
+                    win_shard[disp:disp + n])
+    return win_shard.at[disp:disp + n].set(seg)
 
 
 def rma_get(win_shard, n: int, axis: str, num_devices: int, origin: int,
@@ -435,24 +487,14 @@ def rma_get(win_shard, n: int, axis: str, num_devices: int, origin: int,
     pulls ``n`` elements of the target's window shard at ``disp``.
     Returns the (n,) result — the data on the origin's shard, zeros
     elsewhere."""
-    if not HAVE_PALLAS:
-        raise RuntimeError("pallas unavailable")
-    interpret, credits = _resolve_flags(interpret, credits)
-    chunk = min(_cfg_chunk_elems(win_shard.dtype, chunk_bytes), n)
-    d = _cfg_depth(depth)
-    chunks = _chunks(0, n, chunk)
-    kern = functools.partial(_get_kernel, axis, origin, target, disp,
-                             chunks, d, credits)
-    return pl.pallas_call(
-        kern,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        out_shape=jax.ShapeDtypeStruct((n,), win_shard.dtype),
-        scratch_shapes=_rma_scratch_shapes(d, chunk, win_shard.dtype),
-        compiler_params=compiler_params(collective_id=_CID_GET,
-                                        has_side_effects=True),
-        interpret=interpret,
-    )(win_shard)
+    rows = _tile_rows(n, win_shard.dtype)
+    seg_t = _tiles(win_shard[disp:disp + n], rows)
+    got = _rma_call(_get_kernel, (axis, origin, target), _CID_GET,
+                    (seg_t,), seg_t, {}, rows, win_shard.dtype,
+                    chunk_bytes, depth, credits, interpret)
+    me = lax.axis_index(axis)
+    return jnp.where(me == origin, _untile(got, n),
+                     jnp.zeros((n,), win_shard.dtype))
 
 
 def rma_accumulate(src, win_shard, axis: str, num_devices: int,
@@ -465,43 +507,46 @@ def rma_accumulate(src, win_shard, axis: str, num_devices: int,
     schedule with the fold at the target. ``quantized=True`` carries
     each chunk as the pallas_quant block-scaled int32 wire (f32 only;
     the caller owns the declared_bound budget check — acc_quant_ok)."""
-    if not HAVE_PALLAS:
-        raise RuntimeError("pallas unavailable")
-    interpret, credits = _resolve_flags(interpret, credits)
     n = src.shape[0]
+    seg = win_shard[disp:disp + n]
+    if not quantized:
+        rows = _tile_rows(n, src.dtype)
+        s_t, seg_t = _tiles(src, rows), _tiles(seg, rows)
+        out = _rma_call(_acc_kernel, (axis, origin, target), _CID_ACC,
+                        (s_t, seg_t), seg_t, {1: 0}, rows, src.dtype,
+                        chunk_bytes, depth, credits, interpret,
+                        tail=(None, None))     # exact: no quant codec
+        return win_shard.at[disp:disp + n].set(_untile(out, n))
+    # quantized wire: the flat 1-D form the block codec is written for
+    interpret, credits = _resolve_flags(interpret, credits)
+    from ..coll.tuning import quant_params
+    from .pallas_quant import quant_block_elems, wire_words
+    quant_block = min(quant_block_elems(src.dtype), n)
+    wire, _budget = quant_params()
+    if n % quant_block:
+        raise ValueError("quantized accumulate needs a block-"
+                         f"multiple count (n={n}, block="
+                         f"{quant_block})")
+    # wire slots carry whole blocks: chunk snaps to a block multiple
     chunk = min(_cfg_chunk_elems(src.dtype, chunk_bytes), n)
+    chunk = max(quant_block, (chunk // quant_block) * quant_block)
     d = _cfg_depth(depth)
-    quant_block = wire = wire_chunk = None
-    cid = _CID_ACC
-    if quantized:
-        from ..coll.tuning import quant_params
-        from .pallas_quant import quant_block_elems, wire_words
-        quant_block = min(quant_block_elems(src.dtype), n)
-        wire, _budget = quant_params()
-        # wire slots carry whole blocks: chunk snaps to a block multiple
-        chunk = max(quant_block, (chunk // quant_block) * quant_block)
-        if n % quant_block:
-            raise ValueError("quantized accumulate needs a block-"
-                             f"multiple count (n={n}, block="
-                             f"{quant_block})")
-        wire_chunk = wire_words(chunk, quant_block)
-        cid = _CID_ACC_QUANT
-    chunks = _chunks(0, n, chunk)
-    kern = functools.partial(_acc_kernel, axis, origin, target, disp,
-                             chunks, d, credits, quant_block, wire)
-    return pl.pallas_call(
-        kern,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        out_shape=jax.ShapeDtypeStruct(win_shard.shape, win_shard.dtype),
-        scratch_shapes=_rma_scratch_shapes(d, chunk, src.dtype,
-                                           wire_chunk),
+    kern = functools.partial(_acc_kernel, axis, origin, target,
+                             _chunks(0, n, chunk), d, credits)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
+        functools.partial(kern, quant_block, wire),
+        in_specs=[hbm, hbm],
+        out_specs=hbm,
+        out_shape=jax.ShapeDtypeStruct(seg.shape, seg.dtype),
+        scratch_shapes=_rma_scratch_shapes(
+            d, chunk, src.dtype, wire_words(chunk, quant_block)),
         input_output_aliases={1: 0},
-        compiler_params=compiler_params(collective_id=cid,
+        compiler_params=compiler_params(collective_id=_CID_ACC_QUANT,
                                         has_side_effects=True),
         interpret=interpret,
-    )(src, win_shard)
+    )(src, seg)
+    return win_shard.at[disp:disp + n].set(out)
 
 
 # ---------------------------------------------------------------------------
@@ -536,7 +581,7 @@ def planned_rma_tier(kind: str, nbytes: int, dtype, contiguous: bool,
     kind the kernels cannot carry). A 'quant' bin the accumulate
     cannot actually quantize degrades to the exact 'rdma' tier."""
     from .pallas_ici import _kernels_runnable
-    if not HAVE_PALLAS or not _kernels_runnable(interpret):
+    if not _kernels_runnable(interpret):
         return "epoch", "platform"
     if not contiguous:
         return "epoch", "noncontig"

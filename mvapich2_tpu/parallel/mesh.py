@@ -20,29 +20,13 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import ops
+from ..utils.compile_cache import ensure_compile_cache
 from ..utils.detect import detect
 from ..utils.mlog import get_logger
 
 log = get_logger("mesh")
 
-shard_map = jax.shard_map if hasattr(jax, "shard_map") else None
-if shard_map is None:  # jax < 0.5: experimental shard_map, check_rep era
-    import inspect
-
-    from jax.experimental.shard_map import shard_map as _sm
-
-    _SM_PARAMS = set(inspect.signature(_sm).parameters)
-
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None, **kw):
-        # callers use the modern keyword (check_vma); the experimental
-        # signature spells it check_rep — translate, and drop anything
-        # the installed version does not know rather than TypeError-ing
-        # the whole device path (the r6 seed failure mode)
-        if "check_vma" in kw and "check_vma" not in _SM_PARAMS:
-            kw["check_rep"] = kw.pop("check_vma")
-        kw = {k: v for k, v in kw.items() if k in _SM_PARAMS}
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   **kw)
+shard_map = jax.shard_map
 
 
 def mesh_shape_for(n: int, naxes: int = 2) -> Tuple[int, ...]:
@@ -64,6 +48,7 @@ def make_mesh(shape: Optional[Sequence[int]] = None,
               axis_names: Sequence[str] = ("x",),
               devices=None) -> Mesh:
     """Build a Mesh over the available devices (row-major assignment)."""
+    ensure_compile_cache()
     devices = devices if devices is not None else jax.devices()
     n = len(devices)
     if shape is None:

@@ -599,6 +599,18 @@ class ShmChannel(Channel):
         # nemesis fastbox-signal discipline.
         self._bell = socket.socket(socket.AF_UNIX, socket.SOCK_DGRAM)
         bell_path = f"{path}.bell-{my_rank}"
+        if len(os.fsencode(bell_path)) > 100:
+            # sockaddr_un.sun_path holds 108 bytes: a segment directory
+            # nested deep (a test's tmp_path, a long TMPDIR) would make
+            # bind() fail and cost the job its whole shm fast path.
+            # Peers learn the address from the card, so any unique
+            # short name serves.
+            import hashlib
+            import tempfile
+            bell_path = os.path.join(
+                tempfile.gettempdir(), "mv2t-bell-"
+                + hashlib.sha1(os.fsencode(path)).hexdigest()[:16]
+                + f"-{my_rank}")
         try:
             os.unlink(bell_path)
         except OSError:

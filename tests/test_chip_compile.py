@@ -1,0 +1,223 @@
+"""Ask the chip's compiler, without the chip.
+
+The only test file that describes the TPU: the kernels of the device
+path are lowered and compiled for a described (not attached) v5e at the
+sizes the chip runs them, through their kernel wrappers with
+``interpret=False`` — the dispatchers ask ``jax.default_backend()``,
+see the CPU, and would compile nothing. A case passes when Mosaic
+accepts the kernel and the compiled module holds a ``tpu_custom_call``;
+what the compiler still refuses is a strict xfail that quotes it.
+
+Topology, mesh and shardings are built inside module-scoped fixtures
+(never at import): one process at a time may load libtpu, and every
+xdist worker imports every test file.
+"""
+
+import functools
+import os
+
+import numpy as np
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+KiB, MiB = 1 << 10, 1 << 20
+P4 = 4
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — no libtpu / no such topology
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-topology compile is written to the persistent cache
+    # but can never be read back without a chip: keep it out
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    from jax.sharding import Mesh
+    return Mesh(np.asarray(topo.devices[:P4]), ("x",))
+
+
+def _compile_sharded(mesh4, kernel, nelems, dtype):
+    """Compile ``kernel(shard)`` under shard_map over the 4-chip mesh,
+    one [nelems] shard per chip; returns the compiled module text."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    sm = jax.shard_map(lambda s: kernel(s.reshape(-1)).reshape(1, -1),
+                       mesh=mesh4, in_specs=(P("x", None),),
+                       out_specs=P("x", None), check_vma=False)
+    x = jax.ShapeDtypeStruct((P4, nelems), dtype,
+                             sharding=NamedSharding(mesh4, P("x", None)))
+    return jax.jit(sm).lower(x).compile().as_text()
+
+
+def test_slot_allreduce_one_chip(one_chip):
+    """The HBMSlotChannel kernel at chip_smoke's size: 8 ranks x 64 MiB
+    f32 co-resident on one chip."""
+    import jax
+    import jax.numpy as jnp
+
+    from mvapich2_tpu.ops import pallas_hbm
+    x = jax.ShapeDtypeStruct((8, 64 * MiB // 4), jnp.float32,
+                             sharding=one_chip)
+    f = functools.partial(pallas_hbm.hbm_slot_allreduce, interpret=False)
+    assert "tpu_custom_call" in jax.jit(f).lower(x).compile().as_text()
+
+
+_RING_SIZES = [(4 * KiB, "float32"), (1 * MiB, "float32"),
+               (64 * MiB, "float32"), (1 * MiB, "bfloat16")]
+
+
+@pytest.mark.parametrize("nbytes,dtype", _RING_SIZES)
+@pytest.mark.parametrize("coll", ["all_reduce", "all_gather",
+                                  "reduce_scatter"])
+def test_hbm_ring_compiles(mesh4, coll, nbytes, dtype):
+    """The three _RingStreamer kernels, both lanes, credits and entry
+    barrier on, from one tile per block up to the 64 MiB shard."""
+    from mvapich2_tpu.ops import pallas_ici
+    fn = getattr(pallas_ici, f"hbm_ring_{coll}")
+    dt = np.dtype(dtype)
+    text = _compile_sharded(
+        mesh4, lambda s: fn(s, "x", P4, interpret=False),
+        nbytes // dt.itemsize, dt)
+    assert "tpu_custom_call" in text
+
+
+def test_hbm_ring_max_compiles(mesh4):
+    """The non-sum reducer (chip_smoke --chips 4 runs max at 1 MiB)."""
+    from mvapich2_tpu.ops import pallas_ici
+    text = _compile_sharded(
+        mesh4, lambda s: pallas_ici.hbm_ring_all_reduce(
+            s, "x", P4, "max", interpret=False),
+        MiB // 4, np.dtype("float32"))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("nbytes", [4 * KiB, 1 * MiB, 4 * MiB])
+@pytest.mark.parametrize("coll", ["ring_all_reduce", "ring_all_gather"])
+def test_vmem_ring_compiles(mesh4, coll, nbytes):
+    """The VMEM-resident flat rings from one tile per block up to the
+    tier's 4 MiB cap (shard for the allreduce, gathered output for the
+    all-gather)."""
+    from mvapich2_tpu.ops import pallas_ring
+    fn = getattr(pallas_ring, coll)
+    if coll == "ring_all_gather":
+        nbytes //= P4
+    text = _compile_sharded(
+        mesh4, lambda s: fn(s, "x", P4, interpret=False),
+        nbytes // 4, np.dtype("float32"))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("nbytes", [4 * MiB, 16 * MiB])
+def test_alltoall_compiles(mesh4, nbytes):
+    from mvapich2_tpu.ops import pallas_alltoall
+    text = _compile_sharded(
+        mesh4,
+        lambda s: pallas_alltoall.hbm_alltoall(s, "x", P4,
+                                               interpret=False),
+        nbytes // 4, np.dtype("float32"))
+    assert "tpu_custom_call" in text
+
+
+def test_alltoallv_compiles(mesh4):
+    """Skewed counts, a zero pair, unaligned displacements."""
+    from mvapich2_tpu.ops import pallas_alltoall
+    counts = ((1000, 70000, 0, 5), (3, 128, 4096, 9), (0, 0, 1, 40000),
+              (777, 12, 12, 12))
+    _, _, in_len, _ = pallas_alltoall.packed_displs(counts)
+    text = _compile_sharded(
+        mesh4,
+        lambda s: pallas_alltoall.hbm_alltoallv(s, "x", P4, counts,
+                                                interpret=False),
+        in_len, np.dtype("float32"))
+    assert "tpu_custom_call" in text
+
+
+def test_remote_sendrecv_compiles(mesh4):
+    from mvapich2_tpu.ops import pallas_ici
+    text = _compile_sharded(
+        mesh4,
+        lambda s: pallas_ici.remote_sendrecv(s, "x", P4, 0, 2,
+                                             interpret=False),
+        MiB // 4, np.dtype("float32"))
+    assert "tpu_custom_call" in text
+
+
+# -- what the chip's compiler still refuses (ISSUE 22 stop rule) --------
+# These kernels are off chip_smoke's path. On a TPU they raise the
+# compiler's error — no XLA fallback covers them — and each case below
+# flips to a failure the day its kernel compiles (strict), so the marks
+# cannot outlive the repair. ROADMAP A3 carries the work.
+
+@pytest.mark.parametrize("n,disp", [(MiB // 4, 0), (100003, 77)])
+@pytest.mark.parametrize("kind", ["put", "get", "accumulate"])
+def test_rma_kernels_compile(mesh4, kind, n, disp):
+    """The exact one-sided kernels over (rows, 128) segments — whole
+    tiles, and an odd count at an unaligned displacement (the wrappers
+    cut and restore the segment on the XLA side)."""
+    from mvapich2_tpu.ops import pallas_rma
+    win = 2 * MiB // 4
+
+    def kernel(w):
+        src = w[:n] * 2
+        if kind == "put":
+            return pallas_rma.rma_put(src, w, "x", P4, 0, 2, disp,
+                                      interpret=False)
+        if kind == "get":
+            return pallas_rma.rma_get(w, n, "x", P4, 0, 2, disp,
+                                      interpret=False)
+        return pallas_rma.rma_accumulate(src, w, "x", P4, 0, 2, disp,
+                                         interpret=False)
+    assert "tpu_custom_call" in _compile_sharded(
+        mesh4, kernel, win, np.dtype("float32"))
+
+
+@pytest.mark.xfail(strict=True, reason=(
+    "the quantized accumulate shares pallas_quant's codec (and keeps "
+    "its flat 1-D slots): \"Mosaic failed to compile TPU kernel: "
+    "infer-vector-layout: unsupported shape cast\""))
+def test_rma_quantized_accumulate_compiles(mesh4):
+    from mvapich2_tpu.ops import pallas_rma
+    n = MiB // 4
+    assert "tpu_custom_call" in _compile_sharded(
+        mesh4,
+        lambda w: pallas_rma.rma_accumulate(w * 2, w, "x", P4, 0, 2,
+                                            quantized=True,
+                                            interpret=False),
+        n, np.dtype("float32"))
+
+
+@pytest.mark.xfail(strict=True, reason=(
+    "quant_ring_all_reduce packs 4 codes per int32 wire word with a "
+    "reshape Mosaic cannot lay out: \"infer-vector-layout: unsupported "
+    "shape cast\" on tpu.reshape (512x128xi32) -> (512x32x4xi32); its "
+    "1-D (ndir, depth, chunk) slots also still put the slot index on a "
+    "tiled dimension"))
+def test_quant_ring_compiles(mesh4):
+    from mvapich2_tpu.ops import pallas_quant
+    text = _compile_sharded(
+        mesh4,
+        lambda s: pallas_quant.quant_ring_all_reduce(
+            s, "x", P4, wire="q8", interpret=False),
+        4 * MiB // 4, np.dtype("float32"))
+    assert "tpu_custom_call" in text

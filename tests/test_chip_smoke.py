@@ -1,0 +1,65 @@
+"""CPU rehearsal of chip_smoke.py's phases at tiny sizes.
+
+The script itself refuses to run without a TPU; these tests import its
+phases and drive them on the virtual CPU mesh (kernels interpreted), so
+a wrong path, argument, reference or pvar count is found here and not on
+the chip.
+"""
+
+import os
+import sys
+
+import jax
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import chip_smoke  # noqa: E402
+
+from mvapich2_tpu import mpit  # noqa: E402
+from mvapich2_tpu.utils.config import get_config  # noqa: E402
+
+
+def test_library_door_on_one_device_slot_channel():
+    from mvapich2_tpu.parallel.mesh import make_mesh
+    chip0 = mpit.pvar("coll_level_chip").read()
+    calls = chip_smoke.library_door(
+        seed=7, big=8 * 1024, mid=8 * 256,
+        device_mesh=make_mesh((1,), ("x",), jax.devices()[:1]),
+        expect_kernel=False)
+    assert calls == 10 * (1 + chip_smoke.STEADY_CALLS)
+    assert mpit.pvar("coll_level_chip").read() - chip0 == \
+        chip_smoke.NRANKS * calls
+    assert "MV2T_ALLREDUCE_ALGO" not in os.environ
+
+
+def test_launcher_door_counts_the_size_table(monkeypatch, capsys):
+    # run the launcher in-process on the 8 virtual CPU devices (what its
+    # re-exec'd child does), so the pvars are this process' own
+    monkeypatch.setenv("MV2T_VPOD_CHILD", "1")
+    ici0 = mpit.pvar("coll_level_ici").read()
+    calls = chip_smoke.launcher_door(osu_args=["-m", "4096", "-i", "3",
+                                               "-x", "1"])
+    assert calls == 11 * 4 + 1
+    assert mpit.pvar("coll_level_ici").read() - ici0 == \
+        chip_smoke.NRANKS * calls
+    assert "No Errors" in capsys.readouterr().out
+
+
+def test_four_chip_phase_under_the_interpreter(monkeypatch):
+    cfg = get_config()
+    monkeypatch.setenv("MV2T_ICI_INTERPRET", "1")
+    monkeypatch.setenv("MV2T_DEV_TIER_VMEM_MAX", "8192")
+    # the committed CPU profile sends large shards back to XLA
+    monkeypatch.setenv("MV2T_DEV_TIER_XLA_MIN", "-1")
+    cfg.reload()
+    try:
+        chip_smoke.four_chips(seed=3, scale=2048)
+    finally:
+        monkeypatch.undo()
+        cfg.reload()
+
+
+def test_main_refuses_without_a_tpu(capsys):
+    assert chip_smoke.main([]) != 0
+    out = capsys.readouterr().out
+    assert '"ok"' not in out and "no TPU" in out

@@ -217,10 +217,14 @@ def test_pallas_ring_all_reduce_2d(comm8):
         np.testing.assert_allclose(blk, expected)
 
 
-def test_pallas_fallback_nondivisible(comm8):
-    """Non-divisible shapes take the lax.psum fallback (the crossover)."""
+def test_pallas_ring_nondivisible_pads(comm8):
+    """Non-divisible shapes are zero-padded to whole-tile ring blocks
+    and run the kernel (no lax.psum fallback any more)."""
     from mvapich2_tpu.ops import pallas_ring
     x = jnp.arange(8 * 5, dtype=jnp.float32)  # shard 5 elems, 5 % 8 != 0
-    out = comm8.run(lambda s: pallas_ring.ring_all_reduce(s, "x", 8), x)
+    ip = _interp()
+    out = comm8.run(lambda s: pallas_ring.ring_all_reduce(s, "x", 8,
+                                                          interpret=ip),
+                    x)
     expected = np.arange(40, dtype=np.float32).reshape(8, 5).sum(axis=0)
     np.testing.assert_allclose(np.asarray(out).reshape(8, 5)[0], expected)

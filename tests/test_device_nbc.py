@@ -360,11 +360,14 @@ def _nbc_app_with_tracecap(seen):
         req = comm.iallreduce(x, out)
         assert getattr(req, "device_nbc", False)
         req.wait()
-        if comm.rank == 0:
-            tr = comm.u.engine.tracer
-            if tr is not None:
-                seen["names"] = {e[2] for e in tr.tail(100000)
-                                 if e[1] == "device"}
+        comm.barrier()      # every rank's polls have landed their segments
+        # a segment's issue/complete instants go to the tracer of the
+        # rank whose poll launched / landed it — whichever got there
+        # first — so the lane is the union over ranks
+        tr = comm.u.engine.tracer
+        if tr is not None:
+            seen[comm.rank] = {e[2] for e in tr.tail(100000)
+                               if e[1] == "device"}
     return app
 
 
@@ -380,8 +383,8 @@ def test_nbc_device_observability(monkeypatch):
     seen = {}
     run_ranks(2, _nbc_app_with_tracecap(seen), device_mesh=True)
     assert h.count > c0, "lat_dev_nbc histogram did not record"
-    assert {"nbc_dev_issue", "nbc_dev_complete"} <= seen.get(
-        "names", set()), seen
+    assert {"nbc_dev_issue", "nbc_dev_complete"} <= set().union(
+        *seen.values()), seen
 
 
 def test_nbc_histogram_gated_off():

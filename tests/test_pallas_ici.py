@@ -78,6 +78,92 @@ def test_allreduce_chunk_boundaries_bitwise(comm8, shard, chunk_bytes):
         np.testing.assert_array_equal(row, exp)
 
 
+# ---------------------------------------------------------------------------
+# tile-scale streams: the layout moves whole (rows, 128) tiles, so a
+# multi-chunk pipeline needs blocks of many rows — the tiny shards above
+# are one tile per block and a single chunk. These drive, per lane,
+# several chunks, slot reuse past the pipeline depth, a short last
+# chunk, uneven lanes and an identity-padded tail. They run on a 4-shard
+# sub-mesh: the interpreter parks one host thread per shard in every
+# blocking wait, and a multi-chunk ring over all 8 virtual devices of an
+# 8-thread host starves its pool (7 shards pass, 8 hang; 8 of 16 pass).
+# ---------------------------------------------------------------------------
+
+P4 = 4
+ROW = 128
+_TILE_STREAMS = [
+    # (elements per ring block, chunk_bytes, depth)
+    (48 * ROW, 4096, 2),       # 3 chunks per lane: slots reused past depth
+    (40 * ROW, 4096, 2),       # uneven lanes: 24 rows cw, 16 ccw
+    (44 * ROW - 5, 4096, 2),   # block not a whole tile: identity pad
+    (48 * ROW, 8192, 2),       # 24-row lane / 16-row chunk: short last
+    (64 * ROW, 4096, 3),       # deeper pipeline, 4 chunks per lane
+]
+
+
+@pytest.fixture(scope="module")
+def comm4():
+    return MeshComm(make_mesh((P4,), ("x",), jax.devices()[:P4]))
+
+
+@pytest.mark.parametrize("blk,chunk_bytes,depth", _TILE_STREAMS)
+def test_allreduce_tile_streams_bitwise(comm4, blk, chunk_bytes, depth):
+    xv = (np.arange(P4 * P4 * blk) % 7).astype(np.float32)
+    out = comm4.run(lambda s: pallas_ici.hbm_ring_all_reduce(
+        s, "x", P4, interpret=True, chunk_bytes=chunk_bytes,
+        depth=depth), jnp.asarray(xv))
+    exp = xv.reshape(P4, -1).sum(0)
+    for row in np.asarray(out).reshape(P4, -1):
+        np.testing.assert_array_equal(row, exp)
+
+
+def test_allreduce_tile_streams_bf16_max(comm4):
+    """16-row bf16 tiles, the non-sum reducer, multi-chunk."""
+    blk = 96 * ROW      # 48-row lanes = 3 chunks of 16 rows
+    xv = (np.arange(P4 * P4 * blk) % 11 - 5).astype(np.float32)
+    out = comm4.run(lambda s: pallas_ici.hbm_ring_all_reduce(
+        s, "x", P4, op="max", interpret=True, chunk_bytes=4096),
+        jnp.asarray(xv, dtype=jnp.bfloat16))
+    exp = xv.reshape(P4, -1).max(0)
+    for row in np.asarray(out.astype(jnp.float32)).reshape(P4, -1):
+        np.testing.assert_array_equal(row, exp)
+
+
+@pytest.mark.parametrize("m", [48 * ROW, 44 * ROW - 5])
+def test_all_gather_tile_streams_bitwise(comm4, m):
+    xv = (np.arange(P4 * m) % 13).astype(np.float32)
+    out = comm4.run(lambda s: pallas_ici.hbm_ring_all_gather(
+        s, "x", P4, chunk_bytes=4096, interpret=True), jnp.asarray(xv),
+        out_specs=P("x"))
+    for row in np.asarray(out).reshape(P4, -1):
+        np.testing.assert_array_equal(row, xv)
+
+
+@pytest.mark.parametrize("blk", [48 * ROW, 44 * ROW - 5])
+def test_reduce_scatter_tile_streams_bitwise(comm4, blk):
+    n = P4 * blk - 3            # p does not divide n: padded tail block
+    xv = (np.arange(P4 * n) % 7).astype(np.float32)
+    out = comm4.run(lambda s: pallas_ici.hbm_ring_reduce_scatter(
+        s, "x", P4, chunk_bytes=4096, interpret=True), jnp.asarray(xv),
+        out_specs=P("x"))
+    full = np.zeros(P4 * blk, np.float32)
+    full[:n] = xv.reshape(P4, n).sum(0)
+    np.testing.assert_array_equal(np.asarray(out).reshape(-1), full)
+
+
+@pytest.mark.parametrize("c", [48 * ROW, 20 * ROW - 7])
+def test_alltoall_tile_streams_bitwise(comm4, c):
+    """The pairwise-permutation streamer, multi-chunk on both lanes."""
+    from mvapich2_tpu.ops import pallas_alltoall
+    xv = np.arange(P4 * P4 * c, dtype=np.float32) % 1021
+    out = comm4.run(lambda s: pallas_alltoall.hbm_alltoall(
+        s, "x", P4, chunk_bytes=4096, interpret=True), jnp.asarray(xv),
+        out_specs=P("x"))
+    got = np.asarray(out).reshape(P4, P4, c)
+    sent = xv.reshape(P4, P4, c)
+    np.testing.assert_array_equal(got, sent.transpose(1, 0, 2))
+
+
 @pytest.mark.parametrize("op,dtype", [
     ("max", np.int32),
     ("min", np.int32),
@@ -165,7 +251,8 @@ def test_scratch_scales_with_depth_and_chunk():
     a = pallas_ici._scratch_shapes(2, 2, 64, jnp.float32)
     b = pallas_ici._scratch_shapes(2, 4, 64, jnp.float32)
     # three data buffers lead; VMEM bytes double with depth
-    assert a[0].shape == (2, 2, 64) and b[0].shape == (2, 4, 64)
+    assert a[0].shape == (2, 2, 64, 128) and \
+        b[0].shape == (2, 4, 64, 128)
     assert len(a) == len(b)
 
 
@@ -258,10 +345,11 @@ def test_dispatcher_routes_hbm(comm8):
 def test_vmem_reject_counts_fallback_pvar(comm8):
     """The once-silent pallas_ring rejection now bumps the pvar family
     (per traced shape)."""
-    before = mpit.pvar("dev_coll_fallback_shape").read()
-    xv = np.arange(NP * 5, dtype=np.float32)   # shard 5 % 8 != 0
+    before = mpit.pvar("dev_coll_fallback_size").read()
+    n = pallas_ring.VMEM_LIMIT_BYTES // 4 + 128   # one shard past the cap
+    xv = (np.arange(NP * n) % 5).astype(np.float32)
     out = comm8.run(lambda s: pallas_ring.ring_all_reduce(s, "x", NP),
                     jnp.asarray(xv))
     exp = _expect(xv, "sum")
     np.testing.assert_array_equal(np.asarray(out).reshape(NP, -1)[0], exp)
-    assert mpit.pvar("dev_coll_fallback_shape").read() >= before + 1
+    assert mpit.pvar("dev_coll_fallback_size").read() >= before + 1
