@@ -25,6 +25,8 @@ is a no-op, logged).
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import threading
 from typing import Dict, List, Optional, Tuple
 
@@ -177,6 +179,54 @@ class _ImportedProgram:
         return out
 
 
+# -- phase spans inside dev_<coll> (device lane) --------------------------
+# One blocking device collective is one ``dev_<coll>`` B/E span per rank
+# (``_run``); inside it the rendezvous and the leader open these, all
+# with the collective's ``seq`` and ``coll`` in their args so a reader
+# can join rank 0's leader phases with the other ranks' waits:
+#
+#   dev_arrive       every rank: slot deposit -> first barrier returned
+#   dev_stage        rank 0: assembling the program's input
+#   dev_dispatch     rank 0: program-cache lookup + enqueue; its E says
+#                    ``built`` when the call made or loaded the program
+#   dev_device_wait  rank 0, slot channel: the leader's block_until_ready
+#   dev_collect      rank 0: one result per rank out of the output
+#   dev_release      every rank: the second barrier wait
+#   dev_deliver      every rank, after dev_<coll> E: _deliver
+#
+# Names are literals at the call sites (analysis/events.py resolves them
+# through ``_phase``'s callers) and every E sits in ``__exit__``.
+
+_NO_PHASE = contextlib.nullcontext()    # ``with`` target while untraced
+
+
+class _Phase:
+    """One open phase span; leaving it records the E with whatever the
+    site put into ``args`` meanwhile. ``end`` is the recorder's
+    ``record`` already bound to the span's lane, name and ``"E"``, so
+    the name stays the literal the events lint saw at the B."""
+
+    __slots__ = ("_end", "args")
+
+    def __init__(self, end, args: dict):
+        self._end = end
+        self.args = args
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self._end(**self.args)
+        return False
+
+
+def _device_resident(recvbuf) -> bool:
+    """The caller keeps the result on the device (no host recvbuf to
+    write through)."""
+    return recvbuf is None or is_device_array(recvbuf) \
+        or type(recvbuf).__name__ == "_InPlace"
+
+
 class _Rendezvous:
     """Per-bound-comm meeting point: slots for each rank's shard, two
     barrier phases per collective (deposit -> leader compute -> pickup).
@@ -231,6 +281,12 @@ class DeviceCollChannel:
     SUPPORTED: Tuple[str, ...] = ("allreduce", "reduce", "bcast",
                                   "allgather", "alltoall",
                                   "reduce_scatter_block", "alltoallv")
+    # what _phase reads, set per call by _run: the rank's recorder while
+    # its blocking collective is traced, that collective's name, and its
+    # ``seq`` (the blocking collectives this rank has begun)
+    _tr = None
+    _coll = ""
+    _seq = 0
 
     def __init__(self, mesh, axis, rendezvous: _Rendezvous, rank: int):
         self.mesh = mesh
@@ -285,6 +341,19 @@ class DeviceCollChannel:
             got = self._programs[key] = self._cached_build(
                 name, n, dtype_str, op, root, extra)
         return got
+
+    def _phase(self, name: str):
+        """Context manager for one phase span of the collective _run is
+        in (module comment above): B now, E on leaving, both with its
+        ``seq`` and ``coll``. Untraced it is the shared no-op, so a site
+        costs this attribute check; ``as`` then binds None."""
+        tr = self._tr
+        if tr is None:
+            return _NO_PHASE
+        args = {"seq": self._seq, "coll": self._coll}
+        tr.record("device", name, "B", **args)
+        return _Phase(functools.partial(tr.record, "device", name, "E"),
+                      args)
 
     def _chan_desc(self) -> str:
         """The mesh half of the executable-cache key: channel flavor,
@@ -519,12 +588,14 @@ class DeviceCollChannel:
         its result. Returns whatever the leader deposited for this rank
         (device array)."""
         rv = self.rv
-        rv.slots[self.rank] = local
-        try:
-            rv.barrier.wait()
-        except threading.BrokenBarrierError:
-            raise RuntimeError(
-                "device collective aborted: a peer rank failed") from None
+        with self._phase("dev_arrive"):
+            rv.slots[self.rank] = local
+            try:
+                rv.barrier.wait()
+            except threading.BrokenBarrierError:
+                raise RuntimeError(
+                    "device collective aborted: a peer rank failed"
+                ) from None
         if self.rank == 0:
             try:
                 rv.result = self._leader(name, op, root)
@@ -532,12 +603,14 @@ class DeviceCollChannel:
             except BaseException as e:   # noqa: BLE001 — must release peers
                 rv.error = e
                 rv.result = [None] * self.size
-        try:
-            rv.barrier.wait()
-        except threading.BrokenBarrierError:
-            rv.slots[self.rank] = None
-            raise RuntimeError(
-                "device collective aborted: a peer rank failed") from None
+        with self._phase("dev_release"):
+            try:
+                rv.barrier.wait()
+            except threading.BrokenBarrierError:
+                rv.slots[self.rank] = None
+                raise RuntimeError(
+                    "device collective aborted: a peer rank failed"
+                ) from None
         # release this rank's references promptly — retained slots/results
         # would pin device memory for the life of an idle comm
         res, rv.result[self.rank] = rv.result[self.rank], None
@@ -557,24 +630,41 @@ class DeviceCollChannel:
         if name == "alltoallv":
             return self._leader_v()
         n, dtype = self._slot_extent(rv.slots[0])
-        shards = []
-        for r in range(self.size):
-            s = rv.slots[r]
-            if is_device_array(s) and \
-                    s.devices() == {self.devices[r]}:
-                shards.append(s.reshape(1, n))
-            else:
-                shards.append(jax.device_put(
-                    np.asarray(s).reshape(1, n), self.devices[r]))
+        with self._phase("dev_stage"):
+            shards = []
+            for r in range(self.size):
+                s = rv.slots[r]
+                if is_device_array(s) and \
+                        s.devices() == {self.devices[r]}:
+                    shards.append(s.reshape(1, n))
+                else:
+                    shards.append(jax.device_put(
+                        np.asarray(s).reshape(1, n), self.devices[r]))
+            global_arr = self._global(shards, n)
+        # spelled out in each leader, not a helper: a frame more under
+        # the program's first call moved its lowering from 20 s to 44 s
+        # on the chip's host (PERF.md, PR 26)
+        with self._phase("dev_dispatch") as ph:
+            had = len(self._programs)
+            out = self._program(name, n, str(dtype), op, root)(global_arr)
+            if ph is not None:      # this call made or loaded the program
+                ph.args["built"] = len(self._programs) > had
+        return self._per_rank(out)
+
+    def _global(self, shards: List, n: int):
+        """The mesh-sharded ``[len(shards), n]`` program input over one
+        ``[1, n]`` shard per mesh device."""
+        import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
-        global_arr = jax.make_array_from_single_device_arrays(
-            (self.size, n),
+        return jax.make_array_from_single_device_arrays(
+            (len(shards), n),
             NamedSharding(self.mesh, P(self._pspec0(), None)), shards)
-        out = self._program(name, n, str(dtype), op, root)(global_arr)
-        per_dev: Dict = {}
-        for s in out.addressable_shards:
-            per_dev[s.device] = s.data
-        return [per_dev[self.devices[r]] for r in range(self.size)]
+
+    def _per_rank(self, out) -> List:
+        """Each rank's own device's shard of the program's output."""
+        with self._phase("dev_collect"):
+            per_dev = {s.device: s.data for s in out.addressable_shards}
+            return [per_dev[self.devices[r]] for r in range(self.size)]
 
     def _v_shards(self, slots, in_len: int, dtype) -> List:
         """Per-rank device shards for an alltoallv call: each rank's
@@ -602,24 +692,21 @@ class DeviceCollChannel:
         matrix from every rank's deposited scounts row, stage the padded
         packed payloads, run the counts-keyed program (the matrix is
         part of the program/executable cache key)."""
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
         from ..ops.pallas_alltoall import packed_displs
         rv = self.rv
-        counts = tuple(tuple(s.scounts) for s in rv.slots)
-        _, _, in_len, _ = packed_displs(counts)
-        _, dtype = self._slot_extent(rv.slots[0])
-        shards = self._v_shards(rv.slots, in_len, dtype)
-        global_arr = jax.make_array_from_single_device_arrays(
-            (self.size, in_len),
-            NamedSharding(self.mesh, P(self._pspec0(), None)), shards)
-        out = self._program("alltoallv", in_len, str(dtype), "none", 0,
-                            counts)(global_arr)
-        per_dev: Dict = {}
-        for s in out.addressable_shards:
-            per_dev[s.device] = s.data
-        return [per_dev[self.devices[r]] for r in range(self.size)]
+        with self._phase("dev_stage"):
+            counts = tuple(tuple(s.scounts) for s in rv.slots)
+            _, _, in_len, _ = packed_displs(counts)
+            _, dtype = self._slot_extent(rv.slots[0])
+            global_arr = self._global(
+                self._v_shards(rv.slots, in_len, dtype), in_len)
+        with self._phase("dev_dispatch") as ph:
+            had = len(self._programs)
+            out = self._program("alltoallv", in_len, str(dtype), "none", 0,
+                                counts)(global_arr)
+            if ph is not None:
+                ph.args["built"] = len(self._programs) > had
+        return self._per_rank(out)
 
     # -- per-call tier accounting (the observable-fallback contract) -----
     def _note_tier(self, comm, name: str, local, op: Optional[str]) -> str:
@@ -630,7 +717,7 @@ class DeviceCollChannel:
         at the kernel wrappers (programs are cached per signature).
         Returns the tier label the call will run on ('vmem'/'hbm'/
         'quant'/'xla', 'slot' on the single-device channel) — the
-        dispatch span and the dev_effbw watermark key off it."""
+        dispatch span and the lat_dev_<tier> histogram key off it."""
         if self.mesh is None:
             return "slot"   # single-device slot channel: no ICI tiers
         from .. import mpit
@@ -677,10 +764,13 @@ class DeviceCollChannel:
              root: int = 0):
         """Traced dispatch: one B/E span in the 'device' lane carrying
         tier/op/bytes/duration around the whole rendezvous+execute, the
-        per-tier dev_effbw watermark (end-to-end GB/s), and the
-        MV2T_JAX_PROFILE bracket for hardware runs. The span is what
-        makes the device path visible on the same Perfetto axis as the
-        host layers — the r5/r6 rounds tuned it blind."""
+        phase spans inside it (``_phase``), and the MV2T_JAX_PROFILE
+        bracket for hardware runs. Every span of one collective carries
+        its ``seq``: this rank's count of blocking collectives on the
+        channel, equal on every rank because MPI orders collectives.
+        While a recorder is attached the call also lies on the jax
+        profiler's host plane as a TraceAnnotation of the same name, so
+        an MV2T_JAX_PROFILE trace shows it beside the device's ops."""
         import time as _time
 
         tier = self._note_tier(comm, name, local,
@@ -688,60 +778,72 @@ class DeviceCollChannel:
         from .. import mpit
         for lv in self.LEVELS:   # which hierarchy levels this call rides
             mpit.pvar(f"coll_level_{lv}").inc()
-        n, dtype = self._slot_extent(local)
-        nbytes = int(n * dtype.itemsize)
-        tr = getattr(comm.u.engine, "tracer", None)
+        self._seq += 1
+        tr = self._tr = getattr(comm.u.engine, "tracer", None)
+        note = _NO_PHASE
         if tr is not None:
+            import jax
+            self._coll = name
             tr.record("device", f"dev_{name}", "B", tier=tier, op=op,
-                      bytes=nbytes)
+                      bytes=int((local.data if isinstance(local, _VDeposit)
+                                 else local).nbytes),
+                      seq=self._seq, coll=name)
+            note = jax.profiler.TraceAnnotation(f"dev_{name}",
+                                                seq=self._seq)
         _maybe_start_jax_profile()
         t0 = _time.perf_counter()
         try:
-            out = self._execute(name, local, op=op, root=root)
+            with note:
+                out = self._execute(name, local, op=op, root=root)
         finally:
             dt = _time.perf_counter() - t0
             if tr is not None:
                 tr.record("device", f"dev_{name}", "E", tier=tier,
-                          us=round(dt * 1e6, 3))
-        if dt > 0 and nbytes > 0:
-            from .. import mpit
-            mpit.pvar(f"dev_effbw_{tier}").mark(nbytes / dt / 1e9)
+                          us=round(dt * 1e6, 3), seq=self._seq, coll=name)
         from .. import metrics as _metrics
         mx = _metrics.LIVE
         if mx is not None:
-            # per-tier latency distribution (the watermark above keeps
-            # only the peak; quantiles need the whole shape)
+            # the rank's time in rendezvous + leader, per tier; on the
+            # mesh channel that ends at the enqueue, not at the result
             mx.rec_us(f"lat_dev_{tier}", dt * 1e6)
         return out
+
+    def _hand_back(self, out, recvbuf, *v):
+        """``_deliver`` (``_deliver_v`` given alltoallv's ``rcounts,
+        rdispls``) inside the collective's dev_deliver phase span."""
+        with self._phase("dev_deliver"):
+            if v:
+                return self._deliver_v(out, recvbuf, *v)
+            return _deliver(out, recvbuf)
 
     # -- MPI-shaped entry points (match coll_fns signatures) -------------
     def allreduce(self, comm, sendbuf, recvbuf, count, datatype, op):
         local = _as_local(sendbuf, recvbuf, count)
         out = self._run(comm, "allreduce", local, op=_op_name(op))
-        return _deliver(out, recvbuf)
+        return self._hand_back(out, recvbuf)
 
     def reduce(self, comm, sendbuf, recvbuf, count, datatype, op, root):
         local = _as_local(sendbuf, recvbuf, count)
         out = self._run(comm, "reduce", local, op=_op_name(op))
         if comm.rank != root:
             return None
-        return _deliver(out, recvbuf)
+        return self._hand_back(out, recvbuf)
 
     def bcast(self, comm, buf, count, datatype, root):
         out = self._run(comm, "bcast", _as_local(buf, buf, count),
                         root=root)
-        return _deliver(out, buf)
+        return self._hand_back(out, buf)
 
     def allgather(self, comm, sendbuf, recvbuf, count, datatype):
         local = _as_local(sendbuf, recvbuf, count,
                           in_place_start=comm.rank * count)
         out = self._run(comm, "allgather", local, op=None)
-        return _deliver(out, recvbuf)
+        return self._hand_back(out, recvbuf)
 
     def alltoall(self, comm, sendbuf, recvbuf, count, datatype):
         local = _as_local(sendbuf, recvbuf, count * comm.size)
         out = self._run(comm, "alltoall", local)
-        return _deliver(out, recvbuf)
+        return self._hand_back(out, recvbuf)
 
     def alltoallv(self, comm, sendbuf, scounts, sdispls, recvbuf,
                   rcounts, rdispls, datatype):
@@ -752,7 +854,7 @@ class DeviceCollChannel:
         on the way out."""
         dep = _VDeposit(_pack_v(sendbuf, scounts, sdispls), scounts)
         out = self._run(comm, "alltoallv", dep, op=None)
-        return self._deliver_v(out, recvbuf, rcounts, rdispls)
+        return self._hand_back(out, recvbuf, rcounts, rdispls)
 
     def _deliver_v(self, out, recvbuf, rcounts, rdispls):
         """Scatter the canonical packed device result (dense sender
@@ -760,8 +862,7 @@ class DeviceCollChannel:
         into the caller's layout."""
         rtotal = int(sum(rcounts))
         dense = _dense_displs(rcounts)
-        if recvbuf is None or is_device_array(recvbuf) \
-                or type(recvbuf).__name__ == "_InPlace":
+        if _device_resident(recvbuf):
             flat = out.reshape(-1)
             if list(rdispls) == dense:
                 return flat[:rtotal]
@@ -789,7 +890,7 @@ class DeviceCollChannel:
         local = _as_local(sendbuf, recvbuf, count * comm.size)
         out = self._run(comm, "reduce_scatter_block", local,
                         op=_op_name(op))
-        return _deliver(out, recvbuf)
+        return self._hand_back(out, recvbuf)
 
     # -- nonblocking device collectives on the NBC DAG (ISSUE 18) --------
     # The blocking path rendezvouses on a threading.Barrier; that cannot
@@ -1161,30 +1262,38 @@ class HBMSlotChannel(DeviceCollChannel):
         rv = self.rv
         R = self.size
         n, dtype = self._slot_extent(rv.slots[root])
-        if name == "bcast":
-            x = rv.slots[root]
-            x = (x.reshape(n) if is_device_array(x)
-                 else jax.device_put(
-                     np.asarray(x).reshape(n), self.device))
-        elif all(is_device_array(s) and s.devices() == {self.device}
-                 for s in rv.slots):
-            import jax.numpy as jnp
-            x = jnp.stack([s.reshape(n) for s in rv.slots])
-        else:
-            # host slots, or device arrays committed elsewhere on a
-            # multi-device host: stage everything onto the slot device
-            x = jax.device_put(
-                np.stack([np.asarray(s).reshape(n)
-                          for s in rv.slots]), self.device)
-        out = jax.block_until_ready(
-            self._program(name, n, str(dtype), op, root)(x))
-        if name == "alltoall":
-            return [out[r] for r in range(R)]
-        if name == "reduce_scatter_block":
-            c = n // R
-            return [out[r * c:(r + 1) * c] for r in range(R)]
-        # the zero-copy share: every rank gets the same array
-        return [out] * R
+        with self._phase("dev_stage"):
+            if name == "bcast":
+                x = rv.slots[root]
+                x = (x.reshape(n) if is_device_array(x)
+                     else jax.device_put(
+                         np.asarray(x).reshape(n), self.device))
+            elif all(is_device_array(s) and s.devices() == {self.device}
+                     for s in rv.slots):
+                import jax.numpy as jnp
+                x = jnp.stack([s.reshape(n) for s in rv.slots])
+            else:
+                # host slots, or device arrays committed elsewhere on a
+                # multi-device host: stage everything onto the slot
+                # device
+                x = jax.device_put(
+                    np.stack([np.asarray(s).reshape(n)
+                              for s in rv.slots]), self.device)
+        with self._phase("dev_dispatch") as ph:
+            had = len(self._programs)
+            out = self._program(name, n, str(dtype), op, root)(x)
+            if ph is not None:
+                ph.args["built"] = len(self._programs) > had
+        with self._phase("dev_device_wait"):
+            out = jax.block_until_ready(out)
+        with self._phase("dev_collect"):
+            if name == "alltoall":
+                return [out[r] for r in range(R)]
+            if name == "reduce_scatter_block":
+                c = n // R
+                return [out[r * c:(r + 1) * c] for r in range(R)]
+            # the zero-copy share: every rank gets the same array
+            return [out] * R
 
 
 class DeviceFoldChannel(DeviceCollChannel):
@@ -1298,59 +1407,58 @@ class DeviceFoldChannel(DeviceCollChannel):
         """Leader compute: fold per chip, run the mesh program over the
         folded shards, fan the chip outputs back to their ranks."""
         import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
 
         rv = self.rv
         nd, k = self.ndev, self.k
         n, dtype = self._slot_extent(rv.slots[0])
         shards, prog_root, prog_n = [], 0, n
-        if name == "bcast":
-            # only the root chip's shard matters: stage the root rank's
-            # payload there, zero-fill the rest (the mesh bcast program
-            # overwrites them)
-            prog_root = root // k
-            for j in range(nd):
-                if j == prog_root:
-                    s = rv.slots[root]
-                    s = (s.reshape(1, n) if is_device_array(s)
-                         and s.devices() == {self._mesh_devices[j]}
-                         else jax.device_put(
-                             np.asarray(s).reshape(1, n),
-                             self._mesh_devices[j]))
-                else:
-                    s = jax.device_put(np.zeros((1, n), dtype),
-                                       self._mesh_devices[j])
-                shards.append(s)
-        elif name == "allgather":
-            # chip fold is CONCATENATION: blocked rank->chip mapping
-            # makes the stacked chip payload already rank-ordered
-            prog_n = k * n
-            for j in range(nd):
-                shards.append(self._chip_stack(j, n, dtype)
-                              .reshape(1, prog_n))
-        else:   # allreduce / reduce / reduce_scatter_block
-            for j in range(nd):
-                shards.append(self._fold_chip(j, n, dtype, op)
-                              .reshape(1, n))
-        global_arr = jax.make_array_from_single_device_arrays(
-            (nd, prog_n),
-            NamedSharding(self.mesh, P(self._pspec0(), None)), shards)
-        out = self._program(name, prog_n, str(dtype), op, prog_root)(
-            global_arr)
-        per_dev: Dict = {}
-        for s in out.addressable_shards:
-            per_dev[s.device] = s.data
+        with self._phase("dev_stage"):
+            if name == "bcast":
+                # only the root chip's shard matters: stage the root
+                # rank's payload there, zero-fill the rest (the mesh
+                # bcast program overwrites them)
+                prog_root = root // k
+                for j in range(nd):
+                    if j == prog_root:
+                        s = rv.slots[root]
+                        s = (s.reshape(1, n) if is_device_array(s)
+                             and s.devices() == {self._mesh_devices[j]}
+                             else jax.device_put(
+                                 np.asarray(s).reshape(1, n),
+                                 self._mesh_devices[j]))
+                    else:
+                        s = jax.device_put(np.zeros((1, n), dtype),
+                                           self._mesh_devices[j])
+                    shards.append(s)
+            elif name == "allgather":
+                # chip fold is CONCATENATION: blocked rank->chip mapping
+                # makes the stacked chip payload already rank-ordered
+                prog_n = k * n
+                for j in range(nd):
+                    shards.append(self._chip_stack(j, n, dtype)
+                                  .reshape(1, prog_n))
+            else:   # allreduce / reduce / reduce_scatter_block
+                for j in range(nd):
+                    shards.append(self._fold_chip(j, n, dtype, op)
+                                  .reshape(1, n))
+            global_arr = self._global(shards, prog_n)
+        with self._phase("dev_dispatch") as ph:
+            had = len(self._programs)
+            out = self._program(name, prog_n, str(dtype), op, prog_root)(
+                global_arr)
+            if ph is not None:
+                ph.args["built"] = len(self._programs) > had
         if name == "reduce_scatter_block":
             # chip shard = its k ranks' contiguous blocks: slice per rank
             c = (n // nd) // k
-            res = []
-            for r in range(self.size):
-                blk = per_dev[self.devices[r]].reshape(-1)
-                s = r % k
-                res.append(blk[s * c:(s + 1) * c])
-            return res
+            with self._phase("dev_collect"):
+                per_dev = {s.device: s.data.reshape(-1)
+                           for s in out.addressable_shards}
+                return [per_dev[self.devices[r]][(r % k) * c:
+                                                 (r % k + 1) * c]
+                        for r in range(self.size)]
         # zero-copy share per chip: every rank gets its chip's shard
-        return [per_dev[self.devices[r]] for r in range(self.size)]
+        return self._per_rank(out)
 
 
 def _dense_displs(counts) -> List[int]:
@@ -1402,8 +1510,7 @@ def _deliver(out, recvbuf):
     """Write the device result into a host recvbuf (host-staged mode) or
     hand the flat device array back (device-resident mode — the comm
     methods return it to the caller)."""
-    if recvbuf is None or is_device_array(recvbuf) \
-            or type(recvbuf).__name__ == "_InPlace":
+    if _device_resident(recvbuf):
         return out.reshape(-1)
     host = np.asarray(out).reshape(-1)
     dst = np.asarray(recvbuf)

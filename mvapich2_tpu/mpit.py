@@ -90,8 +90,15 @@ class PVar:
 
     # -- instrumentation API ---------------------------------------------
     def inc(self, n: float = 1.0) -> None:
-        with self._lock:
+        # acquire/release spelled out: the hot call of every counted
+        # site, and ``with`` on a Lock costs about as much again as the
+        # rest of the call (tests/progs/trace_overhead_prog.py)
+        lock = self._lock
+        lock.acquire()
+        try:
             self._value += n
+        finally:
+            lock.release()
 
     def mark(self, v: float) -> None:
         """High-watermark update."""
@@ -467,22 +474,14 @@ pvar("dev_nbc_segments", PVAR_CLASS_COUNTER, "device",
      "is one async jitted dispatch the engine then pumps to "
      "completion)")
 
-# device-lane timing observability (ISSUE 10): per-tier effective-
-# bandwidth watermarks measured at the dispatch wrapper
-# (coll/device.py _run — wall time of the whole rendezvous+execute, so
-# the number is end-to-end, not kernel-only), plus the optional
-# hardware-profiler bracket.
+# device-lane timing observability (ISSUE 10): the optional hardware-
+# profiler bracket (the spans are coll/device.py's own).
 cvar("JAX_PROFILE", "", str, "device",
      "Directory for a jax.profiler trace bracketing the device-"
      "collective region (started at the first device collective, "
      "stopped at process exit). Empty = off. The hardware-tuning "
      "workflow for ici_chunk_bytes/ICI_PIPELINE_DEPTH on a real TPU "
      "(ROADMAP item 1) reads this trace in TensorBoard/XProf.")
-for _tier in ("vmem", "hbm", "quant", "xla", "slot"):
-    pvar(f"dev_effbw_{_tier}", PVAR_CLASS_HIGHWATERMARK, "device",
-         f"high watermark of end-to-end algorithmic bandwidth (GB/s, "
-         f"payload bytes / wall seconds) observed on the '{_tier}' "
-         "device tier at the collective dispatch wrapper")
 
 # device one-sided RMA engine knobs + tier observability (ISSUE 16:
 # ops/pallas_rma, rma/device). Same early-declaration contract; the
@@ -605,16 +604,22 @@ for _h, _d in (
     ("lat_coll_net2", "net2 node-leader-tier collective latency "
      "(coll/netcoll.py: group fold + leader bridge + fan-out, "
      "end-to-end)"),
-    ("lat_dev_vmem", "device collective latency on the VMEM flat ring "
-     "tier (coll/device.py _run end-to-end)"),
-    ("lat_dev_hbm", "device collective latency on the HBM-streaming "
-     "chunked ring tier (coll/device.py _run end-to-end)"),
-    ("lat_dev_quant", "device collective latency on the block-scaled "
-     "quantized wire tier (coll/device.py _run end-to-end)"),
-    ("lat_dev_xla", "device collective latency on the XLA lowering "
-     "(coll/device.py _run end-to-end)"),
-    ("lat_dev_slot", "device collective latency on the slot tier "
-     "(coll/device.py _run end-to-end)"),
+    ("lat_dev_vmem", "the rank's time in rendezvous + leader of a "
+     "device collective on the VMEM flat ring tier (coll/device.py "
+     "_run; ends at the enqueue on the mesh channel, not at the "
+     "result)"),
+    ("lat_dev_hbm", "the rank's time in rendezvous + leader of a "
+     "device collective on the HBM-streaming chunked ring tier "
+     "(coll/device.py _run; ends at the enqueue on the mesh channel)"),
+    ("lat_dev_quant", "the rank's time in rendezvous + leader of a "
+     "device collective on the block-scaled quantized wire tier "
+     "(coll/device.py _run; ends at the enqueue on the mesh channel)"),
+    ("lat_dev_xla", "the rank's time in rendezvous + leader of a "
+     "device collective on the XLA lowering (coll/device.py _run; "
+     "ends at the enqueue on the mesh channel)"),
+    ("lat_dev_slot", "the rank's time in rendezvous + leader of a "
+     "device collective on the slot tier (coll/device.py _run; the "
+     "slot leader waits for the device, so this includes the result)"),
     ("lat_dev_nbc", "device nonblocking-collective segment latency "
      "(coll/device.py _nb_poll: async launch to observed completion "
      "on the NBC DAG)"),

@@ -44,6 +44,15 @@ def compiler_params(**kw):
     return pltpu.CompilerParams(**kw)
 
 
+def kernel_name(kernel_fn) -> str:
+    """``name=`` of a ``pallas_call``: ``mv2t`` + the kernel function's
+    own name less its ``_kernel`` (``_hbm_all_reduce_kernel`` ->
+    ``mv2t_hbm_all_reduce``). The compiler names the custom call after
+    it, so a device trace's ``XLA Ops`` line carries the ``mv2t_`` token
+    (chipbench's kernel_us reads it) and leads back to the source."""
+    return "mv2t" + kernel_fn.__name__.removesuffix("_kernel")
+
+
 def interpret_params(**kw):
     """The TPU interpreter config every interpreted kernel runs under."""
     return pltpu.InterpretParams(**kw)

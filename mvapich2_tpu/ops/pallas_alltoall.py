@@ -70,7 +70,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ._compat import compiler_params, note_fallback
+from ._compat import compiler_params, kernel_name, note_fallback
 from .pallas_ici import (_LANES, _RingStreamer, _as_blocks,
                          _cfg_chunk_rows, _cfg_depth, _chunks, _copy,
                          _entry_barrier, _from_blocks, _resolve_flags,
@@ -312,6 +312,7 @@ def _a2a_call(blocks: jax.Array, axis_name: str, p: int, step_rows,
         compiler_params=compiler_params(collective_id=_CID_ALLTOALL,
                                         has_side_effects=True),
         interpret=interpret,
+        name=kernel_name(_hbm_alltoall_kernel),
     )(blocks)
 
 

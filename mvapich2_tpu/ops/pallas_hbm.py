@@ -113,6 +113,7 @@ def fused_reduce_to_slot(x: jax.Array, *, layout: str = "planar",
             dimension_semantics=("arbitrary",),
             has_side_effects=side_effects),
         interpret=resolve_interpret(interpret, local=True),
+        name="mv2t_slot_reduce",
     )(x)
 
 
@@ -145,6 +146,7 @@ def fused_allreduce(x: jax.Array, *, block_m: Optional[int] = None,
         compiler_params=compiler_params(
             dimension_semantics=("parallel" if parallel else "arbitrary",)),
         interpret=resolve_interpret(interpret, local=True),
+        name="mv2t_fused_allreduce",
         **kw,
     )(x)
 

@@ -69,7 +69,7 @@ from jax import lax
 
 from ..utils.config import get_config
 from ..utils.mlog import get_logger
-from ._compat import (compiler_params, note_fallback, on_tpu,
+from ._compat import (compiler_params, kernel_name, note_fallback, on_tpu,
                       resolve_interpret)
 
 log = get_logger("pallas_ici")
@@ -587,6 +587,7 @@ def _ring_call(kernel_fn, static, block_rows: int, dtype, cid: int,
         compiler_params=compiler_params(collective_id=cid,
                                         has_side_effects=True),
         interpret=interpret,
+        name=kernel_name(kernel_fn),
     )(operand)
 
 
@@ -725,6 +726,7 @@ def remote_sendrecv(x: jax.Array, axis_name: str, num_devices: int,
         compiler_params=compiler_params(collective_id=_CID_SENDRECV,
                                         has_side_effects=True),
         interpret=interpret,
+        name=kernel_name(_sendrecv_kernel),
     )(x)
 
 

@@ -58,7 +58,7 @@ import numpy as np
 from jax import lax
 
 from ..utils.mlog import get_logger
-from ._compat import compiler_params
+from ._compat import compiler_params, kernel_name
 
 log = get_logger("pallas_quant")
 
@@ -433,6 +433,7 @@ def quant_ring_all_reduce(x: jax.Array, axis_name: str,
         compiler_params=compiler_params(collective_id=_CID_QUANT_RS,
                                         has_side_effects=True),
         interpret=interpret,
+        name=kernel_name(_quant_rs_kernel),
     )(flat)
     # the final all-gather pass carries the quantized partials over the
     # UNCHANGED chunk-credit engine — int32 wire blocks are just bytes

@@ -42,7 +42,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..utils.mlog import get_logger
-from ._compat import compiler_params, note_fallback, resolve_interpret
+from ._compat import (compiler_params, kernel_name, note_fallback,
+                      resolve_interpret)
 from .pallas_ici import (_LANES, _as_blocks, _entry_barrier, _from_blocks,
                          _tile_rows)
 
@@ -151,6 +152,7 @@ def ring_all_gather(x: jax.Array, axis_name: str, num_devices: int,
         scratch_shapes=_ring_scratch(rows, x.dtype),
         compiler_params=compiler_params(collective_id=_CID_ALLGATHER),
         interpret=resolve_interpret(interpret),
+        name=kernel_name(_ring_all_gather_kernel),
     )(_as_blocks(x.reshape(m), 1, rows)[0])
     out = _from_blocks(out, m)
     return out.reshape((p * shape[0],) + shape[1:]) if shape else out
@@ -229,6 +231,7 @@ def ring_all_reduce(x: jax.Array, axis_name: str, num_devices: int,
         scratch_shapes=_ring_scratch(rows, x.dtype),
         compiler_params=compiler_params(collective_id=_CID_ALLREDUCE),
         interpret=resolve_interpret(interpret),
+        name=kernel_name(_ring_all_reduce_kernel),
     )(flat.reshape(p, rows, _LANES))
     out = out.reshape(n_pad)
     return (out[:n] if n_pad > n else out).reshape(shape)

@@ -64,7 +64,7 @@ import numpy as np
 from jax import lax
 
 from ..utils.mlog import get_logger
-from ._compat import compiler_params
+from ._compat import compiler_params, kernel_name
 
 log = get_logger("pallas_rma")
 
@@ -454,6 +454,7 @@ def _rma_call(kern_fn, static, cid: int, operands, out_like, aliases,
         compiler_params=compiler_params(collective_id=cid,
                                         has_side_effects=True),
         interpret=interpret,
+        name=kernel_name(kern_fn),
     )(*operands)
 
 
@@ -545,6 +546,7 @@ def rma_accumulate(src, win_shard, axis: str, num_devices: int,
         compiler_params=compiler_params(collective_id=_CID_ACC_QUANT,
                                         has_side_effects=True),
         interpret=interpret,
+        name=kernel_name(_acc_kernel) + "_quant",
     )(src, seg)
     return win_shard.at[disp:disp + n].set(out)
 

@@ -16,6 +16,7 @@ from __future__ import annotations
 import glob
 import json
 import os
+import statistics
 from typing import Any, Dict, List, Optional
 
 from .recorder import LAYERS
@@ -91,17 +92,25 @@ def merge_dir(trace_dir: str,
 # ---------------------------------------------------------------------------
 
 def summarize(dumps: List[Dict[str, Any]]) -> str:
-    """Text report: per (rank, layer) span time, event count, and bytes.
+    """Text report: per (rank, layer) span time, event count, and bytes;
+    under the ``device`` lane one row per span name (count, total,
+    median): the phases of a device collective, the operator's view of
+    what chipbench's phase metrics read.
 
     Span time pairs each 'E' with the most recent unmatched same-name 'B'
     in its (rank, layer) lane; a truncated ring (oldest events dropped)
-    can orphan an 'E' — those are skipped, not an error."""
+    can orphan an 'E' — those are skipped, not an error. A lane's time
+    counts its outermost spans only: a span opened inside another of its
+    lane (``dev_stage`` inside ``dev_allreduce``) is that span's time
+    once more, not more time."""
     lines = ["# trace summary (per rank, per layer)",
              f"# {'rank':>4} {'layer':<9} {'events':>8} {'span(s)':>10} "
              f"{'bytes':>12}"]
     for d in dumps:
         per: Dict[str, Dict[str, float]] = {}
         stacks: Dict[tuple, list] = {}
+        depth: Dict[str, int] = {}
+        by_name: Dict[str, List[float]] = {}     # device lane only
         for ts, layer, name, ph, args in d["events"]:
             st = per.setdefault(layer, {"n": 0, "t": 0.0, "b": 0})
             st["n"] += 1
@@ -110,14 +119,26 @@ def summarize(dumps: List[Dict[str, Any]]) -> str:
             key = (layer, name)
             if ph == "B":
                 stacks.setdefault(key, []).append(ts)
+                depth[layer] = depth.get(layer, 0) + 1
             elif ph == "E":
                 opens = stacks.get(key)
                 if opens:
-                    st["t"] += ts - opens.pop()
+                    took = ts - opens.pop()
+                    depth[layer] -= 1
+                    if depth[layer] == 0:
+                        st["t"] += took
+                    if layer == "device":
+                        by_name.setdefault(name, []).append(took)
         for layer in LAYERS:
             if layer not in per:
                 continue
             st = per[layer]
             lines.append(f"  {d['rank']:>4} {layer:<9} {int(st['n']):>8} "
                          f"{st['t']:>10.6f} {int(st['b']):>12}")
+            if layer == "device":
+                for name, took in sorted(by_name.items()):
+                    lines.append(
+                        f"       . {name:<18} x{len(took):<6} "
+                        f"{sum(took):>10.6f} s  median "
+                        f"{statistics.median(took) * 1e6:>10.1f} us")
     return "\n".join(lines)

@@ -214,11 +214,6 @@ def _device_report(u) -> list:
                 rma.append(f"{name}={v:g}")
         if rma:
             lines.append("  one-sided counters: " + " ".join(rma))
-        bws = [f"{t}={mpit.pvar(f'dev_effbw_{t}').read():.3g}"
-               for t in ("vmem", "hbm", "quant", "xla", "slot")
-               if mpit.pvar(f"dev_effbw_{t}").read()]
-        if bws:
-            lines.append("  effbw watermarks (GB/s): " + " ".join(bws))
     except Exception:
         pass
     lines.extend(device_map_lines())

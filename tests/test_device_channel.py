@@ -282,29 +282,27 @@ def test_hbm_streaming_tier_end_to_end():
 
 # -- device-lane observability (ISSUE 10) --------------------------------
 
-def test_device_dispatch_spans_and_effbw_watermark(monkeypatch):
+def test_device_dispatch_spans_and_phases(monkeypatch):
     """A traced device collective drops a B/E span in the 'device' lane
-    carrying tier/op/bytes + duration, and bumps the per-tier
-    dev_effbw_* high watermark."""
-    from mvapich2_tpu import mpit
+    carrying tier/op/bytes + duration, and inside it the phase spans of
+    the rendezvous (tests/test_device_phases.py holds their order and
+    nesting per channel)."""
     monkeypatch.setenv("MV2T_TRACE", "1")
     _reload(MV2T_DEVICE_COLL_MIN_BYTES="1")
     tiers = ("vmem", "hbm", "xla", "slot")
-    # watermarks are process-global and never decrease: reset them so
-    # an earlier device test in the same process can't mask this mark
-    for t in tiers:
-        mpit.pvar(f"dev_effbw_{t}").reset()
-    spans = []
+    device_lane = {}
 
     def app(comm):
         out = comm.allreduce(np.ones(BIG, np.float32))
         assert out[0] == comm.size
         rec = comm.u.engine.tracer
         assert rec is not None
-        spans.extend([e for e in rec.events
-                      if e[1] == "device" and e[2] == "dev_allreduce"])
+        device_lane[comm.rank] = [e for e in rec.events
+                                  if e[1] == "device"]
 
     run_ranks(N_RANKS, app, device_mesh=True)
+    spans = [e for r in device_lane for e in device_lane[r]
+             if e[2] == "dev_allreduce"]
     bs = [e for e in spans if e[3] == "B"]
     es = [e for e in spans if e[3] == "E"]
     assert bs and es
@@ -312,12 +310,13 @@ def test_device_dispatch_spans_and_effbw_watermark(monkeypatch):
     assert args["tier"] in tiers
     assert args["op"] == "sum" and args["bytes"] > 0
     assert "us" in es[0][4]
-    after = {t: mpit.pvar(f"dev_effbw_{t}").read() for t in tiers}
-    assert any(v > 0 for v in after.values()), after
-    # watermark semantics: instantaneous, never decreasing
-    hot = max(tiers, key=lambda t: after[t])
-    assert mpit.pvar(f"dev_effbw_{hot}").klass \
-        == mpit.PVAR_CLASS_HIGHWATERMARK
+    for r, events in device_lane.items():
+        names = [e[2] for e in events if e[3] == "B"]
+        assert names[0] == "dev_allreduce"
+        assert {"dev_arrive", "dev_release", "dev_deliver"} <= set(names)
+        leader_only = {"dev_stage", "dev_dispatch", "dev_collect"}
+        assert (leader_only <= set(names)) == (r == 0), (r, names)
+        assert {e[4]["seq"] for e in events if e[3] in "BE"} == {1}
 
 
 def test_jax_profile_hook_brackets_device_region(monkeypatch, tmp_path):

@@ -1,0 +1,256 @@
+"""Phase spans inside ``dev_<coll>`` (ISSUE 26): every blocking device
+collective carries a ``seq`` equal on every rank, and between its
+``dev_<coll>`` B and E the rendezvous and the leader open ``dev_arrive``,
+``dev_stage``, ``dev_dispatch``, ``dev_device_wait`` (slot channel only),
+``dev_collect`` and ``dev_release``; ``dev_deliver`` follows the E. No
+test here asserts a time: only names, order, nesting, ``seq`` and that
+every span closes, on the error paths too.
+"""
+
+import os
+import threading
+
+import jax
+import numpy as np
+import pytest
+
+from mvapich2_tpu.analysis import conform, core
+from mvapich2_tpu.analysis.events import EventCoveragePass
+from mvapich2_tpu.parallel.mesh import make_mesh
+from mvapich2_tpu.runtime.universe import run_ranks
+from mvapich2_tpu.utils.config import get_config
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+N = 4096
+
+# channel -> (ranks, devices of the mesh it binds to, class name)
+CHANNELS = {"mesh": (4, 4, "DeviceCollChannel"),
+            "slot": (4, 1, "HBMSlotChannel"),
+            "fold": (8, 4, "DeviceFoldChannel")}
+LEADER = ["dev_stage", "dev_dispatch", "dev_collect"]
+
+
+def _mesh(channel):
+    ndev = CHANNELS[channel][1]
+    return make_mesh((ndev,), ("x",), jax.devices()[:ndev])
+
+
+@pytest.fixture
+def traced(monkeypatch):
+    monkeypatch.setenv("MV2T_TRACE", "1")
+    monkeypatch.setenv("MV2T_DEVICE_COLL_MIN_BYTES", "1")
+    get_config().reload()
+    yield
+    monkeypatch.undo()
+    get_config().reload()
+
+
+def _device_lane(comm):
+    return [e for e in comm.u.engine.tracer.events if e[1] == "device"]
+
+
+def _run_two_collectives(channel):
+    """allreduce then bcast on ``channel``; every rank's device lane."""
+    ranks, _ndev, klass = CHANNELS[channel]
+    lanes = {}
+
+    def app(comm):
+        assert type(comm.device_channel).__name__ == klass
+        out = comm.allreduce(np.full(N, float(comm.rank + 1), np.float32))
+        assert np.asarray(out)[0] == ranks * (ranks + 1) / 2
+        b = np.full(N, float(comm.rank), np.float32)
+        comm.bcast(b, root=1)
+        assert b[0] == 1.0
+        lanes[comm.rank] = _device_lane(comm)
+
+    run_ranks(ranks, app, device_mesh=_mesh(channel))
+    return lanes
+
+
+def _spans(events):
+    """[(name, seq, depth, closed children's names)] in B order, from
+    one rank's device lane; raises where B and E do not nest."""
+    out, stack = [], []
+    for _t, _layer, name, ph, args in events:
+        if ph == "B":
+            rec = [name, args["seq"], len(stack), []]
+            if stack:
+                stack[-1][3].append(name)
+            stack.append(rec)
+            out.append(rec)
+        elif ph == "E":
+            assert stack and stack[-1][0] == name, (name, stack)
+            assert args["seq"] == stack.pop()[1]
+    assert not stack, stack
+    return [tuple(r) for r in out]
+
+
+def _violations(lanes):
+    events = [conform.Event(t, rank, layer, name, ph, args)
+              for rank, lane in lanes.items()
+              for t, layer, name, ph, args in lane]
+    return conform.check_events(events, options={"peer_timeout": 10.0},
+                                ranks=frozenset(lanes))
+
+
+@pytest.mark.parametrize("channel", list(CHANNELS))
+def test_phases_in_order_nested_one_seq(traced, channel):
+    lanes = _run_two_collectives(channel)
+    waits = ["dev_device_wait"] if channel == "slot" else []
+    for rank, lane in lanes.items():
+        spans = _spans(lane)
+        inside = (["dev_arrive"]
+                  + (LEADER[:2] + waits + LEADER[2:] if rank == 0 else [])
+                  + ["dev_release"])
+        tops = [(name, seq, kids) for name, seq, depth, kids in spans
+                if depth == 0]
+        assert tops == [("dev_allreduce", 1, inside), ("dev_deliver", 1, []),
+                        ("dev_bcast", 2, inside), ("dev_deliver", 2, [])], \
+            (rank, tops)
+        assert max(depth for _n, _s, depth, _k in spans) == 1
+        # a phase shares its collective's seq and names the collective
+        colls = {(a["seq"], a["coll"]) for _t, _l, _n, ph, a in lane
+                 if ph in "BE"}
+        assert colls == {(1, "allreduce"), (2, "bcast")}
+    assert _violations(lanes) == []
+
+
+@pytest.mark.parametrize("channel", list(CHANNELS))
+def test_dispatch_says_which_call_built(traced, channel):
+    """``built`` on dev_dispatch's E: true on the first call of a
+    signature, false when the program was found."""
+    ranks = CHANNELS[channel][0]
+    built = []
+
+    def app(comm):
+        x = np.ones(N, np.float32)
+        for _ in range(3):
+            comm.allreduce(x)
+        if comm.rank == 0:
+            built.extend(e[4]["built"] for e in _device_lane(comm)
+                         if e[2] == "dev_dispatch" and e[3] == "E")
+
+    run_ranks(ranks, app, device_mesh=_mesh(channel))
+    assert built == [True, False, False]
+
+
+@pytest.mark.parametrize("fault", ["leader_raises", "broken_barrier"])
+def test_error_paths_close_every_span(traced, fault):
+    """A leader that raises releases its peers and closes its spans; a
+    rank that dies instead of arriving breaks the barrier under the
+    others, whose dev_arrive and dev_allreduce still close."""
+    lanes, errors = {}, {}
+    gate = threading.Barrier(4)
+
+    def app(comm):
+        ch = comm.device_channel
+        if fault == "leader_raises" and comm.rank == 0:
+            def boom(*_a, **_k):
+                raise ValueError("seeded leader failure")
+            ch._program = boom
+        gate.wait()
+        try:
+            if fault == "broken_barrier" and comm.rank == 3:
+                ch.abort()          # dies before the rendezvous
+            else:
+                comm.allreduce(np.ones(N, np.float32))
+        except RuntimeError as e:
+            errors[comm.rank] = str(e)
+        lanes[comm.rank] = _device_lane(comm)
+
+    run_ranks(4, app, device_mesh=_mesh("mesh"))
+    if fault == "leader_raises":
+        assert sorted(errors) == [0, 1, 2, 3]
+        assert all("failed on the leader" in m for m in errors.values())
+        kids = _spans(lanes[0])[0][3]
+        assert kids == ["dev_arrive", "dev_stage", "dev_dispatch",
+                        "dev_release"]
+    else:
+        assert sorted(errors) == [0, 1, 2]
+        assert all("aborted" in m for m in errors.values())
+        assert lanes[3] == []
+        assert _spans(lanes[0])[0][3] == ["dev_arrive"]
+    for lane in lanes.values():
+        _spans(lane)                # every B has its E, properly nested
+    assert _violations(lanes) == []
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_trace_annotation_only_while_a_recorder_is_attached(
+        monkeypatch, trace):
+    """Untraced: no recorder, no phase object, no TraceAnnotation.
+    Traced: one annotation per collective, named like the span."""
+    import mvapich2_tpu.coll.device as devmod
+    if trace:
+        monkeypatch.setenv("MV2T_TRACE", "1")
+    else:
+        monkeypatch.delenv("MV2T_TRACE", raising=False)
+    monkeypatch.setenv("MV2T_DEVICE_COLL_MIN_BYTES", "1")
+    get_config().reload()
+    made = []
+    real = jax.profiler.TraceAnnotation
+
+    def counting(name, **kw):
+        made.append((name, kw))
+        return real(name, **kw)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", counting)
+    phases = []
+
+    def app(comm):
+        comm.allreduce(np.ones(N, np.float32))
+        assert (comm.u.engine.tracer is not None) == trace
+        with comm.device_channel._phase("dev_arrive") as ph:
+            phases.append(ph)
+
+    try:
+        run_ranks(4, app, device_mesh=_mesh("slot"))
+    finally:
+        monkeypatch.undo()
+        get_config().reload()
+    if trace:
+        assert sorted(made, key=str) == [("dev_allreduce", {"seq": 1})] * 4
+        assert all(isinstance(p, devmod._Phase) for p in phases)
+    else:
+        assert made == []
+        assert phases == [None] * 4     # the shared no-op was entered
+
+
+def test_summarize_counts_nested_spans_once_and_lists_the_phases():
+    """trace/perfetto.summarize (bin/mpitrace): the device lane's time is
+    its outermost spans' (3 + 1 ms here, not 3 + 2 + 1), and each span
+    name of the lane gets a row with count, total and median."""
+    from mvapich2_tpu.trace import perfetto
+    a = {"seq": 1, "coll": "allreduce"}
+    events = [[0.000, "device", "dev_allreduce", "B", dict(a, bytes=64)],
+              [0.001, "device", "dev_stage", "B", a],
+              [0.003, "device", "dev_stage", "E", a],
+              [0.003, "device", "dev_allreduce", "E", a],
+              [0.004, "device", "dev_deliver", "B", a],
+              [0.005, "device", "dev_deliver", "E", a],
+              [0.006, "device", "dev_stage", "E", a]]     # orphan: skipped
+    text = perfetto.summarize([{"rank": 0, "events": events}])
+    lane = next(ln for ln in text.splitlines() if " device " in ln)
+    assert lane.split() == ["0", "device", "7", "0.004000", "64"]
+    rows = {ln.split()[1]: ln.split()[2:] for ln in text.splitlines()
+            if ln.lstrip().startswith(". ")}
+    assert rows == {
+        "dev_allreduce": ["x1", "0.003000", "s", "median", "3000.0", "us"],
+        "dev_deliver": ["x1", "0.001000", "s", "median", "1000.0", "us"],
+        "dev_stage": ["x1", "0.002000", "s", "median", "2000.0", "us"]}
+
+
+def test_phase_names_pass_the_events_lint():
+    """The literal span names reach analysis/events.py through
+    ``_phase``'s call sites and DeviceLaneAutomaton's ``dev_*`` covers
+    each: the pass is clean on coll/device.py, and it did see them."""
+    path = os.path.join(REPO, "mvapich2_tpu", "coll", "device.py")
+    mods, errs = core.scan_paths([path])
+    assert not errs
+    assert EventCoveragePass().run(mods) == []
+    with open(path) as f:
+        src = f.read()
+    for name in ("dev_arrive", "dev_stage", "dev_dispatch",
+                 "dev_device_wait", "dev_collect", "dev_release",
+                 "dev_deliver"):
+        assert f'self._phase("{name}")' in src
+        assert conform.grammar_covers("device", name)

@@ -342,9 +342,6 @@ def test_device_category():
                "dev_coll_tier_quant", "dev_coll_quant_bytes_saved"):
         assert pv in info["pvars"], pv
         assert mpit._pvars.get(pv).klass == mpit.PVAR_CLASS_COUNTER
-    # the per-tier effbw watermark family covers the quant tier too
-    assert mpit._pvars.get("dev_effbw_quant").klass == \
-        mpit.PVAR_CLASS_HIGHWATERMARK
     # cvar surface round-trips through the indexed MPI_T view
     i = mpit.cvar_get_index("ICI_CHUNK_BYTES")
     assert mpit.cvar_get_info(i)["name"] == "ICI_CHUNK_BYTES"
