@@ -141,13 +141,13 @@ class _ExportingProgram:
         self.key = key
         self._stored = False
 
-    def __call__(self, x):
-        out = self.fn(x)
+    def __call__(self, *xs):
+        out = self.fn(*xs)
         if not self._stored:
             self._stored = True    # one export attempt per process
             from ..ops import _compat
             from ..runtime import daemon
-            blob = _compat.serialize_executable(self.fn, x)
+            blob = _compat.serialize_executable(self.fn, *xs)
             if blob is not None:
                 daemon.exec_cache_put(self.key, blob)
         return out
@@ -165,16 +165,16 @@ class _ImportedProgram:
         self.rebuild = rebuild
         self._proven = False
 
-    def __call__(self, x):
+    def __call__(self, *xs):
         if self._proven:
-            return self.fn(x)
+            return self.fn(*xs)
         try:
-            out = self.fn(x)
+            out = self.fn(*xs)
         except Exception as e:   # noqa: BLE001 — cache must not break calls
             log.warn("cached executable failed on first call (%r); "
                      "rebuilding from source", e)
             self.fn = self.rebuild()
-            out = self.fn(x)
+            out = self.fn(*xs)
         self._proven = True
         return out
 
@@ -1181,17 +1181,21 @@ class HBMSlotChannel(DeviceCollChannel):
     """All bound ranks share ONE device: collectives run through an HBM
     slot segment — the device-side analog of the reference's slotted
     shared-memory collective segment (ch3_shmem_coll.c:527-528; see
-    ops/pallas_hbm.py). Every rank deposits at the rendezvous, the
-    leader stages one planar ``(R, n)`` slot array and runs one program:
+    ops/pallas_hbm.py). Every rank deposits at the rendezvous and the
+    leader runs one program on what was deposited. Device arrays on the
+    slot device go in as they lie, ``R`` operands and no eager op; host
+    buffers are stacked on the host and staged as one ``(R, n)``
+    operand. Either way the program is:
 
       * allreduce/reduce: one fused slot-reduce pass writing the result
         ONCE; the broadcast is zero-copy (every rank's result is a view
         of the shared slot) — ``R*m`` read + ``m`` written instead of
-        the materialized ``2*R*m``.
+        the materialized ``2*R*m``. On ``R`` operands of whole 128-lane
+        rows the kernel reads each buffer where it lies.
       * allgather: the slot array *is* the result (no device compute).
       * alltoall: one transpose of the slot array.
       * reduce_scatter_block: slot-reduce, then per-rank slice views.
-      * bcast: stage the root slot only; all ranks share it.
+      * bcast: the root slot only; all ranks share it.
 
     Used when more ranks than devices are bound (the mpirun-on-one-chip
     model); the 1:1 mesh binding uses DeviceCollChannel above.
@@ -1217,6 +1221,12 @@ class HBMSlotChannel(DeviceCollChannel):
         return f"slot{self.size}x{self.device.platform}"
 
     def _build(self, name: str, n: int, op: str, root: int, extra=None):
+        """One jitted ``f(*xs)`` per signature. ``xs`` is what the
+        leader had: the ``R`` deposited ``(n,)`` arrays, or one staged
+        ``(R, n)`` array (bcast: the root's ``(n,)`` alone). The body
+        reads which from its operands; ``extra``, the leader's operand
+        count, only keeps the two forms apart in the program and
+        executable caches."""
         import jax
         import jax.numpy as jnp
 
@@ -1225,63 +1235,61 @@ class HBMSlotChannel(DeviceCollChannel):
         red = {"sum": jnp.sum, "max": jnp.max, "min": jnp.min,
                "prod": jnp.prod}[op or "sum"]
 
-        if name in ("allreduce", "reduce"):
-            if _slot_kernel_op(op):
-                def f(x):
-                    return ph.hbm_slot_allreduce(x)
-            else:
-                def f(x):
-                    return red(x, axis=0)
+        def slots(xs):      # the (R, n) slot array: the one staged
+            # operand, or the R deposited ones stacked inside the trace
+            return xs[0] if xs[0].ndim == 2 else jnp.stack(xs)
+
+        if name in ("allreduce", "reduce", "reduce_scatter_block"):
+            def f(*xs):                     # -> [n]
+                if not _slot_kernel_op(op):
+                    return red(slots(xs), axis=0)
+                if xs[0].ndim == 1 and n % 128 == 0:
+                    return ph.hbm_slot_allreduce_operands(xs)
+                return ph.hbm_slot_allreduce(slots(xs))
         elif name == "bcast":
-            def f(x):                       # staged root slot [n]
+            def f(x):                       # the root slot [n]
                 return x
         elif name == "allgather":
-            def f(x):                       # [R, n] -> [R*n], zero compute
-                return x.reshape(R * n)
+            def f(*xs):                     # -> [R*n], no compute
+                return (jnp.concatenate(xs) if xs[0].ndim == 1
+                        else xs[0].reshape(R * n))
         elif name == "alltoall":
             c = n // R
 
-            def f(x):                       # [R, n] -> [R, R, c] transpose
-                return jnp.transpose(x.reshape(R, R, c), (1, 0, 2))
-        elif name == "reduce_scatter_block":
-            if _slot_kernel_op(op):
-                def f(x):
-                    return ph.hbm_slot_allreduce(x)
-            else:
-                def f(x):
-                    return red(x, axis=0)
+            def f(*xs):                     # [R, R, c] transpose
+                return jnp.transpose(slots(xs).reshape(R, R, c), (1, 0, 2))
         else:  # pragma: no cover
             raise KeyError(name)
         return jax.jit(f)
 
     def _leader(self, name: str, op: str, root: int) -> List:
-        """Leader compute: stage the planar slot array on the one
-        device, run the program, share/scatter the result."""
+        """Leader compute: hand the program what was deposited, share/
+        scatter the result. Device arrays on the slot device are the
+        program's operands as they lie (counted: dev_slot_operands);
+        anything else is stacked on the host and staged once."""
         import jax
 
         rv = self.rv
         R = self.size
         n, dtype = self._slot_extent(rv.slots[root])
         with self._phase("dev_stage"):
-            if name == "bcast":
-                x = rv.slots[root]
-                x = (x.reshape(n) if is_device_array(x)
-                     else jax.device_put(
-                         np.asarray(x).reshape(n), self.device))
-            elif all(is_device_array(s) and s.devices() == {self.device}
-                     for s in rv.slots):
-                import jax.numpy as jnp
-                x = jnp.stack([s.reshape(n) for s in rv.slots])
+            xs = (rv.slots[root],) if name == "bcast" else tuple(rv.slots)
+            if all(is_device_array(s) and s.devices() == {self.device}
+                   for s in xs):
+                from .. import mpit
+                mpit.pvar("dev_slot_operands").inc()
             else:
                 # host slots, or device arrays committed elsewhere on a
                 # multi-device host: stage everything onto the slot
                 # device
-                x = jax.device_put(
-                    np.stack([np.asarray(s).reshape(n)
-                              for s in rv.slots]), self.device)
+                host = [np.asarray(s).reshape(n) for s in xs]
+                xs = (jax.device_put(
+                    host[0] if name == "bcast" else np.stack(host),
+                    self.device),)
         with self._phase("dev_dispatch") as ph:
             had = len(self._programs)
-            out = self._program(name, n, str(dtype), op, root)(x)
+            out = self._program(name, n, str(dtype), op, root,
+                                len(xs))(*xs)
             if ph is not None:
                 ph.args["built"] = len(self._programs) > had
         with self._phase("dev_device_wait"):

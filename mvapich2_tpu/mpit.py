@@ -451,6 +451,11 @@ pvar("dev_coll_fallback_nbc", PVAR_CLASS_COUNTER, "device",
      "slot channel) and took the host schedule instead — the NBC "
      "analog of the dev_coll_fallback_* family (coll/device.py "
      "build_nonblocking_request)")
+pvar("dev_slot_operands", PVAR_CLASS_COUNTER, "device",
+     "slot-channel leader calls that handed the program the deposited "
+     "device arrays as they lay — R operands, no stack, no staging "
+     "copy (coll/device.py HBMSlotChannel._leader); host deposits, "
+     "staged as one stacked array, do not count")
 pvar("coll_level_chip", PVAR_CLASS_COUNTER, "device",
      "collective calls that exercised the chip level of the three-"
      "level hierarchy: an HBM slot fold among co-resident ranks (the "

@@ -25,12 +25,17 @@ ceiling.
 with the result, ``2*R*m`` traffic) for callers that require private
 per-rank device outputs.
 
-Layouts: *planar* ``(R, M, 128)`` (slot r contiguous — deposits are a
-single host-side stack + one transfer) or *interleaved* ``(M, R, 128)``
-(each ``(R, 128)`` tile holds one 128-lane slice of every rank, so each
-grid block is one contiguous HBM slab). Measured on TPU v5e the two are
-within noise of each other for the reduction; planar wins end-to-end on
-staging cost and is the default.
+Layouts: *planar* ``(R, M, 128)`` (slot r contiguous) or *interleaved*
+``(M, R, 128)`` (each ``(R, 128)`` tile holds one 128-lane slice of
+every rank, so each grid block is one contiguous HBM slab). Measured on
+TPU v5e the two are within noise of each other for the reduction. A
+third form needs no slot array at all: ``hbm_slot_allreduce_operands``
+takes the ``R`` deposited ``(n,)`` buffers as ``R`` operands of the same
+kernel and reads each where it lies (a 1-D buffer and its ``(n/128,
+128)`` view hold the same bytes in the same order, so the program is
+the kernel and bitcasts). That is what HBMSlotChannel runs on
+device-resident deposits: no stack, no copy. Host deposits are one
+``np.stack`` + one transfer into the planar form.
 
 Block sizes are a measured, not guessed, crossover (the
 ``allreduce_osu.c:3015-3400`` tuned-path discipline): the tuning
@@ -41,7 +46,8 @@ defaults (autotune.py measures them).
 from __future__ import annotations
 
 import functools
-from typing import Callable, List, Optional, Tuple
+import operator
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +75,38 @@ def _pick_block(M: int, bm: int) -> int:
     return bm
 
 
+def _slot_reduce_call(operands, in_specs, terms, M: int, L: int, bm: int,
+                      scale: float, side_effects: bool, interpret):
+    """The one ``mv2t_slot_reduce`` pallas_call. ``terms(x_refs)`` gives
+    the block's ``(bm, L)`` addends out of the input refs, whatever form
+    the slots came in; they are added in that order (in f32 for
+    narrower floats, as ``jnp.sum`` accumulates), so every form of the
+    same slots returns the same bits."""
+    dtype = operands[0].dtype
+    wide = (jnp.float32 if jnp.issubdtype(dtype, jnp.floating)
+            and dtype.itemsize < 4 else dtype)
+
+    def krnl(*refs):
+        *x_refs, o_ref = refs
+        s = functools.reduce(
+            operator.add, (t.astype(wide) for t in terms(x_refs)))
+        if scale != 1.0:
+            s = s * scale
+        o_ref[...] = s.astype(o_ref.dtype)
+
+    return pl.pallas_call(
+        krnl, grid=(M // bm,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((bm, L), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((M, L), dtype),
+        compiler_params=compiler_params(
+            dimension_semantics=("arbitrary",),
+            has_side_effects=side_effects),
+        interpret=resolve_interpret(interpret, local=True),
+        name="mv2t_slot_reduce",
+    )(*operands)
+
+
 def fused_reduce_to_slot(x: jax.Array, *, layout: str = "planar",
                          block_m: Optional[int] = None,
                          mean: bool = False,
@@ -86,35 +124,19 @@ def fused_reduce_to_slot(x: jax.Array, *, layout: str = "planar",
     """
     if layout == "planar":
         R, M, L = x.shape
-        axis = 0
         in_spec = lambda bm: pl.BlockSpec((R, bm, L), lambda i: (0, i, 0))
+        terms = lambda refs: (refs[0][r] for r in range(R))
     elif layout == "interleaved":
         M, R, L = x.shape
-        axis = 1
         in_spec = lambda bm: pl.BlockSpec((bm, R, L), lambda i: (i, 0, 0))
+        terms = lambda refs: (refs[0][...].sum(axis=1),)
     else:
         raise ValueError(f"bad layout {layout!r}")
     bm = _pick_block(M, block_m or _tuned_default(
         "hbm_slot_block_m", DEFAULT_SLOT_BLOCK_M))
-    scale = (1.0 / R) if mean else 1.0
-
-    def krnl(x_ref, o_ref):
-        s = x_ref[...].sum(axis=axis)
-        if scale != 1.0:
-            s = s * scale
-        o_ref[...] = s
-
-    return pl.pallas_call(
-        krnl, grid=(M // bm,),
-        in_specs=[in_spec(bm)],
-        out_specs=pl.BlockSpec((bm, L), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((M, L), x.dtype),
-        compiler_params=compiler_params(
-            dimension_semantics=("arbitrary",),
-            has_side_effects=side_effects),
-        interpret=resolve_interpret(interpret, local=True),
-        name="mv2t_slot_reduce",
-    )(x)
+    return _slot_reduce_call(
+        (x,), [in_spec(bm)], terms, M, L, bm, (1.0 / R) if mean else 1.0,
+        side_effects, interpret)
 
 
 def fused_allreduce(x: jax.Array, *, block_m: Optional[int] = None,
@@ -175,6 +197,30 @@ def hbm_slot_allreduce(bufs: jax.Array, *, mean: bool = False,
                                layout="planar", mean=mean,
                                block_m=block_m, interpret=interpret)
     return out.reshape(npad)[:n]
+
+
+def hbm_slot_allreduce_operands(bufs: Sequence[jax.Array], *,
+                                mean: bool = False,
+                                block_m: Optional[int] = None,
+                                interpret=None) -> jax.Array:
+    """:func:`hbm_slot_allreduce` for ``R`` separate ``(n,)`` rank
+    buffers, ``n`` a multiple of 128: each is an operand of the kernel
+    under its own ``(bm, 128)`` block spec, so nothing stacks or copies
+    them first. Returns the shared ``(n,)`` result, bit-equal to the
+    stacked entry's on the same buffers."""
+    R, (n,) = len(bufs), bufs[0].shape
+    if n % 128:
+        raise ValueError(f"n={n} is not whole 128-lane rows "
+                         f"(hbm_slot_allreduce pads a stacked array)")
+    M = n // 128
+    bm = _pick_block(M, block_m or _tuned_default(
+        "hbm_slot_block_m", DEFAULT_SLOT_BLOCK_M))
+    out = _slot_reduce_call(
+        [b.reshape(M, 128) for b in bufs],
+        [pl.BlockSpec((bm, 128), lambda i: (i, 0))] * R,
+        lambda refs: (r[...] for r in refs),
+        M, 128, bm, (1.0 / R) if mean else 1.0, False, interpret)
+    return out.reshape(n)
 
 
 def pack_interleaved(bufs: jax.Array) -> jax.Array:

@@ -83,6 +83,39 @@ def test_slot_allreduce_one_chip(one_chip):
     assert "tpu_custom_call" in jax.jit(f).lower(x).compile().as_text()
 
 
+@pytest.mark.parametrize("nbytes", [4 * KiB, 64 * MiB])
+def test_slot_program_reads_operands_in_place(topo, one_chip, monkeypatch,
+                                              nbytes):
+    """The program HBMSlotChannel runs on eight device-resident
+    deposits, as its leader calls it: eight ``(n,)`` operands. Compiled
+    for the chip it is the kernel between bitcasts: no copy, concatenate
+    or fusion touches a rank's buffer on the way in (at 4 KiB the
+    compiler may prefetch one operand, an async copy of 4 KiB)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mvapich2_tpu.coll.device import HBMSlotChannel, _Rendezvous
+    from mvapich2_tpu.ops import _compat
+    # the channel's programs ask the backend whether to interpret
+    monkeypatch.setattr(_compat, "on_tpu", lambda: True)
+    n = nbytes // 4
+    ch = HBMSlotChannel(topo.devices[0], _Rendezvous(8), 0, 8)
+    x = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    text = ch._build("allreduce", n, "sum", 0).lower(
+        *[x] * 8).compile().as_text()
+    assert "tpu_custom_call" in text
+    entry = text[text.index("ENTRY"):].splitlines()[1:]
+    ops = {m.group(1) for ln in entry if "=" in ln
+           for m in [re.search(r"\s([a-z][\w-]*)\(", ln.split("=", 1)[1])]
+           if m}
+    assert {"parameter", "bitcast", "custom-call"} <= ops
+    staging = ops - {"parameter", "bitcast", "custom-call"}
+    assert staging <= ({"copy-start", "copy-done"} if nbytes == 4 * KiB
+                       else set()), staging
+
+
 _RING_SIZES = [(4 * KiB, "float32"), (1 * MiB, "float32"),
                (64 * MiB, "float32"), (1 * MiB, "bfloat16")]
 

@@ -340,3 +340,106 @@ def test_jax_profile_hook_brackets_device_region(monkeypatch, tmp_path):
     finally:
         _reload(MV2T_JAX_PROFILE=None)
         monkeypatch.setattr(devmod, "_jax_profile_started", True)
+
+
+# -- the slot channel's two operand forms (ISSUE 27) ----------------------
+# Eight ranks on ONE device: device-resident deposits are the program's
+# eight operands as they lie (dev_slot_operands counts the leader calls
+# that did so); host deposits are stacked on the host and staged as one
+# (R, n) operand. Same answers either way, one cached program per form.
+
+def _slot_mesh():
+    import jax
+
+    from mvapich2_tpu.parallel.mesh import make_mesh
+    return make_mesh((1,), ("x",), jax.devices()[:1])
+
+
+def _slot_data(c):
+    """Every rank's n = 8c whole numbers in [-2^20, 2^20] as f32: any
+    order of f32 addition over eight ranks is exact."""
+    return [np.random.default_rng([c, r]).integers(
+        -2**20, 2**20, size=N_RANKS * c, endpoint=True).astype(np.float32)
+        for r in range(N_RANKS)]
+
+
+@pytest.mark.parametrize("c", [128, 125], ids=["rows128", "odd"])
+@pytest.mark.parametrize("resident", ["device", "host"])
+def test_slot_channel_every_collective_both_forms(resident, c):
+    """allreduce sum/max, reduce, allgather, alltoall,
+    reduce_scatter_block and bcast through the MPI calls, bit-equal to
+    numpy, at n a multiple of 128 (the R-operand kernel) and not (the
+    traced stack and pad)."""
+    import jax.numpy as jnp
+
+    from mvapich2_tpu import mpit
+    from mvapich2_tpu.core import op as opmod
+    _reload(MV2T_DEVICE_COLL_MIN_BYTES="1")
+    data = _slot_data(c)
+    total = np.sum(data, axis=0)
+    buf = jnp.asarray if resident == "device" else np.array
+    keys = []
+
+    def app(comm):
+        assert type(comm.device_channel).__name__ == "HBMSlotChannel"
+        r, p = comm.rank, comm.size
+        got = lambda out: np.asarray(out).reshape(-1)
+        np.testing.assert_array_equal(
+            got(comm.allreduce(buf(data[r]))), total)
+        np.testing.assert_array_equal(
+            got(comm.allreduce(buf(data[r]), op=opmod.MAX)),
+            np.max(data, axis=0))
+        out = comm.reduce(buf(data[r]), root=3)
+        if r == 3:
+            np.testing.assert_array_equal(got(out), total)
+        np.testing.assert_array_equal(
+            got(comm.allgather(buf(data[r]))), np.concatenate(data))
+        np.testing.assert_array_equal(
+            got(comm.alltoall(buf(data[r]))),
+            np.concatenate([d[r * c:(r + 1) * c] for d in data]))
+        np.testing.assert_array_equal(
+            got(comm.reduce_scatter_block(buf(data[r]))),
+            total[r * c:(r + 1) * c])
+        np.testing.assert_array_equal(
+            got(comm.bcast(buf(data[r]), root=2)), data[2])
+        if r == 0:
+            keys.extend(comm.device_channel._programs)
+
+    before = mpit.pvar("dev_slot_operands").read()
+    run_ranks(N_RANKS, app, device_mesh=_slot_mesh())
+    rose = mpit.pvar("dev_slot_operands").read() - before
+    # one leader call per collective; bcast has one operand either way
+    assert rose == (7 if resident == "device" else 0)
+    assert len(keys) == 7
+    # the key's last field is the leader's operand count
+    assert {k[5] for k in keys if k[0] != "bcast"} == \
+        ({N_RANKS} if resident == "device" else {1})
+    assert [k[5] for k in keys if k[0] == "bcast"] == [1]
+
+
+def test_slot_program_cache_one_entry_per_form():
+    """Device and host deposits of one signature never share a program:
+    the cache key carries the operand form, and repeating either form
+    adds nothing."""
+    import jax.numpy as jnp
+
+    from mvapich2_tpu import mpit
+    _reload(MV2T_DEVICE_COLL_MIN_BYTES="1")
+    data = _slot_data(16)
+    total = np.sum(data, axis=0)
+    sizes = []
+
+    def app(comm):
+        for buf in (jnp.asarray, np.array) * 2:
+            out = comm.allreduce(buf(data[comm.rank]))
+            np.testing.assert_array_equal(np.asarray(out), total)
+            if comm.rank == 0:
+                sizes.append(len(comm.device_channel._programs))
+        if comm.rank == 0:
+            sizes.append(sorted(
+                k[5] for k in comm.device_channel._programs))
+
+    before = mpit.pvar("dev_slot_operands").read()
+    run_ranks(N_RANKS, app, device_mesh=_slot_mesh())
+    assert sizes == [1, 2, 2, 2, [1, N_RANKS]]
+    assert mpit.pvar("dev_slot_operands").read() - before == 2

@@ -60,6 +60,49 @@ def test_hbm_slot_allreduce_ragged():
                                atol=1e-4)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("mean", [False, True])
+@pytest.mark.parametrize("n", [1024, 128 * 24, 128 * 8 * 1024])
+@pytest.mark.parametrize("R", [2, 4, 8])
+def test_hbm_slot_allreduce_operands(R, n, mean, dtype):
+    """R separate (n,) buffers as R operands of the slot kernel. Whole
+    numbers small enough that every partial sum (and the mean over a
+    power of two) is exact in the dtype, so numpy must agree bit for
+    bit whatever order the kernel adds in."""
+    top = 1 << 20 if dtype == "float32" else 16
+    host = np.random.default_rng([R, n]).integers(
+        -top, top, size=(R, n), endpoint=True).astype(np.float32)
+    bufs = [jnp.asarray(h, dtype) for h in host]
+    out = jax.jit(lambda *xs: ph.hbm_slot_allreduce_operands(
+        xs, mean=mean))(*bufs)
+    assert out.shape == (n,) and out.dtype == jnp.dtype(dtype)
+    ref = host.sum(axis=0) / (R if mean else 1)
+    np.testing.assert_array_equal(np.asarray(out, np.float32), ref)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("R", [3, 8])
+def test_slot_operands_and_stacked_bit_equal(R, dtype):
+    # rounding data: the two entries share one kernel body and one
+    # order of addition, so they agree in every bit
+    n = 128 * 40
+    host = np.random.default_rng(R).normal(size=(R, n)) * 1e3
+    stacked = jnp.asarray(host, dtype)
+    a = ph.hbm_slot_allreduce(stacked)
+    b = ph.hbm_slot_allreduce_operands(list(stacked))
+    np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                  np.asarray(b, np.float32))
+    np.testing.assert_allclose(
+        np.asarray(a, np.float32),
+        np.asarray(stacked, np.float32).sum(axis=0),
+        rtol=1e-5 if dtype == "float32" else 1e-2, atol=1e-2)
+
+
+def test_slot_operands_refuse_ragged():
+    with pytest.raises(ValueError, match="128"):
+        ph.hbm_slot_allreduce_operands([jnp.zeros(1000)] * 2)
+
+
 def test_pack_unpack_roundtrip():
     R, n = 4, 512
     bufs = jnp.arange(R * n, dtype=jnp.float32).reshape(R, n)
