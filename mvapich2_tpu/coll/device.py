@@ -109,13 +109,25 @@ def _op_name(op) -> Optional[str]:
 
 
 def _dtype_lowers(dtype: np.dtype) -> bool:
-    """True when the dtype round-trips through the device unchanged.
+    """True when the dtype round-trips through the device unchanged:
+    the float, integer and unsigned kinds, bfloat16 among the floats
+    (numpy, which knows it only through ml_dtypes, says kind 'V').
     With jax x64 disabled, 64-bit types would be silently downcast —
-    wrong answers, so they stay on the host path."""
+    wrong answers, so they stay on the host path. One answer for every
+    collective of the mesh, slot and fold channels; a call it turns
+    away is counted (``_note_turned_away``)."""
     import jax
     if dtype.itemsize == 8 and not jax.config.jax_enable_x64:
         return False
-    return dtype.kind in "fiu"
+    return _kind_lowers(dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _kind_lowers(dtype: np.dtype) -> bool:
+    """The dtype's half of ``_dtype_lowers``, asked once per dtype: the
+    gate sits on every call's path, in every rank's slice."""
+    from ..ops._compat import dtype_kind
+    return dtype_kind(dtype) in "fiu"
 
 
 # -- daemon device-executable cache (ISSUE 14) --------------------------
@@ -729,11 +741,23 @@ class DeviceCollChannel:
             from ..ops import pallas_alltoall
             tier, reason = pallas_alltoall.planned_a2a_tier(
                 max(1, nbytes), dtype)
+            tr = getattr(comm.u.engine, "tracer", None)
             if reason is None:
                 mpit.pvar(f"dev_coll_tier_{tier}").inc()
+                if name == "alltoall" and not self.multi_axis:
+                    # what the kernel this call runs puts on the wire,
+                    # per rank, by the kernel module's own reckoning;
+                    # noted here, in a frame that has returned before
+                    # the leader runs (PERF.md, PR 26), under the seq
+                    # _run is about to give the call
+                    wire = pallas_alltoall.alltoall_wire_bytes(
+                        n, dtype, self.size)
+                    mpit.pvar("dev_a2a_wire_bytes").inc(wire)
+                    if tr is not None:
+                        tr.record("device", "dev_a2a_wire", "i", coll=name,
+                                  seq=self._seq + 1, wire_bytes=wire)
                 return tier
             mpit.pvar(f"dev_coll_fallback_{reason}").inc()
-            tr = getattr(comm.u.engine, "tracer", None)
             if tr is not None:
                 tr.record("channel", "dev_coll_fallback", "i", coll=name,
                           nbytes=int(nbytes), reason=reason)
@@ -1556,35 +1580,53 @@ def _select_transport(comm, name: str, nbytes: int, op, buf) -> str:
     (coll/tuning.py docstring). Note: the decision must be identical on
     every rank of the call; all inputs (msg size, op, dtype, env) are
     required-uniform by MPI except buffer residency, which therefore must
-    also be uniform across ranks (device arrays everywhere or nowhere)."""
+    also be uniform across ranks (device arrays everywhere or nowhere).
+    The dtype is asked last: a call every other gate sends to the device
+    and the dtype alone keeps off it is counted on its way to the host
+    arm (``_note_turned_away``)."""
     cfg = get_config()
     forced = cfg.get(f"{_CVAR_OF[name]}_ALGO", "")
-    lowers = ((op is None or _op_name(op) is not None)
-              and _dtype_ok(buf))
+    op_ok = op is None or _op_name(op) is not None
     if forced == "device":
-        if not lowers:
-            if name not in _warned_no_lower:    # once per collective,
-                _warned_no_lower.add(name)      # not once per call
-                log.warn("%s forced to device but op/dtype does not "
-                         "lower; using host path", name)
-            return "host"
-        return "device"
-    if forced:
+        if name not in _warned_no_lower and \
+                not (op_ok and _dtype_ok(buf)):
+            _warned_no_lower.add(name)  # once per collective, not per call
+            log.warn("%s forced to device but op/dtype does not "
+                     "lower; using host path", name)
+    elif forced or not cfg["USE_DEVICE_COLL"]:
         return "host"          # a named host algorithm wins
-    if not cfg["USE_DEVICE_COLL"] or not lowers:
-        return "host"
-    if is_device_array(buf):
-        return "device"        # already resident: never stage through host
-    if name == "alltoallv":
-        # the one size input that is NOT required-uniform: each rank
-        # keys on its own sum(scounts), and a zero-count row is legal —
-        # a size-gated decision could diverge (one rank host, peers
+    elif not is_device_array(buf) and name != "alltoallv":
+        # host buffer: crossover (autotuner-overridable). Resident
+        # buffers never stage through the host, and alltoallv has the
+        # one size input that is NOT required-uniform: each rank keys
+        # on its own sum(scounts), and a zero-count row is legal — a
+        # size-gated decision could diverge (one rank host, peers
         # device) and deadlock the rendezvous, so the v-variant always
         # takes the device path once the uniform gates pass
-        return "device"
-    # host buffer: crossover (autotuner-overridable)
-    from .tuning import device_crossover
-    return "device" if nbytes >= device_crossover(name, comm) else "host"
+        from .tuning import device_crossover
+        if nbytes < device_crossover(name, comm):
+            return "host"
+    if not op_ok:
+        return "host"
+    if not _dtype_ok(buf):
+        if hasattr(buf, "dtype"):
+            _note_turned_away(comm, name, nbytes, buf)
+        return "host"
+    return "device"
+
+
+def _note_turned_away(comm, name: str, nbytes: int, buf) -> None:
+    """A call the device path would have carried but for its dtype goes
+    to the host arm: count it (dev_coll_fallback_host_dtype) and, traced,
+    drop the instant ``_note_tier`` drops for an XLA take. Once per
+    call: ``_select_transport`` is asked once per call."""
+    from .. import mpit
+    mpit.pvar("dev_coll_fallback_host_dtype").inc()
+    tr = getattr(comm.u.engine, "tracer", None)
+    if tr is not None:
+        tr.record("channel", "dev_coll_fallback", "i", coll=name,
+                  nbytes=int(nbytes), reason="host_dtype",
+                  dtype=str(buf.dtype))
 
 
 def _dtype_ok(buf) -> bool:

@@ -425,6 +425,14 @@ pvar("dev_coll_fallback_size", PVAR_CLASS_COUNTER, "device",
 pvar("dev_coll_fallback_dtype", PVAR_CLASS_COUNTER, "device",
      "device collectives routed to the XLA lowering because the "
      "op/dtype does not lower to the ring kernels")
+pvar("dev_coll_fallback_host_dtype", PVAR_CLASS_COUNTER, "device",
+     "collective calls on a device-bound comm that every other gate "
+     "sent to the device path and the buffer's dtype alone kept off it "
+     "(64-bit without jax x64, complex, bool): they took the "
+     "host arm, device buffers staged through the host "
+     "(coll/device.py _select_transport). The transport-level sibling "
+     "of dev_coll_fallback_dtype, which counts XLA takes at the kernel "
+     "tier")
 pvar("dev_coll_fallback_shape", PVAR_CLASS_COUNTER, "device",
      "device collectives routed to the XLA lowering because of a "
      "degenerate buffer extent")
@@ -445,6 +453,13 @@ pvar("dev_coll_quant_bytes_saved", PVAR_CLASS_COUNTER, "device",
      "bytes kept off the ICI wire by the quantized tier: exact-wire "
      "minus quantized-wire accounting (ops/pallas_quant.wire_stats) "
      "summed per dispatched call at the collective wrapper")
+pvar("dev_a2a_wire_bytes", PVAR_CLASS_COUNTER, "device",
+     "bytes the pairwise alltoall kernel (ops/pallas_alltoall) sends "
+     "over ICI, per rank, summed over the calls it served: tile padding "
+     "included, the local block excluded, as the kernel module reckons "
+     "them (alltoall_wire_bytes; counted per call in coll/device.py "
+     "_note_tier, the same number as wire_bytes on the call's "
+     "dev_a2a_wire trace instant)")
 pvar("dev_coll_fallback_nbc", PVAR_CLASS_COUNTER, "device",
      "nonblocking collectives on a device-capable comm that could not "
      "route through the device tier (op/dtype/residency/size or the "

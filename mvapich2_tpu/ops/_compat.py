@@ -31,11 +31,26 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+import numpy as np
 from jax.experimental.pallas import tpu as pltpu
 
 from ..utils.mlog import get_logger
 
 log = get_logger("pallas")
+
+
+_BFLOAT16 = np.dtype(jax.numpy.bfloat16)
+
+
+def dtype_kind(dtype) -> str:
+    """numpy's kind letter for ``dtype`` as the device holds it: ``'f'``
+    for bfloat16 too, which numpy knows only through ml_dtypes and
+    reports as ``'V'``. The other ml_dtypes types keep their ``'V'``:
+    no kernel or channel here has carried them. The one answer for the
+    transport gate (coll/device._dtype_lowers) and the kernel tier gate
+    (pallas_ici.planned_tier)."""
+    dt = np.dtype(dtype)
+    return "f" if dt == _BFLOAT16 else dt.kind
 
 
 def compiler_params(**kw):

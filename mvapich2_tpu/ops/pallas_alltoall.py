@@ -340,6 +340,22 @@ def hbm_alltoall(x: jax.Array, axis_name: str, num_devices: int, *,
     return _from_blocks(out, c)
 
 
+def wire_bytes(step_rows: Sequence[int], dtype) -> int:
+    """Bytes one shard sends over ICI in one run of the kernel with
+    this step schedule: every permutation step's rows as whole 128-lane
+    tiles, pad included; step 0, the local block, is an HBM-to-HBM DMA
+    and never reaches the wire. As many bytes arrive."""
+    return sum(step_rows[1:]) * _LANES * np.dtype(dtype).itemsize
+
+
+def alltoall_wire_bytes(nelems: int, dtype, num_devices: int) -> int:
+    """``wire_bytes`` of the schedule ``hbm_alltoall`` runs on a flat
+    ``[nelems]`` send buffer: ``p - 1`` blocks of ``nelems / p``
+    elements, each rounded up to whole tiles."""
+    p = num_devices
+    return wire_bytes((_tile_rows(nelems // p, dtype),) * p, dtype)
+
+
 def packed_displs(counts: Sequence[Sequence[int]]
                   ) -> Tuple[tuple, tuple, int, int]:
     """Canonical packed layout for a count matrix: row-major send

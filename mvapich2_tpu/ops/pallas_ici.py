@@ -69,8 +69,8 @@ from jax import lax
 
 from ..utils.config import get_config
 from ..utils.mlog import get_logger
-from ._compat import (compiler_params, kernel_name, note_fallback, on_tpu,
-                      resolve_interpret)
+from ._compat import (compiler_params, dtype_kind, kernel_name,
+                      note_fallback, on_tpu, resolve_interpret)
 
 log = get_logger("pallas_ici")
 
@@ -174,7 +174,7 @@ def _pad_identity(dtype, op: str):
         return 0
     if op == "prod":
         return 1
-    if dt.kind == "f":
+    if dtype_kind(dt) == "f":
         lo = -np.inf
         hi = np.inf
     else:
@@ -763,7 +763,7 @@ def planned_tier(name: str, shard_nbytes: int, dtype, op: Optional[str],
         return "xla", "platform"
     if op is not None and op not in _SUPPORTED_OPS:
         return "xla", "dtype"
-    if np.dtype(dtype).kind not in "fiu":
+    if dtype_kind(dtype) not in "fiu":
         return "xla", "dtype"
     if shard_nbytes <= 0:
         return "xla", "shape"

@@ -196,6 +196,7 @@ def _device_report(u) -> list:
                      "dev_coll_tier_quant",
                      "dev_coll_quant_bytes_saved",
                      "dev_coll_fallback_size", "dev_coll_fallback_dtype",
+                     "dev_coll_fallback_host_dtype",
                      "dev_coll_fallback_shape",
                      "dev_coll_fallback_platform"):
             v = mpit.pvar(name).read()

@@ -1,0 +1,73 @@
+"""Plain numpy references for MPI collectives: what every rank must
+hold afterwards, computed on the host from all ranks' inputs with
+nothing but numpy. Independent of ops/ and coll/device.py (it imports
+neither), so a test may hold the device path to it bit for bit.
+
+``inputs`` is one flat array per rank, in rank order; each function
+returns one array per rank (``None`` where the rank receives nothing).
+"""
+
+from typing import List, Optional, Sequence
+
+import numpy as np
+
+_FOLD = {"sum": np.add, "max": np.maximum, "min": np.minimum,
+         "prod": np.multiply}
+
+
+def _fold(inputs: Sequence[np.ndarray], op: str) -> np.ndarray:
+    """Rank 0's buffer folded with every later rank's, rank by rank, in
+    the inputs' own dtype."""
+    total = inputs[0].copy()
+    for x in inputs[1:]:
+        total = _FOLD[op](total, x).astype(total.dtype)
+    return total
+
+
+def alltoall(inputs: Sequence[np.ndarray]) -> List[np.ndarray]:
+    """MPI_Alltoall: a send buffer is ``p`` equal blocks; rank ``r``
+    receives, in sender order, block ``r`` of every sender's buffer."""
+    p = len(inputs)
+    c = inputs[0].size // p
+    return [np.concatenate([x[r * c:(r + 1) * c] for x in inputs])
+            for r in range(p)]
+
+
+def alltoallv(inputs: Sequence[np.ndarray],
+              counts: Sequence[Sequence[int]]) -> List[np.ndarray]:
+    """MPI_Alltoallv with dense displacements on both sides:
+    ``counts[s][r]`` elements go from sender ``s`` to rank ``r``."""
+    p = len(inputs)
+    starts = [np.concatenate([[0], np.cumsum(counts[s])]) for s in range(p)]
+    return [np.concatenate([inputs[s][starts[s][r]:starts[s][r + 1]]
+                            for s in range(p)])
+            for r in range(p)]
+
+
+def allreduce(inputs: Sequence[np.ndarray], op: str = "sum"
+              ) -> List[np.ndarray]:
+    total = _fold(inputs, op)
+    return [total] * len(inputs)
+
+
+def reduce(inputs: Sequence[np.ndarray], root: int, op: str = "sum"
+           ) -> List[Optional[np.ndarray]]:
+    total = _fold(inputs, op)
+    return [total if r == root else None for r in range(len(inputs))]
+
+
+def bcast(inputs: Sequence[np.ndarray], root: int) -> List[np.ndarray]:
+    return [inputs[root]] * len(inputs)
+
+
+def allgather(inputs: Sequence[np.ndarray]) -> List[np.ndarray]:
+    return [np.concatenate(list(inputs))] * len(inputs)
+
+
+def reduce_scatter_block(inputs: Sequence[np.ndarray], op: str = "sum"
+                         ) -> List[np.ndarray]:
+    """Rank ``r`` keeps block ``r`` of the folded buffer."""
+    p = len(inputs)
+    total = _fold(inputs, op)
+    c = total.size // p
+    return [total[r * c:(r + 1) * c] for r in range(p)]
