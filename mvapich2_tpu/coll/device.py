@@ -391,7 +391,7 @@ class DeviceCollChannel:
         if not daemon.exec_cache_enabled():
             return self._build(name, n, op, root, extra)
         from ..ops import _compat
-        ck = "|".join(("mv2t-exec-v1", self._chan_desc(), name,
+        ck = "|".join(("mv2t-exec-v2", self._chan_desc(), name,
                        f"n{n}", dtype_str, f"op:{op}", f"root:{root}",
                        f"x:{extra!r}", _compat.exec_fingerprint()))
         blob = daemon.exec_cache_get(ck)
@@ -412,63 +412,58 @@ class DeviceCollChannel:
         from ..parallel.mesh import shard_map
         axis, p = self.axis, self._mesh_extent()
 
+        # every f takes its rank's flat [n] block as deposited and
+        # returns a flat block: the kernel wrappers speak flat arrays
         if name in ("allreduce", "reduce"):
-            def f(x):                       # block [1, n]
+            def f(x):
                 # tier dispatch: VMEM flat ring / HBM-streaming chunked
                 # ring / XLA, by shard bytes (coll/tuning.device_tier)
                 from ..ops import pallas_ici
-                return pallas_ici.ici_all_reduce(
-                    x.reshape(-1), axis, p, op=op).reshape(1, -1)
-            out_specs = P(None, None)       # replicated [1, n]
+                return pallas_ici.ici_all_reduce(x, axis, p, op=op)
+            out_specs = P(None)             # replicated [n]
         elif name == "bcast":
             def f(x):
                 return ops.bcast(x, axis, root)
-            out_specs = P(None, None)
+            out_specs = P(None)
         elif name == "allgather":
             def f(x):
                 from ..ops import pallas_ici
-                return pallas_ici.ici_all_gather(
-                    x.reshape(-1), axis, p).reshape(p, -1)
-            out_specs = P(None, None)       # replicated [p, n]
+                return pallas_ici.ici_all_gather(x, axis, p)
+            out_specs = P(None)             # replicated [p*n]
         elif name == "alltoall":
-            c = n // p
-
-            def f(x):                       # block [1, n] -> [p, c]
+            def f(x):                       # [p*c] -> [p*c]
                 # tier dispatch: chunked HBM remote-DMA pairwise streamer
                 # or the XLA lowering (ops/pallas_alltoall)
                 from ..ops import pallas_alltoall
-                return pallas_alltoall.ici_all_to_all(
-                    x.reshape(-1), axis, p).reshape(p, c)
-            out_specs = P(axis, None)       # global [p*p, c]
+                return pallas_alltoall.ici_all_to_all(x, axis, p)
+            out_specs = P(axis)             # global [p*n]
         elif name == "alltoallv":
             counts = extra                  # static p x p matrix
 
-            def f(x):                       # block [1, in_len] -> [1, out]
+            def f(x):                       # [in_len] -> [out_len]
                 from ..ops import pallas_alltoall
-                return pallas_alltoall.ici_all_to_allv(
-                    x.reshape(-1), axis, p, counts).reshape(1, -1)
-            out_specs = P(axis, None)       # global [p, out_len]
+                return pallas_alltoall.ici_all_to_allv(x, axis, p, counts)
+            out_specs = P(axis)             # global [p*out_len]
         elif name == "reduce_scatter_block":
             c = n // p
             if op == "sum":
                 def f(x):
-                    y = ops.reduce_scatter(x.reshape(n), axis,
-                                           scatter_dimension=0, tiled=True)
-                    return y.reshape(1, c)
+                    return ops.reduce_scatter(x, axis, scatter_dimension=0,
+                                              tiled=True)
             else:
                 # non-sum ops: full allreduce then keep this shard's block
                 # (psum_scatter lowers natively only for sum)
                 from jax import lax
 
                 def f(x):
-                    y = ops.allreduce(x.reshape(n), axis, op)
-                    i = lax.axis_index(axis)
-                    return lax.dynamic_slice(y, (i * c,), (c,)).reshape(1, c)
-            out_specs = P(axis, None)       # global [p, c]
+                    y = ops.allreduce(x, axis, op)
+                    return lax.dynamic_slice(
+                        y, (lax.axis_index(axis) * c,), (c,))
+            out_specs = P(axis)             # global [p*c]
         else:  # pragma: no cover
             raise KeyError(name)
 
-        sm = shard_map(f, mesh=self.mesh, in_specs=(P(axis, None),),
+        sm = shard_map(f, mesh=self.mesh, in_specs=(P(axis),),
                        out_specs=out_specs, check_vma=False)
         return jax.jit(sm)
 
@@ -501,11 +496,10 @@ class DeviceCollChannel:
         spec0 = self._pspec0()
 
         if name in ("allreduce", "reduce"):
-            def f(x):                       # block [1, n]
+            def f(x):                       # flat blocks, as in _build
                 from ..ops import pallas_ici
-                return pallas_ici.ici_all_reduce_mesh(
-                    x.reshape(-1), sizes, op=op).reshape(1, -1)
-            out_specs = P(None, None)       # replicated [1, n]
+                return pallas_ici.ici_all_reduce_mesh(x, sizes, op=op)
+            out_specs = P(None)             # replicated [n]
         elif name == "bcast":
             # root's per-axis coordinates, innermost phase first: after
             # axis k's bcast the root's whole k-line carries the payload
@@ -519,34 +513,34 @@ class DeviceCollChannel:
                 for a, c in reversed(tuple(zip(axes, coords))):
                     x = ops.bcast(x, a, c)
                 return x
-            out_specs = P(None, None)
+            out_specs = P(None)
         elif name == "allgather":
             def f(x):
                 from ..ops import pallas_ici
-                return pallas_ici.ici_all_gather_mesh(
-                    x.reshape(-1), sizes).reshape(p, -1)
-            out_specs = P(None, None)       # replicated [p, n]
+                return pallas_ici.ici_all_gather_mesh(x, sizes)
+            out_specs = P(None)             # replicated [p*n]
         elif name == "alltoall":
             c = n // p
 
-            def f(x):                       # block [1, n] -> [p, c]
-                y = lax.all_to_all(x.reshape(p, c), axes, split_axis=0,
-                                   concat_axis=0, tiled=False)
-                return y.reshape(p, c)
-            out_specs = P(spec0, None)      # global [p*p, c]
+            def f(x):                       # [p*c] -> [p*c]
+                return lax.all_to_all(x.reshape(p, c), axes, split_axis=0,
+                                      concat_axis=0,
+                                      tiled=False).reshape(-1)
+            out_specs = P(spec0)            # global [p*n]
         elif name == "alltoallv":
             counts = extra                  # static p x p matrix
             from ..ops.pallas_alltoall import packed_displs
             sdisp, rdisp, in_len, out_len = packed_displs(counts)
 
-            def f(x):                       # block [1, in_len] -> [1, out]
+            def f(x):                       # [in_len] -> [out_len]
                 # gather every rank's packed payload, then assemble ALL
                 # receive rows statically (counts are static) and keep
                 # this rank's — O(p) memory, but structurally correct on
                 # any torus shape
-                g = x.reshape(1, in_len)
+                g = x
                 for a in reversed(axes):
-                    g = lax.all_gather(g, a, tiled=True, axis=0)
+                    g = lax.all_gather(g, a, tiled=True)
+                g = g.reshape(p, in_len)
                 rows = []
                 for dst in range(p):
                     parts = [lax.slice_in_dim(
@@ -561,22 +555,17 @@ class DeviceCollChannel:
                     rows.append(row)
                 me = self._flat_rank()
                 return lax.dynamic_index_in_dim(
-                    jnp.stack(rows), me, axis=0,
-                    keepdims=True).reshape(1, -1)
-            out_specs = P(spec0, None)      # global [p, out_len]
+                    jnp.stack(rows), me, axis=0, keepdims=False)
+            out_specs = P(spec0)            # global [p*out_len]
         elif name == "reduce_scatter_block":
-            c = n // p
-
             def f(x):
                 from ..ops import pallas_ici
-                y = pallas_ici.ici_reduce_scatter_mesh(
-                    x.reshape(n), sizes, op=op)
-                return y.reshape(1, c)
-            out_specs = P(spec0, None)      # global [p, c]
+                return pallas_ici.ici_reduce_scatter_mesh(x, sizes, op=op)
+            out_specs = P(spec0)            # global [n]
         else:  # pragma: no cover
             raise KeyError(name)
 
-        sm = shard_map(f, mesh=self.mesh, in_specs=(P(spec0, None),),
+        sm = shard_map(f, mesh=self.mesh, in_specs=(P(spec0),),
                        out_specs=out_specs, check_vma=False)
         return jax.jit(sm)
 
@@ -634,25 +623,15 @@ class DeviceCollChannel:
         return res
 
     def _leader(self, name: str, op: str, root: int) -> List:
-        """Leader compute: assemble the mesh-sharded global array, run
-        the jitted shard_map program, scatter output shards per rank."""
-        import jax
-
+        """Leader compute: the deposited flat arrays are the shards of
+        the mesh-sharded global array, the jitted shard_map program runs
+        on it, every rank gets its own device's shard of the output."""
         rv = self.rv
         if name == "alltoallv":
             return self._leader_v()
         n, dtype = self._slot_extent(rv.slots[0])
         with self._phase("dev_stage"):
-            shards = []
-            for r in range(self.size):
-                s = rv.slots[r]
-                if is_device_array(s) and \
-                        s.devices() == {self.devices[r]}:
-                    shards.append(s.reshape(1, n))
-                else:
-                    shards.append(jax.device_put(
-                        np.asarray(s).reshape(1, n), self.devices[r]))
-            global_arr = self._global(shards, n)
+            global_arr = self._global(self._shards(rv.slots), n)
         # spelled out in each leader, not a helper: a frame more under
         # the program's first call moved its lowering from 20 s to 44 s
         # on the chip's host (PERF.md, PR 26)
@@ -664,13 +643,13 @@ class DeviceCollChannel:
         return self._per_rank(out)
 
     def _global(self, shards: List, n: int):
-        """The mesh-sharded ``[len(shards), n]`` program input over one
-        ``[1, n]`` shard per mesh device."""
+        """The mesh-sharded flat ``[len(shards) * n]`` program input over
+        one ``[n]`` shard per mesh device; the shards are not copied."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
         return jax.make_array_from_single_device_arrays(
-            (len(shards), n),
-            NamedSharding(self.mesh, P(self._pspec0(), None)), shards)
+            (len(shards) * n,),
+            NamedSharding(self.mesh, P(self._pspec0())), shards)
 
     def _per_rank(self, out) -> List:
         """Each rank's own device's shard of the program's output."""
@@ -678,25 +657,33 @@ class DeviceCollChannel:
             per_dev = {s.device: s.data for s in out.addressable_shards}
             return [per_dev[self.devices[r]] for r in range(self.size)]
 
-    def _v_shards(self, slots, in_len: int, dtype) -> List:
-        """Per-rank device shards for an alltoallv call: each rank's
-        dense packed payload padded to the mesh-wide ``in_len`` (the
-        shard_map shapes must be uniform)."""
+    def _shards(self, slots, pad_to: int = 0) -> List:
+        """One flat ``[n]`` array per rank's device out of what the
+        ranks deposited. A device array on its rank's own device goes in
+        as it lies: no reshape, no eager op, no copy. A host buffer (or
+        an array committed elsewhere) is put there from the host; an
+        alltoallv payload shorter than the mesh-wide ``pad_to`` is
+        padded (the shard_map shapes must be uniform). A call in which
+        every deposit lay counts (dev_mesh_operands)."""
         import jax
-        shards = []
-        for r in range(self.size):
-            d = slots[r].data
-            if is_device_array(d) and d.devices() == {self.devices[r]}:
-                import jax.numpy as jnp
-                v = d.reshape(-1)
-                if int(v.size) < in_len:
-                    v = jnp.pad(v, (0, in_len - int(v.size)))
-                shards.append(v.reshape(1, in_len))
+        shards, lay = [], True
+        for r, dep in enumerate(slots):
+            s = dep = dep.data if isinstance(dep, _VDeposit) else dep
+            short = max(0, pad_to - int(s.size))
+            if is_device_array(s) and s.devices() == {self.devices[r]}:
+                if short:
+                    import jax.numpy as jnp
+                    s = jnp.pad(s, (0, short))
             else:
-                buf = np.zeros((1, in_len), dtype)
-                a = np.asarray(d).reshape(-1)
-                buf[0, :a.size] = a
-                shards.append(jax.device_put(buf, self.devices[r]))
+                s = np.asarray(s).reshape(-1)   # a view
+                if short:
+                    s = np.pad(s, (0, short))
+                s = jax.device_put(s, self.devices[r])
+            lay = lay and s is dep
+            shards.append(s)
+        if lay:
+            from .. import mpit
+            mpit.pvar("dev_mesh_operands").inc()
         return shards
 
     def _leader_v(self) -> List:
@@ -710,8 +697,8 @@ class DeviceCollChannel:
             counts = tuple(tuple(s.scounts) for s in rv.slots)
             _, _, in_len, _ = packed_displs(counts)
             _, dtype = self._slot_extent(rv.slots[0])
-            global_arr = self._global(
-                self._v_shards(rv.slots, in_len, dtype), in_len)
+            global_arr = self._global(self._shards(rv.slots, in_len),
+                                      in_len)
         with self._phase("dev_dispatch") as ph:
             had = len(self._programs)
             out = self._program("alltoallv", in_len, str(dtype), "none", 0,
@@ -887,12 +874,11 @@ class DeviceCollChannel:
         rtotal = int(sum(rcounts))
         dense = _dense_displs(rcounts)
         if _device_resident(recvbuf):
-            flat = out.reshape(-1)
             if list(rdispls) == dense:
-                return flat[:rtotal]
+                return out[:rtotal]
             # non-dense user layout: assemble on the host, push back
             import jax
-            host = np.asarray(flat)
+            host = np.asarray(out)
             ext = max((rdispls[j] + rcounts[j]
                        for j in range(len(rcounts))), default=0)
             dst = np.zeros(ext, host.dtype)
@@ -1040,7 +1026,7 @@ class DeviceCollChannel:
                 if rec is None:
                     rec = rv.nb_calls[seq] = {
                         "slots": [None] * self.size, "arrived": 0,
-                        "shards": None, "counts": None,
+                        "shards": None,
                         "outs": [None] * len(segs),
                         "t0": [None] * len(segs),
                         "landed": [False] * len(segs),
@@ -1118,38 +1104,21 @@ class DeviceCollChannel:
         """Dispatch one program segment (under nb_lock, by whichever
         rank's poll got there first). Staging happens once per call;
         segment launches are plain async jit dispatches."""
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
         if name == "alltoallv":
             from ..ops.pallas_alltoall import packed_displs
             counts = tuple(tuple(s.scounts) for s in rec["slots"])
             _, _, in_len, _ = packed_displs(counts)
-            rec["counts"] = counts
-            shards = self._v_shards(rec["slots"], in_len, dtype)
-            global_arr = jax.make_array_from_single_device_arrays(
-                (self.size, in_len),
-                NamedSharding(self.mesh, P(self._pspec0(), None)), shards)
-            return self._program("alltoallv", in_len, str(dtype), "none",
-                                 0, counts)(global_arr)
+            return self._program(
+                "alltoallv", in_len, str(dtype), "none", 0, counts)(
+                    self._global(self._shards(rec["slots"], in_len),
+                                 in_len))
         if rec["shards"] is None:
-            shards = []
-            for r in range(self.size):
-                s = rec["slots"][r]
-                if is_device_array(s) and \
-                        s.devices() == {self.devices[r]}:
-                    shards.append(s.reshape(1, -1))
-                else:
-                    shards.append(jax.device_put(
-                        np.asarray(s).reshape(1, -1), self.devices[r]))
-            rec["shards"] = shards
+            rec["shards"] = self._shards(rec["slots"])
         shards = rec["shards"]
-        n = int(shards[0].shape[1])
-        seg = shards if (off, ln) == (0, n) else \
-            [s[:, off:off + ln] for s in shards]
-        global_arr = jax.make_array_from_single_device_arrays(
-            (self.size, ln),
-            NamedSharding(self.mesh, P(self._pspec0(), None)), seg)
-        return self._program(name, ln, str(dtype), op, root)(global_arr)
+        seg = shards if (off, ln) == (0, int(shards[0].size)) else \
+            [s[off:off + ln] for s in shards]
+        return self._program(name, ln, str(dtype), op, root)(
+            self._global(seg, ln))
 
     def _nb_finish(self, name: str, seq: int, recvbuf, rcounts,
                    rdispls) -> None:
@@ -1453,13 +1422,12 @@ class DeviceFoldChannel(DeviceCollChannel):
                 for j in range(nd):
                     if j == prog_root:
                         s = rv.slots[root]
-                        s = (s.reshape(1, n) if is_device_array(s)
-                             and s.devices() == {self._mesh_devices[j]}
-                             else jax.device_put(
-                                 np.asarray(s).reshape(1, n),
-                                 self._mesh_devices[j]))
+                        if not (is_device_array(s) and
+                                s.devices() == {self._mesh_devices[j]}):
+                            s = jax.device_put(np.asarray(s).reshape(-1),
+                                               self._mesh_devices[j])
                     else:
-                        s = jax.device_put(np.zeros((1, n), dtype),
+                        s = jax.device_put(np.zeros(n, dtype),
                                            self._mesh_devices[j])
                     shards.append(s)
             elif name == "allgather":
@@ -1468,11 +1436,10 @@ class DeviceFoldChannel(DeviceCollChannel):
                 prog_n = k * n
                 for j in range(nd):
                     shards.append(self._chip_stack(j, n, dtype)
-                                  .reshape(1, prog_n))
+                                  .reshape(prog_n))
             else:   # allreduce / reduce / reduce_scatter_block
                 for j in range(nd):
-                    shards.append(self._fold_chip(j, n, dtype, op)
-                                  .reshape(1, n))
+                    shards.append(self._fold_chip(j, n, dtype, op))
             global_arr = self._global(shards, prog_n)
         with self._phase("dev_dispatch") as ph:
             had = len(self._programs)
@@ -1484,7 +1451,7 @@ class DeviceFoldChannel(DeviceCollChannel):
             # chip shard = its k ranks' contiguous blocks: slice per rank
             c = (n // nd) // k
             with self._phase("dev_collect"):
-                per_dev = {s.device: s.data.reshape(-1)
+                per_dev = {s.device: s.data
                            for s in out.addressable_shards}
                 return [per_dev[self.devices[r]][(r % k) * c:
                                                  (r % k + 1) * c]
@@ -1541,9 +1508,10 @@ def _as_local(sendbuf, recvbuf, count: int, in_place_start: int = 0):
 def _deliver(out, recvbuf):
     """Write the device result into a host recvbuf (host-staged mode) or
     hand the flat device array back (device-resident mode — the comm
-    methods return it to the caller)."""
+    methods return it to the caller; a flat result, which is every mesh
+    and fold program's, is handed back as the object it is)."""
     if _device_resident(recvbuf):
-        return out.reshape(-1)
+        return out if out.ndim == 1 else out.reshape(-1)
     host = np.asarray(out).reshape(-1)
     dst = np.asarray(recvbuf)
     if dst.size == host.size:

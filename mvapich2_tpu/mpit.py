@@ -471,6 +471,13 @@ pvar("dev_slot_operands", PVAR_CLASS_COUNTER, "device",
      "device arrays as they lay — R operands, no stack, no staging "
      "copy (coll/device.py HBMSlotChannel._leader); host deposits, "
      "staged as one stacked array, do not count")
+pvar("dev_mesh_operands", PVAR_CLASS_COUNTER, "device",
+     "mesh-channel leader calls (blocking, alltoallv, nonblocking) in "
+     "which every rank's deposit entered the mesh-sharded global array "
+     "as it lay: a flat device array on the rank's own device, no "
+     "reshape, no eager op, no copy (coll/device.py DeviceCollChannel."
+     "_shards); a call with a host deposit or a padded alltoallv "
+     "payload among its ranks does not count")
 pvar("coll_level_chip", PVAR_CLASS_COUNTER, "device",
      "collective calls that exercised the chip level of the three-"
      "level hierarchy: an HBM slot fold among co-resident ranks (the "

@@ -70,6 +70,17 @@ def _compile_sharded(mesh4, kernel, nelems, dtype):
     return jax.jit(sm).lower(x).compile().as_text()
 
 
+def _entry_ops(text):
+    """``(op, dims)`` of every instruction of the compiled module's
+    ENTRY computation: the HLO op's name and its result's dimensions as
+    a list of strings (empty for a scalar or a tuple)."""
+    import re
+    line = re.compile(r"[^=]*=\s*(?:\w+\[([\d,]*)\])?\S*\s([a-z][\w-]*)\(")
+    return [(m.group(2), [d for d in (m.group(1) or "").split(",") if d])
+            for m in map(line.match,
+                         text[text.index("ENTRY"):].splitlines()[1:]) if m]
+
+
 def test_slot_allreduce_one_chip(one_chip):
     """The HBMSlotChannel kernel at chip_smoke's size: 8 ranks x 64 MiB
     f32 co-resident on one chip."""
@@ -91,8 +102,6 @@ def test_slot_program_reads_operands_in_place(topo, one_chip, monkeypatch,
     for the chip it is the kernel between bitcasts: no copy, concatenate
     or fusion touches a rank's buffer on the way in (at 4 KiB the
     compiler may prefetch one operand, an async copy of 4 KiB)."""
-    import re
-
     import jax
     import jax.numpy as jnp
 
@@ -106,14 +115,49 @@ def test_slot_program_reads_operands_in_place(topo, one_chip, monkeypatch,
     text = ch._build("allreduce", n, "sum", 0).lower(
         *[x] * 8).compile().as_text()
     assert "tpu_custom_call" in text
-    entry = text[text.index("ENTRY"):].splitlines()[1:]
-    ops = {m.group(1) for ln in entry if "=" in ln
-           for m in [re.search(r"\s([a-z][\w-]*)\(", ln.split("=", 1)[1])]
-           if m}
+    ops = {op for op, _ in _entry_ops(text)}
     assert {"parameter", "bitcast", "custom-call"} <= ops
     staging = ops - {"parameter", "bitcast", "custom-call"}
     assert staging <= ({"copy-start", "copy-done"} if nbytes == 4 * KiB
                        else set()), staging
+
+
+@pytest.mark.parametrize("coll,dtype", [
+    ("alltoall", "bfloat16"), ("alltoall", "float32"),
+    ("allreduce", "float32"), ("allgather", "float32")])
+def test_mesh_program_is_the_kernel_between_bitcasts(mesh4, monkeypatch,
+                                                     coll, dtype):
+    """The program DeviceCollChannel runs on four device-resident
+    deposits, as its leader calls it: one flat global ``(4 * n,)``
+    operand sharded over ``x``, 4 MiB a rank. Compiled for the chips it
+    is the kernel between bitcasts and at most one copy (the result out
+    of the memory space the compiler keeps a cross-chip op's output in):
+    nothing relays the payload out on the way in or out, and no shape
+    with a leading 1 exists. Every case fails on the parent of PR 29,
+    whose program takes a ``(4, n)`` operand; fed that, its bfloat16
+    alltoall holds a ``reduce`` of the ``bf16[1, n]`` block before the
+    kernel and a ``copy_bitcast_fusion`` to ``(p, c)`` after it (on the
+    chip, at 192 MiB, 5.9 ms of relayouts around a 3.4 ms kernel)."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from mvapich2_tpu.coll.device import DeviceCollChannel, _Rendezvous
+    from mvapich2_tpu.ops import _compat, pallas_ici
+    # the tier dispatch asks the backend whether the kernels can run
+    monkeypatch.setattr(_compat, "on_tpu", lambda: True)
+    monkeypatch.setattr(pallas_ici, "on_tpu", lambda: True)
+    dt = np.dtype(dtype)
+    n = 4 * MiB // dt.itemsize
+    ch = DeviceCollChannel(mesh4, "x", _Rendezvous(P4), 0)
+    x = jax.ShapeDtypeStruct((P4 * n,), dt,
+                             sharding=NamedSharding(mesh4, P("x")))
+    text = ch._build(coll, n, "sum", 0).lower(x).compile().as_text()
+    assert "tpu_custom_call" in text and "mv2t_" in text
+    entry = _entry_ops(text)
+    ops = [op for op, _ in entry]
+    assert set(ops) <= {"parameter", "bitcast", "custom-call", "copy"}, ops
+    assert ops.count("custom-call") == 1 and ops.count("copy") <= 1, ops
+    assert not [dims for _, dims in entry if dims[:1] == ["1"]], entry
 
 
 _RING_SIZES = [(4 * KiB, "float32"), (1 * MiB, "float32"),

@@ -298,6 +298,43 @@ def test_exec_cache_device_build_roundtrip(ddir):
     _reload(MV2T_DAEMON_DIR=None, MV2T_ALLREDUCE_ALGO=None)
 
 
+def test_exec_cache_parent_artifact_is_never_offered(ddir, monkeypatch):
+    """The mesh program's signature changed under an unchanged (name, n,
+    dtype, op, root) when its operand became flat (ISSUE 29), so the key
+    moved from ``mv2t-exec-v1`` to ``v2``: an artifact a parent of that
+    change exported on this machine is never asked for and never
+    deserialized, whatever else of its key matches."""
+    import numpy as np
+
+    from mvapich2_tpu.ops import _compat
+    from mvapich2_tpu.runtime.universe import run_ranks
+    _reload(MV2T_DAEMON="1", MV2T_DAEMON_DIR=ddir,
+            MV2T_DAEMON_EXEC_CACHE="1", MV2T_ALLREDUCE_ALGO="device")
+    asked, offered = [], []
+    get, load = daemon.exec_cache_get, _compat.deserialize_executable
+    monkeypatch.setattr(daemon, "exec_cache_get",
+                        lambda k, *a: asked.append(k) or get(k, *a))
+    monkeypatch.setattr(_compat, "deserialize_executable",
+                        lambda b: offered.append(b) or load(b))
+
+    def app(comm):
+        out = comm.allreduce(np.full(16384, float(comm.rank + 1),
+                                     np.float32))
+        assert out[0] == sum(range(1, comm.size + 1))
+
+    run_ranks(4, app, device_mesh=True)
+    assert asked and all(k.startswith("mv2t-exec-v2|") for k in asked)
+    poison = b"artifact of the (1, n) program"
+    for k in set(asked):    # the parent's key for the same signature
+        assert daemon.exec_cache_put(
+            k.replace("mv2t-exec-v2|", "mv2t-exec-v1|", 1), poison, ddir)
+    del asked[:]
+    run_ranks(4, app, device_mesh=True)     # fresh channels ask again
+    assert asked and all(k.startswith("mv2t-exec-v2|") for k in asked)
+    assert poison not in offered
+    _reload(MV2T_DAEMON_DIR=None, MV2T_ALLREDUCE_ALGO=None)
+
+
 # -- listener handoff ----------------------------------------------------
 
 def test_take_listener_scm_rights(ddir):

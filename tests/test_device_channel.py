@@ -443,3 +443,127 @@ def test_slot_program_cache_one_entry_per_form():
     run_ranks(N_RANKS, app, device_mesh=_slot_mesh())
     assert sizes == [1, 2, 2, 2, [1, N_RANKS]]
     assert mpit.pvar("dev_slot_operands").read() - before == 2
+
+
+# -- the mesh channel's flat form, from deposit to delivery (ISSUE 29) ----
+# Four ranks 1:1 on four devices: a device-resident deposit is its
+# device's shard of the program's global operand as it lies (same
+# buffer; dev_mesh_operands counts the leader calls in which all four
+# were), the program's result is flat, and _deliver hands a
+# device-resident caller the very object the leader collected.
+
+MESH_RANKS = 4
+_MESH_COLLS = ["allreduce", "reduce", "bcast", "allgather", "alltoall",
+               "alltoallv", "reduce_scatter_block"]
+# ibcast on a device buffer has no host recvbuf to land in and keeps the
+# host schedule; reduce and reduce_scatter_block have no device i-form
+_NONBLOCKING = {"allreduce", "allgather", "alltoall", "alltoallv"}
+# dense alltoallv counts: every rank packs 48 elements, so none is padded
+_V_COUNTS = ((12, 20, 4, 12), (0, 16, 16, 16), (24, 8, 8, 8),
+             (12, 12, 12, 12))
+
+
+def _mesh_call(name, comm, x, recv=None):
+    """One collective on the mesh channel: blocking through the comm's
+    installed entry with no recvbuf (the result stays on the device), or
+    nonblocking into the host ``recv``."""
+    r = comm.rank
+    if name == "alltoallv":
+        from mvapich2_tpu.core.comm import _resolve
+        sc = list(_V_COUNTS[r])
+        rc = [_V_COUNTS[s][r] for s in range(MESH_RANKS)]
+        if recv is not None:
+            return comm.ialltoallv(x, sc, None, recv, rc, None)
+        dense = lambda cs: [int(d) for d in np.cumsum([0] + cs[:-1])]
+        # Comm.alltoallv hands back its recvbuf; the entry, the result
+        return comm.coll_fns["alltoallv"](
+            comm, x, sc, dense(sc), None, rc, dense(rc),
+            _resolve(x, None, None)[1])
+    if recv is not None:
+        return getattr(comm, "i" + name)(x, recv)
+    if name in ("reduce", "bcast"):
+        return getattr(comm, name)(x, root=2)
+    return getattr(comm, name)(x)
+
+
+@pytest.mark.parametrize("deposits", ["device", "one_host"])
+@pytest.mark.parametrize("name", _MESH_COLLS)
+def test_mesh_leader_stages_by_identity(name, deposits):
+    """Every mesh-channel collective, blocking and (where the device
+    tier has one) nonblocking, bit-equal to the plain reference. With
+    all four deposits on their own devices the global operand's shards
+    are the deposited buffers, ``_deliver`` hands back the object the
+    leader collected, and dev_mesh_operands rises once per call; with
+    one host deposit among them it does not rise."""
+    import jax
+
+    import plain_reference as ref
+    from mvapich2_tpu import mpit
+    from mvapich2_tpu.parallel.mesh import make_mesh
+    _reload(MV2T_DEVICE_COLL_MIN_BYTES="1")
+    n = 48
+    data = [np.random.default_rng([29, r]).integers(
+        -2**20, 2**20, size=n, endpoint=True).astype(np.float32)
+        for r in range(MESH_RANKS)]
+    want = {"alltoallv": lambda: ref.alltoallv(data, _V_COUNTS),
+            "reduce": lambda: ref.reduce(data, 2),
+            "bcast": lambda: ref.bcast(data, 2)}.get(
+                name, lambda: getattr(ref, name)(data))()
+    lay = deposits == "device"
+    seen = {"ptr": [None] * MESH_RANKS, "same": [None] * MESH_RANKS}
+
+    def rose(comm, call):
+        """dev_mesh_operands over one collective, fenced by barriers."""
+        comm.barrier()
+        before = mpit.pvar("dev_mesh_operands").read()
+        comm.barrier()
+        out = call()
+        comm.barrier()
+        return out, mpit.pvar("dev_mesh_operands").read() - before
+
+    def app(comm):
+        ch = comm.device_channel
+        assert type(ch).__name__ == "DeviceCollChannel"
+        r = comm.rank
+        host = not lay and r == 1
+        x = data[r].copy() if host else jax.device_put(data[r], ch.device)
+        if not host:
+            seen["ptr"][r] = x.unsafe_buffer_pointer()
+        if r == 0:      # the leader: keep what it staged and collected
+            glob, per = ch._global, ch._per_rank
+
+            def keep(key, val):     # the first call's, the blocking one
+                seen.setdefault(key, list(val) if key == "res" else val)
+                return val
+            ch._global = lambda sh, m: keep("glob", glob(sh, m))
+            ch._per_rank = lambda out: keep("res", per(out))
+        out, up = rose(comm, lambda: _mesh_call(name, comm, x))
+        assert up == (1 if lay else 0)
+        if want[r] is None:
+            assert out is None
+        else:
+            np.testing.assert_array_equal(np.asarray(out), want[r])
+            if lay:
+                assert out.ndim == 1 and out.devices() == {ch.device}
+                if name != "alltoallv":     # _deliver_v cuts the pad off
+                    seen["same"][r] = out is seen["res"][r]
+        if name in _NONBLOCKING:
+            recv = np.zeros(want[r].size, np.float32)
+            _, up = rose(comm, lambda: _mesh_call(name, comm, x,
+                                                  recv).wait())
+            assert up == (1 if lay else 0)
+            np.testing.assert_array_equal(recv, want[r])
+
+    run_ranks(MESH_RANKS, app, timeout=30,
+              device_mesh=make_mesh((MESH_RANKS,), ("x",),
+                                    jax.devices()[:MESH_RANKS]))
+    assert seen["glob"].shape == (MESH_RANKS * n,)
+    shards = {s.device: s.data for s in seen["glob"].addressable_shards}
+    for r, dev in enumerate(jax.devices()[:MESH_RANKS]):
+        assert shards[dev].shape == (n,)
+        if seen["ptr"][r] is not None:      # the deposit, not a copy
+            assert shards[dev].unsafe_buffer_pointer() == seen["ptr"][r]
+    assert all(s is not False for s in seen["same"]), seen["same"]
+    if lay and name != "alltoallv":
+        assert sum(s is True for s in seen["same"]) == \
+            sum(w is not None for w in want)
