@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """acceptance — capture the BASELINE.md acceptance configs as one JSON
-artifact (VERDICT r2 weak #6 / next-step #9: the single 64 MiB bench
-point leaves regressions off that point invisible).
+artifact (a single 64 MiB point leaves regressions off that point
+invisible).
 
 Five configs (BASELINE.md "Acceptance configs"):
   1. osu_allreduce f32, 8 ranks, 4 B..4 MiB  (CPU host channel)
@@ -14,7 +14,8 @@ north-star path at more than one point).
 
 Each config runs in its own subprocess (its own JAX platform env), so
 the rank-based configs stay on CPU while the sweep config can own the
-TPU. Aggregate artifact: BENCH_SWEEP_r{N}.json at the repo root.
+TPU. Aggregate artifact: --out, or acceptance.json in the working
+directory.
 
 Usage:
     python benchmarks/acceptance.py               # all configs
@@ -265,7 +266,7 @@ def main():
               f"{'ok' if 'error' not in results[cfg] else results[cfg]['error'][:120]}",
               file=sys.stderr, flush=True)
 
-    out = a.out or os.path.join(REPO, "BENCH_SWEEP_r03.json")
+    out = a.out or os.path.abspath("acceptance.json")
     with open(out, "w") as f:
         json.dump({"quick": a.quick, "configs": results}, f, indent=1)
     print(json.dumps({"written": out,

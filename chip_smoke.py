@@ -18,8 +18,10 @@ plain numpy reference computed on the host from the same ``--seed``:
      process (the launcher runs rank threads in-process on the real
      device), to the port's own ``No Errors``.
   4. *Proof the chip did the work.* ``coll_level_chip`` rose by exactly
-     the device collectives issued, every ``dev_coll_fallback_*`` pvar
-     is 0, and the lowered text of the slot program that ran holds a
+     the device collectives issued, ``dev_coll_fallback_host_dtype`` by
+     exactly the port's float64 latency statistics (x64 is off, so they
+     are turned away and counted), every other ``dev_coll_fallback_*``
+     pvar is 0, and the lowered text of the slot program that ran holds a
      ``tpu_custom_call``.
 
 ``--chips 4`` runs the four-chip phase instead, and nothing else:
@@ -59,10 +61,12 @@ def say(msg: str) -> None:
 
 
 def rank_data(seed: int, tag: int, rank: int, nelems: int) -> np.ndarray:
-    """Rank ``rank``'s f32 buffer for phase ``tag``: small-integer
-    values, so f32 sums over 8 ranks are exact in any order."""
+    """Rank ``rank``'s f32 buffer for phase ``tag``: whole numbers from
+    [-2^20, 2^20] as ``chipbench`` draws them, so f32 sums over 8 ranks
+    are exact in any order and a sum carried in bfloat16 is not."""
     rng = np.random.default_rng([seed, tag, rank])
-    return rng.integers(-8, 9, nelems).astype(np.float32)
+    return rng.integers(-(1 << 20), 1 << 20, nelems,
+                        endpoint=True).astype(np.float32)
 
 
 def fallback_pvars() -> dict:
@@ -238,9 +242,10 @@ class _Tee:
         self.inner.flush()
 
 
-def launcher_door(nranks: int = NRANKS, osu_args=OSU_ARGS) -> int:
+def launcher_door(nranks: int = NRANKS, osu_args=OSU_ARGS):
     """Door 2: the mpirun front door, in-process on the real device.
-    Returns the number of device collectives issued (per rank)."""
+    Returns, per rank, the number of device collectives issued and the
+    number of calls turned away from the device for their dtype."""
     from types import SimpleNamespace
 
     from mvapich2_tpu.bench import osu_util
@@ -262,18 +267,20 @@ def launcher_door(nranks: int = NRANKS, osu_args=OSU_ARGS) -> int:
         raise AssertionError(f"launcher door failed: rc={rc}, "
                              f"'No Errors' {'in' if 'No Errors' in out else 'not in'} output")
     # the size table the port walked: skip + iters calls per size, plus
-    # the int32 error-count allreduce of finalize_ok (the f64 latency
-    # statistics do not lower with x64 off and keep the host path)
+    # the int32 error-count allreduce of finalize_ok; the three f64
+    # latency statistics a size do not lower with x64 off, keep the host
+    # path and are counted as turned away
     it = iter(osu_args)
     o = dict(zip(it, it))
     opts = SimpleNamespace(min_size=4, max_size=int(o["-m"]),
                            iterations=int(o["-i"]), skip=int(o["-x"]))
+    sizes = list(osu_util.sizes(opts))
     calls = sum(opts.skip + osu_util.scale_iters(opts, s)
-                for s in osu_util.sizes(opts)) + 1
+                for s in sizes) + 1
     say(f"launcher door: No Errors; {calls} device allreduces per rank "
         f"up to {opts.max_size} B in {time.perf_counter() - t0:.1f} s "
         f"(smoke timing)")
-    return calls
+    return calls, 3 * len(sizes)
 
 
 def one_chip(seed: int) -> None:
@@ -286,12 +293,15 @@ def one_chip(seed: int) -> None:
                               else "not built; python fallback"))
     chip0, fb0 = mpit.pvar("coll_level_chip").read(), fallback_pvars()
     calls = library_door(seed)
-    calls += launcher_door()
+    osu_calls, osu_stats = launcher_door()
+    calls += osu_calls
     rose = mpit.pvar("coll_level_chip").read() - chip0
     fb = {n: v - fb0[n] for n, v in fallback_pvars().items()}
     say(f"proof: coll_level_chip rose by {rose} = {NRANKS} ranks x {calls} "
         f"device collectives issued; fallbacks {fb}")
     assert rose == NRANKS * calls, (rose, NRANKS * calls)
+    assert fb.pop("dev_coll_fallback_host_dtype") == NRANKS * osu_stats, \
+        (fb, osu_stats)
     assert fb and not any(fb.values()), fb
     say(f"proof: peak_bytes_in_use {_peak_bytes(jax.devices()[0])}")
 

@@ -22,10 +22,9 @@ contribution on this host (interpreter/CPU mode): cold trace+compile
 vs warm deserialize of the same device-collective program build
 (coll/device.py ``_build`` through the ops/_compat.py export seam).
 
-``python -m mvapich2_tpu.bench.churn --artifact BENCH_CHURN_rNN.json``
-writes the committed artifact ``bin/perf_gate`` compares (serial band,
-concurrent band + the in-artifact conc>=serial guard, exec-cache
-probe); ``bin/bench_osu`` still embeds the serial band in BENCH_OSU;
+``python -m mvapich2_tpu.bench.churn --artifact <path>`` writes the
+serial band, the concurrent band and the exec-cache probe as one JSON
+file; ``bin/bench_osu`` embeds the serial band in its own output;
 tests/test_daemon.py keeps a tier-1 smoke on both scenarios.
 """
 
@@ -182,14 +181,14 @@ def run_artifact(prog: List[str], jobs: int = 8,
                  inflight: int = 4,
                  geometries: Sequence[int] = (2, 3),
                  env_extra: Optional[dict] = None) -> dict:
-    """The committed-churn-artifact body (BENCH_CHURN_r*.json):
+    """The body of the ``--artifact`` file:
 
       * ``churn_np2`` — the serial per-geometry band (daemon 0 vs 1),
         osu_compare's existing churn comparison shape;
       * ``churn_concurrent`` — serial equal-load baseline (inflight=1)
         vs the overlapping run (inflight=N), BOTH with the daemon on
-        and the same total jobs — perf_gate's in-artifact guard
-        requires conc cps >= serial cps;
+        and the same total jobs (overlap that loses to the serial
+        run is the defect to look for);
       * ``exec_cache`` — the warm-hit probe (cold trace+compile vs
         cache deserialize, interpreter/CPU mode off-TPU).
     """
@@ -227,7 +226,7 @@ def main(argv=None) -> int:
     ap.add_argument("--geometries", type=int, nargs="+",
                     default=[2, 3])
     ap.add_argument("--artifact", default=None,
-                    help="write the full BENCH_CHURN artifact (serial "
+                    help="write the full churn artifact (serial "
                          "+ concurrent bands + exec-cache probe) to "
                          "this path")
     a = ap.parse_args(argv)

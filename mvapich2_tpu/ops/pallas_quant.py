@@ -44,7 +44,8 @@ budget 0/unset, and budgets below the declared bound all keep the
 exact hbm tier. Interpreter-proven correctness (like PR 8); the
 effective-bandwidth half of the EQuARX ~2x claim waits for the ROADMAP
 item 1 TPU host run — the wire-byte accounting (``wire_stats``) is the
-hardware-independent half and is gated by bin/perf_gate.
+hardware-independent half and is pinned by
+tests/test_pallas_quant.py::test_wire_stats_ratio_under_bound.
 """
 
 from __future__ import annotations

@@ -4,7 +4,7 @@ One mmap'd /dev/shm region per node, created at bootstrap alongside the
 shm ring segment and carved into size-classed blocks with a handle
 table. It replaces the per-send scratch files the staged rendezvous used
 to create (two full copies plus open/write/unlink syscalls per transfer,
-the cost cliff BENCH_OSU_r05 shows at the eager->rendezvous switch): a
+a cost cliff at the eager->rendezvous switch): a
 block is allocated once, reused across sends, and freed when the FIN
 arrives — the steady-state reuse discipline of MVAPICH2's registration
 cache (dreg.c) applied to a shared scratch pool.

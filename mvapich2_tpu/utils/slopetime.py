@@ -1,4 +1,5 @@
-"""Two-point-slope device-op timing, shared by bench.py and autotune.
+"""Two-point-slope device-op timing, shared by autotune and
+benchmarks/acceptance.py.
 
 A single timed call of a short device op measures mostly the constant
 cost of the call — dispatch, and the host readback that ends it.

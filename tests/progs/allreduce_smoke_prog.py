@@ -3,8 +3,8 @@
 Times a handful of 1 MiB allreduces at np=4 and prints the per-call
 average. The harness (tests/test_perf_smoke.py) asserts the average
 stays under a generous wall-clock budget — the scratch-file cliff this
-guards against was ~33 ms/call (BENCH_OSU_r05), an order of magnitude
-over the budget, so the check is variance-proof while still catching
+guards against was ~33 ms/call on the host that showed it, an order
+of magnitude over the budget, so the check is variance-proof while still catching
 any silent return of per-send staging files.
 
 Launched via: python -m mvapich2_tpu.run -np 4 tests/progs/allreduce_smoke_prog.py

@@ -10,6 +10,8 @@ import os
 import sys
 
 import jax
+import ml_dtypes
+import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -37,11 +39,17 @@ def test_launcher_door_counts_the_size_table(monkeypatch, capsys):
     # re-exec'd child does), so the pvars are this process' own
     monkeypatch.setenv("MV2T_VPOD_CHILD", "1")
     ici0 = mpit.pvar("coll_level_ici").read()
-    calls = chip_smoke.launcher_door(osu_args=["-m", "4096", "-i", "3",
-                                               "-x", "1"])
-    assert calls == 11 * 4 + 1
+    fb0 = chip_smoke.fallback_pvars()
+    calls, stats = chip_smoke.launcher_door(
+        osu_args=["-m", "4096", "-i", "3", "-x", "1"])
+    assert (calls, stats) == (11 * 4 + 1, 11 * 3)
     assert mpit.pvar("coll_level_ici").read() - ici0 == \
         chip_smoke.NRANKS * calls
+    # the port's float64 statistics are turned away and counted: what
+    # one_chip's proof expects of this door
+    turned_away = "dev_coll_fallback_host_dtype"
+    assert chip_smoke.fallback_pvars()[turned_away] - fb0[turned_away] == \
+        chip_smoke.NRANKS * stats
     assert "No Errors" in capsys.readouterr().out
 
 
@@ -63,3 +71,16 @@ def test_main_refuses_without_a_tpu(capsys):
     assert chip_smoke.main([]) != 0
     out = capsys.readouterr().out
     assert '"ok"' not in out and "no TPU" in out
+
+
+def test_inputs_tell_a_bfloat16_sum_from_an_f32_one():
+    """Bit-equality with numpy means what ``chipbench``'s ``correct``
+    means only if a sum carried in a narrower type would miss it."""
+    xs = [chip_smoke.rank_data(7, 1, r, 1024)
+          for r in range(chip_smoke.NRANKS)]
+    f32 = np.sum(xs, axis=0)
+    assert np.array_equal(f32, np.sum(np.asarray(xs, np.float64), axis=0))
+    bf16 = np.zeros(1024, ml_dtypes.bfloat16)
+    for x in xs:
+        bf16 = bf16 + x.astype(ml_dtypes.bfloat16)
+    assert not np.array_equal(bf16.astype(np.float32), f32)

@@ -15,6 +15,7 @@ import jax
 import ml_dtypes
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 import plain_reference as ref
 from mvapich2_tpu import mpit
@@ -151,6 +152,51 @@ def test_alltoall_wire_bytes_by_hand():
     assert pallas_alltoall.alltoall_wire_bytes(4000, F32, 4) == 3 * 4096
     # a skewed alltoallv pads every step to its largest pair (A10)
     assert pallas_alltoall.wire_bytes((8, 16, 0, 24), F32) == 40 * 128 * 4
+
+
+# tokens device i sends expert j, 64 a device on four devices, as the MoE
+# step harness routed them: every expert alike; a zipf-like share
+# rotated per device; half of every device's tokens on expert 0
+MOE_ROUTING = {
+    "uniform": ([16, 16, 16, 16],) * 4,
+    "skew": ([32, 15, 10, 7], [15, 12, 7, 30], [10, 7, 32, 15],
+             [7, 30, 15, 12]),
+    "hot": ([40, 8, 8, 8],) * 4,
+}
+
+
+@pytest.mark.parametrize("shape,payload", [
+    ("uniform", 1536), ("skew", 1664), ("hot", 1792)])
+def test_alltoallv_wire_counts(monkeypatch, shape, payload):
+    """ROADMAP A10's three counts, 64 tokens of 8 f32 a device on four
+    devices (2 048 B a shard). ``payload`` is what the routing needs to
+    move: the busiest rank's off-device elements, 4 B each, the figure
+    the CPU-era record this test replaces held under ``wire_bytes``
+    (1 536 / 1 664 / 1 792). What the kernel sends is counted in
+    ``pallas_alltoall.wire_bytes``' own unit, which is not the record's:
+    each permutation step padded to its largest pair and rounded up to
+    whole (8, 128) f32 tiles, on the step schedule ``hbm_alltoallv``
+    hands its kernel. At this width every pair, the hot expert's 1 280 B
+    too, fits one 4 096 B tile: 12 288 B whatever the routing, seven to
+    eight times the payload. Pad-to-max proper needs B2's shapes."""
+    p, dmodel = 4, 8
+    counts = [[c * dmodel for c in row] for row in MOE_ROUTING[shape]]
+    assert 4 * max(sum(c for j, c in enumerate(row) if j != i)
+                   for i, row in enumerate(counts)) == payload
+    seen = []
+
+    def record(blocks, axis_name, p_, step_rows, *rest):
+        seen.append(tuple(step_rows))
+        return blocks
+    monkeypatch.setattr(pallas_alltoall, "_a2a_call", record)
+    in_len = pallas_alltoall.packed_displs(counts)[2]
+    jax.eval_shape(
+        jax.shard_map(lambda v: pallas_alltoall.hbm_alltoallv(
+            v, "x", p, counts), mesh=_mesh("mesh"), in_specs=(P("x"),),
+            out_specs=P("x"), check_vma=False),
+        jax.ShapeDtypeStruct((p * in_len,), F32))
+    assert len(seen) == 1
+    assert pallas_alltoall.wire_bytes(seen[0], F32) == 3 * 4096
 
 
 # -- every other collective the gate lets through on bfloat16 ------------

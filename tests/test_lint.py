@@ -293,7 +293,7 @@ def test_device_engine_under_lint_ratchet():
     assert not errors
     names = {os.path.relpath(m.path, pkg) for m in modules}
     for need in ("ops/pallas_ici.py", "ops/_compat.py",
-                 "ops/pallas_ring.py", "bench/dev_sweep.py"):
+                 "ops/pallas_ring.py"):
         assert need in names, need
     # the committed device modules are clean under the pvars +
     # traceguard passes (no new baseline entries)

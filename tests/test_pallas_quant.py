@@ -10,8 +10,8 @@ wire x dtype x chunk-boundary shape x ring width, bit-exactness where
 the codec is lossless by construction, bit-identical results across
 ranks (every rank decodes the same gathered code words), and that all
 exact-mode fallbacks (budget 0/unset, integer dtypes, min/max) really
-run the exact tiers. The wire-byte accounting (the perf_gate-guarded
-half of the quant claim) is asserted analytically, and the tier is
+run the exact tiers. The wire-byte accounting (the
+hardware-independent half of the quant claim) is asserted analytically, and the tier is
 driven end-to-end through coll/device.py on a >= 1 MiB f32 allreduce.
 """
 
@@ -237,7 +237,7 @@ def test_exact_mode_bit_identical_when_cvar_unset(comm8):
 
 
 # ---------------------------------------------------------------------------
-# wire-byte accounting (the perf_gate-guarded half of the claim)
+# wire-byte accounting (the hardware-independent half of the claim)
 # ---------------------------------------------------------------------------
 
 def test_wire_stats_ratio_under_bound():

@@ -1,14 +1,14 @@
 """Tier-1 perf smoke: the large-message datapath must stay fast.
 
 A 4-rank 1 MiB allreduce through the arena/CMA sectioned exchange runs
-at ~2-3 ms/call; the per-send scratch-file path it replaced was ~33 ms
-(BENCH_OSU_r05 osu_allreduce_np4 @ 1 MiB). The 5 s budget for ten
+at ~2-3 ms/call on a one-core host; the per-send scratch-file path it
+replaced was ~33 ms there. The 5 s budget for ten
 timed iterations is generous enough to be variance-proof on an
 oversubscribed CI host while still failing hard if the scratch-file
 cliff (or any comparable per-send staging cost) silently returns.
 
-bin/osu_compare is the fine-grained guard for full bench artifacts;
-this test is the always-on tripwire in the tier-1 lane.
+bin/osu_compare diffs two bin/bench_osu runs size by size; this test
+is the always-on tripwire in the tier-1 lane.
 """
 
 import os
@@ -58,43 +58,6 @@ def test_smallmsg_np4_under_budget():
         f"4 B allreduce too slow: {ar_us:.0f} us/call "
         f"(budget {TINY_ALLREDUCE_BUDGET_US:.0f}) — flat-slot tier "
         f"not engaged?")
-
-
-def test_device_collective_band():
-    """Tier-1 tripwire for the device lane: the dev_sweep band tool
-    (mvapich2_tpu.bench.dev_sweep) runs the tier-dispatched device
-    allreduce across sizes straddling a forced vmem/hbm boundary in
-    interpret mode, emits the osu_compare-compatible artifact, and the
-    artifact survives the gate (self-compare: 0 regressions, 0 device
-    cliffs). On TPU the same tool produces the real device band that
-    bin/osu_compare diffs between rounds; here the check is that the
-    gate machinery is wired end to end, inside a generous budget."""
-    import json
-    import tempfile
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out = os.path.join(tempfile.mkdtemp(prefix="devband-"), "band.json")
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               MV2T_DEV_TIER_VMEM_MAX="8192",   # force a tier boundary
-               MV2T_DEV_TIER_XLA_MIN="-1",      # outrank any profile
-               MV2T_ICI_CHUNK_BYTES="4096",     # inside the swept band
-               XLA_FLAGS="--xla_force_host_platform_device_count=8")
-    r = subprocess.run(
-        [sys.executable, "-m", "mvapich2_tpu.bench.dev_sweep",
-         "--sizes", "4096,16384", "--iters", "2", "--out", out],
-        cwd=repo, capture_output=True, text=True, timeout=600, env=env)
-    assert r.returncode == 0, f"{r.stdout}\n{r.stderr}"
-    art = json.load(open(out))
-    band = art["results"]["dev_allreduce_effbw"]
-    assert set(band) == {"4096", "16384"} and all(
-        v > 0 for v in band.values()), art
-    # both tiers exercised across the forced boundary
-    assert art["tiers"] == {"4096": "vmem", "16384": "hbm"}, art
-    cmp = subprocess.run(
-        [sys.executable, os.path.join(repo, "bin", "osu_compare"),
-         out, out], cwd=repo, capture_output=True, text=True,
-        timeout=120)
-    assert cmp.returncode == 0, f"{cmp.stdout}\n{cmp.stderr}"
 
 
 def test_allreduce_1mib_np4_under_budget():
