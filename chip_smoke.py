@@ -389,7 +389,9 @@ def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
     run_ranks(nranks, app, device_mesh=mesh, timeout=900.0)
     assert len(set(homes)) == nranks, \
         f"the {nranks} shards do not live on {nranks} devices: {homes}"
-    say(f"four chips: shards on {sorted(str(d) for d in homes)}")
+    say(f"four chips: ranks 0..{nranks - 1} on devices "
+        f"{[(d.id, getattr(d, 'coords', None)) for d in homes]} (make_mesh's "
+        f"ring order; given {[d.id for d in devs]})")
 
     for t, name, op, nbytes in cases:
         xs = data[t]

@@ -1816,6 +1816,14 @@ def bind_universes(universes, mesh=None, axis=None) -> bool:
                 log.warn("mesh shape %s does not match %d ranks; host "
                          "path only", dict(mesh.shape), n)
                 return False
+    if slot_device is None:
+        # rank r lives on the r-th device of the mesh: make_mesh lays a
+        # 1-D mesh of TPU chips in ICI-neighbour order, which need not
+        # be the order of jax.devices()
+        log.info("mesh %s binds ranks, in order, to devices %s",
+                 dict(mesh.shape),
+                 [(d.id, getattr(d, "coords", None))
+                  for d in mesh.devices.flat])
     rv = _Rendezvous(n)
     for r, u in enumerate(universes):
         if slot_device is not None:

@@ -478,6 +478,13 @@ pvar("dev_mesh_operands", PVAR_CLASS_COUNTER, "device",
      "reshape, no eager op, no copy (coll/device.py DeviceCollChannel."
      "_shards); a call with a host deposit or a padded alltoallv "
      "payload among its ranks does not count")
+pvar("dev_mesh_reordered", PVAR_CLASS_COUNTER, "device",
+     "1-D meshes parallel/mesh.make_mesh returned with their devices in "
+     "another order than they were given: TPU chips laid along a snake "
+     "over their coords, so that consecutive ring positions are ICI "
+     "neighbours (a 2x2 given as ids 0, 1, 2, 3 is walked 0, 1, 3, 2); "
+     "devices without coords, and meshes given in ring order, do not "
+     "count")
 pvar("coll_level_chip", PVAR_CLASS_COUNTER, "device",
      "collective calls that exercised the chip level of the three-"
      "level hierarchy: an HBM slot fold among co-resident ranks (the "

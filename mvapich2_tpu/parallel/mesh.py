@@ -13,13 +13,13 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .. import ops
+from .. import mpit, ops
 from ..utils.compile_cache import ensure_compile_cache
 from ..utils.detect import detect
 from ..utils.mlog import get_logger
@@ -44,10 +44,53 @@ def mesh_shape_for(n: int, naxes: int = 2) -> Tuple[int, ...]:
     return (best[0],) + rest
 
 
+def _ring_order(devices: Sequence[Any]) -> List[Any]:
+    """The given devices in ICI-neighbour order for a 1-D axis: a snake
+    over their ``coords`` in which consecutive devices are one hop apart
+    in one coordinate. Out along the first line of chips, then back
+    through the rest column by column in alternating direction, so the
+    last device neighbours the first whenever the extent walked first
+    is even (a 2x2 comes back as ids 0, 1, 3, 2; a 2x4 or a 4x4 closes
+    too; a line of chips is sorted along itself). Read from what the
+    devices say about themselves, no table per TPU generation. As given
+    where that says nothing: no ``coords`` (CPU), one or two devices,
+    several cores a chip (equal ``coords``), or chips that span more
+    than two dimensions."""
+    devices = list(devices)
+    coords = [tuple(getattr(d, "coords", None) or ()) for d in devices]
+    if (len(devices) <= 2 or not coords[0]
+            or len(set(coords)) != len(devices)):
+        return devices
+    extent = {k: len({c[k] for c in coords}) for k in range(len(coords[0]))}
+    dims = [k for k, e in extent.items() if e > 1]
+    if len(dims) > 2:
+        return devices
+    at = dict(zip(coords, devices))
+    if len(dims) == 1:
+        return [at[c] for c in sorted(coords, key=lambda c: c[dims[0]])]
+    # walk an even extent first where there is one: the snake then ends
+    # beside its start (sorted is stable: x before y when both are even)
+    a, b = sorted(dims, key=lambda k: extent[k] % 2)
+    b0 = min(c[b] for c in coords)
+    path = sorted((c for c in coords if c[b] == b0), key=lambda c: c[a])
+    for k, av in enumerate(sorted({c[a] for c in coords}, reverse=True)):
+        path += sorted((c for c in coords if c[a] == av and c[b] != b0),
+                       key=lambda c: c[b], reverse=bool(k % 2))
+    return [at[c] for c in path]
+
+
 def make_mesh(shape: Optional[Sequence[int]] = None,
               axis_names: Sequence[str] = ("x",),
               devices=None) -> Mesh:
-    """Build a Mesh over the available devices (row-major assignment)."""
+    """Build a Mesh over the first ``prod(shape)`` of the given devices
+    (default: all of ``jax.devices()``). A multi-axis shape takes them
+    row-major, as given. A one-axis shape promises the *set* of devices
+    given, in ring order (as ``jax.make_mesh`` does): on TPU chips,
+    consecutive positions, and the last and the first where the
+    topology allows, are ICI neighbours (``_ring_order``), so rank r of
+    a channel bound to the mesh lives on ``mesh.devices[r]``, which need
+    not be ``devices[r]``. The pvar ``dev_mesh_reordered`` counts the
+    meshes returned in another order than they were given."""
     ensure_compile_cache()
     devices = devices if devices is not None else jax.devices()
     n = len(devices)
@@ -57,8 +100,11 @@ def make_mesh(shape: Optional[Sequence[int]] = None,
     if total > n:
         raise ValueError(f"mesh shape {shape} needs {total} devices, "
                          f"have {n}")
-    arr = np.asarray(devices[:total]).reshape(shape)
-    return Mesh(arr, tuple(axis_names))
+    given = list(devices[:total])
+    placed = _ring_order(given) if len(shape) == 1 else given
+    if placed != given:
+        mpit.pvar("dev_mesh_reordered").inc()
+    return Mesh(np.asarray(placed).reshape(shape), tuple(axis_names))
 
 
 class MeshComm:

@@ -53,8 +53,12 @@ def one_chip(topo):
 
 @pytest.fixture(scope="module")
 def mesh4(topo):
-    from jax.sharding import Mesh
-    return Mesh(np.asarray(topo.devices[:P4]), ("x",))
+    """The four chips as the library binds them on the chip: the 1-D
+    mesh ``make_mesh`` lays over the 2x2 (ISSUE 31: in ICI-neighbour
+    order, ids 0, 1, 3, 2), so every case below compiles the program
+    the chip runs, device assignment included."""
+    from mvapich2_tpu.parallel.mesh import make_mesh
+    return make_mesh((P4,), ("x",), topo.devices[:P4])
 
 
 def _compile_sharded(mesh4, kernel, nelems, dtype):
@@ -79,6 +83,29 @@ def _entry_ops(text):
     return [(m.group(2), [d for d in (m.group(1) or "").split(",") if d])
             for m in map(line.match,
                          text[text.index("ENTRY"):].splitlines()[1:]) if m]
+
+
+@pytest.mark.parametrize("kernel", ["hbm_ring_all_reduce", "hbm_alltoall"])
+def test_ring_walks_the_2x2_in_neighbour_order(topo, mesh4, kernel):
+    """The described 2x2 lists its chips row-major over (x, y): ids 0,
+    1, 2, 3 at (0,0), (1,0), (0,1), (1,1). A ring in that order crosses
+    the diagonal twice (1 -> 2, 3 -> 0), which no ICI link reaches;
+    ``make_mesh`` walks 0, 1, 3, 2, every hop one link, and the two
+    kernels of the four-chip cells compile over that device assignment
+    (1 MiB a chip; the cells' sizes compile further down)."""
+    from mvapich2_tpu.ops import pallas_alltoall, pallas_ici
+    assert [(d.id, tuple(d.coords)[:2]) for d in topo.devices[:P4]] == [
+        (0, (0, 0)), (1, (1, 0)), (2, (0, 1)), (3, (1, 1))]
+    ring = list(mesh4.devices)
+    assert [d.id for d in ring] == [0, 1, 3, 2]
+    for a, b in zip(ring, ring[1:] + ring[:1]):
+        assert sum(abs(p - q) for p, q in zip(a.coords, b.coords)) == 1
+    fn = {"hbm_ring_all_reduce": lambda s: pallas_ici.hbm_ring_all_reduce(
+              s, "x", P4, "sum", interpret=False),
+          "hbm_alltoall": lambda s: pallas_alltoall.hbm_alltoall(
+              s, "x", P4, interpret=False)}[kernel]
+    text = _compile_sharded(mesh4, fn, MiB // 4, np.dtype("float32"))
+    assert "tpu_custom_call" in text and "mv2t_" in text
 
 
 def test_slot_allreduce_one_chip(one_chip):

@@ -567,3 +567,51 @@ def test_mesh_leader_stages_by_identity(name, deposits):
     if lay and name != "alltoallv":
         assert sum(s is True for s in seen["same"]) == \
             sum(w is not None for w in want)
+
+
+# -- a mesh whose order is not jax.devices()'s (ISSUE 31) -----------------
+# make_mesh lays a 1-D mesh of TPU chips in ICI-neighbour order; the
+# channels take rank r's device from the mesh, so they follow.
+
+def test_channels_follow_a_reordered_mesh(monkeypatch):
+    """Four CPU devices laid 0, 1, 3, 2 (the ordering helper patched, as
+    CPU devices carry no ``coords``): rank r lives on the mesh's r-th
+    device, so rank 2 on device 3; ``comm.allreduce`` and
+    ``comm.alltoall`` are bit-equal to the plain reference, every result
+    on its rank's own device, four distinct devices, and
+    ``dev_mesh_reordered`` rose by one."""
+    import jax
+
+    import plain_reference as ref
+    from mvapich2_tpu import mpit
+    from mvapich2_tpu.parallel import mesh as pmesh
+    _reload(MV2T_DEVICE_COLL_MIN_BYTES="1")
+    devs = jax.devices()[:MESH_RANKS]
+    monkeypatch.setattr(pmesh, "_ring_order",
+                        lambda given: [given[i] for i in (0, 1, 3, 2)])
+    before = mpit.pvar("dev_mesh_reordered").read()
+    mesh = pmesh.make_mesh((MESH_RANKS,), ("x",), devs)
+    assert mpit.pvar("dev_mesh_reordered").read() - before == 1
+    assert [d.id for d in mesh.devices] == [devs[i].id for i in (0, 1, 3, 2)]
+    n = MESH_RANKS * 48
+    data = [np.random.default_rng([31, r]).integers(
+        -2**20, 2**20, size=n, endpoint=True).astype(np.float32)
+        for r in range(MESH_RANKS)]
+    want = {"allreduce": ref.allreduce(data), "alltoall": ref.alltoall(data)}
+    lived = [None] * MESH_RANKS
+
+    def app(comm):
+        ch = comm.device_channel
+        assert type(ch).__name__ == "DeviceCollChannel"
+        r = comm.rank
+        assert ch.device == mesh.devices[r]
+        lived[r] = ch.device
+        x = jax.device_put(data[r], ch.device)
+        for name in ("allreduce", "alltoall"):
+            out = getattr(comm, name)(x)
+            assert out.devices() == {ch.device}
+            np.testing.assert_array_equal(np.asarray(out), want[name][r])
+
+    run_ranks(MESH_RANKS, app, timeout=60, device_mesh=mesh)
+    assert lived == [devs[0], devs[1], devs[3], devs[2]]
+    assert len(set(lived)) == MESH_RANKS
