@@ -11,8 +11,10 @@ plain numpy reference computed on the host from the same ``--seed``:
      device-resident ``jax.Array`` buffers, 64 MiB f32 per rank:
      allreduce sum (the Pallas slot kernel), allreduce max, bcast,
      reduce_scatter_block at 64 MiB; allgather and alltoall at 8 MiB;
-     allreduce sum at 4 B / 4 KiB / 1 MiB; and allreduce at 1 MiB on
-     host numpy buffers under ``MV2T_ALLREDUCE_ALGO=device`` (staging).
+     allreduce sum at 4 B / 4 KiB / 1 MiB; allreduce at 1 MiB on host
+     numpy buffers under ``MV2T_ALLREDUCE_ALGO=device`` (staging); and
+     alltoall at 128 MiB, 16 MiB a pair, the size the benchmark's cell
+     ``osu1.alltoall.128MiB.dev`` times.
   3. *Launcher door.* ``mvapich2_tpu.run --vpod -np 8
      benchmarks/osu_allreduce.py -m 67108864 -i 3 -x 1`` in this same
      process (the launcher runs rank threads in-process on the real
@@ -100,9 +102,10 @@ class _Phase:
         self.call, self.ref, self.host = call, ref, host
 
 
-def _library_phases(nranks: int, big: int, mid: int):
-    """The library door's calls. ``big``/``mid``: elements per rank at
-    the 64 MiB / 8 MiB points (cut for the CPU rehearsal)."""
+def _library_phases(nranks: int, big: int, mid: int, fft: int):
+    """The library door's calls. ``big``/``mid``/``fft``: elements per
+    rank at the 64 MiB / 8 MiB / 128 MiB points (cut for the CPU
+    rehearsal)."""
     from mvapich2_tpu.core import op as opmod
     root = 3 % nranks
 
@@ -138,6 +141,7 @@ def _library_phases(nranks: int, big: int, mid: int):
         P(6, "allreduce sum [host buffers, MV2T_ALLREDUCE_ALGO=device]",
           MiB // 4, lambda c, x: c.allreduce(x),
           lambda xs: np.sum(xs, axis=0), host=True),
+        P(7, "alltoall", fft, lambda c, x: c.alltoall(x), a2a_ref),
     ]
 
 
@@ -155,7 +159,7 @@ def _peak_bytes(device) -> int:
 
 
 def library_door(seed: int, nranks: int = NRANKS, big: int = 16 * MiB,
-                 mid: int = 2 * MiB, device_mesh=True,
+                 mid: int = 2 * MiB, fft: int = 32 * MiB, device_mesh=True,
                  channel: str = "HBMSlotChannel",
                  expect_kernel: bool = True) -> int:
     """Door 1: MPI calls on thread-ranks bound to the device. Returns
@@ -164,7 +168,7 @@ def library_door(seed: int, nranks: int = NRANKS, big: int = 16 * MiB,
 
     from mvapich2_tpu import run_ranks
 
-    phases = _library_phases(nranks, big, mid)
+    phases = _library_phases(nranks, big, mid, fft)
     data, refs = {}, {}
     for ph in phases:       # inputs + references: host, outside timing
         if ph.tag not in data:

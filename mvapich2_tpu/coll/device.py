@@ -202,9 +202,12 @@ class _ImportedProgram:
 #   dev_dispatch     rank 0: program-cache lookup + enqueue; its E says
 #                    ``built`` when the call made or loaded the program
 #   dev_device_wait  rank 0, slot channel: the leader's block_until_ready
-#   dev_collect      rank 0: one result per rank out of the output
+#   dev_collect      rank 0: one result per rank out of the output; its
+#                    E says ``parts``, the arrays cut out of it by eager
+#                    device ops (0: shared, or the program's own outputs)
 #   dev_release      every rank: the second barrier wait
-#   dev_deliver      every rank, after dev_<coll> E: _deliver
+#   dev_deliver      every rank, after dev_<coll> E: _deliver; its E says
+#                    ``relaid``, 1 when _deliver issued a reshape
 #
 # Names are literals at the call sites (analysis/events.py resolves them
 # through ``_phase``'s callers) and every E sits in ``__exit__``.
@@ -653,7 +656,9 @@ class DeviceCollChannel:
 
     def _per_rank(self, out) -> List:
         """Each rank's own device's shard of the program's output."""
-        with self._phase("dev_collect"):
+        with self._phase("dev_collect") as ph:
+            if ph is not None:
+                ph.args["parts"] = 0    # the program's own outputs
             per_dev = {s.device: s.data for s in out.addressable_shards}
             return [per_dev[self.devices[r]] for r in range(self.size)]
 
@@ -822,10 +827,13 @@ class DeviceCollChannel:
     def _hand_back(self, out, recvbuf, *v):
         """``_deliver`` (``_deliver_v`` given alltoallv's ``rcounts,
         rdispls``) inside the collective's dev_deliver phase span."""
-        with self._phase("dev_deliver"):
+        with self._phase("dev_deliver") as ph:
             if v:
                 return self._deliver_v(out, recvbuf, *v)
-            return _deliver(out, recvbuf)
+            res = _deliver(out, recvbuf)
+            if ph is not None:      # _deliver issued an eager reshape
+                ph.args["relaid"] = int(res is not None and res is not out)
+            return res
 
     # -- MPI-shaped entry points (match coll_fns signatures) -------------
     def allreduce(self, comm, sendbuf, recvbuf, count, datatype, op):
@@ -1287,14 +1295,22 @@ class HBMSlotChannel(DeviceCollChannel):
                 ph.args["built"] = len(self._programs) > had
         with self._phase("dev_device_wait"):
             out = jax.block_until_ready(out)
-        with self._phase("dev_collect"):
+        with self._phase("dev_collect") as ph:
+            parts = 0   # arrays cut out of the result by eager device ops
             if name == "alltoall":
-                return [out[r] for r in range(R)]
-            if name == "reduce_scatter_block":
+                res, parts = [out[r] for r in range(R)], R
+            elif name == "reduce_scatter_block":
                 c = n // R
-                return [out[r * c:(r + 1) * c] for r in range(R)]
-            # the zero-copy share: every rank gets the same array
-            return [out] * R
+                res, parts = [out[r * c:(r + 1) * c] for r in range(R)], R
+            else:
+                # the zero-copy share: every rank gets the same array
+                res = [out] * R
+            if parts:
+                from .. import mpit
+                mpit.pvar("dev_slot_result_parts").inc(parts)
+            if ph is not None:
+                ph.args["parts"] = parts
+            return res
 
 
 class DeviceFoldChannel(DeviceCollChannel):
@@ -1450,7 +1466,9 @@ class DeviceFoldChannel(DeviceCollChannel):
         if name == "reduce_scatter_block":
             # chip shard = its k ranks' contiguous blocks: slice per rank
             c = (n // nd) // k
-            with self._phase("dev_collect"):
+            with self._phase("dev_collect") as ph:
+                if ph is not None:
+                    ph.args["parts"] = self.size    # one eager slice each
                 per_dev = {s.device: s.data
                            for s in out.addressable_shards}
                 return [per_dev[self.devices[r]][(r % k) * c:

@@ -149,10 +149,54 @@ def test_slot_program_reads_operands_in_place(topo, one_chip, monkeypatch,
                        else set()), staging
 
 
+def test_slot_alltoall_at_the_fft_cell_size_fits_the_chip(topo, one_chip):
+    """The program HBMSlotChannel runs for ``osu1.alltoall.128MiB.dev``,
+    as its leader calls it: eight ``(n,)`` float32 operands of 128 MiB.
+    The chip's compiler takes it, and it asks for the 1 GiB of its
+    output and no temporary beside the 1 GiB of operands (a chip has 16
+    GB); the stack, the reshape and the transpose are one op, a
+    ``concatenate`` into a transposed layout. No ``mv2t_`` kernel runs
+    in it, which is why the cell is on neither ``kernel_us`` nor
+    ``kernel_roofline_pct``."""
+    import jax
+    import jax.numpy as jnp
+
+    from mvapich2_tpu.coll.device import HBMSlotChannel, _Rendezvous
+    n = 128 * MiB // 4
+    ch = HBMSlotChannel(topo.devices[0], _Rendezvous(8), 0, 8)
+    x = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    compiled = ch._build("alltoall", n, "sum", 0).lower(*[x] * 8).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == 8 * 128 * MiB
+    assert mem.output_size_in_bytes == 8 * 128 * MiB
+    assert mem.temp_size_in_bytes < MiB
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text and "mv2t_" not in text
+    entry = _entry_ops(text)
+    moving = [op for op, _ in entry if op not in ("parameter", "bitcast")]
+    assert moving == ["concatenate"], moving
+    assert entry[-1][1] == ["8", "8", str(n // 8)]
+
+
+@pytest.fixture
+def default_tier_edges(monkeypatch):
+    """The program's default tier edges, said out loud: once an earlier
+    test of the same worker has bound ranks, the CPU's measured profile
+    is loaded for the life of the process and sends every size to XLA."""
+    from mvapich2_tpu.utils.config import get_config
+    monkeypatch.setenv("MV2T_DEV_TIER_VMEM_MAX", str(4 * MiB))
+    monkeypatch.setenv("MV2T_DEV_TIER_XLA_MIN", "-1")
+    get_config().reload()
+    yield
+    monkeypatch.undo()
+    get_config().reload()
+
+
 @pytest.mark.parametrize("coll,dtype", [
     ("alltoall", "bfloat16"), ("alltoall", "float32"),
     ("allreduce", "float32"), ("allgather", "float32")])
 def test_mesh_program_is_the_kernel_between_bitcasts(mesh4, monkeypatch,
+                                                     default_tier_edges,
                                                      coll, dtype):
     """The program DeviceCollChannel runs on four device-resident
     deposits, as its leader calls it: one flat global ``(4 * n,)``

@@ -25,10 +25,11 @@ def test_library_door_on_one_device_slot_channel():
     from mvapich2_tpu.parallel.mesh import make_mesh
     chip0 = mpit.pvar("coll_level_chip").read()
     calls = chip_smoke.library_door(
-        seed=7, big=8 * 1024, mid=8 * 256,
+        seed=7, big=8 * 1024, mid=8 * 256, fft=8 * 512,
         device_mesh=make_mesh((1,), ("x",), jax.devices()[:1]),
         expect_kernel=False)
-    assert calls == 10 * (1 + chip_smoke.STEADY_CALLS)
+    # ten phases and the alltoall once more at the benchmark cell's size
+    assert calls == 11 * (1 + chip_smoke.STEADY_CALLS)
     assert mpit.pvar("coll_level_chip").read() - chip0 == \
         chip_smoke.NRANKS * calls
     assert "MV2T_ALLREDUCE_ALGO" not in os.environ
