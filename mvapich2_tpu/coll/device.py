@@ -204,10 +204,14 @@ class _ImportedProgram:
 #   dev_device_wait  rank 0, slot channel: the leader's block_until_ready
 #   dev_collect      rank 0: one result per rank out of the output; its
 #                    E says ``parts``, the arrays cut out of it by eager
-#                    device ops (0: shared, or the program's own outputs)
+#                    device ops: 0 where the ranks share one array or get
+#                    the program's own outputs (the mesh and slot
+#                    channels, always), k*ndev for the fold channel's
+#                    reduce_scatter_block
 #   dev_release      every rank: the second barrier wait
 #   dev_deliver      every rank, after dev_<coll> E: _deliver; its E says
-#                    ``relaid``, 1 when _deliver issued a reshape
+#                    ``relaid``, 1 when _deliver issued a reshape (0
+#                    for every program here: their results are flat)
 #
 # Names are literals at the call sites (analysis/events.py resolves them
 # through ``_phase``'s callers) and every E sits in ``__exit__``.
@@ -394,7 +398,7 @@ class DeviceCollChannel:
         if not daemon.exec_cache_enabled():
             return self._build(name, n, op, root, extra)
         from ..ops import _compat
-        ck = "|".join(("mv2t-exec-v2", self._chan_desc(), name,
+        ck = "|".join(("mv2t-exec-v3", self._chan_desc(), name,
                        f"n{n}", dtype_str, f"op:{op}", f"root:{root}",
                        f"x:{extra!r}", _compat.exec_fingerprint()))
         blob = daemon.exec_cache_get(ck)
@@ -1194,8 +1198,12 @@ class HBMSlotChannel(DeviceCollChannel):
         the materialized ``2*R*m``. On ``R`` operands of whole 128-lane
         rows the kernel reads each buffer where it lies.
       * allgather: the slot array *is* the result (no device compute).
-      * alltoall: one transpose of the slot array.
-      * reduce_scatter_block: slot-reduce, then per-rank slice views.
+      * alltoall: ``R`` flat outputs, one per rank: output ``r`` is
+        block ``r`` of every operand in sender order, written once in
+        its final layout (each operand read once; no stack, no
+        transposed intermediate, nothing cut out afterwards).
+      * reduce_scatter_block: slot-reduce, then ``R`` ``(c,)`` outputs
+        cut inside the program, one per rank.
       * bcast: the root slot only; all ranks share it.
 
     Used when more ranks than devices are bound (the mpirun-on-one-chip
@@ -1227,7 +1235,10 @@ class HBMSlotChannel(DeviceCollChannel):
         ``(R, n)`` array (bcast: the root's ``(n,)`` alone). The body
         reads which from its operands; ``extra``, the leader's operand
         count, only keeps the two forms apart in the program and
-        executable caches."""
+        executable caches. It returns one array every rank shares, or
+        (alltoall, reduce_scatter_block) a tuple of ``R`` flat arrays,
+        rank ``r``'s own result at ``r``; no operand is donated or
+        aliased, the callers keep their send buffers."""
         import jax
         import jax.numpy as jnp
 
@@ -1240,13 +1251,22 @@ class HBMSlotChannel(DeviceCollChannel):
             # operand, or the R deposited ones stacked inside the trace
             return xs[0] if xs[0].ndim == 2 else jnp.stack(xs)
 
-        if name in ("allreduce", "reduce", "reduce_scatter_block"):
+        def reduced(xs):                    # -> [n]
+            if not _slot_kernel_op(op):
+                return red(slots(xs), axis=0)
+            if xs[0].ndim == 1 and n % 128 == 0:
+                return ph.hbm_slot_allreduce_operands(xs)
+            return ph.hbm_slot_allreduce(slots(xs))
+
+        if name in ("allreduce", "reduce"):
             def f(*xs):                     # -> [n]
-                if not _slot_kernel_op(op):
-                    return red(slots(xs), axis=0)
-                if xs[0].ndim == 1 and n % 128 == 0:
-                    return ph.hbm_slot_allreduce_operands(xs)
-                return ph.hbm_slot_allreduce(slots(xs))
+                return reduced(xs)
+        elif name == "reduce_scatter_block":
+            c = n // R
+
+            def f(*xs):                     # -> R x [c], rank r's block
+                y = reduced(xs)             # cut inside the program
+                return tuple(y[r * c:(r + 1) * c] for r in range(R))
         elif name == "bcast":
             def f(x):                       # the root slot [n]
                 return x
@@ -1257,17 +1277,25 @@ class HBMSlotChannel(DeviceCollChannel):
         elif name == "alltoall":
             c = n // R
 
-            def f(*xs):                     # [R, R, c] transpose
-                return jnp.transpose(slots(xs).reshape(R, R, c), (1, 0, 2))
+            def f(*xs):                     # -> R x [n], rank r's result:
+                # block r of every sender in sender order, written once
+                # where it stays (compiled: one fusion per operand,
+                # reading it once and writing into all R outputs)
+                if xs[0].ndim == 2:
+                    return tuple(xs[0][:, r * c:(r + 1) * c].reshape(n)
+                                 for r in range(R))
+                return tuple(jnp.concatenate(
+                    [x[r * c:(r + 1) * c] for x in xs]) for r in range(R))
         else:  # pragma: no cover
             raise KeyError(name)
         return jax.jit(f)
 
     def _leader(self, name: str, op: str, root: int) -> List:
-        """Leader compute: hand the program what was deposited, share/
-        scatter the result. Device arrays on the slot device are the
-        program's operands as they lie (counted: dev_slot_operands);
-        anything else is stacked on the host and staged once."""
+        """Leader compute: hand the program what was deposited, share
+        its one result or hand out its per-rank outputs. Device arrays
+        on the slot device are the program's operands as they lie
+        (counted: dev_slot_operands); anything else is stacked on the
+        host and staged once."""
         import jax
 
         rv = self.rv
@@ -1296,21 +1324,11 @@ class HBMSlotChannel(DeviceCollChannel):
         with self._phase("dev_device_wait"):
             out = jax.block_until_ready(out)
         with self._phase("dev_collect") as ph:
-            parts = 0   # arrays cut out of the result by eager device ops
-            if name == "alltoall":
-                res, parts = [out[r] for r in range(R)], R
-            elif name == "reduce_scatter_block":
-                c = n // R
-                res, parts = [out[r * c:(r + 1) * c] for r in range(R)], R
-            else:
-                # the zero-copy share: every rank gets the same array
-                res = [out] * R
-            if parts:
-                from .. import mpit
-                mpit.pvar("dev_slot_result_parts").inc(parts)
             if ph is not None:
-                ph.args["parts"] = parts
-            return res
+                ph.args["parts"] = 0    # no eager op cuts anything out
+            # a program that returned one output per rank hands them
+            # out; one array is the zero-copy share, every rank gets it
+            return list(out) if isinstance(out, tuple) else [out] * R
 
 
 class DeviceFoldChannel(DeviceCollChannel):

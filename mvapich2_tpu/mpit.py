@@ -471,12 +471,6 @@ pvar("dev_slot_operands", PVAR_CLASS_COUNTER, "device",
      "device arrays as they lay — R operands, no stack, no staging "
      "copy (coll/device.py HBMSlotChannel._leader); host deposits, "
      "staged as one stacked array, do not count")
-pvar("dev_slot_result_parts", PVAR_CLASS_COUNTER, "device",
-     "arrays the slot-channel leader cut out of its program's result "
-     "with eager device ops, one per rank per call of alltoall and "
-     "reduce_scatter_block (coll/device.py HBMSlotChannel._leader, "
-     "dev_collect; the same number as parts on the span's E); a call "
-     "whose ranks share one result array adds nothing")
 pvar("dev_mesh_operands", PVAR_CLASS_COUNTER, "device",
      "mesh-channel leader calls (blocking, alltoallv, nonblocking) in "
      "which every rank's deposit entered the mesh-sharded global array "

@@ -79,7 +79,8 @@ def _entry_ops(text):
     ENTRY computation: the HLO op's name and its result's dimensions as
     a list of strings (empty for a scalar or a tuple)."""
     import re
-    line = re.compile(r"[^=]*=\s*(?:\w+\[([\d,]*)\])?\S*\s([a-z][\w-]*)\(")
+    line = re.compile(r"[^=]*=\s*(?:\w+\[([\d,]*)\]\S*|\(.*?\))"
+                      r"\s([a-z][\w-]*)\(")
     return [(m.group(2), [d for d in (m.group(1) or "").split(",") if d])
             for m in map(line.match,
                          text[text.index("ENTRY"):].splitlines()[1:]) if m]
@@ -149,33 +150,77 @@ def test_slot_program_reads_operands_in_place(topo, one_chip, monkeypatch,
                        else set()), staging
 
 
-def test_slot_alltoall_at_the_fft_cell_size_fits_the_chip(topo, one_chip):
-    """The program HBMSlotChannel runs for ``osu1.alltoall.128MiB.dev``,
-    as its leader calls it: eight ``(n,)`` float32 operands of 128 MiB.
-    The chip's compiler takes it, and it asks for the 1 GiB of its
-    output and no temporary beside the 1 GiB of operands (a chip has 16
-    GB); the stack, the reshape and the transpose are one op, a
-    ``concatenate`` into a transposed layout. No ``mv2t_`` kernel runs
-    in it, which is why the cell is on neither ``kernel_us`` nor
-    ``kernel_roofline_pct``."""
+def _slot_compiled(topo, one_chip, coll, nbytes):
+    """``HBMSlotChannel``'s program for ``coll`` as its leader calls it
+    on eight device-resident deposits, compiled for the chip: eight
+    ``(n,)`` float32 operands of ``nbytes``. Returns the compiled
+    program, its outputs' ``(shape, dtype)`` and its ENTRY's ops that
+    are not plumbing (``parameter``, ``bitcast``, ``get-tuple-element``,
+    ``tuple``); no operand is aliased to an output (the callers keep
+    their send buffers)."""
     import jax
     import jax.numpy as jnp
 
     from mvapich2_tpu.coll.device import HBMSlotChannel, _Rendezvous
-    n = 128 * MiB // 4
     ch = HBMSlotChannel(topo.devices[0], _Rendezvous(8), 0, 8)
-    x = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
-    compiled = ch._build("alltoall", n, "sum", 0).lower(*[x] * 8).compile()
+    x = jax.ShapeDtypeStruct((nbytes // 4,), jnp.float32, sharding=one_chip)
+    compiled = ch._build(coll, nbytes // 4, "sum", 0).lower(
+        *[x] * 8).compile()
     mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes == 8 * 128 * MiB
-    assert mem.output_size_in_bytes == 8 * 128 * MiB
+    assert mem.argument_size_in_bytes == 8 * nbytes
+    assert mem.alias_size_in_bytes == 0
+    assert "input_output_alias" not in compiled.as_text()
+    outs = [(o.shape, o.dtype.name) for o in jax.tree.leaves(
+        compiled.out_info)]
+    moving = [op for op, _ in _entry_ops(compiled.as_text()) if op not in (
+        "parameter", "bitcast", "get-tuple-element", "tuple")]
+    return compiled, outs, moving
+
+
+def test_slot_alltoall_at_the_fft_cell_size_fits_the_chip(topo, one_chip):
+    """The program HBMSlotChannel runs for ``osu1.alltoall.128MiB.dev``:
+    eight operands of 128 MiB. Since ISSUE 33 its outputs are the ranks'
+    results: eight flat ``f32[33554432]``, output r block r of every
+    operand in sender order. The chip's compiler takes it and asks for
+    the 1 GiB of outputs beside the 1 GiB of operands and for no
+    temporary: at most one fusion an operand, each reading its operand
+    once and writing into all eight outputs in place, 2 GiB through HBM
+    (the cell's ``least_bytes``); no stack, no transposed ``(8, 8, c)``,
+    nothing left to cut out or relay afterwards. No ``mv2t_`` kernel
+    runs in it, which is why the cell is on neither ``kernel_us`` nor
+    ``kernel_roofline_pct``."""
+    nbytes = 128 * MiB
+    compiled, outs, moving = _slot_compiled(topo, one_chip, "alltoall",
+                                            nbytes)
+    mem = compiled.memory_analysis()
+    assert 8 * nbytes <= mem.output_size_in_bytes < 8 * nbytes + KiB
     assert mem.temp_size_in_bytes < MiB
     text = compiled.as_text()
     assert "tpu_custom_call" not in text and "mv2t_" not in text
-    entry = _entry_ops(text)
-    moving = [op for op, _ in entry if op not in ("parameter", "bitcast")]
-    assert moving == ["concatenate"], moving
-    assert entry[-1][1] == ["8", "8", str(n // 8)]
+    assert outs == [((nbytes // 4,), "float32")] * 8, outs
+    assert 1 <= len(moving) <= 8 and set(moving) == {"fusion"}, moving
+
+
+def test_slot_reduce_scatter_block_cuts_inside_the_program(topo, one_chip,
+                                                           monkeypatch):
+    """reduce_scatter_block on eight deposits of 64 MiB: the
+    ``mv2t_slot_reduce`` call of the allreduce on the operands where
+    they lie, then eight ``(c,)`` outputs cut inside the program;
+    nothing for the leader to slice. No temporary beyond the
+    ``n``-element reduction."""
+    from mvapich2_tpu.ops import _compat
+    # the channel's programs ask the backend whether to interpret
+    monkeypatch.setattr(_compat, "on_tpu", lambda: True)
+    nbytes = 64 * MiB
+    compiled, outs, moving = _slot_compiled(
+        topo, one_chip, "reduce_scatter_block", nbytes)
+    mem = compiled.memory_analysis()
+    assert nbytes <= mem.output_size_in_bytes < nbytes + KiB
+    assert mem.temp_size_in_bytes <= nbytes
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "mv2t_slot_reduce" in text
+    assert outs == [((nbytes // 32,), "float32")] * 8, outs
+    assert moving.count("custom-call") == 1 and len(moving) <= 9, moving
 
 
 @pytest.fixture

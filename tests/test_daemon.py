@@ -298,18 +298,49 @@ def test_exec_cache_device_build_roundtrip(ddir):
     _reload(MV2T_DAEMON_DIR=None, MV2T_ALLREDUCE_ALGO=None)
 
 
-def test_exec_cache_parent_artifact_is_never_offered(ddir, monkeypatch):
-    """The mesh program's signature changed under an unchanged (name, n,
-    dtype, op, root) when its operand became flat (ISSUE 29), so the key
-    moved from ``mv2t-exec-v1`` to ``v2``: an artifact a parent of that
-    change exported on this machine is never asked for and never
-    deserialized, whatever else of its key matches."""
+def _mesh_allreduce(comm):
     import numpy as np
+    out = comm.allreduce(np.full(16384, float(comm.rank + 1), np.float32))
+    assert out[0] == sum(range(1, comm.size + 1))
+
+
+def _slot_alltoall(comm):
+    import numpy as np
+    n = comm.size * 256
+    x = np.arange(n, dtype=np.float32) + 10000.0 * comm.rank
+    out = np.asarray(comm.alltoall(x))
+    want = np.concatenate([
+        np.arange(comm.rank * 256, (comm.rank + 1) * 256,
+                  dtype=np.float32) + 10000.0 * s
+        for s in range(comm.size)])
+    assert out.shape == (n,) and np.array_equal(out, want)
+
+
+@pytest.mark.parametrize("ranks,ndev,app", [
+    (4, 4, _mesh_allreduce), (8, 1, _slot_alltoall)],
+    ids=["mesh-allreduce", "slot-alltoall"])
+def test_exec_cache_parent_artifact_is_never_offered(ddir, monkeypatch,
+                                                     ranks, ndev, app):
+    """A program's signature can change under an unchanged (name, n,
+    dtype, op, root, extra): the mesh programs' operand became flat
+    (ISSUE 29, key ``mv2t-exec-v1`` -> ``v2``), the slot channel's
+    alltoall and reduce_scatter_block return one output per rank where
+    they returned one array (ISSUE 33, ``v2`` -> ``v3``; the parent's
+    artifact runs on the same operands without an error and its one
+    array would be shared out as every rank's result). An artifact a
+    parent of either change exported on this machine is never asked for
+    and never deserialized, whatever else of its key matches; the
+    second job, which does load what the first exported, is still
+    right."""
+    import jax
 
     from mvapich2_tpu.ops import _compat
+    from mvapich2_tpu.parallel.mesh import make_mesh
     from mvapich2_tpu.runtime.universe import run_ranks
     _reload(MV2T_DAEMON="1", MV2T_DAEMON_DIR=ddir,
-            MV2T_DAEMON_EXEC_CACHE="1", MV2T_ALLREDUCE_ALGO="device")
+            MV2T_DAEMON_EXEC_CACHE="1", MV2T_ALLREDUCE_ALGO="device",
+            MV2T_DEVICE_COLL_MIN_BYTES="1")
+    mesh = make_mesh((ndev,), ("x",), jax.devices()[:ndev])
     asked, offered = [], []
     get, load = daemon.exec_cache_get, _compat.deserialize_executable
     monkeypatch.setattr(daemon, "exec_cache_get",
@@ -317,22 +348,19 @@ def test_exec_cache_parent_artifact_is_never_offered(ddir, monkeypatch):
     monkeypatch.setattr(_compat, "deserialize_executable",
                         lambda b: offered.append(b) or load(b))
 
-    def app(comm):
-        out = comm.allreduce(np.full(16384, float(comm.rank + 1),
-                                     np.float32))
-        assert out[0] == sum(range(1, comm.size + 1))
-
-    run_ranks(4, app, device_mesh=True)
-    assert asked and all(k.startswith("mv2t-exec-v2|") for k in asked)
-    poison = b"artifact of the (1, n) program"
-    for k in set(asked):    # the parent's key for the same signature
-        assert daemon.exec_cache_put(
-            k.replace("mv2t-exec-v2|", "mv2t-exec-v1|", 1), poison, ddir)
+    run_ranks(ranks, app, device_mesh=mesh)
+    assert asked and all(k.startswith("mv2t-exec-v3|") for k in asked)
+    poison = b"artifact of a parent's program"
+    for k in set(asked):    # the parents' keys for the same signature
+        for old in ("mv2t-exec-v1|", "mv2t-exec-v2|"):
+            assert daemon.exec_cache_put(
+                k.replace("mv2t-exec-v3|", old, 1), poison, ddir)
     del asked[:]
-    run_ranks(4, app, device_mesh=True)     # fresh channels ask again
-    assert asked and all(k.startswith("mv2t-exec-v2|") for k in asked)
-    assert poison not in offered
-    _reload(MV2T_DAEMON_DIR=None, MV2T_ALLREDUCE_ALGO=None)
+    run_ranks(ranks, app, device_mesh=mesh)     # fresh channels ask again
+    assert asked and all(k.startswith("mv2t-exec-v3|") for k in asked)
+    assert offered and poison not in offered
+    _reload(MV2T_DAEMON_DIR=None, MV2T_ALLREDUCE_ALGO=None,
+            MV2T_DEVICE_COLL_MIN_BYTES=None)
 
 
 # -- listener handoff ----------------------------------------------------
