@@ -197,7 +197,8 @@ class _ImportedProgram:
 # with the collective's ``seq`` and ``coll`` in their args so a reader
 # can join rank 0's leader phases with the other ranks' waits:
 #
-#   dev_arrive       every rank: slot deposit -> first barrier returned
+#   dev_arrive       every rank: slot deposit -> counted in at the gate;
+#                    on rank 0 also its wait for the last rank
 #   dev_stage        rank 0: assembling the program's input
 #   dev_dispatch     rank 0: program-cache lookup + enqueue; its E says
 #                    ``built`` when the call made or loaded the program
@@ -208,7 +209,8 @@ class _ImportedProgram:
 #                    the program's own outputs (the mesh and slot
 #                    channels, always), k*ndev for the fold channel's
 #                    reduce_scatter_block
-#   dev_release      every rank: the second barrier wait
+#   dev_release      rank 0: opening the gate; the others: their wait
+#                    in line, from their arrival to being let go
 #   dev_deliver      every rank, after dev_<coll> E: _deliver; its E says
 #                    ``relaid``, 1 when _deliver issued a reshape (0
 #                    for every program here: their results are flat)
@@ -246,19 +248,106 @@ def _device_resident(recvbuf) -> bool:
         or type(recvbuf).__name__ == "_InPlace"
 
 
+class _Gate:
+    """The blocking collectives' one meeting point. Every rank counts
+    itself in (``arrive``) and only the leader waits for the count; the
+    others wait to be let out (``leave``), which the leader does when
+    the results lie ready (``open``). They leave one at a time, in the
+    order they came, each letting the next go before it does anything
+    else: first in, first out, so every rank's call lasts one period of
+    the loop. Woken all at once, as a ``threading.Barrier`` wakes them,
+    eight rank threads take the interpreter lock in whatever order the
+    OS deals it, and a rank early in and late out makes the slowest
+    rank of a call a millisecond slower than the period, differently
+    from run to run (PERF.md, PR 34).
+
+    One ``threading.Lock`` a rank is its semaphore: taken at the start,
+    released by whoever lets the rank go. ``abort`` breaks the gate for
+    good, as ``threading.Barrier.abort`` did: whoever waits or comes
+    later raises ``threading.BrokenBarrierError``."""
+
+    def __init__(self, size: int):
+        self.size = size
+        self.broken = False
+        self._lock = threading.Lock()
+        self._arrived = 0
+        self._line: List[int] = []      # the waiting ranks, as they came
+        self._after: List[Optional[int]] = [None] * size
+        self._sems = [threading.Lock() for _ in range(size)]
+        for sem in self._sems:
+            sem.acquire()
+        self._leader_sem = threading.Lock()
+        self._leader_sem.acquire()
+        self._leader_waits = False
+
+    @property
+    def n_waiting(self) -> int:
+        return self._arrived
+
+    def arrive(self, rank: int, leader: bool) -> None:
+        """Count ``rank`` in; the leader returns when every rank is."""
+        with self._lock:
+            if self.broken:
+                raise threading.BrokenBarrierError
+            self._arrived += 1
+            full = self._arrived == self.size
+            if leader:
+                self._leader_waits = not full
+            else:
+                self._line.append(rank)
+                if full and self._leader_waits:
+                    self._leader_waits = False
+                    self._leader_sem.release()
+        if leader and not full:
+            self._leader_sem.acquire()
+            if self.broken:
+                raise threading.BrokenBarrierError
+
+    def open(self) -> None:
+        """The leader lets the line go, its head first."""
+        with self._lock:
+            line, self._line = self._line, []
+            self._arrived = 0
+            for rank, nxt in zip(line, line[1:] + [None]):
+                self._after[rank] = nxt
+        if line:
+            self._sems[line[0]].release()
+        if self.broken:
+            raise threading.BrokenBarrierError
+
+    def leave(self, rank: int) -> None:
+        """Wait to be let go, and let the next in line go."""
+        self._sems[rank].acquire()
+        nxt, self._after[rank] = self._after[rank], None
+        if nxt is not None:
+            self._sems[nxt].release()
+        if self.broken:
+            raise threading.BrokenBarrierError
+
+    def abort(self) -> None:
+        with self._lock:
+            self.broken = True
+            line, self._line = self._line, []
+            if self._leader_waits:
+                self._leader_waits = False
+                self._leader_sem.release()
+        for rank in line:       # a line no leader will open any more
+            self._sems[rank].release()
+
+
 class _Rendezvous:
-    """Per-bound-comm meeting point: slots for each rank's shard, two
-    barrier phases per collective (deposit -> leader compute -> pickup).
+    """Per-bound-comm meeting point: slots for each rank's shard and one
+    gate per collective (deposit -> leader compute -> pickup).
     MPI already requires every rank to issue collectives on a comm in the
     same order, so one in-flight collective per comm is the contract."""
 
     def __init__(self, size: int):
         self.size = size
-        self.barrier = threading.Barrier(size)
+        self.gate = _Gate(size)
         self.slots: List = [None] * size
         self.result: List = [None] * size
         self.error: Optional[BaseException] = None
-        # nonblocking rendezvous: no barrier to block in — ranks deposit
+        # nonblocking rendezvous: no gate to block in — ranks deposit
         # under nb_lock into per-sequence call records and the NBC DAG's
         # poll vertices observe arrival/launch/completion state instead
         self.nb_lock = threading.Lock()
@@ -266,13 +355,13 @@ class _Rendezvous:
         self.nb_failed = False
 
     def abort(self) -> None:
-        """Break the barrier so peers blocked in a device collective see
+        """Break the gate so peers blocked in a device collective see
         a failure instead of deadlocking (called when a rank dies).
-        In-flight NONBLOCKING device collectives have no barrier to
+        In-flight NONBLOCKING device collectives have no gate to
         break: the sticky nb_failed flag makes every later poll raise
         MPIX_ERR_PROC_FAILED so survivor DAGs unwind."""
         self.nb_failed = True
-        self.barrier.abort()
+        self.gate.abort()
 
 
 class _VDeposit:
@@ -596,15 +685,16 @@ class DeviceCollChannel:
         its result. Returns whatever the leader deposited for this rank
         (device array)."""
         rv = self.rv
+        leader = self.rank == 0
         with self._phase("dev_arrive"):
             rv.slots[self.rank] = local
             try:
-                rv.barrier.wait()
+                rv.gate.arrive(self.rank, leader)
             except threading.BrokenBarrierError:
                 raise RuntimeError(
                     "device collective aborted: a peer rank failed"
                 ) from None
-        if self.rank == 0:
+        if leader:
             try:
                 rv.result = self._leader(name, op, root)
                 rv.error = None
@@ -613,7 +703,10 @@ class DeviceCollChannel:
                 rv.result = [None] * self.size
         with self._phase("dev_release"):
             try:
-                rv.barrier.wait()
+                if leader:
+                    rv.gate.open()
+                else:
+                    rv.gate.leave(self.rank)
             except threading.BrokenBarrierError:
                 rv.slots[self.rank] = None
                 raise RuntimeError(
@@ -746,12 +839,9 @@ class DeviceCollChannel:
                     # noted here, in a frame that has returned before
                     # the leader runs (PERF.md, PR 26), under the seq
                     # _run is about to give the call
-                    wire = pallas_alltoall.alltoall_wire_bytes(
-                        n, dtype, self.size)
-                    mpit.pvar("dev_a2a_wire_bytes").inc(wire)
-                    if tr is not None:
-                        tr.record("device", "dev_a2a_wire", "i", coll=name,
-                                  seq=self._seq + 1, wire_bytes=wire)
+                    self._note_wire(tr, "dev_a2a_wire", name,
+                                    pallas_alltoall.alltoall_wire_bytes(
+                                        n, dtype, self.size))
                 return tier
             mpit.pvar(f"dev_coll_fallback_{reason}").inc()
             if tr is not None:
@@ -760,16 +850,24 @@ class DeviceCollChannel:
             return "xla"
         if name not in ("allreduce", "reduce", "allgather"):
             return "xla"    # ops without a ring-kernel lowering
+        p = self._mesh_extent()
         tier, reason = pallas_ici.planned_tier(name, nbytes, dtype, op,
-                                               num_devices=self._mesh_extent())
+                                               num_devices=p)
         if reason is None:
             mpit.pvar(f"dev_coll_tier_{tier}").inc()
+            if (name == "allgather" and tier == "hbm"
+                    and not self.multi_axis and p == self.size):
+                # the ring all-gather's wire, noted as the alltoall's
+                # is above: on the 1:1 binding, where the rank's shard
+                # is the kernel's operand
+                self._note_wire(getattr(comm.u.engine, "tracer", None),
+                                "dev_ag_wire", name,
+                                pallas_ici.all_gather_wire_bytes(n, dtype, p))
             if tier == "quant":
                 # the measurable half of the quant claim: bytes kept
                 # off the ICI wire by this call, per rank
                 from ..ops import pallas_quant
-                exact_b, wire_b = pallas_quant.wire_stats(
-                    n, dtype, self._mesh_extent())
+                exact_b, wire_b = pallas_quant.wire_stats(n, dtype, p)
                 mpit.pvar("dev_coll_quant_bytes_saved").inc(
                     max(0, exact_b - wire_b))
             return tier
@@ -779,6 +877,16 @@ class DeviceCollChannel:
             tr.record("channel", "dev_coll_fallback", "i", coll=name,
                       nbytes=int(nbytes), reason=reason)
         return "xla"
+
+    def _note_wire(self, tr, instant: str, name: str, wire: int) -> None:
+        """Sum ``wire`` into the pvar ``<instant>_bytes`` and, traced,
+        leave the ``device``-lane instant under the seq ``_run`` is
+        about to give the call."""
+        from .. import mpit
+        mpit.pvar(instant + "_bytes").inc(wire)
+        if tr is not None:
+            tr.record("device", instant, "i", coll=name,
+                      seq=self._seq + 1, wire_bytes=wire)
 
     def _run(self, comm, name: str, local, op: str = "sum",
              root: int = 0):

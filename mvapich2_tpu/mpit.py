@@ -460,6 +460,14 @@ pvar("dev_a2a_wire_bytes", PVAR_CLASS_COUNTER, "device",
      "them (alltoall_wire_bytes; counted per call in coll/device.py "
      "_note_tier, the same number as wire_bytes on the call's "
      "dev_a2a_wire trace instant)")
+pvar("dev_ag_wire_bytes", PVAR_CLASS_COUNTER, "device",
+     "bytes the HBM-streaming ring all-gather kernel (ops/pallas_ici) "
+     "sends over ICI, per rank, summed over the calls it served on the "
+     "1:1 mesh channel: p - 1 blocks a call, tile padding included, the "
+     "rank's own block excluded, as the kernel module reckons them "
+     "(all_gather_wire_bytes; counted per call in coll/device.py "
+     "_note_tier, the same number as wire_bytes on the call's "
+     "dev_ag_wire trace instant)")
 pvar("dev_coll_fallback_nbc", PVAR_CLASS_COUNTER, "device",
      "nonblocking collectives on a device-capable comm that could not "
      "route through the device tier (op/dtype/residency/size or the "

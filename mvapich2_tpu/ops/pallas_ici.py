@@ -131,19 +131,27 @@ def _as_blocks(flat: jax.Array, nblocks: int, block_rows: int,
     ``nblocks`` equal runs padded with ``fill`` to a whole-tile slab."""
     c = flat.size // nblocks
     width = block_rows * _LANES
-    x = flat.reshape(nblocks, c)
+    # one block stays flat while it is padded: a (1, c) array of a
+    # 2-byte type is tiled (2, 128) at twice its bytes, and the compiler
+    # builds it in a loop (PERF.md, PR 29 and PR 34)
+    lead = (nblocks,) if nblocks > 1 else ()
+    x = flat.reshape(lead + (c,))
     if width > c:
-        x = jnp.pad(x, ((0, 0), (0, width - c)), constant_values=fill)
+        x = jnp.pad(x, ((0, 0),) * len(lead) + ((0, width - c),),
+                    constant_values=fill)
     return x.reshape(nblocks, block_rows, _LANES)
 
 
 def _from_blocks(blocks: jax.Array, c: int) -> jax.Array:
-    """Inverse of :func:`_as_blocks`: drop each block's pad, flatten."""
+    """Inverse of :func:`_as_blocks`: drop each block's pad, flatten.
+    Padded blocks are cut one by one out of their own tiles and joined:
+    a slice of the ``(nb, width)`` view costs the compiler a relayout
+    of the whole result and a loop behind it (PERF.md, PR 34)."""
     nb = blocks.shape[0]
-    x = blocks.reshape(nb, -1)
-    if x.shape[1] > c:
-        x = x[:, :c]
-    return x.reshape(nb * c)
+    if blocks.size // nb > c:
+        return jnp.concatenate([blocks[i].reshape(-1)[:c]
+                                for i in range(nb)])
+    return blocks.reshape(nb * c)
 
 
 def _entry_barrier(peers) -> None:
@@ -650,6 +658,17 @@ def hbm_ring_all_gather(x: jax.Array, axis_name: str, num_devices: int,
         mesh_ctx, _as_blocks(x.reshape(m), 1, rows)[0])
     out = _from_blocks(out, m)
     return out.reshape((p * shape[0],) + shape[1:]) if shape else out
+
+
+def all_gather_wire_bytes(nelems: int, dtype, num_devices: int) -> int:
+    """Bytes one shard sends over ICI in one run of
+    ``hbm_ring_all_gather`` on a ``[nelems]`` shard: ``p - 1`` blocks
+    (its own, then each one it forwards; both lanes' halves together
+    are one block a round), each the shard rounded up to whole tiles.
+    The shard's own copy into the output is an HBM-to-HBM DMA and never
+    reaches the wire. As many bytes arrive."""
+    return ((num_devices - 1) * _tile_rows(nelems, dtype) * _LANES
+            * np.dtype(dtype).itemsize)
 
 
 def hbm_ring_reduce_scatter(x: jax.Array, axis_name: str,

@@ -187,9 +187,9 @@ def _device_report(u) -> list:
              f"rank {ch.rank}/{ch.size})"]
     rv = getattr(ch, "rv", None)
     if rv is not None:
-        bar = rv.barrier
-        lines.append(f"  rendezvous: {bar.n_waiting}/{rv.size} ranks "
-                     f"waiting, broken={bar.broken}")
+        gate = rv.gate
+        lines.append(f"  rendezvous: {gate.n_waiting}/{rv.size} ranks "
+                     f"waiting, broken={gate.broken}")
     try:
         pvs = []
         for name in ("dev_coll_tier_vmem", "dev_coll_tier_hbm",

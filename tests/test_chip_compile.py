@@ -201,6 +201,27 @@ def test_slot_alltoall_at_the_fft_cell_size_fits_the_chip(topo, one_chip):
     assert 1 <= len(moving) <= 8 and set(moving) == {"fusion"}, moving
 
 
+def test_slot_alltoall_at_16MiB_is_another_program(topo, one_chip):
+    """``osu1.alltoall.16MiB.dev``'s program: the same eight fusions and
+    eight flat outputs, but at 16 MiB a rank the compiler feeds them
+    through memory space ``S(1)`` with asynchronous slices and copies
+    around them, which the 128 MiB program has none of: 72 device ops a
+    call on the chip where the 128 MiB cell runs 8 (PERF.md, PR 34)."""
+    nbytes = 16 * MiB
+    compiled, outs, moving = _slot_compiled(topo, one_chip, "alltoall",
+                                            nbytes)
+    mem = compiled.memory_analysis()
+    assert 8 * nbytes <= mem.output_size_in_bytes < 8 * nbytes + KiB
+    assert mem.temp_size_in_bytes < MiB
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text and "mv2t_" not in text
+    assert outs == [((nbytes // 4,), "float32")] * 8, outs
+    assert moving.count("fusion") == 8
+    assert set(moving) - {"fusion"} <= {"slice-start", "slice-done",
+                                        "copy-start", "copy-done",
+                                        "custom-call"}, moving
+
+
 def test_slot_reduce_scatter_block_cuts_inside_the_program(topo, one_chip,
                                                            monkeypatch):
     """reduce_scatter_block on eight deposits of 64 MiB: the
@@ -274,6 +295,49 @@ def test_mesh_program_is_the_kernel_between_bitcasts(mesh4, monkeypatch,
     assert set(ops) <= {"parameter", "bitcast", "custom-call", "copy"}, ops
     assert ops.count("custom-call") == 1 and ops.count("copy") <= 1, ops
     assert not [dims for _, dims in entry if dims[:1] == ["1"]], entry
+
+
+@pytest.mark.parametrize("n,moving,args,outs,temp", [
+    # the cell: 16 MiB in, 64 MiB out, nothing held besides
+    (8388608, ["custom-call", "copy"], 16 * MiB, 64 * MiB, 0),
+    # Moonlight's own shard, 60 937.125 rows of 128: one pad in, the
+    # padded blocks cut out of their own tiles and joined; a quarter of
+    # the result as temporary (the parent: 24 ops, two ``while`` loops,
+    # a temporary the size of the result; 3.6 ms on the chip for 1.0)
+    (7799952, ["fusion", "custom-call", "fusion", "concatenate"],
+     15601664, 62400512, 16 * MiB),
+], ids=["cell", "moonlight"])
+def test_allgather_program_at_its_own_size(mesh4, monkeypatch,
+                                           default_tier_edges, n, moving,
+                                           args, outs, temp):
+    """``osu4.allgather.16MiB.dev``'s program as the leader builds it,
+    at the cell's 8 388 608 bfloat16 a rank and at the FSDP shard the
+    cell pads: the streaming ring between bitcasts and the one ROOT
+    copy, or between one pad and one un-pad."""
+    import jax
+    import ml_dtypes
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from mvapich2_tpu.coll.device import DeviceCollChannel, _Rendezvous
+    from mvapich2_tpu.ops import _compat, pallas_ici
+    monkeypatch.setattr(_compat, "on_tpu", lambda: True)
+    monkeypatch.setattr(pallas_ici, "on_tpu", lambda: True)
+    dt = np.dtype(ml_dtypes.bfloat16)
+    assert pallas_ici.planned_tier("allgather", P4 * n * dt.itemsize, dt,
+                                   None) == ("hbm", None)
+    ch = DeviceCollChannel(mesh4, "x", _Rendezvous(P4), 0)
+    x = jax.ShapeDtypeStruct((P4 * n,), dt,
+                             sharding=NamedSharding(mesh4, P("x")))
+    compiled = ch._build("allgather", n, None, 0).lower(x).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "mv2t_hbm_all_gather" in text
+    ops = [op for op, _ in _entry_ops(text) if op not in (
+        "parameter", "bitcast", "get-tuple-element", "tuple")]
+    assert ops == moving, ops
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes, mem.output_size_in_bytes) == \
+        (args, outs)
+    assert mem.temp_size_in_bytes <= temp
 
 
 _RING_SIZES = [(4 * KiB, "float32"), (1 * MiB, "float32"),

@@ -19,7 +19,7 @@ from jax.sharding import PartitionSpec as P
 
 import plain_reference as ref
 from mvapich2_tpu import mpit
-from mvapich2_tpu.ops import pallas_alltoall
+from mvapich2_tpu.ops import pallas_alltoall, pallas_ici
 from mvapich2_tpu.parallel.mesh import make_mesh
 from mvapich2_tpu.runtime.universe import run_ranks
 from mvapich2_tpu.utils.config import get_config
@@ -75,7 +75,8 @@ def _drive(channel, inputs, call, calls=1, on_device=True):
     rank's own device."""
     ranks, _ndev, klass, levels = CHANNELS[channel]
     assert len(inputs) == ranks
-    watch = levels + ("dev_coll_tier_hbm", "dev_a2a_wire_bytes")
+    watch = levels + ("dev_coll_tier_hbm", "dev_a2a_wire_bytes",
+                      "dev_ag_wire_bytes")
     before = {n: mpit.pvar(n).read() for n in watch}
     fb0 = _fallbacks()
     got, on_own = [None] * ranks, [None] * ranks
@@ -152,6 +153,55 @@ def test_alltoall_wire_bytes_by_hand():
     assert pallas_alltoall.alltoall_wire_bytes(4000, F32, 4) == 3 * 4096
     # a skewed alltoallv pads every step to its largest pair (A10)
     assert pallas_alltoall.wire_bytes((8, 16, 0, 24), F32) == 40 * 128 * 4
+
+
+# -- allgather (ISSUE 34): both element types, a whole-tile shard and an
+# FSDP-ragged one, two channels. One expert layer of Moonlight-16B-A3B
+# outside its routed experts is 31 199 808 parameters, 7 799 952 a rank
+# over four: 60 937.125 rows of 128. Cut to what the interpreter holds,
+# 7 799 952 // 2**10 = 7 617 elements are 59.5 rows: no whole row, let
+# alone a whole tile; 8 192 are whole tiles of both types.
+FSDP_SHARD = 7_799_952
+RAGGED = FSDP_SHARD >> 10       # 7 617
+
+
+@pytest.mark.parametrize("n", [8192, RAGGED])
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=str)
+@pytest.mark.parametrize("channel", ["mesh", "slot"])
+def test_allgather_is_the_plain_reference(interpreted, channel, dtype, n):
+    ranks = CHANNELS[channel][0]
+    inputs = [_data(3401, r, n, dtype, 2 ** 20 if dtype == F32 else 16)
+              for r in range(ranks)]
+    got, rose = _drive(channel, inputs, lambda comm, x: comm.allgather(x),
+                       calls=2)
+    _bit_equal(got, ref.allgather(inputs))
+    if channel == "mesh":
+        # the streaming ring carried it, and reckoned its wire: p - 1
+        # blocks a rank a call, each the shard rounded up to whole tiles
+        assert rose["dev_coll_tier_hbm"] == ranks * 2
+        tile = 2048 if dtype == BF16 else 1024
+        padded = -(-n // tile) * tile
+        assert rose["dev_ag_wire_bytes"] == \
+            ranks * 2 * (ranks - 1) * padded * dtype.itemsize
+    else:
+        assert rose["dev_ag_wire_bytes"] == 0
+    assert rose["dev_a2a_wire_bytes"] == 0
+
+
+@pytest.mark.parametrize("n,dtype,p,want", [
+    # the benchmark cell: 16 MiB of bfloat16 a rank is 4 096 whole tiles
+    (8388608, BF16, 4, 3 * 16777216),
+    # Moonlight's own shard: 60 937.125 rows travel as 3 809 (16, 128)
+    # tiles, 60 944 rows
+    (FSDP_SHARD, BF16, 4, 3 * 60944 * 128 * 2),
+    # 7 617 elements: four (16, 128) tiles of bfloat16, eight (8, 128)
+    # of float32, 8 192 elements either way
+    (RAGGED, BF16, 4, 3 * 8192 * 2),
+    (RAGGED, F32, 4, 3 * 8192 * 4),
+    (8192, F32, 8, 7 * 8192 * 4),
+], ids=["cell", "moonlight", "ragged-bf16", "ragged-f32", "whole-f32"])
+def test_all_gather_wire_bytes_by_hand(n, dtype, p, want):
+    assert pallas_ici.all_gather_wire_bytes(n, dtype, p) == want
 
 
 # tokens device i sends expert j, 64 a device on four devices, as the MoE
@@ -355,3 +405,35 @@ def test_the_alltoall_call_carries_its_wire_bytes_in_the_trace(
         begun = [a["seq"] for _t, _l, name, ph, a in lane
                  if name == "dev_alltoall" and ph == "B"]
         assert begun == [2, 3]
+
+
+def test_the_allgather_call_carries_its_wire_bytes_in_the_trace(
+        interpreted, monkeypatch):
+    """One ``dev_ag_wire`` instant a call in the device lane, under the
+    call's own ``seq``, with the count the pvar sums; an alltoall
+    between two of them leaves none of this name."""
+    monkeypatch.setenv("MV2T_TRACE", "1")
+    get_config().reload()
+    inputs = [_data(3402, r, RAGGED, BF16, 16) for r in range(4)]
+    lanes = [None] * 4
+    before = mpit.pvar("dev_ag_wire_bytes").read()
+
+    def app(comm):
+        x = jax.device_put(inputs[comm.rank], comm.device_channel.device)
+        comm.allgather(x)               # seq 1
+        comm.alltoall(x[:4096])         # seq 2: the other kernel's wire
+        comm.allgather(x)               # seq 3
+        lanes[comm.rank] = [e for e in comm.u.engine.tracer.events
+                            if e[1] == "device"]
+
+    run_ranks(4, app, device_mesh=_mesh("mesh"))
+    wire = 3 * 8192 * 2
+    assert mpit.pvar("dev_ag_wire_bytes").read() - before == 4 * 2 * wire
+    for lane in lanes:
+        wires = [(a["seq"], a["coll"], a["wire_bytes"])
+                 for _t, _l, name, ph, a in lane
+                 if name == "dev_ag_wire" and ph == "i"]
+        assert wires == [(1, "allgather", wire), (3, "allgather", wire)]
+        begun = [a["seq"] for _t, _l, name, ph, a in lane
+                 if name == "dev_allgather" and ph == "B"]
+        assert begun == [1, 3]
