@@ -32,6 +32,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .. import mpit
 from ..utils.config import cvar, get_config
 from ..utils.mlog import get_logger
 
@@ -57,6 +58,9 @@ cvar("DEVICE_NBC_MAX_SEGS", 8, int, "coll",
      "splitting would thrash the program/executable caches).")
 
 from ..utils import is_device_array  # noqa: E402 — shared predicate
+
+# counted in every rank's every call, so bound once, not fetched by name
+_DEPOSIT_AS_IS = mpit.pvar("dev_deposit_as_is")
 
 # -- MV2T_JAX_PROFILE: hardware-profiler bracket ------------------------
 # When the cvar names a directory, the FIRST device collective starts a
@@ -673,7 +677,7 @@ class DeviceCollChannel:
         if isinstance(slot, _VDeposit):
             slot = slot.data
         if is_device_array(slot):
-            return int(np.prod(slot.shape)), np.dtype(str(slot.dtype))
+            return slot.size, slot.dtype
         arr = np.asarray(slot)
         return int(arr.size), arr.dtype
 
@@ -784,7 +788,6 @@ class DeviceCollChannel:
             lay = lay and s is dep
             shards.append(s)
         if lay:
-            from .. import mpit
             mpit.pvar("dev_mesh_operands").inc()
         return shards
 
@@ -821,7 +824,6 @@ class DeviceCollChannel:
         dispatch span and the lat_dev_<tier> histogram key off it."""
         if self.mesh is None:
             return "slot"   # single-device slot channel: no ICI tiers
-        from .. import mpit
         from ..ops import pallas_ici
         n, dtype = self._slot_extent(local)
         nbytes = n * dtype.itemsize * (self.size if name == "allgather"
@@ -882,20 +884,21 @@ class DeviceCollChannel:
         """Sum ``wire`` into the pvar ``<instant>_bytes`` and, traced,
         leave the ``device``-lane instant under the seq ``_run`` is
         about to give the call."""
-        from .. import mpit
         mpit.pvar(instant + "_bytes").inc(wire)
         if tr is not None:
             tr.record("device", instant, "i", coll=name,
                       seq=self._seq + 1, wire_bytes=wire)
 
-    def _run(self, comm, name: str, local, op: str = "sum",
+    def _run(self, comm, name: str, local, as_is: bool, op: str = "sum",
              root: int = 0):
         """Traced dispatch: one B/E span in the 'device' lane carrying
         tier/op/bytes/duration around the whole rendezvous+execute, the
         phase spans inside it (``_phase``), and the MV2T_JAX_PROFILE
         bracket for hardware runs. Every span of one collective carries
         its ``seq``: this rank's count of blocking collectives on the
-        channel, equal on every rank because MPI orders collectives.
+        channel, equal on every rank because MPI orders collectives;
+        the B also says ``as_is``, ``_as_local``'s word that ``local`` is
+        the caller's own array object.
         While a recorder is attached the call also lies on the jax
         profiler's host plane as a TraceAnnotation of the same name, so
         an MV2T_JAX_PROFILE trace shows it beside the device's ops."""
@@ -903,7 +906,6 @@ class DeviceCollChannel:
 
         tier = self._note_tier(comm, name, local,
                                op if name != "bcast" else None)
-        from .. import mpit
         for lv in self.LEVELS:   # which hierarchy levels this call rides
             mpit.pvar(f"coll_level_{lv}").inc()
         self._seq += 1
@@ -915,7 +917,7 @@ class DeviceCollChannel:
             tr.record("device", f"dev_{name}", "B", tier=tier, op=op,
                       bytes=int((local.data if isinstance(local, _VDeposit)
                                  else local).nbytes),
-                      seq=self._seq, coll=name)
+                      seq=self._seq, coll=name, as_is=as_is)
             note = jax.profiler.TraceAnnotation(f"dev_{name}",
                                                 seq=self._seq)
         _maybe_start_jax_profile()
@@ -949,31 +951,31 @@ class DeviceCollChannel:
 
     # -- MPI-shaped entry points (match coll_fns signatures) -------------
     def allreduce(self, comm, sendbuf, recvbuf, count, datatype, op):
-        local = _as_local(sendbuf, recvbuf, count)
-        out = self._run(comm, "allreduce", local, op=_op_name(op))
+        local, as_is = _as_local(sendbuf, recvbuf, count)
+        out = self._run(comm, "allreduce", local, as_is, op=_op_name(op))
         return self._hand_back(out, recvbuf)
 
     def reduce(self, comm, sendbuf, recvbuf, count, datatype, op, root):
-        local = _as_local(sendbuf, recvbuf, count)
-        out = self._run(comm, "reduce", local, op=_op_name(op))
+        local, as_is = _as_local(sendbuf, recvbuf, count)
+        out = self._run(comm, "reduce", local, as_is, op=_op_name(op))
         if comm.rank != root:
             return None
         return self._hand_back(out, recvbuf)
 
     def bcast(self, comm, buf, count, datatype, root):
-        out = self._run(comm, "bcast", _as_local(buf, buf, count),
+        out = self._run(comm, "bcast", *_as_local(buf, buf, count),
                         root=root)
         return self._hand_back(out, buf)
 
     def allgather(self, comm, sendbuf, recvbuf, count, datatype):
-        local = _as_local(sendbuf, recvbuf, count,
-                          in_place_start=comm.rank * count)
-        out = self._run(comm, "allgather", local, op=None)
+        local, as_is = _as_local(sendbuf, recvbuf, count,
+                                 in_place_start=comm.rank * count)
+        out = self._run(comm, "allgather", local, as_is, op=None)
         return self._hand_back(out, recvbuf)
 
     def alltoall(self, comm, sendbuf, recvbuf, count, datatype):
-        local = _as_local(sendbuf, recvbuf, count * comm.size)
-        out = self._run(comm, "alltoall", local)
+        local, as_is = _as_local(sendbuf, recvbuf, count * comm.size)
+        out = self._run(comm, "alltoall", local, as_is)
         return self._hand_back(out, recvbuf)
 
     def alltoallv(self, comm, sendbuf, scounts, sdispls, recvbuf,
@@ -984,7 +986,7 @@ class DeviceCollChannel:
         canonical packed result is rearranged to the caller's rdispls
         on the way out."""
         dep = _VDeposit(_pack_v(sendbuf, scounts, sdispls), scounts)
-        out = self._run(comm, "alltoallv", dep, op=None)
+        out = self._run(comm, "alltoallv", dep, False, op=None)
         return self._hand_back(out, recvbuf, rcounts, rdispls)
 
     def _deliver_v(self, out, recvbuf, rcounts, rdispls):
@@ -1017,8 +1019,8 @@ class DeviceCollChannel:
 
     def reduce_scatter_block(self, comm, sendbuf, recvbuf, count, datatype,
                              op):
-        local = _as_local(sendbuf, recvbuf, count * comm.size)
-        out = self._run(comm, "reduce_scatter_block", local,
+        local, as_is = _as_local(sendbuf, recvbuf, count * comm.size)
+        out = self._run(comm, "reduce_scatter_block", local, as_is,
                         op=_op_name(op))
         return self._hand_back(out, recvbuf)
 
@@ -1116,7 +1118,7 @@ class DeviceCollChannel:
         if name == "alltoallv":
             local = _VDeposit(_pack_v(sendbuf, scounts, sdispls), scounts)
         else:
-            local = _as_local(sendbuf, recvbuf, n)
+            local, _ = _as_local(sendbuf, recvbuf, n)
         return self._build_nonblocking(comm, name, local, opn or "sum",
                                        root, recvbuf, rcounts, rdispls)
 
@@ -1175,7 +1177,6 @@ class DeviceCollChannel:
         itself happens here, on the first poll past full arrival."""
         import time as _time
 
-        from .. import mpit
         from ..core.errors import MPIException, MPIX_ERR_PROC_FAILED
         rv = self.rv
         if rv.nb_failed:
@@ -1413,7 +1414,6 @@ class HBMSlotChannel(DeviceCollChannel):
             xs = (rv.slots[root],) if name == "bcast" else tuple(rv.slots)
             if all(is_device_array(s) and s.devices() == {self.device}
                    for s in xs):
-                from .. import mpit
                 mpit.pvar("dev_slot_operands").inc()
             else:
                 # host slots, or device arrays committed elsewhere on a
@@ -1635,18 +1635,27 @@ def _pack_v(sendbuf, scounts, sdispls):
 
 
 def _as_local(sendbuf, recvbuf, count: int, in_place_start: int = 0):
-    """This rank's contribution as a flat [count] array (device or host).
-    MPI_IN_PLACE reads from recvbuf; ``in_place_start`` selects the
-    rank's chunk (allgather-style in-place semantics)."""
+    """This rank's contribution as a flat [count] array (device or host)
+    and whether it is the caller's own array object. MPI_IN_PLACE reads
+    from recvbuf; ``in_place_start`` selects the rank's chunk
+    (allgather-style in-place semantics).
+
+    A flat device array asked for whole is deposited as it is (counted:
+    dev_deposit_as_is). jax's ``reshape(-1)[0:n]`` hands back that same
+    object too, after a hundred microseconds of its indexing machinery
+    in every rank's slice of the interpreter lock (PERF.md, PR 35)."""
     buf = sendbuf
     start = 0
     if type(sendbuf).__name__ == "_InPlace":
         buf = recvbuf
         start = in_place_start
     if is_device_array(buf):
-        return buf.reshape(-1)[start:start + count]
+        if start == 0 and buf.ndim == 1 and count == buf.shape[0]:
+            _DEPOSIT_AS_IS.inc()
+            return buf, True
+        return buf.reshape(-1)[start:start + count], False
     return np.ascontiguousarray(
-        np.asarray(buf).reshape(-1)[start:start + count])
+        np.asarray(buf).reshape(-1)[start:start + count]), False
 
 
 def _deliver(out, recvbuf):
@@ -1732,7 +1741,6 @@ def _note_turned_away(comm, name: str, nbytes: int, buf) -> None:
     to the host arm: count it (dev_coll_fallback_host_dtype) and, traced,
     drop the instant ``_note_tier`` drops for an XLA take. Once per
     call: ``_select_transport`` is asked once per call."""
-    from .. import mpit
     mpit.pvar("dev_coll_fallback_host_dtype").inc()
     tr = getattr(comm.u.engine, "tracer", None)
     if tr is not None:
@@ -1870,7 +1878,6 @@ def build_nonblocking_request(comm, name: str, *a):
                  "schedule", name, e)
         req = None
     if req is None:
-        from .. import mpit
         mpit.pvar("dev_coll_fallback_nbc").inc()
     return req
 

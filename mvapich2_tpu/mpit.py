@@ -474,6 +474,13 @@ pvar("dev_coll_fallback_nbc", PVAR_CLASS_COUNTER, "device",
      "slot channel) and took the host schedule instead — the NBC "
      "analog of the dev_coll_fallback_* family (coll/device.py "
      "build_nonblocking_request)")
+pvar("dev_deposit_as_is", PVAR_CLASS_COUNTER, "device",
+     "device collective calls, per rank, whose deposit is the caller's "
+     "own array object: a flat device array asked for whole, handed on "
+     "without a call into jax's reshape or indexing (coll/device.py "
+     "_as_local; the blocking entries and the nonblocking build); a "
+     "shaped buffer, a count below the size, MPI_IN_PLACE at an offset "
+     "and host buffers do not count")
 pvar("dev_slot_operands", PVAR_CLASS_COUNTER, "device",
      "slot-channel leader calls that handed the program the deposited "
      "device arrays as they lay — R operands, no stack, no staging "

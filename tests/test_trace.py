@@ -385,7 +385,7 @@ def test_mixed_abi_merged_trace(tmp_path):
     assert "flat_fanin" in by_pid[0] and "flat_fanin" in by_pid[1]
     # python ranks still carry mpi spans, on the same rebased axis
     py_mpi = [e for e in merged["traceEvents"] if e.get("ph") != "M"
-              and e["cat"] == "mpi" and e["pid"] in (1, 3)]
+              and e.get("cat") == "mpi" and e["pid"] in (1, 3)]
     assert py_mpi
     lo = min(e["ts"] for e in merged["traceEvents"]
              if e.get("ph") != "M")
