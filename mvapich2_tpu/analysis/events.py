@@ -12,7 +12,8 @@ found by hand) becomes a mechanically caught lint finding:
     emit a (layer, name) the conformance grammar covers — f-string
     names become prefix patterns (``f"rma_{kind}"`` -> ``rma_*``), and
     a name passed through a wrapper parameter is resolved one level
-    through the wrapper's call sites (the ``_trace_rma`` idiom);
+    through the wrapper's call sites (the ``_trace_rma`` idiom), a
+    local bound once to a literal or an f-string reads as that;
   * every ``_NT_EVENTS`` member (trace/native.py's NTE->region map —
     the python mirror the native pass already proves dense against the
     C enum) must carry a protocol region AND be covered by the
@@ -135,6 +136,16 @@ class EventCoveragePass(LintPass):
             return [pat]
         if isinstance(node, ast.Name):
             fdef = self._enclosing_def(call, parents)
+            # a local bound once in the function, to a literal or an
+            # f-string (coll/device.py:_run's ``span``), reads as that
+            bound = [] if fdef is None else [
+                n.value for n in ast.walk(fdef)
+                if isinstance(n, ast.Assign) and any(
+                    isinstance(t, ast.Name) and t.id == node.id
+                    for t in n.targets)]
+            pat = _name_pattern(bound[0]) if len(bound) == 1 else None
+            if pat is not None:
+                return [pat]
             if fdef is not None and node.id in \
                     [a.arg for a in fdef.args.args]:
                 idx = [a.arg for a in fdef.args.args].index(node.id)

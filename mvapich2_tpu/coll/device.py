@@ -32,6 +32,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .. import metrics as _metrics
 from .. import mpit
 from ..utils.config import cvar, get_config
 from ..utils.mlog import get_logger
@@ -223,25 +224,40 @@ class _ImportedProgram:
 # through ``_phase``'s callers) and every E sits in ``__exit__``.
 
 _NO_PHASE = contextlib.nullcontext()    # ``with`` target while untraced
+_profiler = None    # ``jax.profiler``, bound by the first traced ``_run``
 
 
 class _Phase:
-    """One open phase span; leaving it records the E with whatever the
-    site put into ``args`` meanwhile. ``end`` is the recorder's
-    ``record`` already bound to the span's lane, name and ``"E"``, so
-    the name stays the literal the events lint saw at the B."""
+    """One open phase span: the recorder, the span's name and the args
+    its B went into the ring with. A site puts what it learns meanwhile
+    into ``args`` (a dict of its own, made on first use); leaving
+    records the E, with the B's args as they are, or a copy with the
+    site's beside them: a B in the ring is never changed."""
 
-    __slots__ = ("_end", "args")
+    __slots__ = ("_tr", "_name", "_began", "_more")
 
-    def __init__(self, end, args: dict):
-        self._end = end
-        self.args = args
+    def __init__(self, tr, name: str, began: dict):
+        self._tr = tr
+        self._name = name
+        self._began = began
+        self._more = None
+
+    @property
+    def args(self) -> dict:
+        more = self._more
+        if more is None:
+            more = self._more = {}
+        return more
 
     def __enter__(self):
         return self
 
     def __exit__(self, *exc):
-        self._end(**self.args)
+        more = self._more
+        began = self._began if more is None else {**self._began, **more}
+        # the name _phase's B went under, which the events lint read
+        self._tr.record("device", self._name, "E",  # mv2tlint: ignore[events]
+                        began)
         return False
 
 
@@ -394,11 +410,12 @@ class DeviceCollChannel:
                                   "allgather", "alltoall",
                                   "reduce_scatter_block", "alltoallv")
     # what _phase reads, set per call by _run: the rank's recorder while
-    # its blocking collective is traced, that collective's name, and its
-    # ``seq`` (the blocking collectives this rank has begun)
+    # its blocking collective is traced, its ``seq`` (the blocking
+    # collectives this rank has begun), and the one args dict every
+    # phase event of the call carries, ``seq`` and ``coll``
     _tr = None
-    _coll = ""
     _seq = 0
+    _args: Optional[dict] = None
 
     def __init__(self, mesh, axis, rendezvous: _Rendezvous, rank: int):
         self.mesh = mesh
@@ -462,10 +479,9 @@ class DeviceCollChannel:
         tr = self._tr
         if tr is None:
             return _NO_PHASE
-        args = {"seq": self._seq, "coll": self._coll}
-        tr.record("device", name, "B", **args)
-        return _Phase(functools.partial(tr.record, "device", name, "E"),
-                      args)
+        args = self._args
+        tr.record("device", name, "B", args)
+        return _Phase(tr, name, args)
 
     def _chan_desc(self) -> str:
         """The mesh half of the executable-cache key: channel flavor,
@@ -886,24 +902,26 @@ class DeviceCollChannel:
         about to give the call."""
         mpit.pvar(instant + "_bytes").inc(wire)
         if tr is not None:
-            tr.record("device", instant, "i", coll=name,
-                      seq=self._seq + 1, wire_bytes=wire)
+            tr.record("device", instant, "i",
+                      {"coll": name, "seq": self._seq + 1,
+                       "wire_bytes": wire})
 
     def _run(self, comm, name: str, local, as_is: bool, op: str = "sum",
              root: int = 0):
-        """Traced dispatch: one B/E span in the 'device' lane carrying
-        tier/op/bytes/duration around the whole rendezvous+execute, the
+        """Traced dispatch: one B/E span in the 'device' lane around the
+        whole rendezvous+execute, its B carrying tier/op/bytes, the
         phase spans inside it (``_phase``), and the MV2T_JAX_PROFILE
         bracket for hardware runs. Every span of one collective carries
         its ``seq``: this rank's count of blocking collectives on the
         channel, equal on every rank because MPI orders collectives;
         the B also says ``as_is``, ``_as_local``'s word that ``local`` is
-        the caller's own array object.
+        the caller's own array object. The span's length is its two
+        stamps' difference; no clock is read for a call that is neither
+        traced nor metered (``metrics.LIVE``).
         While a recorder is attached the call also lies on the jax
         profiler's host plane as a TraceAnnotation of the same name, so
         an MV2T_JAX_PROFILE trace shows it beside the device's ops."""
-        import time as _time
-
+        global _profiler
         tier = self._note_tier(comm, name, local,
                                op if name != "bcast" else None)
         for lv in self.LEVELS:   # which hierarchy levels this call rides
@@ -912,30 +930,35 @@ class DeviceCollChannel:
         tr = self._tr = getattr(comm.u.engine, "tracer", None)
         note = _NO_PHASE
         if tr is not None:
-            import jax
-            self._coll = name
-            tr.record("device", f"dev_{name}", "B", tier=tier, op=op,
-                      bytes=int((local.data if isinstance(local, _VDeposit)
-                                 else local).nbytes),
-                      seq=self._seq, coll=name, as_is=as_is)
-            note = jax.profiler.TraceAnnotation(f"dev_{name}",
-                                                seq=self._seq)
+            if _profiler is None:
+                import jax
+                _profiler = jax.profiler
+            span = f"dev_{name}"
+            # the one dict the call's E and every phase event carry
+            args = self._args = {"seq": self._seq, "coll": name}
+            tr.record("device", span, "B",
+                      {"tier": tier, "op": op,
+                       "bytes": int((local.data
+                                     if isinstance(local, _VDeposit)
+                                     else local).nbytes),
+                       "seq": self._seq, "coll": name, "as_is": as_is})
+            note = _profiler.TraceAnnotation(span, seq=self._seq)
         _maybe_start_jax_profile()
-        t0 = _time.perf_counter()
+        mx = _metrics.LIVE
+        if mx is not None:
+            import time as _time
+            t0 = _time.perf_counter()
         try:
             with note:
                 out = self._execute(name, local, op=op, root=root)
         finally:
-            dt = _time.perf_counter() - t0
             if tr is not None:
-                tr.record("device", f"dev_{name}", "E", tier=tier,
-                          us=round(dt * 1e6, 3), seq=self._seq, coll=name)
-        from .. import metrics as _metrics
-        mx = _metrics.LIVE
+                tr.record("device", span, "E", args)
         if mx is not None:
             # the rank's time in rendezvous + leader, per tier; on the
             # mesh channel that ends at the enqueue, not at the result
-            mx.rec_us(f"lat_dev_{tier}", dt * 1e6)
+            mx.rec_us(f"lat_dev_{tier}",
+                      (_time.perf_counter() - t0) * 1e6)
         return out
 
     def _hand_back(self, out, recvbuf, *v):
@@ -1214,7 +1237,6 @@ class DeviceCollChannel:
                 if tr is not None:
                     tr.record("device", "nbc_dev_complete", "i",
                               coll=name, seg=si, us=round(dt * 1e6, 3))
-                from .. import metrics as _metrics
                 mx = _metrics.LIVE
                 if mx is not None:
                     mx.rec_us("lat_dev_nbc", dt * 1e6)

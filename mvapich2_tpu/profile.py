@@ -21,7 +21,8 @@ from __future__ import annotations
 import threading
 import time
 from collections import defaultdict
-from typing import Callable, Dict, List
+from types import MethodType
+from typing import Callable, Dict, List, Tuple
 
 from .core.comm import Comm
 
@@ -39,6 +40,10 @@ PROFILED_METHODS = [
 
 _lock = threading.Lock()
 _interceptors: List[Callable] = []
+# what a wrapped call walks, last installed first: ``_interceptors`` as
+# a tuple, made anew under ``_lock`` by install and uninstall, so a call
+# copies nothing
+_chain: Tuple[Callable, ...] = ()
 _originals: Dict[str, Callable] = {}     # the PMPI_* table
 _installed = False
 
@@ -50,18 +55,24 @@ def pmpi(name: str) -> Callable:
 
 def _make_wrapper(name: str, real: Callable) -> Callable:
     def wrapper(self, *args, **kwargs):
-        chain = list(_interceptors)
+        tools = _chain
+        if len(tools) == 1:
+            # one tool (the recorder's, as a rule): its ``call`` is the
+            # implementation bound to the comm, nothing built per call
+            return tools[0](name, MethodType(real, self), (self,) + args,
+                            kwargs)
+        if not tools:
+            return real(self, *args, **kwargs)
+        left = len(tools) - 1      # several: last installed is outermost
 
         def call(*a, **kw):
-            if chain:
-                tool = chain.pop()
-                return tool(name, call, (self,) + a, kw)
+            nonlocal left
+            if left:
+                left -= 1
+                return tools[left](name, call, (self,) + a, kw)
             return real(self, *a, **kw)
 
-        if not chain:
-            return real(self, *args, **kwargs)
-        tool = chain.pop()
-        return tool(name, call, (self,) + args, kwargs)
+        return tools[left](name, call, (self,) + args, kwargs)
 
     wrapper.__name__ = name
     wrapper.__wrapped__ = real
@@ -71,7 +82,7 @@ def _make_wrapper(name: str, real: Callable) -> Callable:
 def install(interceptor: Callable) -> None:
     """Register a tool interceptor (outermost-first, like LD_PRELOAD
     layering of PMPI tools)."""
-    global _installed
+    global _installed, _chain
     with _lock:
         if not _installed:
             for name in PROFILED_METHODS:
@@ -82,17 +93,19 @@ def install(interceptor: Callable) -> None:
                 setattr(Comm, name, _make_wrapper(name, real))
             _installed = True
         _interceptors.append(interceptor)
+        _chain = tuple(_interceptors)
 
 
 def uninstall(interceptor: Callable = None) -> None:
     """Remove one interceptor (or all); restore the raw table when the
     last tool leaves."""
-    global _installed
+    global _installed, _chain
     with _lock:
         if interceptor is None:
             _interceptors.clear()
         elif interceptor in _interceptors:
             _interceptors.remove(interceptor)
+        _chain = tuple(_interceptors)
         if not _interceptors and _installed:
             for name, real in _originals.items():
                 setattr(Comm, name, real)

@@ -29,7 +29,10 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from .. import faults as _faults
 from ..utils.config import cvar, get_config
+
+_monotonic = time.monotonic
 
 cvar("TRACE", False, bool, "trace",
      "Enable the per-rank ring-buffer event recorder (near-zero cost when "
@@ -55,31 +58,45 @@ LAYERS = ("mpi", "protocol", "channel", "progress", "nbc", "device",
 class Recorder:
     """One rank's bounded event ring. ``record`` is the only hot call."""
 
-    __slots__ = ("rank", "events", "dropped_floor")
+    __slots__ = ("rank", "events", "dropped_floor", "_append")
 
     def __init__(self, rank: int, capacity: int):
         self.rank = rank
         self.events: collections.deque = collections.deque(maxlen=capacity)
+        self._append = self.events.append
         # number of events ever recorded minus len(events) = dropped count
         self.dropped_floor = 0
 
-    def record(self, layer: str, name: str, ph: str = "i", **args) -> None:
+    def record(self, layer: str, name: str, ph: str = "i",
+               args: Optional[dict] = None, **kw) -> None:
         """Append one event. ``ph`` follows the Chrome trace-event phases:
         'B'egin / 'E'nd for spans, 'i' for instants. deque.append with a
         maxlen is atomic under the GIL, so no lock on the hot path.
 
-        The ``trace_stamp`` fault site lives here: ``skip_stamp`` drops
-        the stamp, ``reorder`` swaps it behind its predecessor — seeded
-        trace corruption that the conformance checker (bin/mv2tconform)
-        must catch by a named invariant, never by silence. The site is
-        one ``fire()`` call (a single attribute test while MV2T_FAULTS
-        is empty) and corrupts only the trace, never the datapath."""
-        from .. import faults
-        kind = faults.fire("trace_stamp")
+        The event's args are keywords, or on a hot site one dict handed
+        over as the fourth argument and put into the ring as the object
+        it is: whoever hands it over does not change it afterwards.
+
+        The ``trace_stamp`` fault site lives here (``_record_faulted``);
+        while no fault is armed it costs this one attribute test of the
+        fault table, the test ``faults.fire`` itself starts with."""
+        if kw:
+            args = {**args, **kw} if args else kw
+        if _faults._active is not None:
+            return self._record_faulted(layer, name, ph, args)
+        self._append((_monotonic(), layer, name, ph, args or None))
+
+    def _record_faulted(self, layer: str, name: str, ph: str,
+                        args: Optional[dict]) -> None:
+        """``record`` while MV2T_FAULTS arms something: ``skip_stamp``
+        drops the stamp, ``reorder`` swaps it behind its predecessor —
+        seeded trace corruption that the conformance checker
+        (bin/mv2tconform) must catch by a named invariant, never by
+        silence. It corrupts only the trace, never the datapath."""
+        kind = _faults.fire("trace_stamp")
         if kind == "skip_stamp":
             return
-        self.events.append((time.monotonic(), layer, name, ph,
-                            args or None))
+        self.events.append((_monotonic(), layer, name, ph, args or None))
         if kind == "reorder" and len(self.events) >= 2:
             # swap ring position AND timestamp with the predecessor, so
             # the corruption survives both ring-order and ts-order

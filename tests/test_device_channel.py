@@ -284,9 +284,10 @@ def test_hbm_streaming_tier_end_to_end():
 
 def test_device_dispatch_spans_and_phases(monkeypatch):
     """A traced device collective drops a B/E span in the 'device' lane
-    carrying tier/op/bytes + duration, and inside it the phase spans of
-    the rendezvous (tests/test_device_phases.py holds their order and
-    nesting per channel)."""
+    whose B carries tier/op/bytes (its length is its two stamps'), and
+    inside it the phase spans of the rendezvous
+    (tests/test_device_phases.py holds their order and nesting per
+    channel)."""
     monkeypatch.setenv("MV2T_TRACE", "1")
     _reload(MV2T_DEVICE_COLL_MIN_BYTES="1")
     tiers = ("vmem", "hbm", "xla", "slot")
@@ -309,7 +310,7 @@ def test_device_dispatch_spans_and_phases(monkeypatch):
     args = bs[0][4]
     assert args["tier"] in tiers
     assert args["op"] == "sum" and args["bytes"] > 0
-    assert "us" in es[0][4]
+    assert es[0][4] == {"seq": 1, "coll": "allreduce"}
     for r, events in device_lane.items():
         names = [e[2] for e in events if e[3] == "B"]
         assert names[0] == "dev_allreduce"

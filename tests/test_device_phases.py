@@ -254,3 +254,132 @@ def test_phase_names_pass_the_events_lint():
                  "dev_deliver"):
         assert f'self._phase("{name}")' in src
         assert conform.grammar_covers("device", name)
+
+
+# -- what one event holds, and what recording it reads (ISSUE 36) --------
+
+PHASE_ARGS = {"seq", "coll"}
+# what a site learns after its B, on the E alone
+ADDED = {"dev_dispatch": "built", "dev_collect": "parts",
+         "dev_deliver": "relaid"}
+
+
+@pytest.mark.parametrize("channel", list(CHANNELS))
+def test_the_ring_holds_the_same_tuple_and_args(traced, channel):
+    """Every event is ``(time.monotonic, lane, name, ph, args)``; the
+    ``dev_<coll>`` B says tier, op, bytes, seq, coll and as_is, its E
+    and every phase event seq and coll, and a phase's E besides them
+    what its site added after the B, which the B in the ring never
+    gains. ``us`` (and the E's ``tier``) are gone: the two stamps say
+    it."""
+    lanes = _run_two_collectives(channel)
+    for rank, lane in lanes.items():
+        for ev in lane:
+            t, layer, name, ph, args = ev           # five, as ever
+            assert isinstance(t, float) and layer == "device"
+            assert isinstance(args, dict)
+            if ph == "i":       # ops/pallas_ici.py's trace-time instants
+                assert name.startswith("ici_")
+                continue
+            assert ph in ("B", "E")
+            if name in ("dev_allreduce", "dev_bcast"):
+                assert set(args) == (PHASE_ARGS | (
+                    {"tier", "op", "bytes", "as_is"} if ph == "B"
+                    else set())), ev
+            elif ph == "E" and name in ADDED:
+                assert set(args) == PHASE_ARGS | {ADDED[name]}, ev
+            else:
+                assert set(args) == PHASE_ARGS, ev
+        first = next(e[4] for e in lane if e[2] == "dev_allreduce")
+        assert first["op"] == "sum" and first["bytes"] == 4 * N
+        assert first["tier"] in ("vmem", "hbm", "xla", "slot")
+        assert first["as_is"] is False              # a host buffer
+        added = {e[2]: e[4][ADDED[e[2]]] for e in lane
+                 if e[3] == "E" and e[2] in ADDED and e[4]["seq"] == 2}
+        assert added == ({"dev_dispatch": True, "dev_collect": 0,
+                          "dev_deliver": 0} if rank == 0
+                         else {"dev_deliver": 0})
+
+
+def test_the_mpi_lane_through_one_tool_and_through_two(traced):
+    """The recorder's tool alone is handed the implementation bound to
+    the comm; with a second tool installed the chain runs last
+    installed first and ends at the same implementation. Either way
+    one ``mpi`` B/E pair a call, with no args."""
+    from mvapich2_tpu import profile
+    order, lanes = [], {}
+
+    def outer(name, call, args, kwargs):
+        order.append((name, type(args[0]).__name__))
+        return call(*args[1:], **kwargs)
+
+    def app(comm):
+        x = np.ones(N, np.float32)
+        comm.allreduce(x)
+        if comm.rank == 0:
+            profile.install(outer)
+        comm.barrier()
+        try:
+            comm.allreduce(x)
+        finally:
+            comm.barrier()
+            if comm.rank == 0:
+                profile.uninstall(outer)
+        lanes[comm.rank] = [e for e in comm.u.engine.tracer.events
+                            if e[1] == "mpi" and e[2] == "allreduce"]
+
+    run_ranks(4, app, device_mesh=_mesh("slot"))
+    assert sorted(n for n, _c in order).count("allreduce") == 4
+    assert {c for _n, c in order} == {"Comm"}
+    for lane in lanes.values():
+        assert [(e[3], e[4]) for e in lane] == [("B", None), ("E", None)] * 2
+    assert not profile._installed and profile._chain == ()
+
+
+@pytest.mark.parametrize("metered", [False, True])
+def test_an_untraced_unmetered_run_reads_no_clock(monkeypatch, metered):
+    """``_run`` times the call for the ``lat_dev_<tier>`` histogram
+    alone: with no recorder and ``metrics.LIVE`` None (MV2T_METRICS=0)
+    it reads neither clock; metered, as by default, it reads
+    ``perf_counter`` twice a call."""
+    import sys
+    import time
+
+    import mvapich2_tpu.coll.device as devmod
+    from mvapich2_tpu import metrics
+    monkeypatch.delenv("MV2T_TRACE", raising=False)
+    monkeypatch.setenv("MV2T_DEVICE_COLL_MIN_BYTES", "1")
+    monkeypatch.setenv("MV2T_METRICS", "1" if metered else "0")
+    get_config().reload()
+    reads, samples = [], []
+
+    def counting(real):
+        def clock():
+            if sys._getframe(1).f_code is devmod.DeviceCollChannel._run \
+                    .__code__:
+                reads.append(real.__name__)
+            return real()
+        return clock
+
+    class _Live:
+        def rec_us(self, name, us):
+            samples.append(name)
+
+    monkeypatch.setattr(time, "perf_counter", counting(time.perf_counter))
+    monkeypatch.setattr(time, "monotonic", counting(time.monotonic))
+    monkeypatch.setattr(metrics, "LIVE", _Live() if metered else None)
+
+    def app(comm):
+        assert comm.u.engine.tracer is None
+        comm.allreduce(np.ones(N, np.float32))
+
+    try:
+        run_ranks(4, app, device_mesh=_mesh("slot"))
+    finally:
+        monkeypatch.undo()
+        get_config().reload()
+    if metered:
+        assert reads == ["perf_counter"] * 8
+        assert samples == ["lat_dev_slot"] * 4
+    else:
+        assert reads == [] and samples == []

@@ -431,3 +431,67 @@ def test_watchdog_report_carries_native_tail(monkeypatch, tmp_path):
         cwd=REPO, env=env, capture_output=True, text=True, timeout=180)
     assert r.returncode == 0, f"stdout={r.stdout}\nstderr={r.stderr}"
     assert "No Errors" in r.stdout
+
+
+# -- the recorder's hot path (ISSUE 36) -----------------------------------
+
+def test_record_enters_no_import_and_no_fire_while_no_fault_is_armed(
+        monkeypatch):
+    """One event while MV2T_FAULTS arms nothing costs the fault table's
+    one attribute test: no import statement runs and ``faults.fire`` is
+    not called; armed, the ``trace_stamp`` site fires once an event."""
+    import builtins
+
+    from mvapich2_tpu import faults
+    from mvapich2_tpu.trace.recorder import Recorder
+    from mvapich2_tpu.utils.config import get_config
+    faults.deconfigure()
+    rec = Recorder(0, 256)
+    imports, fired = [], []
+    real_import, real_fire = builtins.__import__, faults.fire
+
+    def fire(site):
+        fired.append(site)
+        return real_fire(site)
+
+    def counting_import(name, *a, **kw):
+        imports.append(name)
+        return real_import(name, *a, **kw)
+
+    monkeypatch.setattr(faults, "fire", fire)
+    args = {"seq": 1, "coll": "allreduce"}
+    builtins.__import__ = counting_import
+    try:
+        rec.record("mpi", "allreduce", "B")
+        rec.record("device", "dev_arrive", "B", args)
+        rec.record("device", "dev_arrive", "E", args, built=True)
+        rec.record("channel", "shm_send", "i", bytes=64)
+    finally:
+        builtins.__import__ = real_import
+    assert imports == [] and fired == []
+    got = list(rec.events)
+    assert [e[1:4] for e in got] == [
+        ("mpi", "allreduce", "B"), ("device", "dev_arrive", "B"),
+        ("device", "dev_arrive", "E"), ("channel", "shm_send", "i")]
+    assert got[0][4] is None
+    assert got[1][4] is args            # handed through as the object
+    assert got[2][4] == {"seq": 1, "coll": "allreduce", "built": True}
+    assert args == {"seq": 1, "coll": "allreduce"}      # and not changed
+    assert got[3][4] == {"bytes": 64}
+    assert all(isinstance(e[0], float) and len(e) == 5 for e in got)
+    assert [e[0] for e in got] == sorted(e[0] for e in got)
+
+    cfg = get_config()
+    old = cfg.get("FAULTS", "")
+    try:
+        cfg.set("FAULTS", "trace_stamp:skip_stamp:0:2")
+        assert faults.configure(0) == 1
+        rec.record("mpi", "barrier", "B")
+        rec.record("mpi", "barrier", "E")       # the second: dropped
+        rec.record("mpi", "bcast", "B")
+    finally:
+        cfg.set("FAULTS", old)
+        faults.deconfigure()
+    assert fired == ["trace_stamp"] * 3
+    assert [e[2:4] for e in list(rec.events)[4:]] == [("barrier", "B"),
+                                                      ("bcast", "B")]
