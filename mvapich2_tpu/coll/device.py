@@ -62,6 +62,9 @@ from ..utils import is_device_array  # noqa: E402 — shared predicate
 
 # counted in every rank's every call, so bound once, not fetched by name
 _DEPOSIT_AS_IS = mpit.pvar("dev_deposit_as_is")
+_PLAN_HIT = mpit.pvar("dev_call_plan_hit")
+_PLAN_FILED = mpit.pvar("dev_call_plan_filed")
+_config = get_config()
 
 # -- MV2T_JAX_PROFILE: hardware-profiler bracket ------------------------
 # When the cvar names a directory, the FIRST device collective starts a
@@ -268,6 +271,32 @@ def _device_resident(recvbuf) -> bool:
         or type(recvbuf).__name__ == "_InPlace"
 
 
+class _CallPlan:
+    """What one blocking device collective decided that its arguments,
+    the cvars and the loaded profile settle: the first call of a
+    signature on a rank walks ``_select_transport``, ``_as_local``,
+    ``_op_name`` and ``_decide_tier`` and files their answers here; every
+    later call of that signature finds them with one lookup
+    (``DeviceCollChannel.plan_of``) and runs ``_run`` on them
+    (``run_plan``). Only a call that took the device with the caller's
+    own flat array is filed, so a plan's existence says both. ``key`` is
+    what the call was found by; ``writes`` the cvar write count
+    (``Config.writes``) it was decided under: once that has moved the
+    plan is stale, and the call decides, and files, again. ``name``,
+    ``op`` and ``root`` are what ``_run`` was given. Pvars are kept as
+    objects."""
+
+    __slots__ = ("key", "writes", "name", "op", "root", "tier", "fallback",
+                 "bumps", "wire")
+
+    def __init__(self, key=None, writes: int = 0):
+        self.key = key
+        self.writes = writes
+        self.fallback = None    # the XLA lowering's (reason, nbytes)
+        self.bumps = ()         # (pvar, by how much), once per call
+        self.wire = None        # (instant, wire bytes per rank per call)
+
+
 class _Gate:
     """The blocking collectives' one meeting point. Every rank counts
     itself in (``arrive``) and only the leader waits for the count; the
@@ -416,6 +445,10 @@ class DeviceCollChannel:
     _tr = None
     _seq = 0
     _args: Optional[dict] = None
+    # the filed plan the call in ``_run`` runs on (``plan_of`` found
+    # it), and the draft a call that has to decide files on its way out
+    _plan: Optional[_CallPlan] = None
+    _draft: Optional[_CallPlan] = None
 
     def __init__(self, mesh, axis, rendezvous: _Rendezvous, rank: int):
         self.mesh = mesh
@@ -438,6 +471,16 @@ class DeviceCollChannel:
         # freed channels + their compiled executables for process life)
         self._programs: Dict = {}
         self._nb_seq = 0     # per-rank nonblocking-collective sequence
+        self._bind_calls()
+
+    def _bind_calls(self) -> None:
+        """What every blocking call on this rank's channel reads: its
+        filed plans by signature (one dict a rank: each rank has its own
+        channel object, so no lock; they go with the channel) and the
+        level pvars it bumps."""
+        self._plans: Dict[tuple, _CallPlan] = {}
+        self._level_pvars = tuple(mpit.pvar(f"coll_level_{lv}")
+                                  for lv in self.LEVELS)
 
     @property
     def multi_axis(self) -> bool:
@@ -837,74 +880,86 @@ class DeviceCollChannel:
         at the kernel wrappers (programs are cached per signature).
         Returns the tier label the call will run on ('vmem'/'hbm'/
         'quant'/'xla', 'slot' on the single-device channel) — the
-        dispatch span and the lat_dev_<tier> histogram key off it."""
+        dispatch span and the lat_dev_<tier> histogram key off it.
+
+        Which tier, and what that counts, is decided by ``_decide_tier``
+        unless ``plan_of`` found the call a filed plan: then it is the
+        plan's, decided by that same function on an earlier call. The
+        counting and the instants are this call's either way."""
+        plan = self._plan
+        if plan is None:
+            plan = self._draft or _CallPlan()
+            self._decide_tier(plan, name, local, op)
+        for pv, by in plan.bumps:
+            pv.inc(by)
+        if plan.wire is None and plan.fallback is None:
+            return plan.tier
+        tr = getattr(comm.u.engine, "tracer", None)
+        if tr is not None:
+            if plan.fallback is not None:
+                tr.record("channel", "dev_coll_fallback", "i", coll=name,
+                          nbytes=plan.fallback[1], reason=plan.fallback[0])
+            else:
+                # what the kernel this call runs puts on the wire, per
+                # rank, by the kernel module's own reckoning; noted
+                # here, in a frame that has returned before the leader
+                # runs (PERF.md, PR 26), under the seq _run is about to
+                # give the call
+                tr.record("device", plan.wire[0], "i",  # mv2tlint: ignore[events]
+                          {"coll": name, "seq": self._seq + 1,
+                           "wire_bytes": plan.wire[1]})
+        return plan.tier
+
+    def _decide_tier(self, plan: _CallPlan, name: str, local,
+                     op: Optional[str]) -> None:
+        """The one place that says which tier a device collective takes
+        and what the call therefore counts: ``plan.tier``, the pvars to
+        bump (``bumps``), the XLA lowering's ``fallback`` instant, the
+        kernel's ``wire`` instant. Reads the call's extent, the
+        cvars and the loaded profile, nothing else."""
         if self.mesh is None:
-            return "slot"   # single-device slot channel: no ICI tiers
+            plan.tier = "slot"  # single-device slot channel: no ICI tiers
+            return
         from ..ops import pallas_ici
         n, dtype = self._slot_extent(local)
         nbytes = n * dtype.itemsize * (self.size if name == "allgather"
                                        else 1)
+        p = self._mesh_extent()
         if name in ("alltoall", "alltoallv"):
             from ..ops import pallas_alltoall
             tier, reason = pallas_alltoall.planned_a2a_tier(
                 max(1, nbytes), dtype)
-            tr = getattr(comm.u.engine, "tracer", None)
-            if reason is None:
-                mpit.pvar(f"dev_coll_tier_{tier}").inc()
-                if name == "alltoall" and not self.multi_axis:
-                    # what the kernel this call runs puts on the wire,
-                    # per rank, by the kernel module's own reckoning;
-                    # noted here, in a frame that has returned before
-                    # the leader runs (PERF.md, PR 26), under the seq
-                    # _run is about to give the call
-                    self._note_wire(tr, "dev_a2a_wire", name,
-                                    pallas_alltoall.alltoall_wire_bytes(
-                                        n, dtype, self.size))
-                return tier
-            mpit.pvar(f"dev_coll_fallback_{reason}").inc()
-            if tr is not None:
-                tr.record("channel", "dev_coll_fallback", "i", coll=name,
-                          nbytes=int(nbytes), reason=reason)
-            return "xla"
-        if name not in ("allreduce", "reduce", "allgather"):
-            return "xla"    # ops without a ring-kernel lowering
-        p = self._mesh_extent()
-        tier, reason = pallas_ici.planned_tier(name, nbytes, dtype, op,
-                                               num_devices=p)
-        if reason is None:
-            mpit.pvar(f"dev_coll_tier_{tier}").inc()
-            if (name == "allgather" and tier == "hbm"
-                    and not self.multi_axis and p == self.size):
-                # the ring all-gather's wire, noted as the alltoall's
-                # is above: on the 1:1 binding, where the rank's shard
-                # is the kernel's operand
-                self._note_wire(getattr(comm.u.engine, "tracer", None),
-                                "dev_ag_wire", name,
-                                pallas_ici.all_gather_wire_bytes(n, dtype, p))
-            if tier == "quant":
-                # the measurable half of the quant claim: bytes kept
-                # off the ICI wire by this call, per rank
-                from ..ops import pallas_quant
-                exact_b, wire_b = pallas_quant.wire_stats(n, dtype, p)
-                mpit.pvar("dev_coll_quant_bytes_saved").inc(
-                    max(0, exact_b - wire_b))
-            return tier
-        mpit.pvar(f"dev_coll_fallback_{reason}").inc()
-        tr = getattr(comm.u.engine, "tracer", None)
-        if tr is not None:
-            tr.record("channel", "dev_coll_fallback", "i", coll=name,
-                      nbytes=int(nbytes), reason=reason)
-        return "xla"
-
-    def _note_wire(self, tr, instant: str, name: str, wire: int) -> None:
-        """Sum ``wire`` into the pvar ``<instant>_bytes`` and, traced,
-        leave the ``device``-lane instant under the seq ``_run`` is
-        about to give the call."""
-        mpit.pvar(instant + "_bytes").inc(wire)
-        if tr is not None:
-            tr.record("device", instant, "i",
-                      {"coll": name, "seq": self._seq + 1,
-                       "wire_bytes": wire})
+        elif name in ("allreduce", "reduce", "allgather"):
+            tier, reason = pallas_ici.planned_tier(name, nbytes, dtype, op,
+                                                   num_devices=p)
+        else:
+            plan.tier = "xla"   # ops without a ring-kernel lowering
+            return
+        if reason is not None:
+            plan.tier, plan.fallback = "xla", (reason, int(nbytes))
+            plan.bumps = ((mpit.pvar(f"dev_coll_fallback_{reason}"), 1),)
+            return
+        plan.tier = tier
+        bumps = [(mpit.pvar(f"dev_coll_tier_{tier}"), 1)]
+        if name == "alltoall" and not self.multi_axis:
+            plan.wire = ("dev_a2a_wire", pallas_alltoall.alltoall_wire_bytes(
+                n, dtype, self.size))
+        elif (name == "allgather" and tier == "hbm"
+                and not self.multi_axis and p == self.size):
+            # the ring all-gather's wire: on the 1:1 binding, where the
+            # rank's shard is the kernel's operand
+            plan.wire = ("dev_ag_wire",
+                         pallas_ici.all_gather_wire_bytes(n, dtype, p))
+        if plan.wire is not None:
+            bumps.append((mpit.pvar(plan.wire[0] + "_bytes"), plan.wire[1]))
+        if tier == "quant":
+            # the measurable half of the quant claim: bytes kept off
+            # the ICI wire by this call, per rank
+            from ..ops import pallas_quant
+            exact_b, wire_b = pallas_quant.wire_stats(n, dtype, p)
+            bumps.append((mpit.pvar("dev_coll_quant_bytes_saved"),
+                          max(0, exact_b - wire_b)))
+        plan.bumps = tuple(bumps)
 
     def _run(self, comm, name: str, local, as_is: bool, op: str = "sum",
              root: int = 0):
@@ -924,8 +979,8 @@ class DeviceCollChannel:
         global _profiler
         tier = self._note_tier(comm, name, local,
                                op if name != "bcast" else None)
-        for lv in self.LEVELS:   # which hierarchy levels this call rides
-            mpit.pvar(f"coll_level_{lv}").inc()
+        for lv in self._level_pvars:    # the hierarchy levels it rides
+            lv.inc()
         self._seq += 1
         tr = self._tr = getattr(comm.u.engine, "tracer", None)
         note = _NO_PHASE
@@ -941,7 +996,8 @@ class DeviceCollChannel:
                        "bytes": int((local.data
                                      if isinstance(local, _VDeposit)
                                      else local).nbytes),
-                       "seq": self._seq, "coll": name, "as_is": as_is})
+                       "seq": self._seq, "coll": name, "as_is": as_is,
+                       "planned": self._plan is not None})
             note = _profiler.TraceAnnotation(span, seq=self._seq)
         _maybe_start_jax_profile()
         mx = _metrics.LIVE
@@ -959,7 +1015,70 @@ class DeviceCollChannel:
             # mesh channel that ends at the enqueue, not at the result
             mx.rec_us(f"lat_dev_{tier}",
                       (_time.perf_counter() - t0) * 1e6)
+        if self._draft is not None:     # decided on this call, and it ran
+            self._file(name, local, as_is, op, root)
         return out
+
+    # -- the call plan: a signature's decisions, made once ---------------
+    def plan_of(self, name: str, sendbuf, count, datatype, op=None,
+                root: int = 0) -> Optional[_CallPlan]:
+        """``comm.<coll>``'s first question once the comm is known to be
+        alive: has this rank decided a call of this signature before,
+        under the cvars as they stand? Then here is what it filed, for
+        ``run_plan``. Else None, and the caller goes on down today's
+        chain, which decides and, where the call takes the device with
+        the caller's own flat array, files (``_file``, out of ``_run``).
+
+        The signature is what the call can observe in its arguments:
+        the collective, the buffer's type, shape and dtype, the count
+        and datatype as given, the op object and the root. A host
+        buffer, a shaped or partial one, MPI_IN_PLACE and a type or op
+        that does not lower are never filed, so they are never found."""
+        try:
+            key = (name, type(sendbuf), sendbuf.shape, sendbuf.dtype, count,
+                   datatype, op, root)
+            plan = self._plans.get(key)
+        except (AttributeError, TypeError):     # no array, or unhashable
+            self._draft = None
+            return None
+        if plan is None or plan.writes != _config.writes:
+            self._draft = _CallPlan(key, _config.writes)
+            return None
+        return plan
+
+    def run_plan(self, comm, plan: _CallPlan, sendbuf, recvbuf):
+        """One call of a filed signature: the counters, ``_run`` as it
+        is and with what it was given when the plan was filed (so the
+        program is that call's: a planned call never builds one), the
+        result handed back. ``comm.<coll>``'s lines, ``entry``,
+        ``_select_transport``, ``_as_local``, ``_op_name`` and
+        ``_decide_tier`` are not walked again."""
+        self._draft = None
+        self._plan = plan
+        try:
+            out = self._run(comm, plan.name, sendbuf, True, plan.op,
+                            plan.root)
+        finally:
+            self._plan = None
+        if plan.name == "reduce" and comm.rank != plan.key[-1]:
+            return None     # the caller's root: the key's last word
+        return self._hand_back(out, recvbuf)
+
+    def _file(self, name: str, local, as_is: bool, op, root: int) -> None:
+        """File the draft ``plan_of`` keyed and ``_decide_tier`` filled,
+        now that the call it was decided on has run: ``_run`` got here,
+        so ``_select_transport`` said device, and ``as_is`` is
+        ``_as_local``'s word that the deposit is the caller's own flat
+        array. From the next call on ``_note_tier`` counts the deposit
+        too, which ``_as_local`` did on this one. A 64-bit type lowers
+        or not by a jax flag no write count sees: decided every call."""
+        plan, self._draft = self._draft, None
+        if not as_is or local.dtype.itemsize == 8:
+            return
+        plan.name, plan.op, plan.root = name, op, root
+        plan.bumps += ((_DEPOSIT_AS_IS, 1), (_PLAN_HIT, 1))
+        self._plans[plan.key] = plan
+        _PLAN_FILED.inc()
 
     def _hand_back(self, out, recvbuf, *v):
         """``_deliver`` (``_deliver_v`` given alltoallv's ``rcounts,
@@ -1009,6 +1128,7 @@ class DeviceCollChannel:
         canonical packed result is rearranged to the caller's rdispls
         on the way out."""
         dep = _VDeposit(_pack_v(sendbuf, scounts, sdispls), scounts)
+        self._draft = None  # its key would be a count vector: never filed
         out = self._run(comm, "alltoallv", dep, False, op=None)
         return self._hand_back(out, recvbuf, rcounts, rdispls)
 
@@ -1356,6 +1476,7 @@ class HBMSlotChannel(DeviceCollChannel):
         self.size = size
         self._programs: Dict = {}
         self._nb_seq = 0
+        self._bind_calls()
 
     def _chan_desc(self) -> str:
         return f"slot{self.size}x{self.device.platform}"
@@ -1513,6 +1634,7 @@ class DeviceFoldChannel(DeviceCollChannel):
         self._mesh_devices = mesh_devs
         self._programs: Dict = {}
         self._nb_seq = 0
+        self._bind_calls()
 
     def _mesh_extent(self) -> int:
         return self.ndev
@@ -1819,6 +1941,7 @@ def install_device_coll(comm, channel: DeviceCollChannel) -> None:
             # host path selected (forced algo / op or dtype doesn't lower):
             # device-array buffers are staged through the host and the
             # result pushed back to this rank's device
+            channel._draft = None   # nothing here for a call plan
             if name == "bcast":
                 if not is_device_array(a[0]):
                     return hostfn(comm_, *a)

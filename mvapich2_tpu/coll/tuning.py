@@ -21,7 +21,7 @@ from __future__ import annotations
 
 from typing import Callable, Dict, List, Optional, Tuple
 
-from ..utils.config import cvar, get_config
+from ..utils.config import cvar, get_config, note_write
 from ..utils.mlog import get_logger
 from . import algorithms as alg
 
@@ -245,6 +245,7 @@ def load_profile(tables: Optional[Dict[str, Dict[str, Table]]] = None,
         _DEVICE_CROSSOVERS.update(device_crossovers)
     if kernel_params:
         _KERNEL_PARAMS.update(kernel_params)
+    note_write()    # tier edges and crossovers are read from these
 
 
 def kernel_param(key: str, default: int) -> int:

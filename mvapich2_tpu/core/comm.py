@@ -466,6 +466,14 @@ class Comm:
     def bcast(self, buf, root: int = 0, count: Optional[int] = None,
               datatype: Optional[Datatype] = None):
         self._check()
+        if self.device_channel is not None:
+            # a signature this rank has decided before runs on what it
+            # filed then (coll/device.py plan_of); None: decide it here
+            ret = self.device_channel.plan_of("bcast", buf, count,
+                                              datatype, root=root)
+            if ret is not None:
+                ret = self.device_channel.run_plan(self, ret, buf, buf)
+                return ret if ret is not None else buf
         count, datatype = _resolve(buf, count, datatype)
         staged, _ = self._stage_if_unbound(buf, None)
         ret = self._coll("bcast")(self, staged, count, datatype, root)
@@ -477,6 +485,12 @@ class Comm:
                count: Optional[int] = None,
                datatype: Optional[Datatype] = None):
         self._check()
+        if self.device_channel is not None:
+            ret = self.device_channel.plan_of("reduce", sendbuf, count,
+                                              datatype, op, root)
+            if ret is not None:
+                ret = self.device_channel.run_plan(self, ret, sendbuf, recvbuf)
+                return ret if ret is not None else recvbuf
         from . import op as opmod
         op = op or opmod.SUM
         count, datatype = _resolve(sendbuf, count, datatype, alt=recvbuf)
@@ -491,6 +505,12 @@ class Comm:
                   count: Optional[int] = None,
                   datatype: Optional[Datatype] = None):
         self._check()
+        if self.device_channel is not None:
+            ret = self.device_channel.plan_of("allreduce", sendbuf, count,
+                                              datatype, op)
+            if ret is not None:
+                ret = self.device_channel.run_plan(self, ret, sendbuf, recvbuf)
+                return ret if ret is not None else recvbuf
         from . import op as opmod
         op = op or opmod.SUM
         count, datatype = _resolve(sendbuf, count, datatype, alt=recvbuf)
@@ -504,6 +524,12 @@ class Comm:
     def allgather(self, sendbuf, recvbuf=None, count: Optional[int] = None,
                   datatype: Optional[Datatype] = None):
         self._check()
+        if self.device_channel is not None:
+            ret = self.device_channel.plan_of("allgather", sendbuf, count,
+                                              datatype)
+            if ret is not None:
+                ret = self.device_channel.run_plan(self, ret, sendbuf, recvbuf)
+                return ret if ret is not None else recvbuf
         count, datatype = _resolve(sendbuf, count, datatype, alt=recvbuf)
         sendbuf, recvbuf = self._stage_if_unbound(sendbuf, recvbuf)
         if recvbuf is None and not _is_device(sendbuf):
@@ -534,6 +560,12 @@ class Comm:
     def alltoall(self, sendbuf, recvbuf=None, count: Optional[int] = None,
                  datatype: Optional[Datatype] = None):
         self._check()
+        if self.device_channel is not None:
+            ret = self.device_channel.plan_of("alltoall", sendbuf, count,
+                                              datatype)
+            if ret is not None:
+                ret = self.device_channel.run_plan(self, ret, sendbuf, recvbuf)
+                return ret if ret is not None else recvbuf
         if count is None:
             sb = recvbuf if _is_in_place(sendbuf) else sendbuf
             count = int(getattr(sb, "size", 0) or len(sb)) // self.size
@@ -548,6 +580,12 @@ class Comm:
                              count: Optional[int] = None,
                              datatype: Optional[Datatype] = None):
         self._check()
+        if self.device_channel is not None:
+            ret = self.device_channel.plan_of("reduce_scatter_block",
+                                              sendbuf, count, datatype, op)
+            if ret is not None:
+                ret = self.device_channel.run_plan(self, ret, sendbuf, recvbuf)
+                return ret if ret is not None else recvbuf
         from . import op as opmod
         op = op or opmod.SUM
         if count is None:

@@ -481,6 +481,20 @@ pvar("dev_deposit_as_is", PVAR_CLASS_COUNTER, "device",
      "_as_local; the blocking entries and the nonblocking build); a "
      "shaped buffer, a count below the size, MPI_IN_PLACE at an offset "
      "and host buffers do not count")
+pvar("dev_call_plan_hit", PVAR_CLASS_COUNTER, "device",
+     "blocking device collective calls, per rank, that ran on a filed "
+     "call plan: the signature (collective, buffer type, shape and "
+     "dtype, count, datatype, op, root) was decided by an earlier call "
+     "of this rank under the cvars as they stand, so comm.<coll>'s "
+     "lines, _select_transport, _as_local, _op_name and _decide_tier "
+     "were not walked again (coll/device.py plan_of, run_plan)")
+pvar("dev_call_plan_filed", PVAR_CLASS_COUNTER, "device",
+     "blocking device collective calls, per rank, that decided their "
+     "signature and filed a call plan on their way out of _run: a "
+     "signature's first call, and its first after a cvar was written "
+     "or a tuning profile loaded (Config.writes moved: the old plan "
+     "is stale). Host buffers, shaped or partial buffers, MPI_IN_PLACE, "
+     "64-bit types and alltoallv decide every call and file nothing")
 pvar("dev_slot_operands", PVAR_CLASS_COUNTER, "device",
      "slot-channel leader calls that handed the program the deposited "
      "device arrays as they lay — R operands, no stack, no staging "

@@ -104,6 +104,7 @@ class CVar:
             raise ValueError(f"{self.name}: must be one of {self.choices}")
         self._value = val
         self._explicit = True
+        note_write()
 
 
 class Config:
@@ -112,6 +113,11 @@ class Config:
     def __init__(self) -> None:
         self._vars: Dict[str, CVar] = {}
         self._lock = threading.Lock()
+        # how often a value was written at run time (set, set_value,
+        # reload, MPI_T's cvar write, a tuning profile loaded): whoever
+        # remembers an answer decided from cvars remembers this count
+        # beside it, and decides again once it has moved
+        self.writes = 0
 
     def declare(self, name: str, default: Any, typ: Optional[type] = None,
                 group: str = "general", desc: str = "",
@@ -139,6 +145,7 @@ class Config:
         """Re-read every cvar from the environment (used at Init time)."""
         for cv in self._vars.values():
             cv.load()
+        note_write()
 
     def cvars(self) -> Dict[str, CVar]:
         return dict(self._vars)
@@ -159,6 +166,12 @@ _config = Config()
 
 def get_config() -> Config:
     return _config
+
+
+def note_write() -> None:
+    """A cvar, or a measured profile that overrides one, was written
+    (``Config.writes``)."""
+    _config.writes += 1
 
 
 def cvar(name: str, default: Any, typ: Optional[type] = None,

@@ -267,8 +267,8 @@ ADDED = {"dev_dispatch": "built", "dev_collect": "parts",
 @pytest.mark.parametrize("channel", list(CHANNELS))
 def test_the_ring_holds_the_same_tuple_and_args(traced, channel):
     """Every event is ``(time.monotonic, lane, name, ph, args)``; the
-    ``dev_<coll>`` B says tier, op, bytes, seq, coll and as_is, its E
-    and every phase event seq and coll, and a phase's E besides them
+    ``dev_<coll>`` B says tier, op, bytes, seq, coll, as_is and planned,
+    its E and every phase event seq and coll, and a phase's E besides them
     what its site added after the B, which the B in the ring never
     gains. ``us`` (and the E's ``tier``) are gone: the two stamps say
     it."""
@@ -284,7 +284,7 @@ def test_the_ring_holds_the_same_tuple_and_args(traced, channel):
             assert ph in ("B", "E")
             if name in ("dev_allreduce", "dev_bcast"):
                 assert set(args) == (PHASE_ARGS | (
-                    {"tier", "op", "bytes", "as_is"} if ph == "B"
+                    {"tier", "op", "bytes", "as_is", "planned"} if ph == "B"
                     else set())), ev
             elif ph == "E" and name in ADDED:
                 assert set(args) == PHASE_ARGS | {ADDED[name]}, ev
@@ -294,6 +294,7 @@ def test_the_ring_holds_the_same_tuple_and_args(traced, channel):
         assert first["op"] == "sum" and first["bytes"] == 4 * N
         assert first["tier"] in ("vmem", "hbm", "xla", "slot")
         assert first["as_is"] is False              # a host buffer
+        assert first["planned"] is False            # ... decides each call
         added = {e[2]: e[4][ADDED[e[2]]] for e in lane
                  if e[3] == "E" and e[2] in ADDED and e[4]["seq"] == 2}
         assert added == ({"dev_dispatch": True, "dev_collect": 0,
