@@ -26,10 +26,14 @@ plain numpy reference computed on the host from the same ``--seed``:
      pvar is 0, and the lowered text of the slot program that ran holds a
      ``tpu_custom_call``.
 
-``--chips 4`` runs the four-chip phase instead, and nothing else:
+``--chips 4`` runs the four-chip phases instead, and nothing else:
 ``run_ranks(4, app, device_mesh=True)`` (1:1 ``DeviceCollChannel``, the
 Pallas ICI ring kernels) compared with numpy AND with the stock XLA
-lowering (``lax.psum`` / ``all_gather`` / ``all_to_all``).
+lowering (``lax.psum`` / ``all_gather`` / ``all_to_all``); then the fold
+phase, ``run_ranks(8, app, device_mesh=<the four chips>)`` (two ranks a
+chip, ``DeviceFoldChannel``): allreduce sum and max, allgather,
+reduce_scatter_block, bcast and reduce at 1 MiB a rank on device-resident
+buffers, once each, compared with numpy.
 
 Any failed phase raises: the exit code is non-zero and no result line is
 printed. Timings are host-clock smoke timings around
@@ -428,11 +432,90 @@ def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
     assert fb and not any(fb.values()), fb
 
 
+def fold_phase(seed: int, nranks: int = 8, ndev: int = 4,
+               nbytes: int = MiB) -> None:
+    """Two ranks a chip: ``run_ranks(8, app, device_mesh=<four chips>)``
+    binds ``DeviceFoldChannel``; its five supported collectives once
+    each on device-resident buffers, against numpy (``nbytes`` a rank;
+    a CPU rehearsal passes a smaller one)."""
+    import jax
+
+    from mvapich2_tpu import mpit, run_ranks
+    from mvapich2_tpu.core import op as opmod
+    from mvapich2_tpu.parallel.mesh import make_mesh
+
+    devs = jax.devices()[:ndev]
+    assert len(set(devs)) == ndev, f"need {ndev} devices, have {devs}"
+    mesh = make_mesh((ndev,), ("x",), devs)
+    k, n = nranks // ndev, nbytes // 4
+    c = n // nranks
+    root = nranks - 3           # not a chip's first rank, not chip 0
+    names = ("allreduce", "allreduce max", "allgather",
+             "reduce_scatter_block", "bcast", "reduce")
+    xs = [rank_data(seed, 200, r, n) for r in range(nranks)]
+    total = np.sum(xs, axis=0)
+    refs = {"allreduce": [total] * nranks,
+            "allreduce max": [np.max(xs, axis=0)] * nranks,
+            "allgather": [np.concatenate(xs)] * nranks,
+            "reduce_scatter_block": [total[r * c:(r + 1) * c]
+                                     for r in range(nranks)],
+            "bcast": [xs[root]] * nranks,
+            "reduce": [total if r == root else None for r in range(nranks)]}
+    results = {name: [None] * nranks for name in names}
+    homes = [None] * nranks
+
+    def app(comm):
+        ch = comm.device_channel
+        assert type(ch).__name__ == "DeviceFoldChannel", type(ch).__name__
+        assert (ch.k, ch.ndev) == (k, ndev), (ch.k, ch.ndev)
+        dev = homes[comm.rank] = ch.device
+        x = jax.device_put(xs[comm.rank], dev)
+        calls = {"allreduce": lambda: comm.allreduce(x),
+                 "allreduce max": lambda: comm.allreduce(x, op=opmod.MAX),
+                 "allgather": lambda: comm.allgather(x),
+                 "reduce_scatter_block":
+                     lambda: comm.reduce_scatter_block(x),
+                 "bcast": lambda: comm.bcast(x, root=root),
+                 "reduce": lambda: comm.reduce(x, root=root)}
+        for name in names:
+            comm.barrier()
+            out = calls[name]()
+            if out is None:         # reduce, off the root
+                continue
+            out = jax.block_until_ready(out)
+            assert out.sharding.device_set == {dev}, \
+                (name, comm.rank, out.sharding.device_set)
+            results[name][comm.rank] = np.asarray(out)
+            if comm.rank == 0:
+                say(f"fold: ran {name} {nbytes} B/rank")
+
+    levels = ("coll_level_chip", "coll_level_ici")
+    before = {n_: mpit.pvar(n_).read() for n_ in levels}
+    fb0 = fallback_pvars()
+    run_ranks(nranks, app, device_mesh=mesh, timeout=900.0)
+    assert len(set(homes)) == ndev and \
+        all(homes[r] == homes[r - r % k] for r in range(nranks)), homes
+    for name in names:
+        for r in range(nranks):
+            got, want = results[name][r], refs[name][r]
+            if (got is None) != (want is None) or \
+                    (want is not None and not np.array_equal(got, want)):
+                raise AssertionError(f"fold {name}: rank {r} differs from "
+                                     f"the numpy reference")
+        say(f"fold: {name:<20} {nbytes:>8} B/rank  bit-equal to numpy on "
+            f"{nranks} ranks over {ndev} chips")
+    rose = {n_: mpit.pvar(n_).read() - v for n_, v in before.items()}
+    fb = {n_: v - fb0[n_] for n_, v in fallback_pvars().items()}
+    say(f"proof (fold): {rose}; fallbacks {fb}")
+    assert all(v == nranks * len(names) for v in rose.values()), rose
+    assert fb and not any(fb.values()), fb
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="4 = the four-chip ICI phase and nothing else")
+                    help="4 = the four-chip ICI and fold phases and nothing else")
     args = ap.parse_args(argv)
 
     import jax
@@ -464,6 +547,7 @@ def main(argv=None) -> int:
     t0 = time.perf_counter()
     if args.chips == 4:
         four_chips(args.seed)
+        fold_phase(args.seed)
     else:
         one_chip(args.seed)
     say(f"compile cache: {cache_dir} ({cache_entries(cache_dir)} entries "

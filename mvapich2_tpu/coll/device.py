@@ -208,6 +208,10 @@ class _ImportedProgram:
 #   dev_arrive       every rank: slot deposit -> counted in at the gate;
 #                    on rank 0 also its wait for the last rank
 #   dev_stage        rank 0: assembling the program's input
+#   dev_chip_fold    rank 0, fold channel, inside dev_stage: level 1,
+#                    every chip's staging and fold dispatches; its E
+#                    says ``k``, ``chips`` and ``stacked``, the planar
+#                    copies of a chip's deposits made this call
 #   dev_dispatch     rank 0: program-cache lookup + enqueue; its E says
 #                    ``built`` when the call made or loaded the program
 #   dev_device_wait  rank 0, slot channel: the leader's block_until_ready
@@ -1612,6 +1616,9 @@ class DeviceFoldChannel(DeviceCollChannel):
     LEVELS = ("chip", "ici")
     SUPPORTED = ("allreduce", "reduce", "bcast", "allgather",
                  "reduce_scatter_block")
+    # planar (k, n) copies ``_chip_stack`` made in the leader call under
+    # way: the dev_chip_fold E's ``stacked`` and the dev_fold_stacked pvar
+    _stacked = 0
 
     def __init__(self, mesh, axis, rendezvous: _Rendezvous, rank: int,
                  nranks: int):
@@ -1668,9 +1675,11 @@ class DeviceFoldChannel(DeviceCollChannel):
 
     def _chip_stack(self, j: int, n: int, dtype):
         """Chip ``j``'s k deposited slots as one planar (k, n) array on
-        its device (device-resident slots stack in place)."""
+        its device (device-resident slots stack in place): a copy of
+        the chip's deposits either way, counted for ``_leader``."""
         import jax
         import jax.numpy as jnp
+        self._stacked += 1
         sl = self.rv.slots[j * self.k:(j + 1) * self.k]
         dev = self._mesh_devices[j]
         if all(is_device_array(s) and s.devices() == {dev} for s in sl):
@@ -1700,32 +1709,42 @@ class DeviceFoldChannel(DeviceCollChannel):
         n, dtype = self._slot_extent(rv.slots[0])
         shards, prog_root, prog_n = [], 0, n
         with self._phase("dev_stage"):
-            if name == "bcast":
-                # only the root chip's shard matters: stage the root
-                # rank's payload there, zero-fill the rest (the mesh
-                # bcast program overwrites them)
-                prog_root = root // k
-                for j in range(nd):
-                    if j == prog_root:
-                        s = rv.slots[root]
-                        if not (is_device_array(s) and
-                                s.devices() == {self._mesh_devices[j]}):
-                            s = jax.device_put(np.asarray(s).reshape(-1),
+            # level 1: every chip's staging and fold, issued from this
+            # one thread; its E says how many planar copies it made
+            with self._phase("dev_chip_fold") as fold:
+                self._stacked = 0
+                if name == "bcast":
+                    # only the root chip's shard matters: stage the root
+                    # rank's payload there, zero-fill the rest (the mesh
+                    # bcast program overwrites them)
+                    prog_root = root // k
+                    for j in range(nd):
+                        if j == prog_root:
+                            s = rv.slots[root]
+                            if not (is_device_array(s) and s.devices()
+                                    == {self._mesh_devices[j]}):
+                                s = jax.device_put(
+                                    np.asarray(s).reshape(-1),
+                                    self._mesh_devices[j])
+                        else:
+                            s = jax.device_put(np.zeros(n, dtype),
                                                self._mesh_devices[j])
-                    else:
-                        s = jax.device_put(np.zeros(n, dtype),
-                                           self._mesh_devices[j])
-                    shards.append(s)
-            elif name == "allgather":
-                # chip fold is CONCATENATION: blocked rank->chip mapping
-                # makes the stacked chip payload already rank-ordered
-                prog_n = k * n
-                for j in range(nd):
-                    shards.append(self._chip_stack(j, n, dtype)
-                                  .reshape(prog_n))
-            else:   # allreduce / reduce / reduce_scatter_block
-                for j in range(nd):
-                    shards.append(self._fold_chip(j, n, dtype, op))
+                        shards.append(s)
+                elif name == "allgather":
+                    # chip fold is CONCATENATION: blocked rank->chip
+                    # mapping makes the stacked chip payload already
+                    # rank-ordered
+                    prog_n = k * n
+                    for j in range(nd):
+                        shards.append(self._chip_stack(j, n, dtype)
+                                      .reshape(prog_n))
+                else:   # allreduce / reduce / reduce_scatter_block
+                    for j in range(nd):
+                        shards.append(self._fold_chip(j, n, dtype, op))
+                if self._stacked:
+                    mpit.pvar("dev_fold_stacked").inc(self._stacked)
+                if fold is not None:
+                    fold.args.update(k=k, chips=nd, stacked=self._stacked)
             global_arr = self._global(shards, prog_n)
         with self._phase("dev_dispatch") as ph:
             had = len(self._programs)

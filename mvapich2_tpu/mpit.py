@@ -507,6 +507,14 @@ pvar("dev_mesh_operands", PVAR_CLASS_COUNTER, "device",
      "reshape, no eager op, no copy (coll/device.py DeviceCollChannel."
      "_shards); a call with a host deposit or a padded alltoallv "
      "payload among its ranks does not count")
+pvar("dev_fold_stacked", PVAR_CLASS_COUNTER, "device",
+     "planar (k, n) copies the fold channel's leader made of a chip's k "
+     "deposits (coll/device.py DeviceFoldChannel._chip_stack: jnp.stack "
+     "of device-resident deposits, np.stack + device_put of host ones): "
+     "+1 per chip per leader call that copied, so `chips` a call of "
+     "allreduce, reduce, reduce_scatter_block and allgather at k > 1 and "
+     "0 for bcast; 0 once the deposits are the fold program's operands "
+     "as they lie, as the slot channel's are (dev_slot_operands)")
 pvar("dev_mesh_reordered", PVAR_CLASS_COUNTER, "device",
      "1-D meshes parallel/mesh.make_mesh returned with their devices in "
      "another order than they were given: TPU chips laid along a snake "

@@ -244,6 +244,40 @@ def test_slot_reduce_scatter_block_cuts_inside_the_program(topo, one_chip,
     assert moving.count("custom-call") == 1 and len(moving) <= 9, moving
 
 
+def test_fold_program_at_the_two_level_cell_size(topo, mesh4, one_chip,
+                                                 monkeypatch):
+    """Level 1 of ``osu4.allreduce_2level.64MiB.dev`` as the fold
+    channel's leader calls it: one chip's two deposits of 64 MiB stacked
+    planar ``(2, n)``, folded by ``mv2t_slot_reduce``. It compiles and
+    fits; between the parameter and the kernel the compiler puts one
+    relayout fusion (the stack arrives tiled ``T(2,128)``, the kernel
+    reads ``T(8,128)``), a second pass over the 128 MiB that the stack
+    already cost: the staging ISSUE 38 leaves for the ``perf_opt`` that
+    follows (operands as they lie, as the slot channel's)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mvapich2_tpu.coll.device import DeviceFoldChannel, _Rendezvous
+    from mvapich2_tpu.ops import _compat
+    monkeypatch.setattr(_compat, "on_tpu", lambda: True)
+    ch = DeviceFoldChannel(mesh4, "x", _Rendezvous(8), 0, 8)
+    assert (ch.k, ch.ndev, ch._mesh_extent()) == (2, 4, 4)
+    nbytes = 64 * MiB
+    x = jax.ShapeDtypeStruct((2, nbytes // 4), jnp.float32,
+                             sharding=one_chip)
+    compiled = ch._fold_prog("sum").lower(x).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == 2 * nbytes
+    assert mem.output_size_in_bytes == nbytes
+    assert mem.alias_size_in_bytes == 0
+    assert mem.temp_size_in_bytes <= 2 * nbytes
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "mv2t_slot_reduce" in text
+    moving = [op for op, _ in _entry_ops(text)
+              if op not in ("parameter", "bitcast")]
+    assert moving.count("custom-call") == 1 and len(moving) <= 2, moving
+
+
 @pytest.fixture
 def default_tier_edges(monkeypatch):
     """The program's default tier edges, said out loud: once an earlier

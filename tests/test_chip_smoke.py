@@ -68,6 +68,27 @@ def test_four_chip_phase_under_the_interpreter(monkeypatch):
         cfg.reload()
 
 
+def test_fold_phase_under_the_interpreter(monkeypatch, capsys):
+    """Two ranks a chip: the fold channel's five collectives on
+    device-resident buffers, each bit-equal to numpy, both levels
+    counted."""
+    cfg = get_config()
+    monkeypatch.setenv("MV2T_ICI_INTERPRET", "1")
+    monkeypatch.setenv("MV2T_DEV_TIER_VMEM_MAX", "8192")
+    monkeypatch.setenv("MV2T_DEV_TIER_XLA_MIN", "-1")
+    cfg.reload()
+    stacked0 = mpit.pvar("dev_fold_stacked").read()
+    try:
+        chip_smoke.fold_phase(seed=3, nbytes=16 * 1024)
+    finally:
+        monkeypatch.undo()
+        cfg.reload()
+    out = capsys.readouterr().out
+    assert out.count("bit-equal to numpy on 8 ranks over 4 chips") == 6
+    # a planar copy a chip in every call but the bcast
+    assert mpit.pvar("dev_fold_stacked").read() - stacked0 == 4 * 5
+
+
 def test_main_refuses_without_a_tpu(capsys):
     assert chip_smoke.main([]) != 0
     out = capsys.readouterr().out

@@ -2,7 +2,8 @@
 collective carries a ``seq`` equal on every rank, and between its
 ``dev_<coll>`` B and E the rendezvous and the leader open ``dev_arrive``,
 ``dev_stage``, ``dev_dispatch``, ``dev_device_wait`` (slot channel only),
-``dev_collect`` and ``dev_release``; ``dev_deliver`` follows the E. No
+``dev_collect`` and ``dev_release``; ``dev_deliver`` follows the E; the
+fold channel's leader opens ``dev_chip_fold`` inside its ``dev_stage``. No
 test here asserts a time: only names, order, nesting, ``seq`` and that
 every span closes, on the error paths too.
 """
@@ -107,7 +108,13 @@ def test_phases_in_order_nested_one_seq(traced, channel):
         assert tops == [("dev_allreduce", 1, inside), ("dev_deliver", 1, []),
                         ("dev_bcast", 2, inside), ("dev_deliver", 2, [])], \
             (rank, tops)
-        assert max(depth for _n, _s, depth, _k in spans) == 1
+        # one level of phases, but for the fold leader's level 1, which
+        # lies inside its dev_stage (ISSUE 38)
+        deeper = [(name, kids) for name, _s, depth, kids in spans
+                  if depth == 1 and kids]
+        assert deeper == ([("dev_stage", ["dev_chip_fold"])] * 2
+                          if channel == "fold" and rank == 0 else [])
+        assert max(depth for _n, _s, depth, _k in spans) == 1 + bool(deeper)
         # a phase shares its collective's seq and names the collective
         colls = {(a["seq"], a["coll"]) for _t, _l, _n, ph, a in lane
                  if ph in "BE"}
@@ -249,7 +256,7 @@ def test_phase_names_pass_the_events_lint():
     assert EventCoveragePass().run(mods) == []
     with open(path) as f:
         src = f.read()
-    for name in ("dev_arrive", "dev_stage", "dev_dispatch",
+    for name in ("dev_arrive", "dev_stage", "dev_chip_fold", "dev_dispatch",
                  "dev_device_wait", "dev_collect", "dev_release",
                  "dev_deliver"):
         assert f'self._phase("{name}")' in src
@@ -262,6 +269,7 @@ PHASE_ARGS = {"seq", "coll"}
 # what a site learns after its B, on the E alone
 ADDED = {"dev_dispatch": "built", "dev_collect": "parts",
          "dev_deliver": "relaid"}
+FOLD_ADDED = {"k", "chips", "stacked"}      # the dev_chip_fold E's own
 
 
 @pytest.mark.parametrize("channel", list(CHANNELS))
@@ -288,6 +296,8 @@ def test_the_ring_holds_the_same_tuple_and_args(traced, channel):
                     else set())), ev
             elif ph == "E" and name in ADDED:
                 assert set(args) == PHASE_ARGS | {ADDED[name]}, ev
+            elif ph == "E" and name == "dev_chip_fold":
+                assert set(args) == PHASE_ARGS | FOLD_ADDED, ev
             else:
                 assert set(args) == PHASE_ARGS, ev
         first = next(e[4] for e in lane if e[2] == "dev_allreduce")
