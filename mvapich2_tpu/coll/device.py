@@ -209,9 +209,11 @@ class _ImportedProgram:
 #                    on rank 0 also its wait for the last rank
 #   dev_stage        rank 0: assembling the program's input
 #   dev_chip_fold    rank 0, fold channel, inside dev_stage: level 1,
-#                    every chip's staging and fold dispatches; its E
+#                    every chip's fold dispatch (and its staging, where
+#                    a deposit does not lie flat on its chip); its E
 #                    says ``k``, ``chips`` and ``stacked``, the planar
-#                    copies of a chip's deposits made this call
+#                    copies of a chip's deposits made this call: 0
+#                    where the deposits were the fold's operands
 #   dev_dispatch     rank 0: program-cache lookup + enqueue; its E says
 #                    ``built`` when the call made or loaded the program
 #   dev_device_wait  rank 0, slot channel: the leader's block_until_ready
@@ -1596,11 +1598,13 @@ class DeviceFoldChannel(DeviceCollChannel):
     rank ``r`` on chip ``r // k`` (blocked, so a chip's ranks own
     contiguous result blocks). Each collective runs in two levels:
 
-      * **chip fold** — every chip's ``k`` deposited slots are staged as
-        one planar ``(k, n)`` array on that chip and folded in HBM (the
-        fused slot-reduce kernel for sum, the XLA reduction otherwise;
-        concatenation for allgather), exactly the slot channel's move
-        applied per chip;
+      * **chip fold** — every chip's ``k`` deposited slots are folded in
+        HBM by one program a chip (the fused slot-reduce kernel for sum,
+        the XLA reduction otherwise), exactly the slot channel's move
+        applied per chip: flat device arrays on their chip go in as they
+        lie, ``k`` operands and no eager op; anything else is staged as
+        one planar ``(k, n)`` array on that chip first. allgather's fold
+        is concatenation and still stages that array;
       * **ICI phase** — the ``ndev`` folded shards form one mesh-sharded
         global array and ride the ordinary mesh program (ring RS/AG
         tiers, per-axis torus phases when the mesh is multi-axis), built
@@ -1653,8 +1657,13 @@ class DeviceFoldChannel(DeviceCollChannel):
         return None     # host NBC schedule (fold has no DAG segments yet)
 
     def _fold_prog(self, op: str):
-        """Per-chip fold program: the HBM fused slot-reduce for sum, the
-        XLA reduction for the other ops (cached like any program)."""
+        """Per-chip fold program, one jitted ``f(*xs)`` (cached like any
+        program). ``xs`` is what ``_fold_chip`` had: the chip's ``k``
+        deposited ``(n,)`` arrays, or one staged planar ``(k, n)`` array.
+        The body reads which from its operands, as the slot channel's
+        ``reduced`` does: the HBM fused slot-reduce for sum, on ``k``
+        operands of whole 128-lane rows where they lie; the XLA
+        reduction for the other ops. No operand is donated or aliased."""
         key = ("chipfold", 0, "", op, 0, None)
         got = self._programs.get(key)
         if got is None:
@@ -1664,12 +1673,17 @@ class DeviceFoldChannel(DeviceCollChannel):
             from ..ops import pallas_hbm as ph
             red = {"sum": jnp.sum, "max": jnp.max, "min": jnp.min,
                    "prod": jnp.prod}[op or "sum"]
-            if _slot_kernel_op(op):
-                def f(x):
-                    return ph.hbm_slot_allreduce(x)
-            else:
-                def f(x):
-                    return red(x, axis=0)
+
+            def slots(xs):      # the (k, n) slot array: the one staged
+                # operand, or the k deposited ones stacked inside the trace
+                return xs[0] if xs[0].ndim == 2 else jnp.stack(xs)
+
+            def f(*xs):                         # -> [n]
+                if not _slot_kernel_op(op):
+                    return red(slots(xs), axis=0)
+                if xs[0].ndim == 1 and xs[0].shape[0] % 128 == 0:
+                    return ph.hbm_slot_allreduce_operands(xs)
+                return ph.hbm_slot_allreduce(slots(xs))
             got = self._programs[key] = jax.jit(f)
         return got
 
@@ -1688,15 +1702,23 @@ class DeviceFoldChannel(DeviceCollChannel):
             np.stack([np.asarray(s).reshape(n) for s in sl]), dev)
 
     def _fold_chip(self, j: int, n: int, dtype, op: str):
-        """Fold chip ``j``'s slots to one [n] contribution (level 1)."""
+        """Fold chip ``j``'s slots to one [n] contribution (level 1).
+        Flat device arrays on the chip's device are the fold program's
+        operands as they lie; host deposits, shaped arrays and arrays
+        committed elsewhere are staged by ``_chip_stack`` first."""
         import jax
+        dev = self._mesh_devices[j]
         if self.k == 1:
             s = self.rv.slots[j]
-            if is_device_array(s) and \
-                    s.devices() == {self._mesh_devices[j]}:
+            if is_device_array(s) and s.devices() == {dev}:
                 return s.reshape(n)
-            return jax.device_put(np.asarray(s).reshape(n),
-                                  self._mesh_devices[j])
+            return jax.device_put(np.asarray(s).reshape(n), dev)
+        sl = self.rv.slots[j * self.k:(j + 1) * self.k]
+        if all(is_device_array(s) and s.ndim == 1 and s.devices() == {dev}
+               for s in sl):
+            # the deposits as they lie: k operands, no reshape, no
+            # eager op
+            return self._fold_prog(op)(*sl)
         return self._fold_prog(op)(self._chip_stack(j, n, dtype))
 
     def _leader(self, name: str, op: str, root: int) -> List:
@@ -1709,8 +1731,9 @@ class DeviceFoldChannel(DeviceCollChannel):
         n, dtype = self._slot_extent(rv.slots[0])
         shards, prog_root, prog_n = [], 0, n
         with self._phase("dev_stage"):
-            # level 1: every chip's staging and fold, issued from this
-            # one thread; its E says how many planar copies it made
+            # level 1: every chip's fold (and staging, where a deposit
+            # does not lie flat on its chip), issued from this one
+            # thread; its E says how many planar copies it made
             with self._phase("dev_chip_fold") as fold:
                 self._stacked = 0
                 if name == "bcast":
@@ -1741,6 +1764,8 @@ class DeviceFoldChannel(DeviceCollChannel):
                 else:   # allreduce / reduce / reduce_scatter_block
                     for j in range(nd):
                         shards.append(self._fold_chip(j, n, dtype, op))
+                    if not self._stacked:
+                        mpit.pvar("dev_fold_operands").inc()
                 if self._stacked:
                     mpit.pvar("dev_fold_stacked").inc(self._stacked)
                 if fold is not None:

@@ -511,10 +511,21 @@ pvar("dev_fold_stacked", PVAR_CLASS_COUNTER, "device",
      "planar (k, n) copies the fold channel's leader made of a chip's k "
      "deposits (coll/device.py DeviceFoldChannel._chip_stack: jnp.stack "
      "of device-resident deposits, np.stack + device_put of host ones): "
-     "+1 per chip per leader call that copied, so `chips` a call of "
-     "allreduce, reduce, reduce_scatter_block and allgather at k > 1 and "
-     "0 for bcast; 0 once the deposits are the fold program's operands "
-     "as they lie, as the slot channel's are (dev_slot_operands)")
+     "+1 per chip copied per leader call: every chip of an allgather at "
+     "k > 1; of allreduce, reduce and reduce_scatter_block the chips "
+     "whose deposits do not lie flat on them (every chip on host "
+     "deposits); 0 for bcast, and 0 where the deposits are the fold "
+     "program's operands as they lie (dev_fold_operands), as the slot "
+     "channel's are (dev_slot_operands)")
+pvar("dev_fold_operands", PVAR_CLASS_COUNTER, "device",
+     "fold-channel leader calls of allreduce, reduce and "
+     "reduce_scatter_block in which every chip's k deposits were its "
+     "fold program's operands as they lay: flat device arrays on "
+     "their chip's device, no stack, no reshape, no eager op "
+     "(coll/device.py DeviceFoldChannel._fold_chip); a call with a "
+     "host deposit, a shaped array or one committed to another chip "
+     "among its ranks stages that chip (dev_fold_stacked) and does "
+     "not count")
 pvar("dev_mesh_reordered", PVAR_CLASS_COUNTER, "device",
      "1-D meshes parallel/mesh.make_mesh returned with their devices in "
      "another order than they were given: TPU chips laid along a snake "

@@ -244,24 +244,57 @@ def test_slot_reduce_scatter_block_cuts_inside_the_program(topo, one_chip,
     assert moving.count("custom-call") == 1 and len(moving) <= 9, moving
 
 
-def test_fold_program_at_the_two_level_cell_size(topo, mesh4, one_chip,
-                                                 monkeypatch):
-    """Level 1 of ``osu4.allreduce_2level.64MiB.dev`` as the fold
-    channel's leader calls it: one chip's two deposits of 64 MiB stacked
-    planar ``(2, n)``, folded by ``mv2t_slot_reduce``. It compiles and
-    fits; between the parameter and the kernel the compiler puts one
-    relayout fusion (the stack arrives tiled ``T(2,128)``, the kernel
-    reads ``T(8,128)``), a second pass over the 128 MiB that the stack
-    already cost: the staging ISSUE 38 leaves for the ``perf_opt`` that
-    follows (operands as they lie, as the slot channel's)."""
-    import jax
-    import jax.numpy as jnp
-
+def _fold_channel(mesh4, monkeypatch):
     from mvapich2_tpu.coll.device import DeviceFoldChannel, _Rendezvous
     from mvapich2_tpu.ops import _compat
+    # the fold program asks the backend whether to interpret
     monkeypatch.setattr(_compat, "on_tpu", lambda: True)
     ch = DeviceFoldChannel(mesh4, "x", _Rendezvous(8), 0, 8)
     assert (ch.k, ch.ndev, ch._mesh_extent()) == (2, 4, 4)
+    return ch
+
+
+@pytest.mark.parametrize("chip", [0, P4 - 1])
+def test_fold_program_at_the_two_level_cell_size(topo, mesh4, monkeypatch,
+                                                 chip):
+    """Level 1 of ``osu4.allreduce_2level.64MiB.dev`` as the fold
+    channel's leader calls it since ISSUE 41: one chip's two deposits of
+    64 MiB, two flat operands where they lie, on the mesh's first and
+    last device. Compiled for the chip the program is the
+    ``mv2t_slot_reduce`` kernel between bitcasts: no stack, no relayout,
+    no temporary, nothing aliased (the callers keep their buffers)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    ch = _fold_channel(mesh4, monkeypatch)
+    nbytes = 64 * MiB
+    x = jax.ShapeDtypeStruct(
+        (nbytes // 4,), jnp.float32,
+        sharding=SingleDeviceSharding(ch._mesh_devices[chip]))
+    compiled = ch._fold_prog("sum").lower(x, x).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == 2 * nbytes
+    assert mem.output_size_in_bytes == nbytes
+    assert mem.alias_size_in_bytes == 0
+    assert mem.temp_size_in_bytes == 0
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "mv2t_slot_reduce" in text
+    moving = [op for op, _ in _entry_ops(text)
+              if op not in ("parameter", "bitcast")]
+    assert moving == ["custom-call"], moving
+
+
+def test_fold_program_on_a_staged_host_deposit(topo, mesh4, one_chip,
+                                               monkeypatch):
+    """The form host deposits (and anything that does not lie flat on
+    its chip) still take: the chip's two deposits stacked planar
+    ``(2, n)`` by ``_chip_stack``, one operand of the same program,
+    folded by ``mv2t_slot_reduce``. It compiles and fits; between the
+    parameter and the kernel the compiler keeps one relayout fusion (the
+    stack arrives tiled ``T(2,128)``, the kernel reads ``T(8,128)``)."""
+    import jax
+    import jax.numpy as jnp
+    ch = _fold_channel(mesh4, monkeypatch)
     nbytes = 64 * MiB
     x = jax.ShapeDtypeStruct((2, nbytes // 4), jnp.float32,
                              sharding=one_chip)

@@ -78,6 +78,7 @@ def test_fold_phase_under_the_interpreter(monkeypatch, capsys):
     monkeypatch.setenv("MV2T_DEV_TIER_XLA_MIN", "-1")
     cfg.reload()
     stacked0 = mpit.pvar("dev_fold_stacked").read()
+    operands0 = mpit.pvar("dev_fold_operands").read()
     try:
         chip_smoke.fold_phase(seed=3, nbytes=16 * 1024)
     finally:
@@ -85,8 +86,11 @@ def test_fold_phase_under_the_interpreter(monkeypatch, capsys):
         cfg.reload()
     out = capsys.readouterr().out
     assert out.count("bit-equal to numpy on 8 ranks over 4 chips") == 6
-    # a planar copy a chip in every call but the bcast
-    assert mpit.pvar("dev_fold_stacked").read() - stacked0 == 4 * 5
+    # the deposits are device arrays on their chips: the reduce family
+    # folds them as they lie (ISSUE 41); allgather alone still makes a
+    # planar copy a chip
+    assert mpit.pvar("dev_fold_stacked").read() - stacked0 == 4 * 1
+    assert mpit.pvar("dev_fold_operands").read() - operands0 == 4
 
 
 def test_main_refuses_without_a_tpu(capsys):

@@ -392,7 +392,9 @@ def test_the_alltoall_call_carries_its_wire_bytes_in_the_trace(
         x = jax.device_put(inputs[comm.rank], comm.device_channel.device)
         comm.allreduce(x)               # seq 1: no wire instant
         comm.alltoall(x)                # seq 2
-        comm.alltoall(x)                # seq 3
+        # waited for: a kernel left in flight keeps the interpreter's
+        # process-wide state into the next test
+        jax.block_until_ready(comm.alltoall(x))     # seq 3
         lanes[comm.rank] = [e for e in comm.u.engine.tracer.events
                             if e[1] == "device"]
 
@@ -422,7 +424,7 @@ def test_the_allgather_call_carries_its_wire_bytes_in_the_trace(
         x = jax.device_put(inputs[comm.rank], comm.device_channel.device)
         comm.allgather(x)               # seq 1
         comm.alltoall(x[:4096])         # seq 2: the other kernel's wire
-        comm.allgather(x)               # seq 3
+        jax.block_until_ready(comm.allgather(x))    # seq 3 (waited for)
         lanes[comm.rank] = [e for e in comm.u.engine.tracer.events
                             if e[1] == "device"]
 

@@ -312,6 +312,33 @@ def test_the_ring_holds_the_same_tuple_and_args(traced, channel):
                          else {"dev_deliver": 0})
 
 
+@pytest.mark.parametrize("resident,stacked", [(True, 0), (False, 4)])
+def test_the_chip_fold_span_says_what_level_1_copied(traced, resident,
+                                                     stacked):
+    """The fold leader's ``dev_chip_fold`` E of an allreduce: two ranks
+    a chip on four chips, and no planar copy where the deposits lie flat
+    on their chips (ISSUE 41: they are the fold program's operands); a
+    host deposit is still staged, one copy a chip."""
+    ranks = CHANNELS["fold"][0]
+    lanes = {}
+
+    def app(comm):
+        x = np.full(N, float(comm.rank + 1), np.float32)
+        if resident:
+            x = jax.device_put(x, comm.device_channel.device)
+        out = comm.allreduce(x)
+        assert np.asarray(out)[0] == ranks * (ranks + 1) / 2
+        lanes[comm.rank] = _device_lane(comm)
+
+    run_ranks(ranks, app, device_mesh=_mesh("fold"))
+    ends = {rank: [a for _t, _l, name, ph, a in lane
+                   if name == "dev_chip_fold" and ph == "E"]
+            for rank, lane in lanes.items()}
+    assert ends.pop(0) == [{"seq": 1, "coll": "allreduce", "k": 2,
+                            "chips": 4, "stacked": stacked}]
+    assert not any(ends.values())       # the leader's span alone
+
+
 def test_the_mpi_lane_through_one_tool_and_through_two(traced):
     """The recorder's tool alone is handed the implementation bound to
     the comm; with a second tool installed the chain runs last
