@@ -29,7 +29,10 @@ plain numpy reference computed on the host from the same ``--seed``:
 ``--chips 4`` runs the four-chip phases instead, and nothing else:
 ``run_ranks(4, app, device_mesh=True)`` (1:1 ``DeviceCollChannel``, the
 Pallas ICI ring kernels) compared with numpy AND with the stock XLA
-lowering (``lax.psum`` / ``all_gather`` / ``all_to_all``); then the fold
+lowering (``lax.psum`` / ``all_gather`` / ``all_to_all`` /
+``psum_scatter``: allreduce at 4 KiB, 1 MiB and 64 MiB, allgather and
+alltoall at 16 MiB, allreduce max and reduce_scatter_block at 1 MiB, the
+last on the ring's fold rounds alone); then the fold
 phase, ``run_ranks(8, app, device_mesh=<the four chips>)`` (two ranks a
 chip, ``DeviceFoldChannel``): allreduce sum and max, allgather,
 reduce_scatter_block, bcast and reduce at 1 MiB a rank on device-resident
@@ -332,6 +335,8 @@ def _stock(mesh, name: str, op: str, xs):
             x.reshape(-1), "x", tiled=True).reshape(1, -1),
         ("alltoall", None): lambda x: lax.all_to_all(
             x.reshape(p, -1), "x", 0, 0).reshape(1, -1),
+        ("reduce_scatter_block", "sum"): lambda x: lax.psum_scatter(
+            x.reshape(-1), "x", tiled=True).reshape(1, -1),
     }[(name, op)]
     g = jax.device_put(np.stack(xs), NamedSharding(mesh, P("x", None)))
     f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("x", None),),
@@ -355,6 +360,7 @@ def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
         (1, "allreduce", "sum", 4 * K), (2, "allreduce", "sum", MiB),
         (3, "allreduce", "sum", 64 * MiB), (4, "allgather", None, 16 * MiB),
         (5, "alltoall", None, 16 * MiB), (6, "allreduce", "max", MiB),
+        (7, "reduce_scatter_block", "sum", MiB),
     ]
     cases = [(t, n, o, max(nranks * 128 * 4, b // scale))
              for t, n, o, b in cases]
@@ -375,7 +381,9 @@ def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
             call = {"allreduce": lambda: comm.allreduce(
                         x, op=opmod.MAX if op == "max" else opmod.SUM),
                     "allgather": lambda: comm.allgather(x),
-                    "alltoall": lambda: comm.alltoall(x)}[name]
+                    "alltoall": lambda: comm.alltoall(x),
+                    "reduce_scatter_block":
+                        lambda: comm.reduce_scatter_block(x)}[name]
             times = []
             for _ in range(1 + STEADY_CALLS):
                 comm.barrier()
@@ -392,7 +400,7 @@ def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
 
     before = {n: mpit.pvar(n).read()
               for n in ("dev_coll_tier_vmem", "dev_coll_tier_hbm",
-                        "coll_level_ici")}
+                        "coll_level_ici", "dev_rs_wire_bytes")}
     fb0 = fallback_pvars()
     run_ranks(nranks, app, device_mesh=mesh, timeout=900.0)
     assert len(set(homes)) == nranks, \
@@ -409,7 +417,9 @@ def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
                "allgather": lambda: [np.concatenate(xs)] * nranks,
                "alltoall": lambda: [np.concatenate(
                    [xs[s][r * c:(r + 1) * c] for s in range(nranks)])
-                   for r in range(nranks)]}[name]()
+                   for r in range(nranks)],
+               "reduce_scatter_block": lambda: list(
+                   np.sum(xs, axis=0).reshape(nranks, c))}[name]()
         stock = _stock(mesh, name, op, xs)
         for r in range(nranks):
             got = results[t][r]
@@ -429,6 +439,13 @@ def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
     say(f"proof: {rose}; fallbacks {fb}")
     assert rose["dev_coll_tier_vmem"] > 0 and rose["dev_coll_tier_hbm"] > 0
     assert rose["coll_level_ici"] == nranks * len(cases) * (1 + STEADY_CALLS)
+    # the reduce-scatter's calls took the ring kernel, which counts what
+    # it sends (three quarters of the send buffer at 1 MiB: whole tiles)
+    from mvapich2_tpu.ops.pallas_ici import reduce_scatter_wire_bytes
+    sent = sum(reduce_scatter_wire_bytes(b // 4, np.float32, nranks)
+               for _t, n, _o, b in cases if n == "reduce_scatter_block")
+    assert rose["dev_rs_wire_bytes"] == \
+        nranks * (1 + STEADY_CALLS) * sent > 0, rose
     assert fb and not any(fb.values()), fb
 
 

@@ -556,7 +556,7 @@ class DeviceCollChannel:
         if not daemon.exec_cache_enabled():
             return self._build(name, n, op, root, extra)
         from ..ops import _compat
-        ck = "|".join(("mv2t-exec-v3", self._chan_desc(), name,
+        ck = "|".join(("mv2t-exec-v4", self._chan_desc(), name,
                        f"n{n}", dtype_str, f"op:{op}", f"root:{root}",
                        f"x:{extra!r}", _compat.exec_fingerprint()))
         blob = daemon.exec_cache_get(ck)
@@ -610,20 +610,11 @@ class DeviceCollChannel:
                 return pallas_alltoall.ici_all_to_allv(x, axis, p, counts)
             out_specs = P(axis)             # global [p*out_len]
         elif name == "reduce_scatter_block":
-            c = n // p
-            if op == "sum":
-                def f(x):
-                    return ops.reduce_scatter(x, axis, scatter_dimension=0,
-                                              tiled=True)
-            else:
-                # non-sum ops: full allreduce then keep this shard's block
-                # (psum_scatter lowers natively only for sum)
-                from jax import lax
-
-                def f(x):
-                    y = ops.allreduce(x, axis, op)
-                    return lax.dynamic_slice(
-                        y, (lax.axis_index(axis) * c,), (c,))
+            def f(x):                       # [p*c] -> [c]
+                # tier dispatch: the ring's fold rounds alone on the
+                # chunked HBM streamer, or the XLA lowering
+                from ..ops import pallas_ici
+                return pallas_ici.ici_reduce_scatter(x, axis, p, op=op)
             out_specs = P(axis)             # global [p*c]
         else:  # pragma: no cover
             raise KeyError(name)
@@ -938,6 +929,11 @@ class DeviceCollChannel:
         elif name in ("allreduce", "reduce", "allgather"):
             tier, reason = pallas_ici.planned_tier(name, nbytes, dtype, op,
                                                    num_devices=p)
+        elif name == "reduce_scatter_block" and not self.multi_axis:
+            # the 1-D program is ici_reduce_scatter on a shard of this
+            # extent (the rank's deposit, or on the fold channel the
+            # chip's fold of them), and asks this same rule
+            tier, reason = pallas_ici.planned_rs_tier(nbytes, dtype, op)
         else:
             plan.tier = "xla"   # ops without a ring-kernel lowering
             return
@@ -956,6 +952,12 @@ class DeviceCollChannel:
             # rank's shard is the kernel's operand
             plan.wire = ("dev_ag_wire",
                          pallas_ici.all_gather_wire_bytes(n, dtype, p))
+        elif (name == "reduce_scatter_block" and tier == "hbm"
+                and p == self.size):
+            # the ring reduce-scatter's wire, on the 1:1 binding (as
+            # the all-gather's)
+            plan.wire = ("dev_rs_wire",
+                         pallas_ici.reduce_scatter_wire_bytes(n, dtype, p))
         if plan.wire is not None:
             bumps.append((mpit.pvar(plan.wire[0] + "_bytes"), plan.wire[1]))
         if tier == "quant":

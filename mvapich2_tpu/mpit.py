@@ -468,6 +468,14 @@ pvar("dev_ag_wire_bytes", PVAR_CLASS_COUNTER, "device",
      "(all_gather_wire_bytes; counted per call in coll/device.py "
      "_note_tier, the same number as wire_bytes on the call's "
      "dev_ag_wire trace instant)")
+pvar("dev_rs_wire_bytes", PVAR_CLASS_COUNTER, "device",
+     "bytes the HBM-streaming ring reduce-scatter kernel (ops/pallas_ici) "
+     "sends over ICI, per rank, summed over the calls it served on the "
+     "1:1 mesh channel: one block in each of the p - 1 fold rounds, tile "
+     "padding included, as the kernel module reckons them "
+     "(reduce_scatter_wire_bytes; counted per call in coll/device.py "
+     "_note_tier, the same number as wire_bytes on the call's "
+     "dev_rs_wire trace instant)")
 pvar("dev_coll_fallback_nbc", PVAR_CLASS_COUNTER, "device",
      "nonblocking collectives on a device-capable comm that could not "
      "route through the device tier (op/dtype/residency/size or the "

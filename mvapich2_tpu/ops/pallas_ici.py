@@ -703,6 +703,19 @@ def hbm_ring_reduce_scatter(x: jax.Array, axis_name: str,
     return _from_blocks(out[None], nblk)
 
 
+def reduce_scatter_wire_bytes(nelems: int, dtype, num_devices: int) -> int:
+    """Bytes one shard sends over ICI in one run of
+    ``hbm_ring_reduce_scatter`` on a ``[nelems]`` contribution: one
+    block in each of the ``p - 1`` fold rounds (both lanes' halves
+    together are one block a round), each ``ceil(nelems / p)`` elements
+    rounded up to whole tiles: what the all-gather of such blocks
+    sends. The copy into the working buffer and the folded block's copy
+    out are HBM-to-HBM DMAs and never reach the wire. As many bytes
+    arrive."""
+    return all_gather_wire_bytes(-(-int(nelems) // num_devices), dtype,
+                                 num_devices)
+
+
 def _xla_reduce_scatter(x: jax.Array, axis_name: str, p: int,
                         op: str) -> jax.Array:
     """The stock lowering of the tiled reduce-scatter: psum_scatter for
@@ -795,6 +808,22 @@ def planned_tier(name: str, shard_nbytes: int, dtype, op: Optional[str],
     if tier == "xla":
         return "xla", "size"
     return tier, None
+
+
+def planned_rs_tier(shard_nbytes: int, dtype, op: Optional[str],
+                    interpret=None) -> Tuple[str, Optional[str]]:
+    """(tier, fallback_reason) for one device reduce-scatter call — the
+    generic device-tier answer collapsed onto the one engine that has a
+    reduce-scatter entry (the flat VMEM kernel has none and the quant
+    wire has no RS-only form; the chunked HBM engine has no size floor,
+    it pads): 'hbm' or 'xla'. ``ici_reduce_scatter`` and the channel's
+    per-call accounting (coll/device.py ``_decide_tier``) both ask it,
+    so the pvar a call bumps is the tier its program took."""
+    tier, reason = planned_tier("reduce_scatter", shard_nbytes, dtype, op,
+                                interpret)
+    if tier in ("vmem", "quant"):
+        tier = "hbm"
+    return tier, reason
 
 
 def _trace_entry(coll: str, tier: str, nbytes: int, op=None,
@@ -907,33 +936,20 @@ def ici_reduce_scatter(x: jax.Array, axis_name: str, num_devices: int,
                        op: str = "sum", interpret=None,
                        mesh_ctx=None) -> jax.Array:
     """Tier-dispatched device reduce-scatter (tiled): this shard's
-    block of the axis-folded array, [ceil(n/p)]. The quant wire has no
-    RS-only form and the flat VMEM kernel has no RS entry, so every
-    non-XLA tier streams through the chunked HBM engine (which has no
-    size floor — it pads)."""
+    block of the axis-folded array, [ceil(n/p)]. Every non-XLA tier
+    streams through the chunked HBM engine (``planned_rs_tier``)."""
     p = num_devices
     if p == 1:
         return x.reshape(-1)
     nbytes = x.size * x.dtype.itemsize
-    mode = _mesh_mode(mesh_ctx, interpret)
-    if mode == "xla":
-        n = int(x.size)
-        flat = x.reshape(n)
-        nblk = -(-n // p)
-        if nblk * p > n:
-            flat = jnp.pad(flat, (0, nblk * p - n),
-                           constant_values=_pad_identity(x.dtype, op))
-        return _xla_reduce_scatter(flat, axis_name, p, op)
-    tier, reason = planned_tier("reduce_scatter", nbytes, x.dtype, op,
-                                interpret, num_devices=p)
-    if tier in ("vmem", "quant"):
-        tier = "hbm"
-    _trace_entry("reduce_scatter", tier, nbytes, op=op)
-    if tier == "hbm":
-        return hbm_ring_reduce_scatter(x, axis_name, p, op,
-                                       interpret=interpret,
-                                       mesh_ctx=mesh_ctx)
-    note_fallback("reduce_scatter", reason or "size", nbytes, x.dtype)
+    if _mesh_mode(mesh_ctx, interpret) != "xla":
+        tier, reason = planned_rs_tier(nbytes, x.dtype, op, interpret)
+        _trace_entry("reduce_scatter", tier, nbytes, op=op)
+        if tier == "hbm":
+            return hbm_ring_reduce_scatter(x, axis_name, p, op,
+                                           interpret=interpret,
+                                           mesh_ctx=mesh_ctx)
+        note_fallback("reduce_scatter", reason or "size", nbytes, x.dtype)
     n = int(x.size)
     flat = x.reshape(n)
     nblk = -(-n // p)

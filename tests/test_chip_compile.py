@@ -407,6 +407,47 @@ def test_allgather_program_at_its_own_size(mesh4, monkeypatch,
     assert mem.temp_size_in_bytes <= temp
 
 
+@pytest.mark.parametrize("nbytes", [64 * MiB, 128 * MiB],
+                         ids=["64MiB", "cell"])
+def test_reduce_scatter_program_at_its_own_size(mesh4, monkeypatch,
+                                                default_tier_edges, nbytes):
+    """``osu4.reduce_scatter.128MiB.dev``'s program as the leader builds
+    it, at the size ``test_hbm_ring_compiles`` takes the kernel to and at
+    the cell's own 33 554 432 float32 a rank (twice that; lowered and
+    compiled here in about 11 s): the ring's fold rounds alone between
+    bitcasts and the one ROOT copy of the rank's quarter; a send buffer
+    in, a quarter out, the kernel's working buffer (one send buffer and
+    32 KiB) the only temporary."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from mvapich2_tpu.coll.device import DeviceCollChannel, _Rendezvous
+    from mvapich2_tpu.ops import _compat, pallas_ici
+    monkeypatch.setattr(_compat, "on_tpu", lambda: True)
+    monkeypatch.setattr(pallas_ici, "on_tpu", lambda: True)
+    dt = np.dtype("float32")
+    n = nbytes // dt.itemsize
+    assert pallas_ici.planned_rs_tier(nbytes, dt, "sum") == ("hbm", None)
+    # whole tiles: three quarters of the send buffer leave every chip
+    assert pallas_ici.reduce_scatter_wire_bytes(n, dt, P4) == 3 * nbytes // 4
+    ch = DeviceCollChannel(mesh4, "x", _Rendezvous(P4), 0)
+    x = jax.ShapeDtypeStruct((P4 * n,), dt,
+                             sharding=NamedSharding(mesh4, P("x")))
+    compiled = ch._build("reduce_scatter_block", n, "sum",
+                         0).lower(x).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "mv2t_hbm_reduce_scatter" in text
+    entry = _entry_ops(text)
+    ops = [op for op, _ in entry if op not in (
+        "parameter", "bitcast", "get-tuple-element", "tuple")]
+    assert ops == ["custom-call", "copy"], ops
+    assert not [dims for _, dims in entry if dims[:1] == ["1"]], entry
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes, mem.output_size_in_bytes) == \
+        (nbytes, nbytes // P4)
+    assert nbytes <= mem.temp_size_in_bytes <= nbytes + 64 * KiB
+
+
 _RING_SIZES = [(4 * KiB, "float32"), (1 * MiB, "float32"),
                (64 * MiB, "float32"), (1 * MiB, "bfloat16")]
 

@@ -327,8 +327,12 @@ def test_exec_cache_parent_artifact_is_never_offered(ddir, monkeypatch,
     alltoall and reduce_scatter_block return one output per rank where
     they returned one array (ISSUE 33, ``v2`` -> ``v3``; the parent's
     artifact runs on the same operands without an error and its one
-    array would be shared out as every rank's result). An artifact a
-    parent of either change exported on this machine is never asked for
+    array would be shared out as every rank's result), the mesh
+    channel's reduce_scatter_block became the ring kernel where it was
+    XLA's psum_scatter (ISSUE 42, ``v3`` -> ``v4``; the parent's
+    artifact is right and is not the program the call counts itself
+    as). An artifact a parent of any of these changes exported on this
+    machine is never asked for
     and never deserialized, whatever else of its key matches; the
     second job, which does load what the first exported, is still
     right."""
@@ -349,15 +353,15 @@ def test_exec_cache_parent_artifact_is_never_offered(ddir, monkeypatch,
                         lambda b: offered.append(b) or load(b))
 
     run_ranks(ranks, app, device_mesh=mesh)
-    assert asked and all(k.startswith("mv2t-exec-v3|") for k in asked)
+    assert asked and all(k.startswith("mv2t-exec-v4|") for k in asked)
     poison = b"artifact of a parent's program"
     for k in set(asked):    # the parents' keys for the same signature
-        for old in ("mv2t-exec-v1|", "mv2t-exec-v2|"):
+        for old in ("mv2t-exec-v1|", "mv2t-exec-v2|", "mv2t-exec-v3|"):
             assert daemon.exec_cache_put(
-                k.replace("mv2t-exec-v3|", old, 1), poison, ddir)
+                k.replace("mv2t-exec-v4|", old, 1), poison, ddir)
     del asked[:]
     run_ranks(ranks, app, device_mesh=mesh)     # fresh channels ask again
-    assert asked and all(k.startswith("mv2t-exec-v3|") for k in asked)
+    assert asked and all(k.startswith("mv2t-exec-v4|") for k in asked)
     assert offered and poison not in offered
     _reload(MV2T_DAEMON_DIR=None, MV2T_ALLREDUCE_ALGO=None,
             MV2T_DEVICE_COLL_MIN_BYTES=None)
