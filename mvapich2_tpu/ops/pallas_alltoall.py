@@ -72,7 +72,7 @@ from jax import lax
 
 from ._compat import compiler_params, kernel_name, note_fallback
 from .pallas_ici import (_LANES, _RingStreamer, _as_blocks,
-                         _cfg_chunk_rows, _cfg_depth, _chunks, _copy,
+                         _cfg_chunk_rows, _cfg_depth, _chunks,
                          _entry_barrier, _from_blocks, _resolve_flags,
                          _resolve_ndir, _tile_rows, _trace_entry,
                          planned_tier)
@@ -92,6 +92,12 @@ _CID_ALLTOALL = 11
 # ---------------------------------------------------------------------------
 # streaming state — the pairwise-permutation form of _RingStreamer
 # ---------------------------------------------------------------------------
+
+def _copy(src, dst, sem):
+    cp = pltpu.make_async_copy(src, dst, sem)
+    cp.start()
+    cp.wait()
+
 
 class _A2AStreamer(_RingStreamer):
     """_RingStreamer with the fixed ring neighbors replaced by per-step
@@ -196,7 +202,7 @@ class _A2AStreamer(_RingStreamer):
 def _mk_a2a_streamer(p, ndir, depth, credits, scratch):
     send_buf, recv_buf, in_sem, st_sem, send_sem, recv_sem, cap_sem = \
         scratch
-    return _A2AStreamer(p, ndir, depth, credits, 0, 0, None,
+    return _A2AStreamer(p, ndir, depth, credits, 0, 0,
                         send_buf, recv_buf, None, in_sem, None, st_sem,
                         send_sem, recv_sem, cap_sem)
 

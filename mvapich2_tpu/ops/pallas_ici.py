@@ -9,11 +9,17 @@ crossover: inputs and outputs stay in HBM (``pl.ANY``) and
 the kernel streams fixed-size chunks through double-buffered VMEM
 scratch slots —
 
-    HBM acc ──local DMA──> send slot ──remote DMA (ICI)──> peer recv slot
+    HBM block ──local DMA──> send slot ──remote DMA (ICI)──> peer recv slot
     peer recv slot + HBM acc chunk ──VPU reduce──> acc slot ──DMA──> HBM
 
 with the remote DMA of chunk *k+1* overlapping the VPU reduce of chunk
-*k* (the ibv_send.c vbuf pipeline, one level up). The allreduce is the
+*k* (the ibv_send.c vbuf pipeline, one level up). A ring block is read
+from the operand, where the caller left it, until a fold or a gather
+round has written it, and from the working or output buffer after: each
+round is told where its send chunks and accumulator chunks are loaded
+from and where its results are stored, so no whole-operand HBM-to-HBM
+copy runs in front of the rounds, the reduce-scatter's last fold lands
+in its output, and the operand is only ever read. The allreduce is the
 pipelined reduce-scatter + all-gather decomposition (the "Multiple
 Processes per GPU" schedule blueprint; EQuARX demonstrates the custom
 chunked form beating stock XLA on TPU); where the mesh axis is a
@@ -219,13 +225,12 @@ class _RingStreamer:
     makes slot reuse collision-free (see module docstring)."""
 
     def __init__(self, p, ndir, depth, credits, left, right,
-                 o_hbm, send_buf, recv_buf, acc_buf,
+                 send_buf, recv_buf, acc_buf,
                  in_sem, acc_sem, st_sem, send_sem, recv_sem, cap_sem,
                  dev_base=0, dev_stride=1):
         self.p, self.ndir, self.depth, self.credits = p, ndir, depth, credits
         self.left, self.right = left, right
         self.dev_base, self.dev_stride = dev_base, dev_stride
-        self.o_hbm = o_hbm
         self.send_buf, self.recv_buf, self.acc_buf = \
             send_buf, recv_buf, acc_buf
         self.in_sem, self.acc_sem, self.st_sem = in_sem, acc_sem, st_sem
@@ -269,13 +274,16 @@ class _RingStreamer:
             h.wait()
             del self.pending_store[key]
 
-    def issue(self, d, sb, off, sz, with_acc, rb):
+    def issue(self, d, src, off, sz, acc):
         """Front half of the chunk pipeline: load the send chunk (and,
         for the reduce phase, prefetch the local accumulator chunk),
         then launch the remote DMA — it flies while the previous
-        chunk's reduce runs. ``sb``/``rb``: traced ring-block indices
-        (leading dim of the HBM operand); ``off``/``sz``: static,
-        tile-aligned row range inside the block."""
+        chunk's reduce runs. ``src``/``acc``: the ``(block_rows, 128)``
+        HBM ring blocks the send chunk and the accumulator chunk are
+        read from (``acc`` None in the gather phase), wherever they lie
+        — the operand until a round has written the block, the working
+        buffer after; ``off``/``sz``: static, tile-aligned row range
+        inside the block."""
         slot = self.gc[d] % self.depth
         prev = self.pending_send.pop((d, slot), None)
         if prev is not None:
@@ -284,13 +292,13 @@ class _RingStreamer:
         if prev_st is not None:
             prev_st.wait()             # acc slot's last store landed
         ld = pltpu.make_async_copy(
-            self.o_hbm.at[sb, pl.ds(off, sz)],
+            src.at[pl.ds(off, sz)],
             self.send_buf.at[d, slot, pl.ds(0, sz)],
             self.in_sem.at[d, slot])
         ld.start()
-        if with_acc:
+        if acc is not None:
             la = pltpu.make_async_copy(
-                self.o_hbm.at[rb, pl.ds(off, sz)],
+                acc.at[pl.ds(off, sz)],
                 self.acc_buf.at[d, slot, pl.ds(0, sz)],
                 self.acc_sem.at[d, slot])
             la.start()
@@ -310,10 +318,11 @@ class _RingStreamer:
         self.gc[d] += 1
         return slot
 
-    def drain(self, d, slot, rb, off, sz, red):
+    def drain(self, d, slot, dst, off, sz, red):
         """Back half: the chunk from upstream has (or is about to have)
-        landed — reduce it into the accumulator chunk (or store it
-        verbatim for the gather phase) and free the slot."""
+        landed — reduce it into the accumulator chunk (or take it
+        verbatim for the gather phase), store the result into rows
+        [off, off+sz) of the HBM block ``dst`` and free the slot."""
         self.pending_send[(d, slot)].wait_recv()
         if red is not None:
             self.pending_acc.pop((d, slot)).wait()
@@ -323,14 +332,14 @@ class _RingStreamer:
             self._grant(d)
             st = pltpu.make_async_copy(
                 self.acc_buf.at[d, slot, pl.ds(0, sz)],
-                self.o_hbm.at[rb, pl.ds(off, sz)],
+                dst.at[pl.ds(off, sz)],
                 self.st_sem.at[d, slot])
             st.start()
             self.pending_store[(d, slot)] = st
         else:
             st = pltpu.make_async_copy(
                 self.recv_buf.at[d, slot, pl.ds(0, sz)],
-                self.o_hbm.at[rb, pl.ds(off, sz)],
+                dst.at[pl.ds(off, sz)],
                 self.st_sem.at[d, slot])
             st.start()
             st.wait()                  # slot must land before re-grant
@@ -365,13 +374,15 @@ class _RingStreamer:
             for d in range(self.ndir):
                 pltpu.semaphore_wait(self.cap_sem.at[d], self.depth)
 
-    def stream_step(self, spans_chunks, sb_offs, rb_offs, red):
+    def stream_step(self, spans_chunks, src, acc, dst, red):
         """One ring step: pipeline every chunk of every direction —
         issue chunk c, then drain chunk c-1 while c is on the wire.
-        ``sb_offs``/``rb_offs`` address the send/receive ring block per
-        direction in whatever unit ``issue``/``drain`` take (block
-        indices here; the quantized streamer still passes flat element
-        offsets) — this loop only hands them through."""
+        ``src``/``acc``/``dst`` say, per direction, where the send
+        chunk is loaded from, where the accumulator chunk is loaded
+        from (None with ``red`` None: the gather phase) and where the
+        result is stored, in whatever unit ``issue``/``drain`` take
+        (HBM block refs here; the quantized streamer still passes flat
+        element offsets) — this loop only hands them through."""
         ndir = self.ndir
         cmax = max(len(c) for c in spans_chunks)
         live: List[List[Optional[int]]] = [[None] * len(spans_chunks[d])
@@ -381,22 +392,20 @@ class _RingStreamer:
                 if c < len(spans_chunks[d]):
                     off, sz = spans_chunks[d][c]
                     live[d][c] = self.issue(
-                        d, sb_offs[d], off, sz, red is not None,
-                        rb_offs[d])
+                        d, src[d], off, sz, acc[d] if acc else None)
             for d in range(ndir):
                 if 1 <= c and c - 1 < len(spans_chunks[d]):
                     off, sz = spans_chunks[d][c - 1]
-                    self.drain(d, live[d][c - 1], rb_offs[d], off, sz,
-                               red)
+                    self.drain(d, live[d][c - 1], dst[d], off, sz, red)
         self.drain_stores()
 
 
-def _mk_streamer(p, ndir, depth, credits, left, right, o_hbm, scratch,
+def _mk_streamer(p, ndir, depth, credits, left, right, scratch,
                  mesh_ctx=None, axis_name=None):
     (send_buf, recv_buf, acc_buf, in_sem, acc_sem, st_sem, send_sem,
      recv_sem, cap_sem) = scratch
     base, stride = _dev_layout(mesh_ctx, axis_name)
-    return _RingStreamer(p, ndir, depth, credits, left, right, o_hbm,
+    return _RingStreamer(p, ndir, depth, credits, left, right,
                          send_buf, recv_buf, acc_buf, in_sem, acc_sem,
                          st_sem, send_sem, recv_sem, cap_sem,
                          dev_base=base, dev_stride=stride)
@@ -435,7 +444,7 @@ def _scratch_shapes(ndir: int, depth: int, chunk: int, dtype):
         pltpu.SemaphoreType.DMA((ndir, depth)),     # remote send
         pltpu.SemaphoreType.DMA((ndir, depth)),     # remote recv
         pltpu.SemaphoreType.REGULAR((ndir,)),       # slot credits
-        pltpu.SemaphoreType.DMA(()),                # init bulk copy
+        pltpu.SemaphoreType.DMA(()),                # all-gather: own block
     ]
 
 
@@ -459,42 +468,60 @@ def _ring_neighbours(axis_name, p):
     return my, lax.rem(my - 1 + p, p), lax.rem(my + 1, p)
 
 
-def _copy(src, dst, sem):
-    cp = pltpu.make_async_copy(src, dst, sem)
-    cp.start()
-    cp.wait()
-
-
-def _rs_rounds(st, my, p, ndir, spans_chunks, red):
+def _rs_rounds(st, my, p, ndir, spans_chunks, red, x_hbm, w_hbm,
+               o_blk=None):
     """Reduce-scatter: cw round s passes the partial of block (my-s-1)
     rightward and folds the arrival into block (my-s-2); the ccw lane
     mirrors with +. After p-1 rounds block ``my`` is fully reduced on
-    both lanes (same convention as pallas_ring.py)."""
+    both lanes (same convention as pallas_ring.py).
+
+    A block is read from the operand ``x_hbm`` until a fold has written
+    it: each round folds into a block no round has touched, so its
+    accumulator chunks come from ``x_hbm``; round 0 sends an untouched
+    block too, every later round the partial the round before stored
+    into the working buffer ``w_hbm``. The last round folds into block
+    ``my`` and stores it into ``o_blk`` where one is given, else into
+    ``w_hbm`` like the others. ``x_hbm`` is only ever read."""
     for s in range(p - 1):
         sb = [lax.rem(my - s - 1 + 2 * p, p), lax.rem(my + s + 1, p)]
         rb = [lax.rem(my - s - 2 + 2 * p, p), lax.rem(my + s + 2, p)]
-        st.stream_step(spans_chunks, sb[:ndir], rb[:ndir], red)
+        sent = x_hbm if s == 0 else w_hbm
+        if o_blk is not None and s == p - 2:
+            dst = [o_blk] * ndir
+        else:
+            dst = [w_hbm.at[b] for b in rb[:ndir]]
+        st.stream_step(spans_chunks, [sent.at[b] for b in sb[:ndir]],
+                       [x_hbm.at[b] for b in rb[:ndir]], dst, red)
 
 
-def _ag_rounds(st, my, p, ndir, spans_chunks):
+def _ag_rounds(st, my, p, ndir, spans_chunks, o_hbm, x_blk=None):
     """All-gather: cw round s passes block (my-s) rightward, receives
-    (my-s-1); ccw mirrors."""
+    (my-s-1); ccw mirrors. Round 0 sends the rank's own block: from
+    ``x_blk`` where one is given, else from block ``my`` of ``o_hbm``
+    like every later round's."""
     for s in range(p - 1):
         sb = [lax.rem(my - s + 2 * p, p), lax.rem(my + s, p)]
         rb = [lax.rem(my - s - 1 + 2 * p, p), lax.rem(my + s + 1, p)]
-        st.stream_step(spans_chunks, sb[:ndir], rb[:ndir], None)
+        if x_blk is not None and s == 0:
+            src = [x_blk] * ndir
+        else:
+            src = [o_hbm.at[b] for b in sb[:ndir]]
+        st.stream_step(spans_chunks, src, None,
+                       [o_hbm.at[b] for b in rb[:ndir]], None)
 
 
 def _hbm_all_reduce_kernel(axis_name, p, op, spans_chunks, depth, ndir,
                            credits, mesh_ctx, x_hbm, o_hbm, *scratch):
-    """x/o: (p, block_rows, 128) in HBM."""
+    """x/o: (p, block_rows, 128) in HBM. ``o_hbm`` is the fold rounds'
+    working buffer: they write every block of it but ``my-1`` (``my+1``
+    on the ccw lane's rows), the gather rounds every block but ``my``,
+    so nothing of ``x_hbm`` is copied ahead of the rounds."""
     my, left, right = _ring_neighbours(axis_name, p)
-    st = _mk_streamer(p, ndir, depth, credits, left, right, o_hbm,
+    st = _mk_streamer(p, ndir, depth, credits, left, right,
                       scratch[:-1], mesh_ctx, axis_name)
-    _copy(x_hbm, o_hbm, scratch[-1])
     st.enter()
-    _rs_rounds(st, my, p, ndir, spans_chunks, _reducer(op))
-    _ag_rounds(st, my, p, ndir, spans_chunks)
+    _rs_rounds(st, my, p, ndir, spans_chunks, _reducer(op), x_hbm, o_hbm)
+    _ag_rounds(st, my, p, ndir, spans_chunks, o_hbm)
     st.finish()
 
 
@@ -503,30 +530,33 @@ def _hbm_reduce_scatter_kernel(axis_name, p, op, spans_chunks, depth,
                                o_hbm, *scratch):
     """The reduce-scatter phase of the allreduce ring alone — the
     per-axis primitive of the multi-axis mesh decomposition. Streams
-    the same p-1 fold rounds over the chunk-credit slot schedule into
-    the working buffer ``w_hbm`` (p, block_rows, 128); after them block
-    ``my`` is fully reduced and lands in the (block_rows, 128)
-    output."""
+    the same p-1 fold rounds over the chunk-credit slot schedule: the
+    operand ``x_hbm`` (p, block_rows, 128) is read where it lies, the
+    partials on their way round live in the working buffer ``w_hbm`` (of
+    the same shape), and the last round, which folds block ``my``
+    whole, stores straight into the (block_rows, 128) output."""
     my, left, right = _ring_neighbours(axis_name, p)
-    st = _mk_streamer(p, ndir, depth, credits, left, right, w_hbm,
+    st = _mk_streamer(p, ndir, depth, credits, left, right,
                       scratch[:-1], mesh_ctx, axis_name)
-    _copy(x_hbm, w_hbm, scratch[-1])
     st.enter()
-    _rs_rounds(st, my, p, ndir, spans_chunks, _reducer(op))
+    _rs_rounds(st, my, p, ndir, spans_chunks, _reducer(op), x_hbm, w_hbm,
+               o_hbm)
     st.finish()
-    _copy(w_hbm.at[my], o_hbm, scratch[-1])
 
 
 def _hbm_all_gather_kernel(axis_name, p, spans_chunks, depth, ndir,
                            credits, mesh_ctx, x_hbm, o_hbm, *scratch):
-    """x: (block_rows, 128); o: (p, block_rows, 128)."""
+    """x: (block_rows, 128); o: (p, block_rows, 128). Round 0 sends the
+    shard from ``x_hbm``; its copy into block ``my`` of the output,
+    which no round reads or writes, runs under the rounds."""
     my, left, right = _ring_neighbours(axis_name, p)
-    st = _mk_streamer(p, ndir, depth, credits, left, right, o_hbm,
+    st = _mk_streamer(p, ndir, depth, credits, left, right,
                       scratch[:-1], mesh_ctx, axis_name)
-    # my shard lands in block ``my`` of the output
-    _copy(x_hbm, o_hbm.at[my], scratch[-1])
+    own = pltpu.make_async_copy(x_hbm, o_hbm.at[my], scratch[-1])
+    own.start()
     st.enter()
-    _ag_rounds(st, my, p, ndir, spans_chunks)
+    _ag_rounds(st, my, p, ndir, spans_chunks, o_hbm, x_hbm)
+    own.wait()
     st.finish()
 
 
@@ -665,8 +695,9 @@ def all_gather_wire_bytes(nelems: int, dtype, num_devices: int) -> int:
     ``hbm_ring_all_gather`` on a ``[nelems]`` shard: ``p - 1`` blocks
     (its own, then each one it forwards; both lanes' halves together
     are one block a round), each the shard rounded up to whole tiles.
-    The shard's own copy into the output is an HBM-to-HBM DMA and never
-    reaches the wire. As many bytes arrive."""
+    The shard's own copy into the output is an HBM-to-HBM DMA that runs
+    under the rounds and never reaches the wire. As many bytes
+    arrive."""
     return ((num_devices - 1) * _tile_rows(nelems, dtype) * _LANES
             * np.dtype(dtype).itemsize)
 
@@ -709,9 +740,9 @@ def reduce_scatter_wire_bytes(nelems: int, dtype, num_devices: int) -> int:
     block in each of the ``p - 1`` fold rounds (both lanes' halves
     together are one block a round), each ``ceil(nelems / p)`` elements
     rounded up to whole tiles: what the all-gather of such blocks
-    sends. The copy into the working buffer and the folded block's copy
-    out are HBM-to-HBM DMAs and never reach the wire. As many bytes
-    arrive."""
+    sends. Nothing else moves but the chunks' own loads and stores: the
+    operand is read where it lies and the last fold stores into the
+    output. As many bytes arrive."""
     return all_gather_wire_bytes(-(-int(nelems) // num_devices), dtype,
                                  num_devices)
 

@@ -215,9 +215,10 @@ class _QuantStreamer(_RingStreamer):
                  scratch, block: int, wire: str):
         (stage_buf, send_buf, recv_buf, acc_buf, in_sem, acc_sem,
          st_sem, send_sem, recv_sem, cap_sem) = scratch
-        super().__init__(p, ndir, depth, credits, left, right, o_hbm,
+        super().__init__(p, ndir, depth, credits, left, right,
                          send_buf, recv_buf, acc_buf, in_sem, acc_sem,
                          st_sem, send_sem, recv_sem, cap_sem)
+        self.o_hbm = o_hbm
         self.stage_buf = stage_buf
         self.block = block
         self.wire = wire
@@ -225,7 +226,7 @@ class _QuantStreamer(_RingStreamer):
     def _wlen(self, sz: int) -> int:
         return wire_words(sz, self.block)
 
-    def issue(self, d, sb_off, off, sz, with_acc, rb_off):
+    def issue(self, d, sb_off, off, sz, rb_off):
         slot = self.gc[d] % self.depth
         prev = self.pending_send.pop((d, slot), None)
         if prev is not None:
@@ -238,7 +239,7 @@ class _QuantStreamer(_RingStreamer):
             self.stage_buf.at[d, slot, pl.ds(0, sz)],
             self.in_sem.at[d, slot])
         ld.start()
-        if with_acc:
+        if rb_off is not None:
             la = pltpu.make_async_copy(
                 self.o_hbm.at[pl.ds(rb_off + off, sz)],
                 self.acc_buf.at[d, slot, pl.ds(0, sz)],
@@ -344,9 +345,10 @@ def _quant_rs_kernel(axis_name, p, nblk, chunk, depth, ndir, credits,
     for s in range(p - 1):
         sb = [lax.rem(my - s - 1 + 2 * p, p), lax.rem(my + s + 1, p)]
         rb = [lax.rem(my - s - 2 + 2 * p, p), lax.rem(my + s + 2, p)]
+        rb_offs = [rb[d] * nblk for d in range(ndir)]
         st.stream_step(spans_chunks,
                        [sb[d] * nblk for d in range(ndir)],
-                       [rb[d] * nblk for d in range(ndir)], red)
+                       rb_offs, rb_offs, red)
     st.finish()
 
     # block ``my`` is fully reduced on both lanes: encode it once into

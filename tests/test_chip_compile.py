@@ -417,7 +417,9 @@ def test_reduce_scatter_program_at_its_own_size(mesh4, monkeypatch,
     compiled here in about 11 s): the ring's fold rounds alone between
     bitcasts and the one ROOT copy of the rank's quarter; a send buffer
     in, a quarter out, the kernel's working buffer (one send buffer and
-    32 KiB) the only temporary."""
+    32 KiB; the partials on their way round live in it, the send buffer
+    is read where it lies and never copied into it) the only
+    temporary."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 

@@ -257,6 +257,188 @@ def test_scratch_scales_with_depth_and_chunk():
 
 
 # ---------------------------------------------------------------------------
+# the operand is read where it lies: no whole-operand copy in front of
+# the rounds, the last fold lands in the reduce-scatter's output, and the
+# caller's send buffer is only ever read
+# ---------------------------------------------------------------------------
+
+_RING_FNS = {
+    "all_reduce": pallas_ici.hbm_ring_all_reduce,
+    "reduce_scatter": pallas_ici.hbm_ring_reduce_scatter,
+    "all_gather": pallas_ici.hbm_ring_all_gather,
+}
+
+
+def _ring_expect(coll, xv, p):
+    """numpy's answer for every shard, stacked: [p, ...]."""
+    rows = xv.reshape(p, -1)
+    if coll == "all_gather":
+        return np.tile(xv, (p, 1))
+    tot = rows.sum(0)
+    if coll == "all_reduce":
+        return np.tile(tot, (p, 1))
+    nblk = -(-tot.size // p)
+    full = np.zeros(p * nblk, xv.dtype)
+    full[:tot.size] = tot
+    return full.reshape(p, nblk)
+
+
+@pytest.mark.parametrize("coll", sorted(_RING_FNS))
+@pytest.mark.parametrize("p,bidir,shard,chunk_bytes", [
+    (2, None, 44 * ROW - 5, 4096),   # one lane (p = 2), ragged, 6 chunks
+    (4, False, 24 * ROW, 4096),      # one lane, 3 chunks a block
+    (4, True, 44 * ROW - 5, 4096),   # two lanes, ragged shard, uneven
+    (8, False, 37, 64),              # one tile a block (see the note above)
+    (8, True, 16 * ROW + 3, 4096),   # two lanes, ragged, one chunk each
+])
+def test_operand_is_left_as_it_was(coll, p, bidir, shard, chunk_bytes):
+    """The kernels read the send buffer where it lies, so what the
+    caller handed in is bit for bit what it was after the call, and the
+    answer is numpy's."""
+    comm = MeshComm(make_mesh((p,), ("x",), jax.devices()[:p]))
+    # an odd shard leaves p not dividing the reduce-scatter's input
+    n = shard if coll == "all_gather" else p * shard - (shard % 2)
+    xv = ((np.arange(p * n) * 7 + 3) % 11).astype(np.float32)
+    xj = jnp.asarray(xv)
+    fn = _RING_FNS[coll]
+
+    def body(s):
+        return fn(s, "x", p, chunk_bytes=chunk_bytes, bidirectional=bidir,
+                  interpret=True), s
+
+    out, seen = comm.run(body, xj, out_specs=(P("x"), P("x")))
+    np.testing.assert_array_equal(np.asarray(xj), xv)
+    np.testing.assert_array_equal(np.asarray(seen), xv)
+    np.testing.assert_array_equal(np.asarray(out).reshape(p, -1),
+                                  _ring_expect(coll, xv, p))
+
+
+def _ring_order_fold(blocks, red):
+    """The fold order of the ring for one block on one lane: ``blocks``
+    in the order the partial visits their ranks; each rank folds the
+    arrival into its own contribution as ``red(own, arrival)``."""
+    acc = blocks[0]
+    for own in blocks[1:]:
+        acc = red(own, acc)
+    return acc
+
+
+@pytest.mark.parametrize("op,dtype", [
+    ("sum", "float32"), ("prod", "float32"), ("sum", "bfloat16"),
+    ("max", "bfloat16")])
+@pytest.mark.parametrize("coll", ["all_reduce", "reduce_scatter"])
+def test_fold_order_is_the_rings(comm4, coll, op, dtype):
+    """Same chunks, same operands of the same reducer in the same
+    order, whichever buffer a round reads them from: on data whose
+    float sums and products depend on the order, block b's result is
+    bit for bit the ring's fold (rank b+1 first, rank b last on the
+    clockwise lane's rows; rank b-1 first on the other lane's)."""
+    blk = 48 * ROW
+    rng = np.random.default_rng(43)
+    xv = rng.uniform(0.5, 1.5, P4 * P4 * blk).astype(np.float32)
+    xj = jnp.asarray(xv, dtype=dtype)
+    out = comm4.run(lambda s: _RING_FNS[coll](
+        s, "x", P4, op, chunk_bytes=4096, interpret=True), xj,
+        out_specs=P("x"))
+    got = np.asarray(out.astype(jnp.float32)).reshape(P4, -1)
+    x = xj.reshape(P4, P4, blk)          # [rank, block, element]
+    red = pallas_ici._reducer(op)
+    half = pallas_ici._block_spans(
+        blk // ROW, 2, pallas_ici._sublanes(dtype))[0][1] * ROW
+    exp = []
+    for b in range(P4):
+        cw = _ring_order_fold([x[(b + 1 + i) % P4, b, :half]
+                               for i in range(P4)], red)
+        ccw = _ring_order_fold([x[(b - 1 - i) % P4, b, half:]
+                                for i in range(P4)], red)
+        exp.append(np.asarray(jnp.concatenate([cw, ccw])
+                              .astype(jnp.float32)))
+    exp = np.concatenate(exp)
+    if coll == "all_reduce":
+        for row in got:
+            np.testing.assert_array_equal(row, exp)
+    else:
+        np.testing.assert_array_equal(got.reshape(-1), exp)
+
+
+def _sub_jaxprs(params):
+    for v in params.values():
+        for sub in (v if isinstance(v, (list, tuple)) else [v]):
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield sub
+
+
+def _eqns(jaxpr, name):
+    for e in jaxpr.eqns:
+        if e.primitive.name == name:
+            yield e
+        for sub in _sub_jaxprs(e.params):
+            yield from _eqns(sub, name)
+
+
+def _local_dmas(coll, p, n, hbm_names, **kw):
+    """Every local DMA the traced kernel of ``coll`` starts, as
+    ``(source, source is whole, destination, destination is whole)``:
+    the HBM operands by the names given (kernel argument order), any
+    scratch buffer as ``vmem``; whole = no index on the ref."""
+    from jax import tree_util
+    comm = MeshComm(make_mesh((p,), ("x",), jax.devices()[:p]))
+    traced = jax.make_jaxpr(lambda x: comm.run(
+        lambda s: _RING_FNS[coll](s, "x", p, interpret=True, **kw), x,
+        out_specs=P("x")))(jnp.zeros(p * n, jnp.float32))
+    (call,) = _eqns(traced.jaxpr, "pallas_call")
+    assert not call.params["input_output_aliases"]
+    kernel = call.params["jaxpr"]
+    names = dict(zip(kernel.invars, hbm_names))
+    dmas = []
+    for e in _eqns(kernel, "dma_start"):
+        (src, src_tf, dst, dst_tf, _sem, _sem_tf, _ssem, _ssem_tf,
+         device) = tree_util.tree_unflatten(e.params["tree"], e.invars)
+        if device is None:
+            dmas.append((names.get(src, "vmem"), not src_tf,
+                         names.get(dst, "vmem"), not dst_tf))
+    return dmas
+
+
+@pytest.mark.parametrize("coll,hbm", [
+    ("all_reduce", ("x", "o")), ("reduce_scatter", ("x", "w", "o")),
+    ("all_gather", ("x", "o"))])
+def test_no_whole_operand_copy_in_front_of_the_rounds(coll, hbm):
+    """Read off the ``pallas_call``'s jaxpr (p = 4, two lanes, 3 chunks
+    a lane): no DMA moves a whole operand into a whole working or
+    output buffer, none writes the operand, and HBM meets HBM only in
+    the all-gather's copy of the shard into its own block. The fold
+    rounds load every accumulator chunk and round 0's send chunks from
+    the operand; the reduce-scatter's last fold stores into its
+    output."""
+    chunks, rounds = 2 * 3, P4 - 1
+    n = 48 * ROW if coll == "all_gather" else P4 * 48 * ROW
+    dmas = _local_dmas(coll, P4, n, hbm, chunk_bytes=4096)
+    assert not [d for d in dmas if d[2] == "x"], "the operand is written"
+    hbm2hbm = [d for d in dmas if d[0] != "vmem" and d[2] != "vmem"]
+    assert not [d for d in hbm2hbm if d[1] and d[3]], hbm2hbm
+    loads = [d[0] for d in dmas if d[2] == "vmem"]
+    stores = [d[2] for d in dmas if d[0] == "vmem"]
+    if coll == "all_gather":
+        assert hbm2hbm == [("x", True, "o", False)]
+        assert loads.count("x") == chunks
+        assert loads.count("o") == (rounds - 1) * chunks
+        assert stores.count("o") == rounds * chunks
+        return
+    assert not hbm2hbm, hbm2hbm
+    # fold round 0 sends from the operand, every round folds into it
+    assert loads.count("x") == (1 + rounds) * chunks
+    if coll == "reduce_scatter":
+        assert loads.count("w") == (rounds - 1) * chunks
+        assert stores.count("w") == (rounds - 1) * chunks
+        assert stores.count("o") == chunks
+    else:
+        assert loads.count("o") == (rounds - 1 + rounds) * chunks
+        assert stores.count("o") == 2 * rounds * chunks
+
+
+# ---------------------------------------------------------------------------
 # all-gather + the pt2pt lane
 # ---------------------------------------------------------------------------
 
