@@ -36,7 +36,8 @@ last on the ring's fold rounds alone); then the fold
 phase, ``run_ranks(8, app, device_mesh=<the four chips>)`` (two ranks a
 chip, ``DeviceFoldChannel``): allreduce sum and max, allgather,
 reduce_scatter_block, bcast and reduce at 1 MiB a rank on device-resident
-buffers, once each, compared with numpy.
+buffers, once each, compared with numpy; level 1 of the four reductions
+has to ride in the mesh program (``dev_fold_fused`` +4).
 
 Any failed phase raises: the exit code is non-zero and no result line is
 printed. Timings are host-clock smoke timings around
@@ -507,7 +508,11 @@ def fold_phase(seed: int, nranks: int = 8, ndev: int = 4,
                 say(f"fold: ran {name} {nbytes} B/rank")
 
     levels = ("coll_level_chip", "coll_level_ici")
-    before = {n_: mpit.pvar(n_).read() for n_ in levels}
+    # level 1 of the four reductions rides in the mesh program, one
+    # launch a call; allgather alone still copies a chip's deposits
+    folds = {"dev_fold_fused": 4, "dev_fold_operands": 4,
+             "dev_fold_stacked": ndev}
+    before = {n_: mpit.pvar(n_).read() for n_ in levels + tuple(folds)}
     fb0 = fallback_pvars()
     run_ranks(nranks, app, device_mesh=mesh, timeout=900.0)
     assert len(set(homes)) == ndev and \
@@ -524,7 +529,8 @@ def fold_phase(seed: int, nranks: int = 8, ndev: int = 4,
     rose = {n_: mpit.pvar(n_).read() - v for n_, v in before.items()}
     fb = {n_: v - fb0[n_] for n_, v in fallback_pvars().items()}
     say(f"proof (fold): {rose}; fallbacks {fb}")
-    assert all(v == nranks * len(names) for v in rose.values()), rose
+    assert all(rose[lv] == nranks * len(names) for lv in levels), rose
+    assert all(rose[n_] == v for n_, v in folds.items()), rose
     assert fb and not any(fb.values()), fb
 
 

@@ -208,12 +208,15 @@ class _ImportedProgram:
 #   dev_arrive       every rank: slot deposit -> counted in at the gate;
 #                    on rank 0 also its wait for the last rank
 #   dev_stage        rank 0: assembling the program's input
-#   dev_chip_fold    rank 0, fold channel, inside dev_stage: level 1,
-#                    every chip's fold dispatch (and its staging, where
-#                    a deposit does not lie flat on its chip); its E
-#                    says ``k``, ``chips`` and ``stacked``, the planar
-#                    copies of a chip's deposits made this call: 0
-#                    where the deposits were the fold's operands
+#   dev_chip_fold    rank 0, fold channel, inside dev_stage: level 1
+#                    as far as the host does it: the look at every
+#                    chip's deposits and, where one does not lie flat
+#                    on its chip, the staging and a fold dispatch a
+#                    chip; its E says ``k``, ``chips``, ``stacked`` (the
+#                    planar copies of a chip's deposits made this call:
+#                    0 where the deposits were the fold's operands) and
+#                    ``fused`` (the fold ran inside the mesh program:
+#                    nothing was launched in this span)
 #   dev_dispatch     rank 0: program-cache lookup + enqueue; its E says
 #                    ``built`` when the call made or loaded the program
 #   dev_device_wait  rank 0, slot channel: the leader's block_until_ready
@@ -1601,16 +1604,23 @@ class DeviceFoldChannel(DeviceCollChannel):
     contiguous result blocks). Each collective runs in two levels:
 
       * **chip fold** — every chip's ``k`` deposited slots are folded in
-        HBM by one program a chip (the fused slot-reduce kernel for sum,
-        the XLA reduction otherwise), exactly the slot channel's move
-        applied per chip: flat device arrays on their chip go in as they
-        lie, ``k`` operands and no eager op; anything else is staged as
-        one planar ``(k, n)`` array on that chip first. allgather's fold
-        is concatenation and still stages that array;
-      * **ICI phase** — the ``ndev`` folded shards form one mesh-sharded
-        global array and ride the ordinary mesh program (ring RS/AG
-        tiers, per-axis torus phases when the mesh is multi-axis), built
-        over the CHIP count (``_mesh_extent``).
+        HBM (the fused slot-reduce kernel for sum, the XLA reduction
+        otherwise), exactly the slot channel's move applied per chip;
+      * **ICI phase** — the ``ndev`` folded shards ride the ordinary
+        mesh collective (ring RS/AG tiers, per-axis torus phases when
+        the mesh is multi-axis), built over the CHIP count
+        (``_mesh_extent``).
+
+    Both levels of a reduction are one program, one launch a call, where
+    every deposit is a flat device array on its own chip (and the mesh
+    is 1-D): ``k`` mesh-sharded operands made of the deposits as they
+    lie, per chip the fold and then the ring on its result. A call with
+    a host deposit, a shaped array or one committed to another chip
+    among its ranks (and any call on a multi-axis mesh) folds chip by
+    chip instead, a launch each (a chip that does not lie is staged as
+    one planar ``(k, n)`` array first), and the ``ndev`` folds form the
+    one global array of the unfused mesh program. allgather's fold is
+    concatenation and always stages that array; bcast stages the root.
 
     Results fan back zero-copy per chip: every rank on a chip shares its
     chip's output shard (slices of it for reduce_scatter_block).
@@ -1658,36 +1668,76 @@ class DeviceFoldChannel(DeviceCollChannel):
     def nonblocking(self, comm, name: str, *a, plan: bool = False):
         return None     # host NBC schedule (fold has no DAG segments yet)
 
+    @staticmethod
+    def _fold_body(op: str):
+        """Level 1's body, ``f(*xs) -> [n]``, traced where it is called:
+        alone in ``_fold_prog``, in front of the ring in the fused mesh
+        program (``_build``). ``xs`` is one chip's ``k`` deposited
+        ``(n,)`` arrays, or one staged planar ``(k, n)`` array; the body
+        reads which from its operands, as the slot channel's ``reduced``
+        does: the HBM fused slot-reduce for sum, on ``k`` operands of
+        whole 128-lane rows where they lie (a ragged length is stacked
+        and padded inside the trace); the XLA reduction for the other
+        ops."""
+        import jax.numpy as jnp
+
+        from ..ops import pallas_hbm as ph
+        red = {"sum": jnp.sum, "max": jnp.max, "min": jnp.min,
+               "prod": jnp.prod}[op or "sum"]
+
+        def slots(xs):      # the (k, n) slot array: the one staged
+            # operand, or the k deposited ones stacked inside the trace
+            return xs[0] if xs[0].ndim == 2 else jnp.stack(xs)
+
+        def f(*xs):                         # -> [n]
+            if not _slot_kernel_op(op):
+                return red(slots(xs), axis=0)
+            if xs[0].ndim == 1 and xs[0].shape[0] % 128 == 0:
+                return ph.hbm_slot_allreduce_operands(xs)
+            return ph.hbm_slot_allreduce(slots(xs))
+        return f
+
     def _fold_prog(self, op: str):
-        """Per-chip fold program, one jitted ``f(*xs)`` (cached like any
-        program). ``xs`` is what ``_fold_chip`` had: the chip's ``k``
-        deposited ``(n,)`` arrays, or one staged planar ``(k, n)`` array.
-        The body reads which from its operands, as the slot channel's
-        ``reduced`` does: the HBM fused slot-reduce for sum, on ``k``
-        operands of whole 128-lane rows where they lie; the XLA
-        reduction for the other ops. No operand is donated or aliased."""
+        """Per-chip fold program, ``_fold_body`` jitted alone (cached
+        like any program): the arm of a call some deposit of which does
+        not lie flat on its chip. No operand is donated or aliased."""
         key = ("chipfold", 0, "", op, 0, None)
         got = self._programs.get(key)
         if got is None:
             import jax
-            import jax.numpy as jnp
-
-            from ..ops import pallas_hbm as ph
-            red = {"sum": jnp.sum, "max": jnp.max, "min": jnp.min,
-                   "prod": jnp.prod}[op or "sum"]
-
-            def slots(xs):      # the (k, n) slot array: the one staged
-                # operand, or the k deposited ones stacked inside the trace
-                return xs[0] if xs[0].ndim == 2 else jnp.stack(xs)
-
-            def f(*xs):                         # -> [n]
-                if not _slot_kernel_op(op):
-                    return red(slots(xs), axis=0)
-                if xs[0].ndim == 1 and xs[0].shape[0] % 128 == 0:
-                    return ph.hbm_slot_allreduce_operands(xs)
-                return ph.hbm_slot_allreduce(slots(xs))
-            got = self._programs[key] = jax.jit(f)
+            got = self._programs[key] = jax.jit(self._fold_body(op))
         return got
+
+    def _build(self, name: str, n: int, op: str, root: int, extra=None):
+        """``extra`` is the count of operands a chip folds inside the
+        program: None for the mesh program over one shard a chip (the
+        1:1 channel's, unchanged), ``k`` for the fused one: ``k``
+        mesh-sharded flat operands, shard ``j`` of operand ``i`` rank
+        ``j * k + i``'s deposit as it lies, and per chip ``_fold_body``,
+        then the collective's ring on its result. One launch where the
+        unfused arm makes one a chip and the ring's. 1-D meshes only
+        (``_leader`` asks); no operand is donated or aliased."""
+        if extra is None:
+            return super()._build(name, n, op, root)
+        import jax
+        from jax.sharding import PartitionSpec as P
+
+        from ..ops import pallas_ici
+        from ..parallel.mesh import shard_map
+        axis, p = self.axis, self._mesh_extent()
+        fold = self._fold_body(op)
+        # the collective's mesh body, as ``DeviceCollChannel._build``
+        # has it: k x [p*c] -> [c], or k x [n] -> replicated [n]
+        ring, out_specs = (
+            (pallas_ici.ici_reduce_scatter, P(axis))
+            if name == "reduce_scatter_block"
+            else (pallas_ici.ici_all_reduce, P(None)))     # and reduce
+
+        def f(*xs):
+            return ring(fold(*xs), axis, p, op=op)
+        sm = shard_map(f, mesh=self.mesh, in_specs=(P(axis),) * extra,
+                       out_specs=out_specs, check_vma=False)
+        return jax.jit(sm)
 
     def _chip_stack(self, j: int, n: int, dtype):
         """Chip ``j``'s k deposited slots as one planar (k, n) array on
@@ -1704,10 +1754,12 @@ class DeviceFoldChannel(DeviceCollChannel):
             np.stack([np.asarray(s).reshape(n) for s in sl]), dev)
 
     def _fold_chip(self, j: int, n: int, dtype, op: str):
-        """Fold chip ``j``'s slots to one [n] contribution (level 1).
-        Flat device arrays on the chip's device are the fold program's
-        operands as they lie; host deposits, shaped arrays and arrays
-        committed elsewhere are staged by ``_chip_stack`` first."""
+        """What chip ``j`` contributes to level 2. Flat device arrays on
+        the chip's device are handed back as they lie, a tuple of the
+        chip's ``k`` deposits for the program to fold (no launch, no
+        reshape, no eager op); host deposits, shaped arrays and arrays
+        committed elsewhere are staged by ``_chip_stack`` and folded
+        here, to one [n] array."""
         import jax
         dev = self._mesh_devices[j]
         if self.k == 1:
@@ -1718,24 +1770,28 @@ class DeviceFoldChannel(DeviceCollChannel):
         sl = self.rv.slots[j * self.k:(j + 1) * self.k]
         if all(is_device_array(s) and s.ndim == 1 and s.devices() == {dev}
                for s in sl):
-            # the deposits as they lie: k operands, no reshape, no
-            # eager op
-            return self._fold_prog(op)(*sl)
+            return tuple(sl)
         return self._fold_prog(op)(self._chip_stack(j, n, dtype))
 
     def _leader(self, name: str, op: str, root: int) -> List:
-        """Leader compute: fold per chip, run the mesh program over the
-        folded shards, fan the chip outputs back to their ranks."""
+        """Leader compute: level 1 per chip, the mesh program over the
+        chips, the chip outputs fanned back to their ranks. Where every
+        chip of a reduction handed its ``k`` deposits over as they lie
+        (and the mesh is 1-D) level 1 runs inside the mesh program, one
+        launch a call (counted: dev_fold_fused); otherwise each chip is
+        folded by its own launch and the mesh program takes the folds."""
         import jax
 
         rv = self.rv
         nd, k = self.ndev, self.k
         n, dtype = self._slot_extent(rv.slots[0])
-        shards, prog_root, prog_n = [], 0, n
+        shards, prog_root, prog_n, fused = [], 0, n, False
         with self._phase("dev_stage"):
-            # level 1: every chip's fold (and staging, where a deposit
-            # does not lie flat on its chip), issued from this one
-            # thread; its E says how many planar copies it made
+            # level 1 as far as the host has a hand in it: the look at
+            # the deposits, and the staging and fold launches of a call
+            # that does not fuse, issued from this one thread; its E
+            # says how many planar copies it made and whether the fold
+            # went into the mesh program
             with self._phase("dev_chip_fold") as fold:
                 self._stacked = 0
                 if name == "bcast":
@@ -1764,19 +1820,35 @@ class DeviceFoldChannel(DeviceCollChannel):
                         shards.append(self._chip_stack(j, n, dtype)
                                       .reshape(prog_n))
                 else:   # allreduce / reduce / reduce_scatter_block
-                    for j in range(nd):
-                        shards.append(self._fold_chip(j, n, dtype, op))
+                    shards = [self._fold_chip(j, n, dtype, op)
+                              for j in range(nd)]
+                    fused = not self.multi_axis and all(
+                        isinstance(c, tuple) and len(c) == k
+                        for c in shards)
+                    if fused:
+                        mpit.pvar("dev_fold_fused").inc()
+                    else:   # a launch for every chip still unfolded
+                        shards = [self._fold_prog(op)(*c)
+                                  if isinstance(c, tuple) else c
+                                  for c in shards]
                     if not self._stacked:
                         mpit.pvar("dev_fold_operands").inc()
                 if self._stacked:
                     mpit.pvar("dev_fold_stacked").inc(self._stacked)
                 if fold is not None:
-                    fold.args.update(k=k, chips=nd, stacked=self._stacked)
-            global_arr = self._global(shards, prog_n)
+                    fold.args.update(k=k, chips=nd, stacked=self._stacked,
+                                     fused=fused)
+            if fused:
+                # operand i: the chips' i-th deposits, shard j rank
+                # j*k + i's array as it lies
+                operands = tuple(self._global([c[i] for c in shards], n)
+                                 for i in range(k))
+            else:
+                operands = (self._global(shards, prog_n),)
         with self._phase("dev_dispatch") as ph:
             had = len(self._programs)
-            out = self._program(name, prog_n, str(dtype), op, prog_root)(
-                global_arr)
+            out = self._program(name, prog_n, str(dtype), op, prog_root,
+                                k if fused else None)(*operands)
             if ph is not None:
                 ph.args["built"] = len(self._programs) > had
         if name == "reduce_scatter_block":

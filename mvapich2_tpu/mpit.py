@@ -534,6 +534,15 @@ pvar("dev_fold_operands", PVAR_CLASS_COUNTER, "device",
      "host deposit, a shaped array or one committed to another chip "
      "among its ranks stages that chip (dev_fold_stacked) and does "
      "not count")
+pvar("dev_fold_fused", PVAR_CLASS_COUNTER, "device",
+     "fold-channel leader calls of allreduce, reduce and "
+     "reduce_scatter_block whose level 1 ran inside the level-2 mesh "
+     "program: one launch a call, k mesh-sharded operands, shard j of "
+     "operand i rank j*k+i's deposit as it lies (coll/device.py "
+     "DeviceFoldChannel._leader, _build); rises with "
+     "dev_fold_operands on a 1-D mesh at k > 1; a call with a chip "
+     "that had to be staged folds every chip by its own launch and "
+     "does not count")
 pvar("dev_mesh_reordered", PVAR_CLASS_COUNTER, "device",
      "1-D meshes parallel/mesh.make_mesh returned with their devices in "
      "another order than they were given: TPU chips laid along a snake "

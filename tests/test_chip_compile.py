@@ -45,6 +45,21 @@ def topo():
     compilation_cache.reset_cache()
 
 
+@pytest.fixture(autouse=True)
+def ring_chunk_as_on_the_chip(monkeypatch):
+    """The ring kernels' default chunk, said out loud: the CPU's measured
+    profile (loaded for the life of the process once an earlier test of
+    the same worker has bound ranks) cuts it to 2 KiB for the
+    interpreter, and a 64 MiB ring then unrolls into tens of thousands
+    of steps and traces for longer than the suite may run."""
+    from mvapich2_tpu.utils.config import get_config
+    monkeypatch.setenv("MV2T_ICI_CHUNK_BYTES", str(256 * KiB))
+    get_config().reload()
+    yield
+    monkeypatch.undo()
+    get_config().reload()
+
+
 @pytest.fixture(scope="module")
 def one_chip(topo):
     from jax.sharding import SingleDeviceSharding
@@ -323,6 +338,53 @@ def default_tier_edges(monkeypatch):
     yield
     monkeypatch.undo()
     get_config().reload()
+
+
+@pytest.mark.parametrize("coll,nbytes,ring", [
+    ("allreduce", 64 * MiB, "mv2t_hbm_all_reduce"),
+    ("reduce_scatter_block", 4 * MiB, "mv2t_hbm_reduce_scatter")],
+    ids=["allreduce_cell", "reduce_scatter_block"])
+def test_fused_fold_program_is_two_kernels_and_the_root_copy(
+        mesh4, monkeypatch, default_tier_edges, coll, nbytes, ring):
+    """``osu4.allreduce_2level.64MiB.dev``'s one program since ISSUE 44,
+    as the fold channel's leader builds it (``extra=k``): two flat
+    mesh-sharded operands, per chip two parameters of the deposit's
+    size as they lie. Compiled for the four chips it is
+    ``mv2t_slot_reduce``, then the ring kernel, between bitcasts, and
+    the one ROOT copy every four-chip program has: no stack, relayout
+    or fusion between a parameter and the fold kernel, nothing aliased
+    (the callers keep their buffers). The same for
+    reduce_scatter_block, at a small size."""
+    import re
+
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from mvapich2_tpu.ops import pallas_ici
+    ch = _fold_channel(mesh4, monkeypatch)
+    monkeypatch.setattr(pallas_ici, "on_tpu", lambda: True)
+    n = nbytes // 4
+    x = jax.ShapeDtypeStruct((P4 * n,), np.dtype("float32"),
+                             sharding=NamedSharding(mesh4, P("x")))
+    compiled = ch._build(coll, n, "sum", 0, ch.k).lower(x, x).compile()
+    text = compiled.as_text()
+    entry = _entry_ops(text)
+    assert [dims for op, dims in entry if op == "parameter"] == \
+        [[str(n)]] * 2, entry
+    ops = [op for op, _ in entry if op not in (
+        "parameter", "bitcast", "get-tuple-element", "tuple")]
+    assert ops == ["custom-call", "custom-call", "copy"], ops
+    # a Pallas call's instruction carries its kernel's name
+    calls = re.findall(r"%(\w+?)(?:\.\d+)? = [^=]*? custom-call\(",
+                       text[text.index("ENTRY"):])
+    assert calls == ["mv2t_slot_reduce", ring], calls
+    assert not [dims for _, dims in entry if dims[:1] == ["1"]], entry
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == 2 * nbytes
+    assert mem.output_size_in_bytes == \
+        (nbytes if coll == "allreduce" else nbytes // P4)
+    assert mem.alias_size_in_bytes == 0
+    assert "input_output_alias" not in text
 
 
 @pytest.mark.parametrize("coll,dtype", [

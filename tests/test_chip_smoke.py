@@ -79,6 +79,7 @@ def test_fold_phase_under_the_interpreter(monkeypatch, capsys):
     cfg.reload()
     stacked0 = mpit.pvar("dev_fold_stacked").read()
     operands0 = mpit.pvar("dev_fold_operands").read()
+    fused0 = mpit.pvar("dev_fold_fused").read()
     try:
         chip_smoke.fold_phase(seed=3, nbytes=16 * 1024)
     finally:
@@ -87,10 +88,13 @@ def test_fold_phase_under_the_interpreter(monkeypatch, capsys):
     out = capsys.readouterr().out
     assert out.count("bit-equal to numpy on 8 ranks over 4 chips") == 6
     # the deposits are device arrays on their chips: the reduce family
-    # folds them as they lie (ISSUE 41); allgather alone still makes a
-    # planar copy a chip
+    # folds them as they lie (ISSUE 41), inside the mesh program, one
+    # launch a call (ISSUE 44); allgather alone still makes a planar
+    # copy a chip. The phase asserts the three itself, and says them
     assert mpit.pvar("dev_fold_stacked").read() - stacked0 == 4 * 1
     assert mpit.pvar("dev_fold_operands").read() - operands0 == 4
+    assert mpit.pvar("dev_fold_fused").read() - fused0 == 4
+    assert "'dev_fold_fused': 4" in out
 
 
 def test_main_refuses_without_a_tpu(capsys):

@@ -269,7 +269,7 @@ PHASE_ARGS = {"seq", "coll"}
 # what a site learns after its B, on the E alone
 ADDED = {"dev_dispatch": "built", "dev_collect": "parts",
          "dev_deliver": "relaid"}
-FOLD_ADDED = {"k", "chips", "stacked"}      # the dev_chip_fold E's own
+FOLD_ADDED = {"k", "chips", "stacked", "fused"}     # dev_chip_fold E's own
 
 
 @pytest.mark.parametrize("channel", list(CHANNELS))
@@ -317,8 +317,9 @@ def test_the_chip_fold_span_says_what_level_1_copied(traced, resident,
                                                      stacked):
     """The fold leader's ``dev_chip_fold`` E of an allreduce: two ranks
     a chip on four chips, and no planar copy where the deposits lie flat
-    on their chips (ISSUE 41: they are the fold program's operands); a
-    host deposit is still staged, one copy a chip."""
+    on their chips (ISSUE 41: they are the fold's operands; ISSUE 44:
+    inside the mesh program, ``fused``); a host deposit is still staged,
+    one copy a chip, and folded by a launch a chip."""
     ranks = CHANNELS["fold"][0]
     lanes = {}
 
@@ -335,7 +336,8 @@ def test_the_chip_fold_span_says_what_level_1_copied(traced, resident,
                    if name == "dev_chip_fold" and ph == "E"]
             for rank, lane in lanes.items()}
     assert ends.pop(0) == [{"seq": 1, "coll": "allreduce", "k": 2,
-                            "chips": 4, "stacked": stacked}]
+                            "chips": 4, "stacked": stacked,
+                            "fused": resident}]
     assert not any(ends.values())       # the leader's span alone
 
 

@@ -7,7 +7,9 @@ the caller's own device; the level pvars, ``dev_fold_stacked``,
 ``dev_chip_fold`` span lies inside the leader's ``dev_stage`` and nowhere
 else. Level 1 of the reduce family takes a chip's deposits as its
 operands where they lie flat on the chip (ISSUE 41) and stages anything
-else. No test here asserts a time."""
+else; where every chip's lie so it rides in the level-2 mesh program,
+one launch a call (ISSUE 44: ``dev_fold_fused``), bit-equal to a launch
+a chip. No test here asserts a time."""
 
 import jax
 import numpy as np
@@ -37,8 +39,8 @@ FOLDED = ("allreduce_sum", "allreduce_max", "reduce_scatter_block",
           "reduce")     # level 1 is _fold_chip
 LEVELS = ("coll_level_chip", "coll_level_ici")
 COUNTED = LEVELS + ("dev_fold_stacked", "dev_fold_operands",
-                    "dev_call_plan_hit", "dev_call_plan_filed",
-                    "dev_deposit_as_is")
+                    "dev_fold_fused", "dev_call_plan_hit",
+                    "dev_call_plan_filed", "dev_deposit_as_is")
 
 
 @pytest.fixture(autouse=True)
@@ -147,17 +149,18 @@ def test_device_resident_deposits_match_the_plain_reference(case):
 @pytest.mark.parametrize("case", list(CASES))
 def test_levels_copies_and_plans_are_counted(case):
     """Both levels rise per rank per call; on device-resident deposits
-    the reduce family makes no planar copy and ``dev_fold_operands``
-    rises by one per leader call, allgather still copies a chip's two;
-    the first call of the signature files a plan and the two after run
-    on it."""
+    the reduce family makes no planar copy and ``dev_fold_operands`` and
+    ``dev_fold_fused`` rise by one per leader call (the deposits went
+    over as they lay, into the one mesh program), allgather still copies
+    a chip's two; the first call of the signature files a plan and the
+    two after run on it."""
     calls = 3
     before = _reads()
     _run(case, seed=38, calls=calls)
     rose = {n: v - before[n] for n, v in _reads().items()}
     assert all(rose[lv] == RANKS * calls for lv in LEVELS), rose
     assert rose["dev_fold_stacked"] == CASES[case][2] * calls, rose
-    assert rose["dev_fold_operands"] == \
+    assert rose["dev_fold_operands"] == rose["dev_fold_fused"] == \
         (calls if case in FOLDED else 0), rose
     assert rose["dev_call_plan_filed"] == RANKS, rose
     assert rose["dev_call_plan_hit"] == RANKS * (calls - 1), rose
@@ -168,7 +171,8 @@ def test_levels_copies_and_plans_are_counted(case):
 def test_host_deposits_are_staged_and_match_the_plain_reference(case):
     """Host buffers take ``_chip_stack`` and the one-operand program, as
     before ISSUE 41: one planar copy per chip per leader call, no call
-    counted as operands, nothing deposited as it is and no plan filed."""
+    counted as operands or as fused, nothing deposited as it is and no
+    plan filed."""
     calls = 2
     before = _reads()
     data, got, _, _ = _run(case, seed=2**31 + 41, calls=calls,
@@ -180,40 +184,130 @@ def test_host_deposits_are_staged_and_match_the_plain_reference(case):
         assert np.count_nonzero(got[r] != want) == 0, (case, r)
     assert all(rose[lv] == RANKS * calls for lv in LEVELS), rose
     assert rose["dev_fold_stacked"] == HOST_STACKED[case] * calls, rose
-    assert rose["dev_fold_operands"] == 0, rose
+    assert rose["dev_fold_operands"] == rose["dev_fold_fused"] == 0, rose
     assert rose["dev_deposit_as_is"] == rose["dev_call_plan_filed"] == 0
 
 
 @pytest.mark.parametrize("case", FOLDED)
 def test_the_fold_programs_operands_are_the_deposited_objects(monkeypatch,
                                                               case):
-    """Level 1 hands its program what the ranks handed over: chip
-    ``j``'s call takes ``k`` operands and operand ``i`` *is* rank
-    ``j * k + i``'s array, no reshape and no eager op between."""
+    """Level 1 rides in the level-2 program: the leader makes one launch
+    a call, of the program keyed ``extra=k``, on ``k`` mesh-sharded
+    operands, and shard ``j`` of operand ``i`` *is* rank ``j * k + i``'s
+    array (the object handed to ``_global``, the buffer the program
+    reads): no per-chip fold launch, no reshape, no eager op between."""
     from mvapich2_tpu.coll.device import DeviceFoldChannel
-    sound = DeviceFoldChannel._fold_prog
-    seen, deposits = [], [None] * RANKS
+    sound_program = DeviceFoldChannel._program
+    sound_global = DeviceFoldChannel._global
+    launches, globals_, deposits = [], [], [None] * RANKS
 
-    def watched(self, op):
-        prog = sound(self, op)
+    def watched(self, name, n, dtype_str, op, root, extra=None):
+        prog = sound_program(self, name, n, dtype_str, op, root, extra)
 
         def call(*xs):
-            seen.append(xs)
+            launches.append((name, extra, xs))
             return prog(*xs)
         return call
-    monkeypatch.setattr(DeviceFoldChannel, "_fold_prog", watched)
+
+    def no_fold_launch(self, op):
+        raise AssertionError("a per-chip fold launch in the fused arm")
+
+    def kept(self, shards, n):
+        globals_.append(list(shards))
+        return sound_global(self, shards, n)
+    monkeypatch.setattr(DeviceFoldChannel, "_program", watched)
+    monkeypatch.setattr(DeviceFoldChannel, "_fold_prog", no_fold_launch)
+    monkeypatch.setattr(DeviceFoldChannel, "_global", kept)
 
     def deposit(comm, x):
         deposits[comm.rank] = _on_own_chip(comm, x)
         return deposits[comm.rank]
     data, got, _, _ = _run(case, seed=41, deposit=deposit)
-    assert len(seen) == CHIPS and all(len(xs) == K for xs in seen)
-    for j, xs in enumerate(seen):
-        for i, x in enumerate(xs):
-            assert x is deposits[j * K + i], (j, i)
+    (name, extra, operands), = launches     # one launch a call
+    assert (name, extra, len(operands)) == (CASES[case][0], K, K)
+    assert len(globals_) == K
+    for i, (shards, operand) in enumerate(zip(globals_, operands)):
+        assert operand.shape == (CHIPS * N,)
+        lying = sorted(operand.addressable_shards,
+                       key=lambda s: s.index[0].start)
+        for j in range(CHIPS):
+            assert shards[j] is deposits[j * K + i], (j, i)
+            assert lying[j].data.unsafe_buffer_pointer() == \
+                deposits[j * K + i].unsafe_buffer_pointer(), (j, i)
     for r, want in enumerate(_want(case, data)):
         if want is not None:
             assert np.count_nonzero(got[r] != want) == 0, (case, r)
+
+
+def _leader_once(name, op, xs, unfused=False):
+    """One leader call of a fold channel bound by hand over
+    ``jax.devices()[:CHIPS]`` on the deposits ``xs`` (no rank threads:
+    the leader's work alone); ``unfused`` folds every chip by its own
+    launch, as the tree did until ISSUE 44. Returns every rank's result
+    read back and what ``dev_fold_fused`` rose by."""
+    from mvapich2_tpu.coll.device import DeviceFoldChannel, _Rendezvous
+    mesh = make_mesh((CHIPS,), ("x",), jax.devices()[:CHIPS])
+    ch = DeviceFoldChannel(mesh, "x", _Rendezvous(RANKS), 0, RANKS)
+    if unfused:
+        hands_over = ch._fold_chip
+
+        def a_launch_a_chip(j, n, dtype, op):
+            c = hands_over(j, n, dtype, op)
+            return ch._fold_prog(op)(*c) if isinstance(c, tuple) else c
+        ch._fold_chip = a_launch_a_chip
+    ch.rv.slots[:] = [jax.device_put(x, ch.devices[r])
+                      for r, x in enumerate(xs)]
+    before = mpit.pvar("dev_fold_fused").read()
+    out = ch._leader(name, op, 0)
+    for r, o in enumerate(out):
+        assert o.devices() == {ch.devices[r]}, r
+    return ([np.asarray(o) for o in out],
+            mpit.pvar("dev_fold_fused").read() - before)
+
+
+@pytest.mark.parametrize("n", [N, 1000], ids=["whole_rows", "ragged"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("op", ["sum", "max", "min", "prod"])
+def test_fused_arm_is_bit_equal_to_a_launch_a_chip(op, dtype, n):
+    """Same fold body on the same operands in the same order, then the
+    same ring on its result: the one mesh program and the five launches
+    give the same bits on every rank, for the four ops, two types, whole
+    128-lane rows and a ragged length (stacked and padded inside the
+    trace); float32 sums are also numpy's."""
+    import ml_dtypes
+    rng = np.random.default_rng([44, n])
+    lim = 2 if op == "prod" else 1 << 20
+    xs = [rng.integers(-lim, lim, size=n, endpoint=True).astype(np.float32)
+          .astype(ml_dtypes.bfloat16 if dtype == "bfloat16" else np.float32)
+          for _ in range(RANKS)]
+    fused, rose = _leader_once("allreduce", op, xs)
+    apart, rose_apart = _leader_once("allreduce", op, xs, unfused=True)
+    assert (rose, rose_apart) == (1, 0)
+    for r in range(RANKS):
+        assert fused[r].dtype == apart[r].dtype == xs[0].dtype
+        assert fused[r].shape == apart[r].shape == (n,)
+        assert fused[r].tobytes() == apart[r].tobytes(), (op, dtype, n, r)
+    if dtype == "float32":
+        red = {"sum": np.sum, "max": np.max, "min": np.min,
+               "prod": np.prod}[op]
+        assert np.array_equal(fused[0], red(np.stack(xs), axis=0))
+
+
+@pytest.mark.parametrize("n", [N, 1000], ids=["whole_rows", "ragged"])
+@pytest.mark.parametrize("op", ["sum", "max"])
+def test_fused_reduce_scatter_block_is_bit_equal_to_a_launch_a_chip(op, n):
+    """reduce_scatter_block through the same two arms: fold, then the
+    ring's fold rounds, then one slice a rank, the same bits."""
+    xs = _inputs(2**31 + 44, n)
+    fused, rose = _leader_once("reduce_scatter_block", op, xs)
+    apart, rose_apart = _leader_once("reduce_scatter_block", op, xs,
+                                     unfused=True)
+    assert (rose, rose_apart) == (1, 0)
+    want = getattr(np, op)(np.stack(xs), axis=0)
+    c = n // RANKS
+    for r in range(RANKS):
+        assert fused[r].tobytes() == apart[r].tobytes(), (op, n, r)
+        assert np.array_equal(fused[r], want[r * c:(r + 1) * c]), (op, n, r)
 
 
 @pytest.mark.parametrize("n", [N, 1000])
@@ -243,8 +337,8 @@ def test_operand_form_is_bit_equal_to_the_stacked_form(op, n):
 
 def test_a_ragged_length_still_goes_in_as_it_lies():
     """``n % 128 != 0``: the deposits are still the program's operands
-    (no eager stack); the program pads them itself and the result agrees
-    with ``numpy``."""
+    (no eager stack, and the one fused program); the program pads them
+    itself and the result agrees with ``numpy``."""
     before = _reads()
     data, got, homes, _ = _run("allreduce_sum", seed=9, n=1000)
     rose = {n: v - before[n] for n, v in _reads().items()}
@@ -252,38 +346,74 @@ def test_a_ragged_length_still_goes_in_as_it_lies():
     for r in range(RANKS):
         assert np.array_equal(got[r], want), r
         assert homes[r][0] == homes[r][1]
-    assert (rose["dev_fold_stacked"], rose["dev_fold_operands"]) == (0, 1)
+    assert (rose["dev_fold_stacked"], rose["dev_fold_operands"],
+            rose["dev_fold_fused"]) == (0, 1, 1)
 
 
-@pytest.mark.parametrize("case", ["allreduce_sum", "allreduce_max"])
-def test_a_deposit_on_another_chips_device_is_staged(case):
-    """Rank 3 hands over an array committed to chip 2's device: chip 1
-    stages its two deposits (one planar copy), the other three take
-    theirs as they lie, the call does not count as operands, and the
+@pytest.mark.parametrize("where", ["another_chip", "host"])
+@pytest.mark.parametrize("case", ["allreduce_sum", "allreduce_max",
+                                  "reduce_scatter_block"])
+def test_one_deposit_off_its_chip_sends_the_call_down_the_unfused_arm(
+        case, where):
+    """Rank 3 hands over an array committed to chip 2's device, or a
+    host buffer: chip 1 stages its two deposits (one planar copy), the
+    other three hand theirs over as they lie and are folded by a launch
+    each, the call counts neither as operands nor as fused, and the
     result agrees with ``numpy`` on every rank's own chip."""
     def deposit(comm, x):
         ch = comm.device_channel
-        if comm.rank == 3:
-            return jax.device_put(x, ch._mesh_devices[2])
-        return _on_own_chip(comm, x)
+        if comm.rank != 3:
+            return _on_own_chip(comm, x)
+        if where == "host":
+            return _on_host(comm, x)
+        return jax.device_put(x, ch._mesh_devices[2])
     before = _reads()
     data, got, homes, _ = _run(case, seed=11, deposit=deposit)
     rose = {n: v - before[n] for n, v in _reads().items()}
     for r, want in enumerate(_want(case, data)):
         assert np.count_nonzero(got[r] != want) == 0, (case, r)
-        assert homes[r][0] == homes[r][1], (case, r)
-    assert (rose["dev_fold_stacked"], rose["dev_fold_operands"]) == (1, 0)
+        if homes[r] is not None:        # a host caller gets a host array
+            assert homes[r][0] == homes[r][1], (case, r)
+    assert (rose["dev_fold_stacked"], rose["dev_fold_operands"],
+            rose["dev_fold_fused"]) == (1, 0, 0)
+
+
+def test_a_chip_answering_with_one_array_reaches_the_ring_as_it_is(
+        monkeypatch):
+    """``_fold_chip`` says what a chip contributes to level 2: an answer
+    that is one array (here chip 1's first deposit alone, as
+    ``chipbench/tests/test_rehearsal_fold.py`` breaks the path) goes to
+    the unfused mesh program as it is, the other chips' deposits are
+    folded by a launch each, and rank 3 is in no sum."""
+    from mvapich2_tpu.coll.device import DeviceFoldChannel
+    sound = DeviceFoldChannel._fold_chip
+
+    def short(self, j, n, dtype, op):
+        if j != 1:
+            return sound(self, j, n, dtype, op)
+        return self.rv.slots[j * self.k]
+    monkeypatch.setattr(DeviceFoldChannel, "_fold_chip", short)
+    before = _reads()
+    data, got, _, _ = _run("allreduce_sum", seed=13)
+    rose = {n: v - before[n] for n, v in _reads().items()}
+    want = np.sum(np.stack(data[:3] + data[4:]), axis=0)
+    for r in range(RANKS):
+        assert np.array_equal(got[r], want), r
+    assert rose["dev_fold_fused"] == 0
 
 
 def _device_lane(comm):
     return [e for e in comm.u.engine.tracer.events if e[1] == "device"]
 
 
-@pytest.mark.parametrize("case", ["allreduce_sum", "allgather", "bcast"])
+@pytest.mark.parametrize("case", ["allreduce_sum", "reduce_scatter_block",
+                                  "allgather", "bcast"])
 def test_chip_fold_span_lies_in_the_leaders_stage(traced, case):
     """``dev_chip_fold``: a B/E pair of the device lane on rank 0 only,
     inside ``dev_stage``, ``seq`` and ``coll`` on both, the E adding
-    ``k``, ``chips`` and ``stacked``; the second call says ``planned``."""
+    ``k``, ``chips``, ``stacked`` and ``fused`` (the reduce family on
+    deposits that lie: level 1 went into the mesh program); the second
+    call says ``planned``."""
     name, _op, stacked = CASES[case]
     _, _, _, lanes = _run(case, seed=7, calls=2, after=_device_lane)
     for rank, lane in enumerate(lanes):
@@ -300,7 +430,8 @@ def test_chip_fold_span_lies_in_the_leaders_stage(traced, case):
             assert (a["seq"], a["coll"]) == (i // 2 + 1, name)
             extra = {k: v for k, v in a.items() if k not in ("seq", "coll")}
             assert extra == ({} if ph == "B" else
-                             {"k": K, "chips": CHIPS, "stacked": stacked})
+                             {"k": K, "chips": CHIPS, "stacked": stacked,
+                              "fused": case in FOLDED})
         # nested: stage B, fold B, fold E, stage E, in that order
         order = [(n, ph) for _t, _l, n, ph, _a in lane
                  if n in ("dev_stage", "dev_chip_fold")]
