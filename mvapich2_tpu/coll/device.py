@@ -17,6 +17,16 @@ output shard. This is exactly how a single-controller JAX job drives a TPU
 pod slice; on a multi-controller (multi-host) job the same ops run under
 ``jax.distributed`` with each host contributing its local shards.
 
+One leader serves the three bindings (``DeviceCollChannel._leader``:
+stage, look the program up and call it, hand out); the 1:1 mesh channel,
+the one-device slot channel and the leaders-per-chip fold channel differ
+in two hooks, ``_stage`` (the program's key and operands out of what was
+deposited) and ``_hand_out`` (every rank's result out of the output).
+Which kernel a program holds is not decided here: the lowering asks the
+kernel modules' one tier rule (``ops/pallas_ici.planned_tier``, with its
+amendments for the op, the collective and the mesh inside it), and
+``_decide_tier`` asks the same rule for what the call counts.
+
 The rendezvous requires all bound ranks to share one process (rank threads
 — the virtual-pod harness, ``mpirun --vpod``) or one jax.distributed
 runtime; process-mode ranks without either keep the host path (the install
@@ -201,13 +211,16 @@ class _ImportedProgram:
 
 # -- phase spans inside dev_<coll> (device lane) --------------------------
 # One blocking device collective is one ``dev_<coll>`` B/E span per rank
-# (``_run``); inside it the rendezvous and the leader open these, all
-# with the collective's ``seq`` and ``coll`` in their args so a reader
-# can join rank 0's leader phases with the other ranks' waits:
+# (``_run``); inside it the rendezvous (``_execute``) and the one leader
+# (``DeviceCollChannel._leader``, with the channel's ``_stage`` and
+# ``_hand_out`` hooks) open these, all with the collective's ``seq`` and
+# ``coll`` in their args so a reader can join rank 0's leader phases
+# with the other ranks' waits:
 #
 #   dev_arrive       every rank: slot deposit -> counted in at the gate;
 #                    on rank 0 also its wait for the last rank
-#   dev_stage        rank 0: assembling the program's input
+#   dev_stage        rank 0: ``_stage``, the program's key and operands
+#                    out of what was deposited
 #   dev_chip_fold    rank 0, fold channel, inside dev_stage: level 1
 #                    as far as the host does it: the look at every
 #                    chip's deposits and, where one does not lie flat
@@ -217,10 +230,13 @@ class _ImportedProgram:
 #                    0 where the deposits were the fold's operands) and
 #                    ``fused`` (the fold ran inside the mesh program:
 #                    nothing was launched in this span)
-#   dev_dispatch     rank 0: program-cache lookup + enqueue; its E says
-#                    ``built`` when the call made or loaded the program
-#   dev_device_wait  rank 0, slot channel: the leader's block_until_ready
-#   dev_collect      rank 0: one result per rank out of the output; its
+#   dev_dispatch     rank 0: program-cache lookup + enqueue, in
+#                    ``_leader``'s own frame; its E says ``built`` when
+#                    the call made or loaded the program
+#   dev_device_wait  rank 0, slot channel, in its ``_hand_out``: the
+#                    leader's block_until_ready
+#   dev_collect      rank 0, in ``_hand_out``: one result per rank out of
+#                    the output; its
 #                    E says ``parts``, the arrays cut out of it by eager
 #                    device ops: 0 where the ranks share one array or get
 #                    the program's own outputs (the mesh and slot
@@ -304,6 +320,20 @@ class _CallPlan:
         self.fallback = None    # the XLA lowering's (reason, nbytes)
         self.bumps = ()         # (pvar, by how much), once per call
         self.wire = None        # (instant, wire bytes per rank per call)
+
+
+# The collectives whose streaming kernel says what it puts on the wire:
+# collective -> (the instant's name, which is also the stem of its
+# ``_bytes`` pvar; the function of the kernel module that said the tier
+# reckoning the bytes a rank sends in one call from ``(n, dtype, p)``).
+# ``_decide_tier`` holds the conditions, once.
+_WIRE = {"alltoall": ("dev_a2a_wire", "alltoall_wire_bytes"),
+         "allgather": ("dev_ag_wire", "all_gather_wire_bytes"),
+         "reduce_scatter_block": ("dev_rs_wire",
+                                  "reduce_scatter_wire_bytes")}
+# The collectives whose result, every rank's deposit, is what must fit
+# the engine: the tier rule is asked with the result's bytes.
+_KEYED_ON_RESULT = ("allgather",)
 
 
 class _Gate:
@@ -786,23 +816,44 @@ class DeviceCollChannel:
         return res
 
     def _leader(self, name: str, op: str, root: int) -> List:
-        """Leader compute: the deposited flat arrays are the shards of
-        the mesh-sharded global array, the jitted shard_map program runs
-        on it, every rank gets its own device's shard of the output."""
-        rv = self.rv
-        if name == "alltoallv":
-            return self._leader_v()
-        n, dtype = self._slot_extent(rv.slots[0])
+        """Leader compute, the one of every channel: ``_stage`` makes
+        the program's key (``_program``'s arguments) and operands out of
+        what was deposited, the program is looked up and called, and
+        ``_hand_out`` gives every rank its result out of the output. The
+        channels differ in the two hooks alone."""
         with self._phase("dev_stage"):
-            global_arr = self._global(self._shards(rv.slots), n)
-        # spelled out in each leader, not a helper: a frame more under
-        # the program's first call moved its lowering from 20 s to 44 s
-        # on the chip's host (PERF.md, PR 26)
+            key, operands = self._stage(name, op, root)
+        # spelled out here, in the leader's own frame, and the hooks
+        # return before it: a frame more under the program's first call
+        # moved its lowering from 20 s to 44 s on the chip's host
+        # (PERF.md, PR 26)
         with self._phase("dev_dispatch") as ph:
             had = len(self._programs)
-            out = self._program(name, n, str(dtype), op, root)(global_arr)
+            out = self._program(*key)(*operands)
             if ph is not None:      # this call made or loaded the program
                 ph.args["built"] = len(self._programs) > had
+        return self._hand_out(name, out)
+
+    def _stage(self, name: str, op: str, root: int) -> Tuple[tuple, tuple]:
+        """The deposited flat arrays are the shards of the one
+        mesh-sharded global array the jitted shard_map program runs on.
+        alltoallv: the static counts matrix is assembled from every
+        rank's deposited scounts row and the packed payloads are padded
+        to the mesh-wide length; the matrix is part of the program's
+        (and the executable cache's) key."""
+        slots = self.rv.slots
+        n, dtype = self._slot_extent(slots[0])
+        if name != "alltoallv":
+            return ((name, n, str(dtype), op, root),
+                    (self._global(self._shards(slots), n),))
+        from ..ops.pallas_alltoall import packed_displs
+        counts = tuple(tuple(s.scounts) for s in slots)
+        _, _, in_len, _ = packed_displs(counts)
+        return (("alltoallv", in_len, str(dtype), "none", 0, counts),
+                (self._global(self._shards(slots, in_len), in_len),))
+
+    def _hand_out(self, name: str, out) -> List:
+        """Every rank gets its own device's shard of the output."""
         return self._per_rank(out)
 
     def _global(self, shards: List, n: int):
@@ -835,7 +886,7 @@ class DeviceCollChannel:
         for r, dep in enumerate(slots):
             s = dep = dep.data if isinstance(dep, _VDeposit) else dep
             short = max(0, pad_to - int(s.size))
-            if is_device_array(s) and s.devices() == {self.devices[r]}:
+            if _lies_on(s, self.devices[r]):
                 if short:
                     import jax.numpy as jnp
                     s = jnp.pad(s, (0, short))
@@ -849,27 +900,6 @@ class DeviceCollChannel:
         if lay:
             mpit.pvar("dev_mesh_operands").inc()
         return shards
-
-    def _leader_v(self) -> List:
-        """Leader compute for alltoallv: assemble the static counts
-        matrix from every rank's deposited scounts row, stage the padded
-        packed payloads, run the counts-keyed program (the matrix is
-        part of the program/executable cache key)."""
-        from ..ops.pallas_alltoall import packed_displs
-        rv = self.rv
-        with self._phase("dev_stage"):
-            counts = tuple(tuple(s.scounts) for s in rv.slots)
-            _, _, in_len, _ = packed_displs(counts)
-            _, dtype = self._slot_extent(rv.slots[0])
-            global_arr = self._global(self._shards(rv.slots, in_len),
-                                      in_len)
-        with self._phase("dev_dispatch") as ph:
-            had = len(self._programs)
-            out = self._program("alltoallv", in_len, str(dtype), "none", 0,
-                                counts)(global_arr)
-            if ph is not None:
-                ph.args["built"] = len(self._programs) > had
-        return self._per_rank(out)
 
     # -- per-call tier accounting (the observable-fallback contract) -----
     def _note_tier(self, comm, name: str, local, op: Optional[str]) -> str:
@@ -912,57 +942,46 @@ class DeviceCollChannel:
 
     def _decide_tier(self, plan: _CallPlan, name: str, local,
                      op: Optional[str]) -> None:
-        """The one place that says which tier a device collective takes
-        and what the call therefore counts: ``plan.tier``, the pvars to
-        bump (``bumps``), the XLA lowering's ``fallback`` instant, the
-        kernel's ``wire`` instant. Reads the call's extent, the
-        cvars and the loaded profile, nothing else."""
+        """What a device collective counts for the tier it takes:
+        ``plan.tier``, the pvars to bump (``bumps``), the XLA lowering's
+        ``fallback`` instant, the kernel's ``wire`` instant. The tier is
+        the kernel modules' rule's (``pallas_ici.planned_tier``,
+        ``pallas_alltoall.planned_a2a_tier``), asked as the program's
+        lowering asks it: of the shard the mesh program sees (the rank's
+        deposit, or on the fold channel the chip's fold of them). Reads
+        the call's extent, the cvars and the loaded profile, nothing
+        else."""
         if self.mesh is None:
             plan.tier = "slot"  # single-device slot channel: no ICI tiers
             return
-        from ..ops import pallas_ici
         n, dtype = self._slot_extent(local)
-        nbytes = n * dtype.itemsize * (self.size if name == "allgather"
-                                       else 1)
+        nbytes = n * dtype.itemsize * (self.size
+                                       if name in _KEYED_ON_RESULT else 1)
         p = self._mesh_extent()
         if name in ("alltoall", "alltoallv"):
-            from ..ops import pallas_alltoall
-            tier, reason = pallas_alltoall.planned_a2a_tier(
-                max(1, nbytes), dtype)
-        elif name in ("allreduce", "reduce", "allgather"):
-            tier, reason = pallas_ici.planned_tier(name, nbytes, dtype, op,
-                                                   num_devices=p)
-        elif name == "reduce_scatter_block" and not self.multi_axis:
-            # the 1-D program is ici_reduce_scatter on a shard of this
-            # extent (the rank's deposit, or on the fold channel the
-            # chip's fold of them), and asks this same rule
-            tier, reason = pallas_ici.planned_rs_tier(nbytes, dtype, op)
+            from ..ops import pallas_alltoall as kernels
+            tier, reason = kernels.planned_a2a_tier(max(1, nbytes), dtype)
         else:
-            plan.tier = "xla"   # ops without a ring-kernel lowering
-            return
+            from ..ops import pallas_ici as kernels
+            tier, reason = kernels.planned_tier(
+                name, nbytes, dtype, op, num_devices=p,
+                multi_axis=self.multi_axis)
+        plan.tier = tier
         if reason is not None:
-            plan.tier, plan.fallback = "xla", (reason, int(nbytes))
+            plan.fallback = (reason, int(nbytes))
             plan.bumps = ((mpit.pvar(f"dev_coll_fallback_{reason}"), 1),)
             return
-        plan.tier = tier
+        if tier == "xla":       # a lowering with no ring kernel to count
+            return
         bumps = [(mpit.pvar(f"dev_coll_tier_{tier}"), 1)]
-        if name == "alltoall" and not self.multi_axis:
-            plan.wire = ("dev_a2a_wire", pallas_alltoall.alltoall_wire_bytes(
-                n, dtype, self.size))
-        elif (name == "allgather" and tier == "hbm"
-                and not self.multi_axis and p == self.size):
-            # the ring all-gather's wire: on the 1:1 binding, where the
-            # rank's shard is the kernel's operand
-            plan.wire = ("dev_ag_wire",
-                         pallas_ici.all_gather_wire_bytes(n, dtype, p))
-        elif (name == "reduce_scatter_block" and tier == "hbm"
+        # a kernel's wire is reckoned where the rank's deposit is the
+        # kernel's operand: the streaming tier of a 1-D mesh on the 1:1
+        # binding
+        if (name in _WIRE and tier == "hbm" and not self.multi_axis
                 and p == self.size):
-            # the ring reduce-scatter's wire, on the 1:1 binding (as
-            # the all-gather's)
-            plan.wire = ("dev_rs_wire",
-                         pallas_ici.reduce_scatter_wire_bytes(n, dtype, p))
-        if plan.wire is not None:
-            bumps.append((mpit.pvar(plan.wire[0] + "_bytes"), plan.wire[1]))
+            instant, reckon = _WIRE[name]
+            plan.wire = (instant, getattr(kernels, reckon)(n, dtype, p))
+            bumps.append((mpit.pvar(instant + "_bytes"), plan.wire[1]))
         if tier == "quant":
             # the measurable half of the quant claim: bytes kept off
             # the ICI wire by this call, per rank
@@ -988,8 +1007,7 @@ class DeviceCollChannel:
         profiler's host plane as a TraceAnnotation of the same name, so
         an MV2T_JAX_PROFILE trace shows it beside the device's ops."""
         global _profiler
-        tier = self._note_tier(comm, name, local,
-                               op if name != "bcast" else None)
+        tier = self._note_tier(comm, name, local, op)
         for lv in self._level_pvars:    # the hierarchy levels it rides
             lv.inc()
         self._seq += 1
@@ -1436,12 +1454,35 @@ class DeviceCollChannel:
             return False
 
 
-def _slot_kernel_op(op: str) -> bool:
-    """The fused Pallas slot kernel (ops/pallas_hbm) carries the sum;
-    other ops take the XLA reduction over the same slot array. A kernel
-    that fails to compile or run fails the collective — there is no
-    second lowering to hide behind."""
-    return op == "sum"
+def _slot_fold(xs, op: str):
+    """The slot reduction, ``xs -> [n]``, traced where it is called: in
+    the slot channel's programs, alone in the fold channel's
+    ``_fold_prog`` and in front of the ring in its fused mesh program.
+    ``xs`` is the deposited flat ``(n,)`` arrays of the ranks sharing a
+    device, or one staged planar ``(R, n)`` array; the body reads which
+    from its operands. The fused Pallas slot kernel (ops/pallas_hbm)
+    carries the sum: over flat operands of whole 128-lane rows it reads
+    each buffer where it lies; the planar operand, or a ragged length
+    stacked inside the trace, it takes as one slot array. Another op is
+    the XLA reduction over that slot array. A kernel that fails to
+    compile or run fails the collective — there is no second lowering
+    to hide behind."""
+    import jax.numpy as jnp
+
+    from ..ops import pallas_hbm as ph
+    if op == "sum" and xs[0].ndim == 1 and xs[0].shape[0] % 128 == 0:
+        return ph.hbm_slot_allreduce_operands(xs)
+    slots = xs[0] if xs[0].ndim == 2 else jnp.stack(xs)
+    if op == "sum":
+        return ph.hbm_slot_allreduce(slots)
+    return {"max": jnp.max, "min": jnp.min, "prod": jnp.prod}[op](
+        slots, axis=0)
+
+
+def _lies_on(s, dev) -> bool:
+    """A device array committed to exactly ``dev``: a deposit a program
+    on that device takes as its operand where it lies."""
+    return is_device_array(s) and s.devices() == {dev}
 
 
 class HBMSlotChannel(DeviceCollChannel):
@@ -1504,31 +1545,16 @@ class HBMSlotChannel(DeviceCollChannel):
         aliased, the callers keep their send buffers."""
         import jax
         import jax.numpy as jnp
-
-        from ..ops import pallas_hbm as ph
         R = self.size
-        red = {"sum": jnp.sum, "max": jnp.max, "min": jnp.min,
-               "prod": jnp.prod}[op or "sum"]
-
-        def slots(xs):      # the (R, n) slot array: the one staged
-            # operand, or the R deposited ones stacked inside the trace
-            return xs[0] if xs[0].ndim == 2 else jnp.stack(xs)
-
-        def reduced(xs):                    # -> [n]
-            if not _slot_kernel_op(op):
-                return red(slots(xs), axis=0)
-            if xs[0].ndim == 1 and n % 128 == 0:
-                return ph.hbm_slot_allreduce_operands(xs)
-            return ph.hbm_slot_allreduce(slots(xs))
 
         if name in ("allreduce", "reduce"):
             def f(*xs):                     # -> [n]
-                return reduced(xs)
+                return _slot_fold(xs, op)
         elif name == "reduce_scatter_block":
             c = n // R
 
             def f(*xs):                     # -> R x [c], rank r's block
-                y = reduced(xs)             # cut inside the program
+                y = _slot_fold(xs, op)      # cut inside the program
                 return tuple(y[r * c:(r + 1) * c] for r in range(R))
         elif name == "bcast":
             def f(x):                       # the root slot [n]
@@ -1553,36 +1579,30 @@ class HBMSlotChannel(DeviceCollChannel):
             raise KeyError(name)
         return jax.jit(f)
 
-    def _leader(self, name: str, op: str, root: int) -> List:
-        """Leader compute: hand the program what was deposited, share
-        its one result or hand out its per-rank outputs. Device arrays
-        on the slot device are the program's operands as they lie
-        (counted: dev_slot_operands); anything else is stacked on the
-        host and staged once."""
-        import jax
-
+    def _stage(self, name: str, op: str, root: int) -> Tuple[tuple, tuple]:
+        """The program is handed what was deposited. Device arrays on
+        the slot device are its operands as they lie (counted:
+        dev_slot_operands); anything else is stacked on the host and
+        staged once."""
         rv = self.rv
-        R = self.size
         n, dtype = self._slot_extent(rv.slots[root])
-        with self._phase("dev_stage"):
-            xs = (rv.slots[root],) if name == "bcast" else tuple(rv.slots)
-            if all(is_device_array(s) and s.devices() == {self.device}
-                   for s in xs):
-                mpit.pvar("dev_slot_operands").inc()
-            else:
-                # host slots, or device arrays committed elsewhere on a
-                # multi-device host: stage everything onto the slot
-                # device
-                host = [np.asarray(s).reshape(n) for s in xs]
-                xs = (jax.device_put(
-                    host[0] if name == "bcast" else np.stack(host),
-                    self.device),)
-        with self._phase("dev_dispatch") as ph:
-            had = len(self._programs)
-            out = self._program(name, n, str(dtype), op, root,
-                                len(xs))(*xs)
-            if ph is not None:
-                ph.args["built"] = len(self._programs) > had
+        xs = (rv.slots[root],) if name == "bcast" else tuple(rv.slots)
+        if all(_lies_on(s, self.device) for s in xs):
+            mpit.pvar("dev_slot_operands").inc()
+        else:
+            # host slots, or device arrays committed elsewhere on a
+            # multi-device host: stage everything onto the slot device
+            import jax
+            host = [np.asarray(s).reshape(n) for s in xs]
+            xs = (jax.device_put(
+                host[0] if name == "bcast" else np.stack(host),
+                self.device),)
+        return (name, n, str(dtype), op, root, len(xs)), xs
+
+    def _hand_out(self, name: str, out) -> List:
+        """The leader waits for the device, then shares the program's
+        one result or hands out its per-rank outputs."""
+        import jax
         with self._phase("dev_device_wait"):
             out = jax.block_until_ready(out)
         with self._phase("dev_collect") as ph:
@@ -1590,7 +1610,8 @@ class HBMSlotChannel(DeviceCollChannel):
                 ph.args["parts"] = 0    # no eager op cuts anything out
             # a program that returned one output per rank hands them
             # out; one array is the zero-copy share, every rank gets it
-            return list(out) if isinstance(out, tuple) else [out] * R
+            return (list(out) if isinstance(out, tuple)
+                    else [out] * self.size)
 
 
 class DeviceFoldChannel(DeviceCollChannel):
@@ -1668,44 +1689,20 @@ class DeviceFoldChannel(DeviceCollChannel):
     def nonblocking(self, comm, name: str, *a, plan: bool = False):
         return None     # host NBC schedule (fold has no DAG segments yet)
 
-    @staticmethod
-    def _fold_body(op: str):
-        """Level 1's body, ``f(*xs) -> [n]``, traced where it is called:
-        alone in ``_fold_prog``, in front of the ring in the fused mesh
-        program (``_build``). ``xs`` is one chip's ``k`` deposited
-        ``(n,)`` arrays, or one staged planar ``(k, n)`` array; the body
-        reads which from its operands, as the slot channel's ``reduced``
-        does: the HBM fused slot-reduce for sum, on ``k`` operands of
-        whole 128-lane rows where they lie (a ragged length is stacked
-        and padded inside the trace); the XLA reduction for the other
-        ops."""
-        import jax.numpy as jnp
-
-        from ..ops import pallas_hbm as ph
-        red = {"sum": jnp.sum, "max": jnp.max, "min": jnp.min,
-               "prod": jnp.prod}[op or "sum"]
-
-        def slots(xs):      # the (k, n) slot array: the one staged
-            # operand, or the k deposited ones stacked inside the trace
-            return xs[0] if xs[0].ndim == 2 else jnp.stack(xs)
-
-        def f(*xs):                         # -> [n]
-            if not _slot_kernel_op(op):
-                return red(slots(xs), axis=0)
-            if xs[0].ndim == 1 and xs[0].shape[0] % 128 == 0:
-                return ph.hbm_slot_allreduce_operands(xs)
-            return ph.hbm_slot_allreduce(slots(xs))
-        return f
-
     def _fold_prog(self, op: str):
-        """Per-chip fold program, ``_fold_body`` jitted alone (cached
-        like any program): the arm of a call some deposit of which does
-        not lie flat on its chip. No operand is donated or aliased."""
+        """Per-chip fold program, ``_slot_fold`` jitted alone as
+        ``f(*xs)`` on one chip's ``k`` deposited ``(n,)`` arrays or one
+        staged planar ``(k, n)`` array (cached like any program): the
+        arm of a call some deposit of which does not lie flat on its
+        chip. No operand is donated or aliased."""
         key = ("chipfold", 0, "", op, 0, None)
         got = self._programs.get(key)
         if got is None:
             import jax
-            got = self._programs[key] = jax.jit(self._fold_body(op))
+
+            def f(*xs):
+                return _slot_fold(xs, op)
+            got = self._programs[key] = jax.jit(f)
         return got
 
     def _build(self, name: str, n: int, op: str, root: int, extra=None):
@@ -1713,10 +1710,10 @@ class DeviceFoldChannel(DeviceCollChannel):
         program: None for the mesh program over one shard a chip (the
         1:1 channel's, unchanged), ``k`` for the fused one: ``k``
         mesh-sharded flat operands, shard ``j`` of operand ``i`` rank
-        ``j * k + i``'s deposit as it lies, and per chip ``_fold_body``,
+        ``j * k + i``'s deposit as it lies, and per chip ``_slot_fold``,
         then the collective's ring on its result. One launch where the
         unfused arm makes one a chip and the ring's. 1-D meshes only
-        (``_leader`` asks); no operand is donated or aliased."""
+        (``_stage`` asks); no operand is donated or aliased."""
         if extra is None:
             return super()._build(name, n, op, root)
         import jax
@@ -1725,7 +1722,6 @@ class DeviceFoldChannel(DeviceCollChannel):
         from ..ops import pallas_ici
         from ..parallel.mesh import shard_map
         axis, p = self.axis, self._mesh_extent()
-        fold = self._fold_body(op)
         # the collective's mesh body, as ``DeviceCollChannel._build``
         # has it: k x [p*c] -> [c], or k x [n] -> replicated [n]
         ring, out_specs = (
@@ -1734,7 +1730,7 @@ class DeviceFoldChannel(DeviceCollChannel):
             else (pallas_ici.ici_all_reduce, P(None)))     # and reduce
 
         def f(*xs):
-            return ring(fold(*xs), axis, p, op=op)
+            return ring(_slot_fold(xs, op), axis, p, op=op)
         sm = shard_map(f, mesh=self.mesh, in_specs=(P(axis),) * extra,
                        out_specs=out_specs, check_vma=False)
         return jax.jit(sm)
@@ -1742,13 +1738,13 @@ class DeviceFoldChannel(DeviceCollChannel):
     def _chip_stack(self, j: int, n: int, dtype):
         """Chip ``j``'s k deposited slots as one planar (k, n) array on
         its device (device-resident slots stack in place): a copy of
-        the chip's deposits either way, counted for ``_leader``."""
+        the chip's deposits either way, counted for ``_stage``."""
         import jax
         import jax.numpy as jnp
         self._stacked += 1
         sl = self.rv.slots[j * self.k:(j + 1) * self.k]
         dev = self._mesh_devices[j]
-        if all(is_device_array(s) and s.devices() == {dev} for s in sl):
+        if all(_lies_on(s, dev) for s in sl):
             return jnp.stack([s.reshape(n) for s in sl])
         return jax.device_put(
             np.stack([np.asarray(s).reshape(n) for s in sl]), dev)
@@ -1764,106 +1760,96 @@ class DeviceFoldChannel(DeviceCollChannel):
         dev = self._mesh_devices[j]
         if self.k == 1:
             s = self.rv.slots[j]
-            if is_device_array(s) and s.devices() == {dev}:
+            if _lies_on(s, dev):
                 return s.reshape(n)
             return jax.device_put(np.asarray(s).reshape(n), dev)
         sl = self.rv.slots[j * self.k:(j + 1) * self.k]
-        if all(is_device_array(s) and s.ndim == 1 and s.devices() == {dev}
-               for s in sl):
+        if all(_lies_on(s, dev) and s.ndim == 1 for s in sl):
             return tuple(sl)
         return self._fold_prog(op)(self._chip_stack(j, n, dtype))
 
-    def _leader(self, name: str, op: str, root: int) -> List:
-        """Leader compute: level 1 per chip, the mesh program over the
-        chips, the chip outputs fanned back to their ranks. Where every
-        chip of a reduction handed its ``k`` deposits over as they lie
-        (and the mesh is 1-D) level 1 runs inside the mesh program, one
-        launch a call (counted: dev_fold_fused); otherwise each chip is
-        folded by its own launch and the mesh program takes the folds."""
+    def _stage(self, name: str, op: str, root: int) -> Tuple[tuple, tuple]:
+        """Level 1 per chip, as far as the host has a hand in it, and
+        the mesh program's operands over the chips. Where every chip of
+        a reduction handed its ``k`` deposits over as they lie (and the
+        mesh is 1-D) level 1 runs inside the mesh program, one launch a
+        call (counted: dev_fold_fused); otherwise each chip is folded by
+        its own launch and the mesh program takes the folds."""
         import jax
 
         rv = self.rv
         nd, k = self.ndev, self.k
         n, dtype = self._slot_extent(rv.slots[0])
         shards, prog_root, prog_n, fused = [], 0, n, False
-        with self._phase("dev_stage"):
-            # level 1 as far as the host has a hand in it: the look at
-            # the deposits, and the staging and fold launches of a call
-            # that does not fuse, issued from this one thread; its E
-            # says how many planar copies it made and whether the fold
-            # went into the mesh program
-            with self._phase("dev_chip_fold") as fold:
-                self._stacked = 0
-                if name == "bcast":
-                    # only the root chip's shard matters: stage the root
-                    # rank's payload there, zero-fill the rest (the mesh
-                    # bcast program overwrites them)
-                    prog_root = root // k
-                    for j in range(nd):
-                        if j == prog_root:
-                            s = rv.slots[root]
-                            if not (is_device_array(s) and s.devices()
-                                    == {self._mesh_devices[j]}):
-                                s = jax.device_put(
-                                    np.asarray(s).reshape(-1),
-                                    self._mesh_devices[j])
-                        else:
-                            s = jax.device_put(np.zeros(n, dtype),
-                                               self._mesh_devices[j])
-                        shards.append(s)
-                elif name == "allgather":
-                    # chip fold is CONCATENATION: blocked rank->chip
-                    # mapping makes the stacked chip payload already
-                    # rank-ordered
-                    prog_n = k * n
-                    for j in range(nd):
-                        shards.append(self._chip_stack(j, n, dtype)
-                                      .reshape(prog_n))
-                else:   # allreduce / reduce / reduce_scatter_block
-                    shards = [self._fold_chip(j, n, dtype, op)
-                              for j in range(nd)]
-                    fused = not self.multi_axis and all(
-                        isinstance(c, tuple) and len(c) == k
-                        for c in shards)
-                    if fused:
-                        mpit.pvar("dev_fold_fused").inc()
-                    else:   # a launch for every chip still unfolded
-                        shards = [self._fold_prog(op)(*c)
-                                  if isinstance(c, tuple) else c
-                                  for c in shards]
-                    if not self._stacked:
-                        mpit.pvar("dev_fold_operands").inc()
-                if self._stacked:
-                    mpit.pvar("dev_fold_stacked").inc(self._stacked)
-                if fold is not None:
-                    fold.args.update(k=k, chips=nd, stacked=self._stacked,
-                                     fused=fused)
-            if fused:
-                # operand i: the chips' i-th deposits, shard j rank
-                # j*k + i's array as it lies
-                operands = tuple(self._global([c[i] for c in shards], n)
-                                 for i in range(k))
-            else:
-                operands = (self._global(shards, prog_n),)
-        with self._phase("dev_dispatch") as ph:
-            had = len(self._programs)
-            out = self._program(name, prog_n, str(dtype), op, prog_root,
-                                k if fused else None)(*operands)
+        # the look at the deposits, and the staging and fold launches of
+        # a call that does not fuse, issued from this one thread; the E
+        # says how many planar copies it made and whether the fold went
+        # into the mesh program
+        with self._phase("dev_chip_fold") as fold:
+            self._stacked = 0
+            if name == "bcast":
+                # only the root chip's shard matters: stage the root
+                # rank's payload there, zero-fill the rest (the mesh
+                # bcast program overwrites them)
+                prog_root = root // k
+                for j, dev in enumerate(self._mesh_devices):
+                    if j != prog_root:
+                        s = jax.device_put(np.zeros(n, dtype), dev)
+                    else:
+                        s = rv.slots[root]
+                        if not _lies_on(s, dev):
+                            s = jax.device_put(np.asarray(s).reshape(-1),
+                                               dev)
+                    shards.append(s)
+            elif name == "allgather":
+                # chip fold is CONCATENATION: blocked rank->chip mapping
+                # makes the stacked chip payload already rank-ordered
+                prog_n = k * n
+                for j in range(nd):
+                    shards.append(self._chip_stack(j, n, dtype)
+                                  .reshape(prog_n))
+            else:   # allreduce / reduce / reduce_scatter_block
+                shards = [self._fold_chip(j, n, dtype, op)
+                          for j in range(nd)]
+                fused = not self.multi_axis and all(
+                    isinstance(c, tuple) and len(c) == k for c in shards)
+                if fused:
+                    mpit.pvar("dev_fold_fused").inc()
+                else:   # a launch for every chip still unfolded
+                    shards = [self._fold_prog(op)(*c)
+                              if isinstance(c, tuple) else c
+                              for c in shards]
+                if not self._stacked:
+                    mpit.pvar("dev_fold_operands").inc()
+            if self._stacked:
+                mpit.pvar("dev_fold_stacked").inc(self._stacked)
+            if fold is not None:
+                fold.args.update(k=k, chips=nd, stacked=self._stacked,
+                                 fused=fused)
+        if fused:
+            # operand i: the chips' i-th deposits, shard j rank
+            # j*k + i's array as it lies
+            operands = tuple(self._global([c[i] for c in shards], n)
+                             for i in range(k))
+        else:
+            operands = (self._global(shards, prog_n),)
+        return ((name, prog_n, str(dtype), op, prog_root,
+                 k if fused else None), operands)
+
+    def _hand_out(self, name: str, out) -> List:
+        """The chip outputs fanned back to their ranks: every rank
+        shares its chip's shard, zero-copy, but for
+        reduce_scatter_block, where a chip's shard is its ``k`` ranks'
+        contiguous blocks and each rank gets its slice."""
+        if name != "reduce_scatter_block":
+            return self._per_rank(out)
+        k, c = self.k, out.shape[0] // self.size
+        with self._phase("dev_collect") as ph:
             if ph is not None:
-                ph.args["built"] = len(self._programs) > had
-        if name == "reduce_scatter_block":
-            # chip shard = its k ranks' contiguous blocks: slice per rank
-            c = (n // nd) // k
-            with self._phase("dev_collect") as ph:
-                if ph is not None:
-                    ph.args["parts"] = self.size    # one eager slice each
-                per_dev = {s.device: s.data
-                           for s in out.addressable_shards}
-                return [per_dev[self.devices[r]][(r % k) * c:
-                                                 (r % k + 1) * c]
-                        for r in range(self.size)]
-        # zero-copy share per chip: every rank gets its chip's shard
-        return self._per_rank(out)
+                ph.args["parts"] = self.size    # one eager slice each
+            per_dev = {s.device: s.data for s in out.addressable_shards}
+            return [per_dev[self.devices[r]][(r % k) * c:(r % k + 1) * c]
+                    for r in range(self.size)]
 
 
 def _dense_displs(counts) -> List[int]:

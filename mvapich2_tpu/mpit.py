@@ -441,11 +441,18 @@ pvar("dev_coll_fallback_platform", PVAR_CLASS_COUNTER, "device",
      "kernels cannot run here (no pallas, or off-TPU without "
      "MV2T_ICI_INTERPRET)")
 pvar("dev_coll_tier_vmem", PVAR_CLASS_COUNTER, "device",
-     "device collective calls served by the VMEM-resident flat ring "
-     "tier (ops/pallas_ring)")
+     "device collective calls whose program holds the VMEM-resident "
+     "flat ring (ops/pallas_ring): a sum or a gather on a 1-D mesh at "
+     "or under DEV_TIER_VMEM_MAX. Counted by the one tier rule "
+     "(ops/pallas_ici.planned_tier), which the program's lowering "
+     "asks too: the tier counted is the kernel that runs")
 pvar("dev_coll_tier_hbm", PVAR_CLASS_COUNTER, "device",
-     "device collective calls served by the HBM-streaming chunked ring "
-     "tier (ops/pallas_ici)")
+     "device collective calls whose program holds the HBM-streaming "
+     "chunked engine (ops/pallas_ici, ops/pallas_alltoall): every "
+     "size between the VMEM edge and the XLA crossover and, by the "
+     "same rule (ops/pallas_ici.planned_tier), what the flat ring "
+     "cannot carry under the edge: max / min / prod, a reduce-scatter, "
+     "an alltoall, a ring along one axis of a multi-axis mesh")
 pvar("dev_coll_tier_quant", PVAR_CLASS_COUNTER, "device",
      "device collective calls served by the block-scaled quantized "
      "wire tier (ops/pallas_quant, gated by MV2T_QUANT_COLL)")
@@ -506,7 +513,7 @@ pvar("dev_call_plan_filed", PVAR_CLASS_COUNTER, "device",
 pvar("dev_slot_operands", PVAR_CLASS_COUNTER, "device",
      "slot-channel leader calls that handed the program the deposited "
      "device arrays as they lay — R operands, no stack, no staging "
-     "copy (coll/device.py HBMSlotChannel._leader); host deposits, "
+     "copy (coll/device.py HBMSlotChannel._stage); host deposits, "
      "staged as one stacked array, do not count")
 pvar("dev_mesh_operands", PVAR_CLASS_COUNTER, "device",
      "mesh-channel leader calls (blocking, alltoallv, nonblocking) in "
@@ -539,7 +546,7 @@ pvar("dev_fold_fused", PVAR_CLASS_COUNTER, "device",
      "reduce_scatter_block whose level 1 ran inside the level-2 mesh "
      "program: one launch a call, k mesh-sharded operands, shard j of "
      "operand i rank j*k+i's deposit as it lies (coll/device.py "
-     "DeviceFoldChannel._leader, _build); rises with "
+     "DeviceFoldChannel._stage, _build); rises with "
      "dev_fold_operands on a 1-D mesh at k > 1; a call with a chip "
      "that had to be staged folds every chip by its own launch and "
      "does not count")

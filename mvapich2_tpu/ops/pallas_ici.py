@@ -51,11 +51,19 @@ indices are leading ones. Wrappers pad each ring block to a whole
 number of tiles with the op identity and reshape the HBM operands to
 ``(p, block_rows, 128)`` before the ``pallas_call``.
 
-Tier selection (``planned_tier``) is data driven: coll/tuning.py's
-``device_tier`` maps shard bytes to vmem (pallas_ring) / hbm (here) /
-quant (pallas_quant — the block-scaled quantized wire above the hbm
-tier, gated by the MV2T_QUANT_COLL accuracy budget) / xla, with the
-boundaries re-measurable by ``bin/measure_crossover --device``. Every fallback to the XLA lowering is counted by the
+Tier selection is one rule, ``planned_tier``, asked once by each
+dispatcher here (``ici_all_reduce`` / ``ici_all_gather`` /
+``ici_reduce_scatter``, which switch on its answer and nothing else) and
+by the MPI channel's per-call accounting (coll/device.py
+``_decide_tier``). It is data driven: coll/tuning.py's ``device_tier``
+maps shard bytes to vmem (pallas_ring) / hbm (here) / quant
+(pallas_quant — the block-scaled quantized wire above the hbm tier,
+gated by the MV2T_QUANT_COLL accuracy budget) / xla, with the
+boundaries re-measurable by ``bin/measure_crossover --device``; the
+rule then names the engine that can carry the call (the flat VMEM ring
+takes sums and gathers of a 1-D mesh only; a reduce-scatter, another
+op, a ring along one axis of a multi-axis mesh stream through the
+engine here). Every fallback to the XLA lowering is counted by the
 ``dev_coll_fallback_*`` pvar family — the 4 MiB cliff is no longer
 silent.
 
@@ -810,20 +818,44 @@ def _kernels_runnable(interpret: Optional[bool]) -> bool:
 
 
 def planned_tier(name: str, shard_nbytes: int, dtype, op: Optional[str],
-                 interpret=None,
-                 num_devices: Optional[int] = None
-                 ) -> Tuple[str, Optional[str]]:
-    """(tier, fallback_reason) for one device collective call. tier is
-    'vmem' | 'hbm' | 'quant' | 'xla'; reason is None unless the XLA
-    lowering was taken, in which case it names the dev_coll_fallback_*
-    pvar bucket: size (past the measured XLA crossover), dtype (op/
-    dtype the kernels cannot reduce), shape (degenerate extent),
-    platform (not a TPU and not interpreting). A 'quant'
-    bin the call cannot actually quantize (non-sum op, int dtype,
-    budget below the declared bound for ``num_devices``) degrades to
-    the exact 'hbm' tier — a bit-exact fallback, not an XLA take."""
+                 interpret=None, num_devices: Optional[int] = None,
+                 multi_axis: bool = False) -> Tuple[str, Optional[str]]:
+    """(tier, fallback_reason) for one device collective call: the one
+    rule. ``ici_all_reduce`` / ``ici_all_gather`` / ``ici_reduce_scatter``
+    lower what it says, and the channel's per-call accounting
+    (coll/device.py ``_decide_tier``) counts what it says, so the pvar a
+    call bumps is the kernel its program holds. ``name`` is the MPI
+    collective whose lowering asks ('allreduce', 'reduce', 'allgather',
+    'reduce_scatter_block', 'bcast'; 'alltoall' through
+    pallas_alltoall.planned_a2a_tier); ``shard_nbytes`` what must fit
+    the engine (the gather keys on its OUTPUT bytes); ``multi_axis``
+    says the ring is one axis of a multi-axis mesh.
+
+    tier is 'vmem' | 'hbm' | 'quant' | 'xla'. reason names the
+    dev_coll_fallback_* pvar bucket of an XLA take that is a fallback:
+    platform (not a TPU and not interpreting), dtype (op/dtype the
+    kernels cannot reduce), shape (degenerate extent), size (past the
+    measured XLA crossover). Two XLA takes are no fallback (reason
+    None): a collective with no ring kernel (bcast), and a multi-axis
+    mesh under the interpreter, whose remote-DMA discharge refuses more
+    than one named axis (the decomposition above the phase is the same,
+    which is what the CPU sweep pins).
+
+    Past the size bins (coll/tuning.device_tier) the answer is the
+    engine that can carry the call, each a bit-exact move, never an XLA
+    take: a 'quant' bin the call cannot quantize (non-sum op, int
+    dtype, budget below the declared bound for ``num_devices``) is
+    'hbm'; the flat VMEM ring carries sums and gathers of a 1-D mesh
+    only, so 'vmem' with another op, for a reduce-scatter (no flat
+    kernel, and the chunked engine has no size floor: it pads) or on a
+    multi-axis mesh (the VMEM and quant engines address devices 1-D)
+    is 'hbm' too."""
+    if name == "bcast":
+        return "xla", None
     if not _kernels_runnable(interpret):
         return "xla", "platform"
+    if multi_axis and not on_tpu():
+        return "xla", None
     if op is not None and op not in _SUPPORTED_OPS:
         return "xla", "dtype"
     if dtype_kind(dtype) not in "fiu":
@@ -832,29 +864,17 @@ def planned_tier(name: str, shard_nbytes: int, dtype, op: Optional[str],
         return "xla", "shape"
     from ..coll.tuning import device_tier
     tier = device_tier(name, shard_nbytes)
-    if tier == "quant":
-        from . import pallas_quant
-        if not pallas_quant.quant_eligible(name, dtype, op, num_devices):
-            tier = "hbm"
     if tier == "xla":
         return "xla", "size"
-    return tier, None
-
-
-def planned_rs_tier(shard_nbytes: int, dtype, op: Optional[str],
-                    interpret=None) -> Tuple[str, Optional[str]]:
-    """(tier, fallback_reason) for one device reduce-scatter call — the
-    generic device-tier answer collapsed onto the one engine that has a
-    reduce-scatter entry (the flat VMEM kernel has none and the quant
-    wire has no RS-only form; the chunked HBM engine has no size floor,
-    it pads): 'hbm' or 'xla'. ``ici_reduce_scatter`` and the channel's
-    per-call accounting (coll/device.py ``_decide_tier``) both ask it,
-    so the pvar a call bumps is the tier its program took."""
-    tier, reason = planned_tier("reduce_scatter", shard_nbytes, dtype, op,
-                                interpret)
-    if tier in ("vmem", "quant"):
+    if tier == "quant":
+        from . import pallas_quant
+        if multi_axis or not pallas_quant.quant_eligible(
+                name, dtype, op, num_devices):
+            tier = "hbm"
+    elif tier == "vmem" and (multi_axis or name == "reduce_scatter_block"
+                             or op not in (None, "sum")):
         tier = "hbm"
-    return tier, reason
+    return tier, None
 
 
 def _trace_entry(coll: str, tier: str, nbytes: int, op=None,
@@ -875,63 +895,41 @@ def _trace_entry(coll: str, tier: str, nbytes: int, op=None,
         pass
 
 
-def _mesh_mode(mesh_ctx, interpret) -> str:
-    """How a per-axis ring behaves inside a multi-axis mesh_ctx:
-    '1d' — no surrounding multi-axis mesh, classic dispatch; 'hw' —
-    multi-axis on hardware, clamp to the HBM streamer with mesh-aware
-    device ids (the VMEM/quant engines only know 1-D addressing);
-    'xla' — multi-axis under the interpreter, whose remote-DMA
-    discharge refuses more than one named axis: the stock lowering
-    carries the phase (the decomposition math above it is identical,
-    which is what the CPU sweep pins)."""
-    if not mesh_ctx or len(mesh_ctx) <= 1:
-        return "1d"
-    if on_tpu():
-        return "hw"         # a TPU backend never interprets
-    if interpret is None:
-        interpret = bool(get_config()["ICI_INTERPRET"])
-    return "xla" if interpret else "hw"
+def _multi_axis(mesh_ctx) -> bool:
+    """The ring is one axis of a surrounding multi-axis mesh."""
+    return bool(mesh_ctx) and len(mesh_ctx) > 1
 
 
 def ici_all_reduce(x: jax.Array, axis_name: str, num_devices: int,
                    op: str = "sum", interpret=None,
                    mesh_ctx=None) -> jax.Array:
-    """Tier-dispatched device allreduce: VMEM-resident flat ring below
-    the VMEM boundary, HBM-streaming chunked ring above it, XLA past
-    the measured crossover (or when the kernels cannot run). The
-    per-call fallback pvar accounting lives in coll/device.py; direct
-    shard_map users are counted once per traced shape."""
+    """Tier-dispatched device allreduce: the engine ``planned_tier``
+    names (VMEM-resident flat ring, HBM-streaming chunked ring,
+    quantized wire) or the XLA lowering. The per-call fallback pvar
+    accounting lives in coll/device.py; direct shard_map users are
+    counted once per traced shape."""
+    from .collectives import allreduce
     p = num_devices
     if p == 1:
-        from .collectives import allreduce
         return allreduce(x, axis_name, op)
-    mode = _mesh_mode(mesh_ctx, interpret)
-    if mode == "xla":
-        from .collectives import allreduce
-        return allreduce(x, axis_name, op)
-    tier, reason = planned_tier("allreduce", x.size * x.dtype.itemsize,
-                                x.dtype, op, interpret, num_devices=p)
-    if mode == "hw" and tier in ("vmem", "quant"):
-        tier = "hbm"
-    _trace_entry("allreduce", tier, x.size * x.dtype.itemsize, op=op)
+    nbytes = x.size * x.dtype.itemsize
+    tier, reason = planned_tier("allreduce", nbytes, x.dtype, op, interpret,
+                                p, _multi_axis(mesh_ctx))
+    _trace_entry("allreduce", tier, nbytes, op=op)
     if tier == "quant":
         from . import pallas_quant
         return pallas_quant.quant_ring_all_reduce(x, axis_name, p, op,
                                                   interpret=interpret)
     if tier == "vmem":
         from . import pallas_ring
-        if op == "sum":
-            return pallas_ring.ring_all_reduce(x, axis_name, p,
-                                               interpret=interpret)
-        # ops the flat kernel cannot take stream instead (no fallback)
-        tier = "hbm"
+        return pallas_ring.ring_all_reduce(x, axis_name, p,
+                                           interpret=interpret)
     if tier == "hbm":
         return hbm_ring_all_reduce(x, axis_name, p, op,
                                    interpret=interpret,
                                    mesh_ctx=mesh_ctx)
-    note_fallback("allreduce", reason or "size",
-                  x.size * x.dtype.itemsize, x.dtype)
-    from .collectives import allreduce
+    if reason is not None:
+        note_fallback("allreduce", reason, nbytes, x.dtype)
     return allreduce(x, axis_name, op)
 
 
@@ -943,14 +941,9 @@ def ici_all_gather(x: jax.Array, axis_name: str, num_devices: int,
     p = num_devices
     if p == 1:
         return lax.all_gather(x, axis_name, tiled=True)
-    mode = _mesh_mode(mesh_ctx, interpret)
-    if mode == "xla":
-        return lax.all_gather(x, axis_name, tiled=True)
     out_nbytes = x.size * x.dtype.itemsize * p
     tier, reason = planned_tier("allgather", out_nbytes, x.dtype, None,
-                                interpret)
-    if mode == "hw" and tier in ("vmem", "quant"):
-        tier = "hbm"
+                                interpret, p, _multi_axis(mesh_ctx))
     _trace_entry("allgather", tier, out_nbytes)
     if tier == "vmem":
         from . import pallas_ring
@@ -959,7 +952,8 @@ def ici_all_gather(x: jax.Array, axis_name: str, num_devices: int,
     if tier == "hbm":
         return hbm_ring_all_gather(x, axis_name, p, interpret=interpret,
                                    mesh_ctx=mesh_ctx)
-    note_fallback("allgather", reason or "size", out_nbytes, x.dtype)
+    if reason is not None:
+        note_fallback("allgather", reason, out_nbytes, x.dtype)
     return lax.all_gather(x, axis_name, tiled=True)
 
 
@@ -967,20 +961,21 @@ def ici_reduce_scatter(x: jax.Array, axis_name: str, num_devices: int,
                        op: str = "sum", interpret=None,
                        mesh_ctx=None) -> jax.Array:
     """Tier-dispatched device reduce-scatter (tiled): this shard's
-    block of the axis-folded array, [ceil(n/p)]. Every non-XLA tier
-    streams through the chunked HBM engine (``planned_rs_tier``)."""
+    block of the axis-folded array, [ceil(n/p)], by the chunked HBM
+    engine (the one with a reduce-scatter entry) or the XLA lowering."""
     p = num_devices
     if p == 1:
         return x.reshape(-1)
     nbytes = x.size * x.dtype.itemsize
-    if _mesh_mode(mesh_ctx, interpret) != "xla":
-        tier, reason = planned_rs_tier(nbytes, x.dtype, op, interpret)
-        _trace_entry("reduce_scatter", tier, nbytes, op=op)
-        if tier == "hbm":
-            return hbm_ring_reduce_scatter(x, axis_name, p, op,
-                                           interpret=interpret,
-                                           mesh_ctx=mesh_ctx)
-        note_fallback("reduce_scatter", reason or "size", nbytes, x.dtype)
+    tier, reason = planned_tier("reduce_scatter_block", nbytes, x.dtype, op,
+                                interpret, p, _multi_axis(mesh_ctx))
+    _trace_entry("reduce_scatter", tier, nbytes, op=op)
+    if tier == "hbm":
+        return hbm_ring_reduce_scatter(x, axis_name, p, op,
+                                       interpret=interpret,
+                                       mesh_ctx=mesh_ctx)
+    if reason is not None:
+        note_fallback("reduce_scatter", reason, nbytes, x.dtype)
     n = int(x.size)
     flat = x.reshape(n)
     nblk = -(-n // p)
@@ -1029,8 +1024,10 @@ def ici_all_reduce_mesh(x: jax.Array, axes, op: str = "sum",
 
     Below the MV2T_DEV_TIER_AXES_MIN edge the decomposition is not
     worth its phase count (4 kernel launches on 2-D vs 2): each axis
-    runs a full allreduce in sequence instead — the latency shape,
-    VMEM-tier eligible per axis. Unit axes are skipped; a single live
+    runs a full allreduce in sequence instead — the latency shape.
+    Each phase asks ``planned_tier`` as one axis of a multi-axis mesh,
+    so it streams through the HBM engine at every size (the flat VMEM
+    ring addresses devices 1-D). Unit axes are skipped; a single live
     axis degenerates to the 1-D dispatch."""
     allx = tuple((str(a), int(s)) for a, s in axes)
     live = [(a, s) for a, s in allx if s > 1]

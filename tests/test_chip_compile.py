@@ -491,7 +491,8 @@ def test_reduce_scatter_program_at_its_own_size(mesh4, monkeypatch,
     monkeypatch.setattr(pallas_ici, "on_tpu", lambda: True)
     dt = np.dtype("float32")
     n = nbytes // dt.itemsize
-    assert pallas_ici.planned_rs_tier(nbytes, dt, "sum") == ("hbm", None)
+    assert pallas_ici.planned_tier("reduce_scatter_block", nbytes, dt,
+                                   "sum") == ("hbm", None)
     # whole tiles: three quarters of the send buffer leave every chip
     assert pallas_ici.reduce_scatter_wire_bytes(n, dt, P4) == 3 * nbytes // 4
     ch = DeviceCollChannel(mesh4, "x", _Rendezvous(P4), 0)

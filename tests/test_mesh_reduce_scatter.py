@@ -8,6 +8,8 @@ instant), a planned call as a deciding one; and a call the kernel cannot
 take, or a platform it cannot run on, is counted where it was silent.
 """
 
+import functools
+
 import jax
 import ml_dtypes
 import numpy as np
@@ -164,9 +166,9 @@ def test_wire_bytes_by_hand():
 
 
 def test_one_rule_says_the_tier_for_dispatcher_and_channel(interpreted):
-    """``planned_rs_tier`` folds the bins that have no reduce-scatter
+    """``planned_tier`` folds the bins that have no reduce-scatter
     entry into the streamer; the XLA takes keep their reasons."""
-    tier = pallas_ici.planned_rs_tier
+    tier = functools.partial(pallas_ici.planned_tier, "reduce_scatter_block")
     assert tier(4096, F32, "sum") == ("hbm", None)          # the vmem bin
     assert tier(128 << 20, F32, "max") == ("hbm", None)
     assert tier(4096, F32, "band") == ("xla", "dtype")
