@@ -19,7 +19,12 @@ plain numpy reference computed on the host from the same ``--seed``:
      benchmarks/osu_allreduce.py -m 67108864 -i 3 -x 1`` in this same
      process (the launcher runs rank threads in-process on the real
      device), to the port's own ``No Errors``.
-  4. *Proof the chip did the work.* ``coll_level_chip`` rose by exactly
+  4. *Point-to-point lane.* ``run_ranks(2, app, device_mesh=True)``: the
+     two ranks swap 1 MiB of float32 by ``comm.sendrecv`` on device
+     buffers, each deletes its own array once the call has returned,
+     and what each received is still bit-equal to what the other sent,
+     on its own device (``dev_pt2pt_send`` +2, no fallback).
+  5. *Proof the chip did the work.* ``coll_level_chip`` rose by exactly
      the device collectives issued, ``dev_coll_fallback_host_dtype`` by
      exactly the port's float64 latency statistics (x64 is off, so they
      are turned away and counted), every other ``dev_coll_fallback_*``
@@ -37,7 +42,9 @@ phase, ``run_ranks(8, app, device_mesh=<the four chips>)`` (two ranks a
 chip, ``DeviceFoldChannel``): allreduce sum and max, allgather,
 reduce_scatter_block, bcast and reduce at 1 MiB a rank on device-resident
 buffers, once each, compared with numpy; level 1 of the four reductions
-has to ride in the mesh program (``dev_fold_fused`` +4).
+has to ride in the mesh program (``dev_fold_fused`` +4); then the
+point-to-point lane between two ranks on two chips (``dev_pt2pt_d2d``
++2).
 
 Any failed phase raises: the exit code is non-zero and no result line is
 printed. Timings are host-clock smoke timings around
@@ -240,6 +247,48 @@ def library_door(seed: int, nranks: int = NRANKS, big: int = 16 * MiB,
     return len(phases) * (1 + STEADY_CALLS)
 
 
+def pt2pt_lane(seed: int, device_mesh=True, nbytes: int = MiB,
+               d2d: int = 0) -> None:
+    """The device point-to-point lane through the library door: two
+    thread-ranks swap ``nbytes`` of float32 by ``comm.sendrecv``; each
+    sender deletes its array once the call has returned (the send has
+    completed: what a donating jit would do next), and what each rank
+    received has to be its own, still valid and bit-equal to what the
+    other sent. ``d2d`` is how often the receiver-owned copy has to be
+    the runtime's device-to-device copy: 0 where both ranks share one
+    chip, 2 where they sit on two."""
+    import jax
+
+    from mvapich2_tpu import mpit, run_ranks
+
+    names = ("dev_pt2pt_send", "dev_pt2pt_recv", "dev_pt2pt_d2d",
+             "dev_pt2pt_fallback_host")
+    xs = [rank_data(seed, 300, r, nbytes // 4) for r in range(2)]
+    homes = [None, None]
+
+    def app(comm):
+        dev = homes[comm.rank] = comm.device_channel.device
+        other = 1 - comm.rank
+        x = jax.device_put(xs[comm.rank], dev)
+        got = comm.sendrecv(x, other, 0, x, other, 0)
+        x.delete()
+        comm.barrier()          # both have deleted before either reads
+        assert got.devices() == {dev}, (comm.rank, got.devices())
+        if not np.array_equal(np.asarray(got), xs[other]):
+            raise AssertionError(f"pt2pt lane: rank {comm.rank} holds "
+                                 f"something else than rank {other} sent")
+
+    before = {n: mpit.pvar(n).read() for n in names}
+    run_ranks(2, app, device_mesh=device_mesh, timeout=300.0)
+    rose = {n: int(mpit.pvar(n).read() - v) for n, v in before.items()}
+    say(f"pt2pt lane: sendrecv {nbytes} B each way between 2 ranks on "
+        f"{len(set(homes))} device(s), senders' arrays deleted, results "
+        f"bit-equal to what was sent | {rose}")
+    assert rose == {"dev_pt2pt_send": 2, "dev_pt2pt_recv": 2,
+                    "dev_pt2pt_d2d": d2d, "dev_pt2pt_fallback_host": 0}, rose
+    assert len(set(homes)) == (2 if d2d else 1), homes
+
+
 class _Tee:
     """Pass-through stdout that keeps what went by."""
 
@@ -307,6 +356,7 @@ def one_chip(seed: int) -> None:
     calls = library_door(seed)
     osu_calls, osu_stats = launcher_door()
     calls += osu_calls
+    pt2pt_lane(seed)            # no collective of the device path in it
     rose = mpit.pvar("coll_level_chip").read() - chip0
     fb = {n: v - fb0[n] for n, v in fallback_pvars().items()}
     say(f"proof: coll_level_chip rose by {rose} = {NRANKS} ranks x {calls} "
@@ -571,6 +621,7 @@ def main(argv=None) -> int:
     if args.chips == 4:
         four_chips(args.seed)
         fold_phase(args.seed)
+        pt2pt_lane(args.seed, d2d=2)
     else:
         one_chip(args.seed)
     say(f"compile cache: {cache_dir} ({cache_entries(cache_dir)} entries "

@@ -174,6 +174,14 @@ class Comm:
     # ------------------------------------------------------------------
     # point-to-point
     # ------------------------------------------------------------------
+    # Device buffers (pt2pt/protocol.py, "the device lane"): a jax.Array
+    # given whole is sent as a device array the receiver owns. As a
+    # receive buffer a jax.Array is a description (capacity, dtype,
+    # shape; a device array cannot be written into, so it is neither
+    # read nor written and may be the send array itself): recv, sendrecv
+    # and mrecv then return the received device array, as the
+    # collectives return their result, and an irecv's request holds it
+    # as ``req.array`` once complete.
     def isend(self, buf, dest: int, tag: int = 0, count: Optional[int] = None,
               datatype: Optional[Datatype] = None,
               mode: str = "standard") -> Request:
@@ -214,23 +222,27 @@ class Comm:
         return self.isend(buf, dest, tag, mode="sync", **kw)
 
     def recv(self, buf, source: int = ANY_SOURCE, tag: int = ANY_TAG,
-             **kw) -> Status:
-        return self.irecv(buf, source, tag, **kw).wait()
+             **kw):
+        req = self.irecv(buf, source, tag, **kw)
+        st = req.wait()
+        return req.array if _is_device(buf) else st
 
     def sendrecv(self, sendbuf, dest: int, sendtag: int,
                  recvbuf, source: int, recvtag: int,
                  send_count: Optional[int] = None,
                  send_datatype: Optional[Datatype] = None,
                  recv_count: Optional[int] = None,
-                 recv_datatype: Optional[Datatype] = None) -> Status:
+                 recv_datatype: Optional[Datatype] = None):
         rreq = self.irecv(recvbuf, source, recvtag, recv_count, recv_datatype)
         sreq = self.isend(sendbuf, dest, sendtag, send_count, send_datatype)
         st = rreq.wait()
         sreq.wait()
-        return st
+        return rreq.array if _is_device(recvbuf) else st
 
     def sendrecv_replace(self, buf, dest: int, sendtag: int, source: int,
-                         recvtag: int) -> Status:
+                         recvtag: int):
+        if _is_device(buf):     # nothing to replace in: the array comes back
+            return self.sendrecv(buf, dest, sendtag, buf, source, recvtag)
         tmp = np.array(buf, copy=True)
         return self.sendrecv(tmp, dest, sendtag, buf, source, recvtag)
 
@@ -248,9 +260,11 @@ class Comm:
         return self.u.protocol.improbe(source, self.ctx_pt2pt, tag)
 
     def mrecv(self, message, buf, count: Optional[int] = None,
-              datatype: Optional[Datatype] = None) -> Status:
+              datatype: Optional[Datatype] = None):
         count, datatype = _resolve(buf, count, datatype)
-        return self.u.protocol.mrecv(message, buf, count, datatype).wait()
+        req = self.u.protocol.mrecv(message, buf, count, datatype)
+        st = req.wait()
+        return req.array if _is_device(buf) else st
 
     # persistent requests (MPI_Send_init / MPI_Recv_init / MPI_Start)
     def send_init(self, buf, dest: int, tag: int = 0, **kw) -> Request:
@@ -302,6 +316,7 @@ class Comm:
 
             def done(ireq):
                 r.status = ireq.status
+                r.array = ireq.array
                 r.status.cancelled = bool(
                     getattr(ireq, "cancelled", False)
                     or ireq.status.cancelled)

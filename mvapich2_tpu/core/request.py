@@ -20,6 +20,11 @@ REQUEST_NULL = None
 
 class Request:
     _ids = iter(range(1, 1 << 62))
+    # a receive whose buffer was a jax.Array (a description: a device
+    # array cannot be written into): the received device array, once
+    # the request is complete; None before, for MPI_PROC_NULL and for
+    # every other kind of request
+    array = None
 
     def __init__(self, engine=None, kind: str = "generic"):
         self.engine = engine          # progress engine that completes me

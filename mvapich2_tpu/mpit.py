@@ -580,6 +580,35 @@ pvar("dev_nbc_segments", PVAR_CLASS_COUNTER, "device",
      "is one async jitted dispatch the engine then pumps to "
      "completion)")
 
+# the device point-to-point lane (pt2pt/protocol.py: a jax.Array given
+# whole to send/recv/isend/irecv/sendrecv between thread-ranks)
+pvar("dev_pt2pt_send", PVAR_CLASS_COUNTER, "device",
+     "messages sent on the device point-to-point lane, at the sender: a "
+     "jax.Array given whole to a peer thread-rank, matched by the "
+     "library's own Matcher and handed over as a device array the "
+     "receiver owns (pt2pt/protocol.py _dev_isend)")
+pvar("dev_pt2pt_recv", PVAR_CLASS_COUNTER, "device",
+     "messages of the device point-to-point lane delivered as a device "
+     "array, at the receiver (pt2pt/protocol.py _dev_deliver); a lane "
+     "message read back into a host receive buffer does not count")
+pvar("dev_pt2pt_bytes", PVAR_CLASS_COUNTER, "device",
+     "payload bytes received on the device point-to-point lane "
+     "(the messages dev_pt2pt_recv counts)")
+pvar("dev_pt2pt_unexpected", PVAR_CLASS_COUNTER, "device",
+     "device point-to-point messages that came before their receive "
+     "was posted and waited in the matcher's unexpected queue")
+pvar("dev_pt2pt_d2d", PVAR_CLASS_COUNTER, "device",
+     "device point-to-point messages whose receiver lives on another "
+     "device of the process: the receiver-owned copy was the runtime's "
+     "device-to-device copy (jax.device_put), made by the sender")
+pvar("dev_pt2pt_fallback_host", PVAR_CLASS_COUNTER, "device",
+     "device buffers handed to a point-to-point call that took the "
+     "host path: a partial count or derived datatype, an array sharded "
+     "over devices, a peer in another process, a plane-owned comm "
+     "(read back once at the sender); a lane message matched by a "
+     "host receive buffer (read back once at the receiver); a host "
+     "message matched by a device receive (staged, then device_put)")
+
 # device-lane timing observability (ISSUE 10): the optional hardware-
 # profiler bracket (the spans are coll/device.py's own).
 cvar("JAX_PROFILE", "", str, "device",

@@ -11,6 +11,14 @@ Analog of the ADI3 protocol layer (SURVEY §2.1, §3.2-3.3):
 
 Thresholds are cvars with per-channel defaults (EAGER_THRESHOLD /
 SMP_EAGERSIZE — the ibv_param.c:776-837,2354-2361 analog).
+
+The device lane: a message whose payload is a ``jax.Array`` given whole
+to a peer thread-rank rides one EAGER_SEND packet of protocol "DEV"
+that carries a device array the *receiver* owns — the sender's
+device-side copy of the message (on the receiver's device, where that
+is another one) — through the same Matcher as every host message. The
+sender may delete or donate its array once the send has completed; the
+host never sees the payload. See ``_dev_isend`` / ``_dev_deliver``.
 """
 
 from __future__ import annotations
@@ -29,6 +37,7 @@ from ..core.errors import (MPIException, MPIX_ERR_PROC_FAILED,
 from ..core.request import Request, CompletedRequest
 from ..core.status import Status, ANY_SOURCE, ANY_TAG, PROC_NULL
 from ..transport.base import PLANE_CTX_FLAG, Packet, PktType
+from ..utils import is_device_array
 from ..utils.config import cvar, get_config
 from ..utils.mlog import get_logger
 from .matching import Matcher
@@ -50,6 +59,39 @@ _pv_rndv = mpit.pvar("pt2pt_rndv_sent", mpit.PVAR_CLASS_COUNTER, "pt2pt",
                      "messages sent on the rendezvous path")
 _pv_bytes = mpit.pvar("pt2pt_bytes_sent", mpit.PVAR_CLASS_COUNTER, "pt2pt",
                       "total payload bytes sent")
+# the device lane's counters (declared in mpit.py)
+_pv_dev_send = mpit.pvar("dev_pt2pt_send")
+_pv_dev_recv = mpit.pvar("dev_pt2pt_recv")
+_pv_dev_bytes = mpit.pvar("dev_pt2pt_bytes")
+_pv_dev_unexpected = mpit.pvar("dev_pt2pt_unexpected")
+_pv_dev_d2d = mpit.pvar("dev_pt2pt_d2d")
+_pv_dev_fallback = mpit.pvar("dev_pt2pt_fallback_host")
+
+_owned_copy = None      # jit(jnp.copy), built by the first lane message
+
+
+def _device_copy(x):
+    """A new device array with ``x``'s contents on ``x``'s device: one
+    device-side copy (read m, write m), enqueued and not awaited. The
+    program is jax's to cache by shape and dtype: the first message of
+    a shape compiles it, no later one does."""
+    global _owned_copy
+    if _owned_copy is None:
+        import jax
+        import jax.numpy as jnp
+        _owned_copy = jax.jit(jnp.copy)
+    return _owned_copy(x)
+
+
+def _given_whole(x, count: int, datatype: Datatype) -> bool:
+    """``x`` (a jax.Array) with the count and datatype the call gave, or
+    that core/comm._resolve inferred: the array as it is, all of it, on
+    one device."""
+    return (count == x.size and datatype.is_contiguous
+            and datatype.basic is not None
+            and datatype.basic == x.dtype
+            and datatype.size == x.dtype.itemsize
+            and len(x.devices()) == 1)
 
 
 class SendRequest(Request):
@@ -75,12 +117,21 @@ class SendRequest(Request):
 
 class RecvRequest(Request):
     def __init__(self, engine, match: Tuple[int, int, int], buf, count: int,
-                 datatype: Datatype):
+                 datatype: Datatype, like=None):
         super().__init__(engine, "recv")
         self.match = match      # (ctx, source, tag)
         self.buf = buf
         self.count = count
         self.datatype = datatype
+        # a device receive: the jax.Array that describes it (capacity,
+        # dtype, shape). ``buf`` is then None until a host message
+        # matches and is staged (``Pt2ptProtocol._stage``), or from the
+        # start the read-back of ``like`` where it was not given whole.
+        self.like = like
+        self.staged = False     # buf is _stage's, not like's read-back
+        self.unexpected = False  # the message was there before the post
+        self.dev_seq = None     # the lane message's per-pair number
+        self.traced = False     # a dev_recv span is open
         self.scratch: Optional[np.ndarray] = None
         self.bytes_expected = 0
         self.bytes_received = 0
@@ -294,6 +345,7 @@ class Pt2ptProtocol:
         eng.register_handler(PktType.CANCEL_SEND_RESP,
                              self._on_cancel_resp)
         self.cfg = get_config()
+        self._dev_seq: dict = {}    # dest world rank -> device messages sent
         pch = getattr(universe, "plane_channel", None)
         if pch is not None and pch.plane:
             pch.plane_client = self
@@ -330,6 +382,16 @@ class Pt2ptProtocol:
         else:
             channel = self.u.channel_for(dest_world)
             is_local = self.u.is_local(dest_world)
+        if is_device_array(buf):
+            if pch is None and channel.carries_device \
+                    and _given_whole(buf, count, datatype):
+                return self._dev_isend(buf, channel, dest_world, comm_src,
+                                       ctx, tag, mode)
+            # a partial count, a derived datatype, an array sharded over
+            # devices, a peer in another process, a plane-owned comm: the
+            # host path, on the array read back once
+            _pv_dev_fallback.inc()
+            buf = np.asarray(buf)
         nbytes = datatype.size * count
         threshold = (self.cfg["SMP_EAGERSIZE"] if is_local
                      else self.cfg["EAGER_THRESHOLD"])
@@ -538,6 +600,169 @@ class Pt2ptProtocol:
                       bytes=nbytes, proto=sreq.protocol)
         return sreq
 
+    # ------------------------------------------------------------------
+    # the device lane
+    # ------------------------------------------------------------------
+    def _dev_isend(self, x, channel, dest_world: int, comm_src: int,
+                   ctx: int, tag: int, mode: str) -> Request:
+        """Send the jax.Array ``x``, whole, to a peer thread-rank.
+
+        The sender makes the receiver's array now: a device-side copy of
+        ``x`` on its own device or, where the receiver is bound to
+        another device of the process, the runtime's device-to-device
+        copy onto that one. The copy is enqueued, not awaited; the
+        packet carries it as the object it is, and the send is complete
+        (all modes but ``sync``, which completes when the receiver's
+        match answers with a FIN). So whatever the sender then does to
+        ``x`` (delete, donate) cannot reach what the receiver holds,
+        whether its receive was posted before or comes after, and a
+        send never waits for a receive, as an eager host send does not.
+        """
+        nbytes = x.size * x.dtype.itemsize
+        seq = self._dev_seq[dest_world] = \
+            self._dev_seq.get(dest_world, 0) + 1
+        there = channel.device_of(dest_world)
+        d2d = there is not None and x.devices() != {there}
+        tr = self.engine.tracer
+        if tr is not None:
+            # ``bytes`` once a message and end (bin/mpitrace sums it):
+            # on the send's B and on the receive's E
+            tr.record("device", "dev_send", "B",
+                      {"dest": dest_world, "tag": tag, "bytes": nbytes,
+                       "seq": seq})
+            tr.record("device", "dev_p2p_copy", "B",
+                      {"bytes": nbytes, "d2d": d2d})
+        if d2d:
+            import jax
+            own = jax.device_put(x, there)
+            _pv_dev_d2d.inc()
+        else:
+            own = _device_copy(x)
+        if tr is not None:
+            tr.record("device", "dev_p2p_copy", "E")
+        sreq = SendRequest(self.engine, dest_world)
+        sync = mode == "sync"
+        pkt = Packet(PktType.EAGER_SEND, self.u.world_rank, ctx, comm_src,
+                     tag, nbytes, own, sreq_id=sreq.req_id, protocol="DEV",
+                     extra={"seq": seq, "sync": sync})
+        if sync:
+            sreq._ctx = ctx     # revoke sweep keys pending sends by ctx
+            with self.engine.mutex:
+                self.engine.track(sreq)
+        self._send_pkt(channel, dest_world, pkt)
+        _pv_dev_send.inc()
+        if not sync:
+            sreq._fire()        # locally complete, cancellable until matched
+        sreq._cancel_fn = lambda: self._cancel_send(sreq, dest_world,
+                                                    channel)
+        if tr is not None:
+            tr.record("device", "dev_send", "E",
+                      {"dest": dest_world, "tag": tag, "seq": seq})
+        return sreq
+
+    def _device_recvbuf(self, buf, count: int, datatype: Datatype,
+                        lane: bool = True):
+        """``(buf, like)`` of a receive. A jax.Array given as the
+        receive buffer is a description (``like``: capacity, dtype,
+        shape) and no buffer (None): a lane message arrives as the
+        device array it is. Where the array is not given whole, or the
+        comm has no lane (plane-owned), the host path receives into its
+        read-back (counted), which is put on the device when the
+        receive ends."""
+        if not is_device_array(buf):
+            return buf, None
+        if lane and _given_whole(buf, count, datatype):
+            return None, buf
+        _pv_dev_fallback.inc()
+        return np.array(buf), buf
+
+    def _plane_recv(self, pch, buf, count: int, datatype: Datatype,
+                    match) -> "CPlaneRecvRequest":
+        """A receive request of a plane-owned comm; a device receive
+        buffer's read-back is received into and then put on the device."""
+        buf, like = self._device_recvbuf(buf, count, datatype, lane=False)
+        req = CPlaneRecvRequest(self.engine, pch, buf, count, datatype,
+                                match)
+        if like is not None:
+            def upload(r):
+                if r.error is None:
+                    r.array = self._upload(buf, like)
+            req.add_callback(upload)
+        return req
+
+    def _dev_posted(self, req: RecvRequest) -> None:
+        """A device receive is posted: its ``dev_recv`` span opens."""
+        if req.like is not None and (tr := self.engine.tracer) is not None:
+            req.traced = True
+            _ctx, source, tag = req.match
+            tr.record("device", "dev_recv", "B",
+                      {"source": source, "tag": tag,
+                       "capacity": req.capacity})
+
+    def _stage(self, req: RecvRequest) -> None:
+        """A host message matched a device receive: it lands in a host
+        buffer of the description's shape (counted), and ``_dev_finish``
+        puts what came on the device."""
+        if req.like is not None and req.buf is None:
+            _pv_dev_fallback.inc()
+            req.buf = np.empty(req.like.shape, req.like.dtype)
+            req.staged = True
+
+    def _upload(self, host: np.ndarray, like):
+        """``host`` as a device array on this rank's device (the one its
+        COMM_WORLD is bound to; unbound, the description's own)."""
+        import jax
+        return jax.device_put(host,
+                              self.u.device or next(iter(like.devices())))
+
+    def _dev_deliver(self, req: RecvRequest, pkt: Packet) -> None:
+        """A message of the device lane meets its receive (engine mutex
+        held). A device receive takes the array as it is; a host receive
+        buffer has it read back (counted)."""
+        if pkt.extra["sync"]:
+            fin = Packet(PktType.RNDV_FIN, self.u.world_rank,
+                         sreq_id=pkt.sreq_id)
+            self.u.channel_for(pkt.src_world).send_packet(pkt.src_world, fin)
+        arr = pkt.data
+        if pkt.nbytes > req.capacity:
+            pass                # MPI_ERR_TRUNCATE: nothing is delivered
+        elif req.buf is not None:
+            _pv_dev_fallback.inc()
+            if pkt.nbytes:
+                req.datatype.unpack(
+                    np.asarray(arr).reshape(-1).view(np.uint8), req.buf,
+                    req.count)
+        elif req.like is not None:
+            # the message's dtype and element count, shaped as the
+            # description where the count fills it, else flat
+            shape = req.like.shape if arr.size == req.like.size \
+                else (arr.size,)
+            req.array = arr if arr.shape == shape else arr.reshape(shape)
+            req.dev_seq = pkt.extra["seq"]
+            _pv_dev_recv.inc()
+            _pv_dev_bytes.inc(pkt.nbytes)
+        self._finish_recv(req, pkt, pkt.nbytes, pkt.comm_src, pkt.tag)
+
+    def _dev_finish(self, req: RecvRequest, nbytes: int, src: int,
+                    tag: int, ok: bool) -> None:
+        """The end of a device receive: what the host path received is
+        put on the device, and the ``dev_recv`` span closes."""
+        # a posted receive and its cancel hook hold each other: let go,
+        # so that the array goes when its holder does, not at the next
+        # cycle collection
+        req._cancel_fn = None
+        if ok and req.array is None and req.buf is not None:
+            host = req.buf
+            if req.staged:
+                n = min(nbytes, req.capacity) // req.like.dtype.itemsize
+                if n != host.size:
+                    host = host.reshape(-1)[:n]
+            req.array = self._upload(host, req.like)
+        if req.traced and (tr := self.engine.tracer) is not None:
+            tr.record("device", "dev_recv", "E",
+                      {"source": src, "tag": tag, "bytes": nbytes,
+                       "seq": req.dev_seq, "unexpected": req.unexpected})
+
     def _plane_cancel_send(self, sreq, pch, dest_world: int) -> bool:
         """Send-cancel for a plane-injected eager: CANCEL_SEND_REQ goes
         through the plane; the C target retracts from its unexpected
@@ -695,8 +920,8 @@ class Pt2ptProtocol:
             return req
         pch = self._plane_route(ctx)
         if pch is not None:
-            req = CPlaneRecvRequest(self.engine, pch, buf, count, datatype,
-                                    (ctx, source, tag))
+            req = self._plane_recv(pch, buf, count, datatype,
+                                   (ctx, source, tag))
             with self.engine.mutex:
                 if self._recv_source_failed(ctx, source, tag):
                     req.complete(MPIException(
@@ -706,11 +931,14 @@ class Pt2ptProtocol:
                 req.post(lambda addr, cap: pch._ring.lib.cp_irecv(
                     pch.plane, addr, cap, ctx, source, tag))
             return req
+        buf, like = self._device_recvbuf(buf, count, datatype)
         req = RecvRequest(self.engine, (ctx, source, tag), buf, count,
-                          datatype)
+                          datatype, like)
+        self._dev_posted(req)
         with self.engine.mutex:
             pkt = self.matcher.match_posted(ctx, source, tag)
             if pkt is not None:
+                req.unexpected = True
                 self._deliver(req, pkt)
             elif self._recv_source_failed(ctx, source, tag):
                 req.complete(MPIException(
@@ -718,8 +946,16 @@ class Pt2ptProtocol:
                     f"recv source failed (ctx={ctx}, src={source})"))
             else:
                 self.matcher.post(req)
-                req._cancel_fn = lambda: self.matcher.cancel_posted(req)
+                req._cancel_fn = lambda: self._cancel_posted(req)
         return req
+
+    def _cancel_posted(self, req: RecvRequest) -> bool:
+        """MPI_Cancel of a posted receive; a device receive's span
+        closes with it."""
+        gone = self.matcher.cancel_posted(req)
+        if gone and req.traced and (tr := self.engine.tracer) is not None:
+            tr.record("device", "dev_recv", "E", {"cancelled": True})
+        return gone
 
     def _recv_source_failed(self, ctx: int, source: int,
                             tag: int) -> bool:
@@ -836,15 +1072,19 @@ class Pt2ptProtocol:
               datatype: Datatype) -> Request:
         if isinstance(message, PlaneMessage):
             pch = self.u.plane_channel
-            req = CPlaneRecvRequest(self.engine, pch, buf, count, datatype,
-                                    (message.ctx, message.comm_src,
-                                     message.tag))
+            req = self._plane_recv(pch, buf, count, datatype,
+                                   (message.ctx, message.comm_src,
+                                    message.tag))
             with self.engine.mutex:
                 req.post(lambda addr, cap: pch._ring.lib.cp_mrecv_start(
                     pch.plane, message.token, addr, cap))
             return req
+        buf, like = self._device_recvbuf(buf, count, datatype)
         req = RecvRequest(self.engine, (message.ctx, message.comm_src,
-                                        message.tag), buf, count, datatype)
+                                        message.tag), buf, count, datatype,
+                          like)
+        req.unexpected = True
+        self._dev_posted(req)
         with self.engine.mutex:
             self._deliver(req, message)
         return req
@@ -873,9 +1113,15 @@ class Pt2ptProtocol:
         if nbytes > req.capacity:
             err = MPIException(MPI_ERR_TRUNCATE,
                                f"message truncated: {nbytes} > {req.capacity}")
+        if req.like is not None:
+            self._dev_finish(req, nbytes, src, tag, err is None)
         req.complete(err)
 
     def _deliver_eager(self, req: RecvRequest, pkt: Packet) -> None:
+        if pkt.protocol == "DEV":
+            self._dev_deliver(req, pkt)
+            return
+        self._stage(req)
         n = min(pkt.nbytes, req.capacity)
         if n > 0 and req.buf is not None:
             req.datatype.unpack(pkt.data[:n], req.buf, req.count)
@@ -885,6 +1131,7 @@ class Pt2ptProtocol:
         self._finish_recv(req, pkt, pkt.nbytes, pkt.comm_src, pkt.tag)
 
     def _rndv_recv_start(self, req: RecvRequest, pkt: Packet) -> None:
+        self._stage(req)
         req.bytes_expected = pkt.nbytes
         src_world = pkt.src_world
         channel = self.u.channel_for(src_world)
@@ -917,6 +1164,8 @@ class Pt2ptProtocol:
         req = self.matcher.match_incoming(pkt)
         if req is not None:
             self._deliver_eager(req, pkt)
+        elif pkt.protocol == "DEV":
+            _pv_dev_unexpected.inc()
 
     def _on_rts(self, pkt: Packet) -> None:
         req = self.matcher.match_incoming(pkt)

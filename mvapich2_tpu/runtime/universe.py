@@ -103,6 +103,13 @@ class Universe:
                                f"no channel for rank {dest_world}")
         return ch
 
+    @property
+    def device(self):
+        """The device this rank is bound to (its COMM_WORLD's device
+        channel: run_ranks(..., device_mesh=...), --vpod), or None."""
+        ch = getattr(self.comm_world, "device_channel", None)
+        return ch.device if ch is not None else None
+
     def is_local(self, dest_world: int) -> bool:
         """Same node? Feeds the SMP-path routing decision
         (mpid_send.c:267 analog) and 2-level collective splits."""

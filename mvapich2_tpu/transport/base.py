@@ -158,6 +158,10 @@ class Channel:
     # engine spins instead of sleeping — the reference's CQ polling
     # discipline (SURVEY §3.5: "this polling loop is THE cpu hot loop").
     busy_poll = False
+    # True where the peer is a thread of this process, so a packet can
+    # carry a device array as the object it is (the device lane of
+    # pt2pt/protocol.py) and ``device_of`` names the peer's device.
+    carries_device = False
 
     def attach(self, engine) -> None:
         """Bind to the owning rank's progress engine."""

@@ -58,18 +58,22 @@ class LocalFabric:
 class LocalChannel(Channel):
     name = "local"
     supports_rget = True
+    carries_device = True
 
     def __init__(self, fabric: LocalFabric, my_rank: int):
         self.fabric = fabric
         self.my_rank = my_rank
 
     def send_packet(self, dest_world: int, pkt: Packet) -> None:
-        if pkt.data is not None:
+        if pkt.data is not None and pkt.protocol != "DEV":
             # Eager payloads are copied at injection so the sender's buffer
             # is immediately reusable (MPI eager semantics; the vbuf copy).
             # Self-sends included: the protocol may hand a live VIEW of
             # the user buffer (zero-copy eager), which the user can
             # overwrite the moment the send completes locally.
+            # A message of the device lane (protocol "DEV") carries
+            # the device array the sender made for the receiver, as
+            # the object it is: the host never sees that payload.
             pkt.data = np.array(pkt.data, dtype=np.uint8, copy=True)
         # no wire blob on the thread fabric: the payload size is the
         # honest byte count (delivery is a reference hop, recv side has
@@ -79,6 +83,11 @@ class LocalChannel(Channel):
 
     def poll(self) -> bool:
         return False  # delivery is push-based into the engine inbox
+
+    def device_of(self, world_rank: int):
+        """The device a peer thread-rank is bound to, or None: where a
+        device message for that rank has to lie."""
+        return self.fabric.engines[world_rank].universe.device
 
     def expose_buffer(self, array: np.ndarray):
         return self.fabric.expose(array)

@@ -1,13 +1,14 @@
-"""Plain numpy references for MPI collectives: what every rank must
-hold afterwards, computed on the host from all ranks' inputs with
-nothing but numpy. Independent of ops/ and coll/device.py (it imports
-neither), so a test may hold the device path to it bit for bit.
+"""Plain numpy references for MPI collectives and point-to-point: what
+every rank must hold afterwards, computed on the host from all ranks'
+inputs with nothing but numpy. Independent of ops/, coll/device.py and
+pt2pt/ (it imports none of them), so a test may hold the device path
+to it bit for bit.
 
 ``inputs`` is one flat array per rank, in rank order; each function
 returns one array per rank (``None`` where the rank receives nothing).
 """
 
-from typing import List, Optional, Sequence
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -71,3 +72,47 @@ def reduce_scatter_block(inputs: Sequence[np.ndarray], op: str = "sum"
     total = _fold(inputs, op)
     c = total.size // p
     return [total[r * c:(r + 1) * c] for r in range(p)]
+
+
+def sendrecv(inputs: Sequence[np.ndarray],
+             pairs: Sequence[Tuple[Optional[int], Optional[int]]]
+             ) -> List[Optional[np.ndarray]]:
+    """MPI_Sendrecv on every rank at once: ``pairs[r]`` is rank ``r``'s
+    ``(dest, source)``, ``None`` for MPI_PROC_NULL. Rank ``r`` holds
+    afterwards what its source sent, which is the source's input if the
+    source's dest is ``r`` (anything else would hang, not answer)."""
+    out = []
+    for r, (_dest, source) in enumerate(pairs):
+        if source is None:
+            out.append(None)
+            continue
+        if pairs[source][0] != r:
+            raise ValueError(f"rank {r} receives from {source}, which "
+                             f"sends to {pairs[source][0]}")
+        out.append(inputs[source])
+    return out
+
+
+def deliver(messages: Sequence[Tuple[int, int, int, np.ndarray]],
+            receives: Sequence[Tuple[int, Optional[int], Optional[int]]]
+            ) -> List[Tuple[int, int, np.ndarray]]:
+    """MPI's matching rule, by a list. ``messages`` are ``(src, dst,
+    tag, payload)`` in the order they were sent; ``receives`` are
+    ``(dst, source, tag)`` in the order they were posted, ``None`` a
+    wildcard. Each receive takes the earliest message to its rank that
+    its envelope matches and that no earlier receive took (messages of
+    one sender do not overtake one another). Returns ``(source, tag,
+    payload)`` for every receive. With wildcards over several senders
+    MPI leaves the order between senders open: give such messages in
+    the order the test forces."""
+    left = list(messages)
+    got = []
+    for dst, source, tag in receives:
+        for i, (s, d, t, payload) in enumerate(left):
+            if d == dst and source in (None, s) and tag in (None, t):
+                got.append((s, t, payload))
+                del left[i]
+                break
+        else:
+            raise ValueError(f"no message for receive {(dst, source, tag)}")
+    return got

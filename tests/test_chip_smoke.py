@@ -12,6 +12,7 @@ import sys
 import jax
 import ml_dtypes
 import numpy as np
+import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -95,6 +96,23 @@ def test_fold_phase_under_the_interpreter(monkeypatch, capsys):
     assert mpit.pvar("dev_fold_operands").read() - operands0 == 4
     assert mpit.pvar("dev_fold_fused").read() - fused0 == 4
     assert "'dev_fold_fused': 4" in out
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_pt2pt_lane_phase(chips, capsys):
+    """Two ranks on one device (the one-chip run) and on two (what
+    ``device_mesh=True`` binds where jax has devices to spare: the
+    four-chip run): the senders delete, the results hold."""
+    from mvapich2_tpu.parallel.mesh import make_mesh
+    if chips == 1:
+        chip_smoke.pt2pt_lane(seed=5, nbytes=16 * 1024, device_mesh=make_mesh(
+            (1,), ("x",), jax.devices()[:1]))
+    else:
+        chip_smoke.pt2pt_lane(seed=5, nbytes=16 * 1024, d2d=2)
+    out = capsys.readouterr().out
+    assert f"on {1 if chips == 1 else 2} device(s)" in out
+    with pytest.raises(AssertionError):     # the count is held, not said
+        chip_smoke.pt2pt_lane(seed=5, nbytes=4096, d2d=1)
 
 
 def test_main_refuses_without_a_tpu(capsys):
