@@ -42,7 +42,11 @@ phase, ``run_ranks(8, app, device_mesh=<the four chips>)`` (two ranks a
 chip, ``DeviceFoldChannel``): allreduce sum and max, allgather,
 reduce_scatter_block, bcast and reduce at 1 MiB a rank on device-resident
 buffers, once each, compared with numpy; level 1 of the four reductions
-has to ride in the mesh program (``dev_fold_fused`` +4); then the
+has to ride in the mesh program (``dev_fold_fused`` +4), and inside the
+ring kernel's fold rounds wherever the streaming ring takes the call
+(``dev_fold_in_ring``: at 1 MiB the max and the reduce_scatter_block; a
+sum of that size rides the flat VMEM ring behind the slot reduction);
+then the
 point-to-point lane between two ranks on two chips (``dev_pt2pt_d2d``
 +2).
 
@@ -559,9 +563,18 @@ def fold_phase(seed: int, nranks: int = 8, ndev: int = 4,
 
     levels = ("coll_level_chip", "coll_level_ici")
     # level 1 of the four reductions rides in the mesh program, one
-    # launch a call; allgather alone still copies a chip's deposits
-    folds = {"dev_fold_fused": 4, "dev_fold_operands": 4,
-             "dev_fold_stacked": ndev}
+    # launch a call, and in the ring kernel itself where the one tier
+    # rule sends the call down the streaming ring (whole-tile blocks at
+    # these sizes); allgather alone still copies a chip's deposits
+    from mvapich2_tpu.ops.pallas_ici import planned_tier, ring_folds
+    in_ring = sum(
+        ring_folds(planned_tier(coll, nbytes, np.float32, op,
+                                num_devices=ndev)[0], n, np.float32, ndev)
+        for coll, op in (("allreduce", "sum"), ("allreduce", "max"),
+                         ("reduce_scatter_block", "sum"), ("reduce", "sum")))
+    assert in_ring >= 2, in_ring    # the max and the reduce-scatter stream
+    folds = {"dev_fold_fused": 4, "dev_fold_in_ring": in_ring,
+             "dev_fold_operands": 4, "dev_fold_stacked": ndev}
     before = {n_: mpit.pvar(n_).read() for n_ in levels + tuple(folds)}
     fb0 = fallback_pvars()
     run_ranks(nranks, app, device_mesh=mesh, timeout=900.0)

@@ -229,7 +229,8 @@ class _ImportedProgram:
 #                    planar copies of a chip's deposits made this call:
 #                    0 where the deposits were the fold's operands) and
 #                    ``fused`` (the fold ran inside the mesh program:
-#                    nothing was launched in this span)
+#                    nothing was launched in this span) and ``in_ring``
+#                    (... and there inside the ring kernel's rounds)
 #   dev_dispatch     rank 0: program-cache lookup + enqueue, in
 #                    ``_leader``'s own frame; its E says ``built`` when
 #                    the call made or loaded the program
@@ -484,6 +485,8 @@ class DeviceCollChannel:
     _tr = None
     _seq = 0
     _args: Optional[dict] = None
+    # the tier ``_note_tier`` said the call in ``_run`` takes
+    _tier: Optional[str] = None
     # the filed plan the call in ``_run`` runs on (``plan_of`` found
     # it), and the draft a call that has to decide files on its way out
     _plan: Optional[_CallPlan] = None
@@ -589,7 +592,7 @@ class DeviceCollChannel:
         if not daemon.exec_cache_enabled():
             return self._build(name, n, op, root, extra)
         from ..ops import _compat
-        ck = "|".join(("mv2t-exec-v4", self._chan_desc(), name,
+        ck = "|".join(("mv2t-exec-v5", self._chan_desc(), name,
                        f"n{n}", dtype_str, f"op:{op}", f"root:{root}",
                        f"x:{extra!r}", _compat.exec_fingerprint()))
         blob = daemon.exec_cache_get(ck)
@@ -1007,7 +1010,7 @@ class DeviceCollChannel:
         profiler's host plane as a TraceAnnotation of the same name, so
         an MV2T_JAX_PROFILE trace shows it beside the device's ops."""
         global _profiler
-        tier = self._note_tier(comm, name, local, op)
+        tier = self._tier = self._note_tier(comm, name, local, op)
         for lv in self._level_pvars:    # the hierarchy levels it rides
             lv.inc()
         self._seq += 1
@@ -1457,7 +1460,10 @@ class DeviceCollChannel:
 def _slot_fold(xs, op: str):
     """The slot reduction, ``xs -> [n]``, traced where it is called: in
     the slot channel's programs, alone in the fold channel's
-    ``_fold_prog`` and in front of the ring in its fused mesh program.
+    ``_fold_prog``, and in its fused mesh program in front of a level-2
+    collective that cannot fold the operands itself (ops/pallas_ici.py
+    ``_fold_unless_ring_does``: every engine but the streaming ring on
+    whole-tile blocks, whose fold rounds read the operands as they lie).
     ``xs`` is the deposited flat ``(n,)`` arrays of the ranks sharing a
     device, or one staged planar ``(R, n)`` array; the body reads which
     from its operands. The fused Pallas slot kernel (ops/pallas_hbm)
@@ -1635,7 +1641,15 @@ class DeviceFoldChannel(DeviceCollChannel):
     Both levels of a reduction are one program, one launch a call, where
     every deposit is a flat device array on its own chip (and the mesh
     is 1-D): ``k`` mesh-sharded operands made of the deposits as they
-    lie, per chip the fold and then the ring on its result. A call with
+    lie, handed to the level-2 dispatcher as they are. Where that is the
+    streaming ring and the deposits make whole-tile ring blocks, level 1
+    runs inside the ring kernel (counted: dev_fold_in_ring): its fold
+    rounds read every chunk from all ``k`` deposits and fold them in
+    VMEM as they use it, so no slot-reduce kernel runs in front of the
+    ring and no fold result is written. Everywhere else (the flat VMEM
+    ring, the quantized wire, the XLA lowering, a ragged length) the
+    dispatcher folds them first, the slot reduction and then the
+    collective on its result, still one program. A call with
     a host deposit, a shaped array or one committed to another chip
     among its ranks (and any call on a multi-axis mesh) folds chip by
     chip instead, a launch each (a chip that does not lie is staged as
@@ -1710,10 +1724,13 @@ class DeviceFoldChannel(DeviceCollChannel):
         program: None for the mesh program over one shard a chip (the
         1:1 channel's, unchanged), ``k`` for the fused one: ``k``
         mesh-sharded flat operands, shard ``j`` of operand ``i`` rank
-        ``j * k + i``'s deposit as it lies, and per chip ``_slot_fold``,
-        then the collective's ring on its result. One launch where the
-        unfused arm makes one a chip and the ring's. 1-D meshes only
-        (``_stage`` asks); no operand is donated or aliased."""
+        ``j * k + i``'s deposit as it lies, and per chip the
+        collective's tier dispatcher on the ``k`` of them: the
+        streaming ring folds them in its rounds, any other engine gets
+        their ``_slot_fold`` (``pallas_ici.ring_folds`` says which).
+        One launch where the unfused arm makes one a chip and the
+        ring's. 1-D meshes only (``_stage`` asks); no operand is donated
+        or aliased."""
         if extra is None:
             return super()._build(name, n, op, root)
         import jax
@@ -1730,7 +1747,7 @@ class DeviceFoldChannel(DeviceCollChannel):
             else (pallas_ici.ici_all_reduce, P(None)))     # and reduce
 
         def f(*xs):
-            return ring(_slot_fold(xs, op), axis, p, op=op)
+            return ring(xs, axis, p, op=op)
         sm = shard_map(f, mesh=self.mesh, in_specs=(P(axis),) * extra,
                        out_specs=out_specs, check_vma=False)
         return jax.jit(sm)
@@ -1773,18 +1790,21 @@ class DeviceFoldChannel(DeviceCollChannel):
         the mesh program's operands over the chips. Where every chip of
         a reduction handed its ``k`` deposits over as they lie (and the
         mesh is 1-D) level 1 runs inside the mesh program, one launch a
-        call (counted: dev_fold_fused); otherwise each chip is folded by
-        its own launch and the mesh program takes the folds."""
+        call (counted: dev_fold_fused), and inside its ring kernel where
+        ``pallas_ici.ring_folds`` says so of the tier this call takes
+        (counted: dev_fold_in_ring; the program's trace asked the same
+        rule); otherwise each chip is folded by its own launch and the
+        mesh program takes the folds."""
         import jax
 
         rv = self.rv
         nd, k = self.ndev, self.k
         n, dtype = self._slot_extent(rv.slots[0])
-        shards, prog_root, prog_n, fused = [], 0, n, False
+        shards, prog_root, prog_n, fused, in_ring = [], 0, n, False, False
         # the look at the deposits, and the staging and fold launches of
         # a call that does not fuse, issued from this one thread; the E
         # says how many planar copies it made and whether the fold went
-        # into the mesh program
+        # into the mesh program, and there into the ring kernel
         with self._phase("dev_chip_fold") as fold:
             self._stacked = 0
             if name == "bcast":
@@ -1815,6 +1835,10 @@ class DeviceFoldChannel(DeviceCollChannel):
                     isinstance(c, tuple) and len(c) == k for c in shards)
                 if fused:
                     mpit.pvar("dev_fold_fused").inc()
+                    from ..ops.pallas_ici import ring_folds
+                    in_ring = ring_folds(self._tier, n, dtype, nd)
+                    if in_ring:
+                        mpit.pvar("dev_fold_in_ring").inc()
                 else:   # a launch for every chip still unfolded
                     shards = [self._fold_prog(op)(*c)
                               if isinstance(c, tuple) else c
@@ -1825,7 +1849,7 @@ class DeviceFoldChannel(DeviceCollChannel):
                 mpit.pvar("dev_fold_stacked").inc(self._stacked)
             if fold is not None:
                 fold.args.update(k=k, chips=nd, stacked=self._stacked,
-                                 fused=fused)
+                                 fused=fused, in_ring=in_ring)
         if fused:
             # operand i: the chips' i-th deposits, shard j rank
             # j*k + i's array as it lies

@@ -549,7 +549,19 @@ pvar("dev_fold_fused", PVAR_CLASS_COUNTER, "device",
      "DeviceFoldChannel._stage, _build); rises with "
      "dev_fold_operands on a 1-D mesh at k > 1; a call with a chip "
      "that had to be staged folds every chip by its own launch and "
-     "does not count")
+     "does not count. Where in the program level 1 runs: inside the "
+     "ring kernel's fold rounds (dev_fold_in_ring) or as the slot "
+     "reduction in front of the level-2 collective")
+pvar("dev_fold_in_ring", PVAR_CLASS_COUNTER, "device",
+     "fold-channel leader calls counted by dev_fold_fused whose level 1 "
+     "ran inside the ring kernel: the streaming ring's fold rounds read "
+     "the chip's k deposits chunk by chunk and fold them in VMEM as "
+     "they use them, so no slot-reduce kernel runs and no fold result "
+     "is written (ops/pallas_ici.py ring_folds, _rs_rounds): the hbm "
+     "tier of a 1-D mesh, deposits of p whole-tile blocks. A ragged "
+     "length, a message under the hbm tier's edge (the flat VMEM ring "
+     "of a sum), the quantized wire and the XLA lowering fold first "
+     "and do not count")
 pvar("dev_mesh_reordered", PVAR_CLASS_COUNTER, "device",
      "1-D meshes parallel/mesh.make_mesh returned with their devices in "
      "another order than they were given: TPU chips laid along a snake "

@@ -19,7 +19,20 @@ round has written it, and from the working or output buffer after: each
 round is told where its send chunks and accumulator chunks are loaded
 from and where its results are stored, so no whole-operand HBM-to-HBM
 copy runs in front of the rounds, the reduce-scatter's last fold lands
-in its output, and the operand is only ever read. The allreduce is the
+in its output, and the operand is only ever read. The fold rounds take
+``k`` operands a shard (two ranks a chip hand the ring both their
+deposits, coll/device.py ``DeviceFoldChannel``): a chunk read from the
+operand is then read from all ``k`` and folded in VMEM with the round's
+own reducer as it is used,
+
+    k HBM acc chunks ──k local DMAs──> acc slot + k-1 fold slots
+    acc slot (+ fold slots, in operand order) + peer recv slot ──VPU──> acc slot
+
+(round 0's send chunk likewise, in its send slot before the remote DMA
+starts), so level 1 of a two-level reduction costs one more load and
+one more VPU op a chunk under rounds the links bound, and no kernel,
+buffer or pass over HBM of its own. At ``k = 1`` the kernels are, op
+for op, what they were before the rounds took ``k``. The allreduce is the
 pipelined reduce-scatter + all-gather decomposition (the "Multiple
 Processes per GPU" schedule blueprint; EQuARX demonstrates the custom
 chunked form beating stock XLA on TPU); where the mesh axis is a
@@ -235,18 +248,24 @@ class _RingStreamer:
     def __init__(self, p, ndir, depth, credits, left, right,
                  send_buf, recv_buf, acc_buf,
                  in_sem, acc_sem, st_sem, send_sem, recv_sem, cap_sem,
-                 dev_base=0, dev_stride=1):
+                 dev_base=0, dev_stride=1, fold_buf=None, fold_sem=None):
         self.p, self.ndir, self.depth, self.credits = p, ndir, depth, credits
         self.left, self.right = left, right
         self.dev_base, self.dev_stride = dev_base, dev_stride
         self.send_buf, self.recv_buf, self.acc_buf = \
             send_buf, recv_buf, acc_buf
+        # the fold slots: beside every accumulator slot one more for
+        # each further operand of a ring block (``k - 1`` of them; none,
+        # and no scratch, where the ring has one operand)
+        self.fold_buf, self.fold_sem = fold_buf, fold_sem
+        self.nfold = 0 if fold_buf is None else fold_buf.shape[0]
         self.in_sem, self.acc_sem, self.st_sem = in_sem, acc_sem, st_sem
         self.send_sem, self.recv_sem, self.cap_sem = \
             send_sem, recv_sem, cap_sem
         self.gc = [0] * ndir                   # global chunk counter / dir
         self.pending_send: Dict = {}           # (d, slot) -> remote handle
         self.pending_acc: Dict = {}
+        self.pending_fold: Dict = {}           # (i, d, slot) -> fold load
         self.pending_store: Dict = {}
 
     def _dev(self, idx):
@@ -282,7 +301,28 @@ class _RingStreamer:
             h.wait()
             del self.pending_store[key]
 
-    def issue(self, d, src, off, sz, acc):
+    def _load_others(self, d, slot, blocks, off, sz):
+        """Start the loads of rows [off, off+sz) of ``blocks``, the
+        further operands' copies of one ring block, into the fold slots
+        beside slot ``(d, slot)``; ``_fold_others`` waits for them."""
+        for i, blk in enumerate(blocks):
+            lf = pltpu.make_async_copy(
+                blk.at[pl.ds(off, sz)],
+                self.fold_buf.at[i, d, slot, pl.ds(0, sz)],
+                self.fold_sem.at[i, d, slot])
+            lf.start()
+            self.pending_fold[(i, d, slot)] = lf
+
+    def _fold_others(self, d, slot, own, sz, red):
+        """``own``, the first operand's chunk, folded with the chunks
+        ``_load_others`` brings, in operand order, as each lands. The
+        VPU reads the fold slots synchronously: they are free after."""
+        for i in range(self.nfold):
+            self.pending_fold.pop((i, d, slot)).wait()
+            own = red(own, self.fold_buf[i, d, slot, :sz])
+        return own
+
+    def issue(self, d, src, off, sz, acc, red=None):
         """Front half of the chunk pipeline: load the send chunk (and,
         for the reduce phase, prefetch the local accumulator chunk),
         then launch the remote DMA — it flies while the previous
@@ -290,8 +330,14 @@ class _RingStreamer:
         HBM ring blocks the send chunk and the accumulator chunk are
         read from (``acc`` None in the gather phase), wherever they lie
         — the operand until a round has written the block, the working
-        buffer after; ``off``/``sz``: static, tile-aligned row range
-        inside the block."""
+        buffer after; a tuple each, because a block no round has
+        written lies in every one of the ring's ``k`` operands: the
+        chunk is then loaded from all of them and is their fold by
+        ``red`` (the send chunk in its slot before the remote DMA
+        starts; the accumulator chunk in ``drain``). The fold slots
+        serve both, so the accumulator's further loads start once the
+        send chunk is folded. ``off``/``sz``: static, tile-aligned row
+        range inside the block."""
         slot = self.gc[d] % self.depth
         prev = self.pending_send.pop((d, slot), None)
         if prev is not None:
@@ -300,18 +346,24 @@ class _RingStreamer:
         if prev_st is not None:
             prev_st.wait()             # acc slot's last store landed
         ld = pltpu.make_async_copy(
-            src.at[pl.ds(off, sz)],
+            src[0].at[pl.ds(off, sz)],
             self.send_buf.at[d, slot, pl.ds(0, sz)],
             self.in_sem.at[d, slot])
         ld.start()
+        self._load_others(d, slot, src[1:], off, sz)
         if acc is not None:
             la = pltpu.make_async_copy(
-                acc.at[pl.ds(off, sz)],
+                acc[0].at[pl.ds(off, sz)],
                 self.acc_buf.at[d, slot, pl.ds(0, sz)],
                 self.acc_sem.at[d, slot])
             la.start()
             self.pending_acc[(d, slot)] = la
         ld.wait()
+        if len(src) > 1:
+            self.send_buf[d, slot, :sz] = self._fold_others(
+                d, slot, self.send_buf[d, slot, :sz], sz, red)
+        if acc is not None:
+            self._load_others(d, slot, acc[1:], off, sz)
         self._take_credit(d)
         dst = self.right if d == 0 else self.left
         rdma = pltpu.make_async_remote_copy(
@@ -328,14 +380,17 @@ class _RingStreamer:
 
     def drain(self, d, slot, dst, off, sz, red):
         """Back half: the chunk from upstream has (or is about to have)
-        landed — reduce it into the accumulator chunk (or take it
-        verbatim for the gather phase), store the result into rows
-        [off, off+sz) of the HBM block ``dst`` and free the slot."""
+        landed — reduce it into the accumulator chunk, itself the fold
+        of the ``k`` operands' chunks where the block lay in them (or
+        take it verbatim for the gather phase), store the result into
+        rows [off, off+sz) of the HBM block ``dst`` and free the slot."""
         self.pending_send[(d, slot)].wait_recv()
         if red is not None:
             self.pending_acc.pop((d, slot)).wait()
+            own = self._fold_others(d, slot, self.acc_buf[d, slot, :sz],
+                                    sz, red)
             self.acc_buf[d, slot, :sz] = red(
-                self.acc_buf[d, slot, :sz], self.recv_buf[d, slot, :sz])
+                own, self.recv_buf[d, slot, :sz])
             # the VPU read of recv_buf is synchronous: the slot is free
             self._grant(d)
             st = pltpu.make_async_copy(
@@ -389,8 +444,9 @@ class _RingStreamer:
         chunk is loaded from, where the accumulator chunk is loaded
         from (None with ``red`` None: the gather phase) and where the
         result is stored, in whatever unit ``issue``/``drain`` take
-        (HBM block refs here; the quantized streamer still passes flat
-        element offsets) — this loop only hands them through."""
+        (tuples of HBM block refs and a block ref here; the quantized
+        streamer still passes flat element offsets) — this loop only
+        hands them through."""
         ndir = self.ndir
         cmax = max(len(c) for c in spans_chunks)
         live: List[List[Optional[int]]] = [[None] * len(spans_chunks[d])
@@ -400,7 +456,7 @@ class _RingStreamer:
                 if c < len(spans_chunks[d]):
                     off, sz = spans_chunks[d][c]
                     live[d][c] = self.issue(
-                        d, src[d], off, sz, acc[d] if acc else None)
+                        d, src[d], off, sz, acc[d] if acc else None, red)
             for d in range(ndir):
                 if 1 <= c and c - 1 < len(spans_chunks[d]):
                     off, sz = spans_chunks[d][c - 1]
@@ -410,13 +466,14 @@ class _RingStreamer:
 
 def _mk_streamer(p, ndir, depth, credits, left, right, scratch,
                  mesh_ctx=None, axis_name=None):
+    """The streamer over a kernel's whole ``_scratch_shapes`` list."""
     (send_buf, recv_buf, acc_buf, in_sem, acc_sem, st_sem, send_sem,
-     recv_sem, cap_sem) = scratch
+     recv_sem, cap_sem, _own_sem, *fold) = scratch
     base, stride = _dev_layout(mesh_ctx, axis_name)
     return _RingStreamer(p, ndir, depth, credits, left, right,
                          send_buf, recv_buf, acc_buf, in_sem, acc_sem,
-                         st_sem, send_sem, recv_sem, cap_sem,
-                         dev_base=base, dev_stride=stride)
+                         st_sem, send_sem, recv_sem, cap_sem, base, stride,
+                         *fold)
 
 
 def _dev_layout(mesh_ctx, axis_name):
@@ -439,9 +496,16 @@ def _dev_layout(mesh_ctx, axis_name):
     return base, strides[axis_name]
 
 
-def _scratch_shapes(ndir: int, depth: int, chunk: int, dtype):
+def _scratch_shapes(ndir: int, depth: int, chunk: int, dtype,
+                    nfold: int = 0):
     """``chunk`` in rows. Slot and direction lead; the tiled trailing
-    (chunk, 128) pair is only ever sliced on whole tiles."""
+    (chunk, 128) pair is only ever sliced on whole tiles. ``nfold``:
+    the ring's further operands (``k - 1``), each one more slot beside
+    every accumulator slot; none adds nothing to the list."""
+    fold = [
+        pltpu.VMEM((nfold, ndir, depth, chunk, _LANES), dtype),
+        pltpu.SemaphoreType.DMA((nfold, ndir, depth)),     # their loads
+    ] if nfold else []
     return [
         pltpu.VMEM((ndir, depth, chunk, _LANES), dtype),   # send slots
         pltpu.VMEM((ndir, depth, chunk, _LANES), dtype),   # recv slots
@@ -453,7 +517,7 @@ def _scratch_shapes(ndir: int, depth: int, chunk: int, dtype):
         pltpu.SemaphoreType.DMA((ndir, depth)),     # remote recv
         pltpu.SemaphoreType.REGULAR((ndir,)),       # slot credits
         pltpu.SemaphoreType.DMA(()),                # all-gather: own block
-    ]
+    ] + fold
 
 
 # ---------------------------------------------------------------------------
@@ -476,30 +540,36 @@ def _ring_neighbours(axis_name, p):
     return my, lax.rem(my - 1 + p, p), lax.rem(my + 1, p)
 
 
-def _rs_rounds(st, my, p, ndir, spans_chunks, red, x_hbm, w_hbm,
+def _rs_rounds(st, my, p, ndir, spans_chunks, red, xs, w_hbm,
                o_blk=None):
     """Reduce-scatter: cw round s passes the partial of block (my-s-1)
     rightward and folds the arrival into block (my-s-2); the ccw lane
     mirrors with +. After p-1 rounds block ``my`` is fully reduced on
     both lanes (same convention as pallas_ring.py).
 
-    A block is read from the operand ``x_hbm`` until a fold has written
-    it: each round folds into a block no round has touched, so its
-    accumulator chunks come from ``x_hbm``; round 0 sends an untouched
-    block too, every later round the partial the round before stored
-    into the working buffer ``w_hbm``. The last round folds into block
-    ``my`` and stores it into ``o_blk`` where one is given, else into
-    ``w_hbm`` like the others. ``x_hbm`` is only ever read."""
+    A block is read from the operands ``xs``, ``k`` of them of one
+    shape whose fold by ``red`` is this shard's contribution, until a
+    fold has written it: each round folds into a block no round has
+    touched, so its accumulator chunks come from ``xs``; round 0 sends
+    an untouched block too, every later round the partial the round
+    before stored into the working buffer ``w_hbm``. A chunk read from
+    ``xs`` is read from all ``k`` and folded in VMEM as it is used, so
+    over the rounds every block of every operand is read once. The last
+    round folds into block ``my`` and stores it into ``o_blk`` where one
+    is given, else into ``w_hbm`` like the others. ``xs`` are only ever
+    read."""
     for s in range(p - 1):
         sb = [lax.rem(my - s - 1 + 2 * p, p), lax.rem(my + s + 1, p)]
         rb = [lax.rem(my - s - 2 + 2 * p, p), lax.rem(my + s + 2, p)]
-        sent = x_hbm if s == 0 else w_hbm
+        sent = xs if s == 0 else (w_hbm,)
         if o_blk is not None and s == p - 2:
             dst = [o_blk] * ndir
         else:
             dst = [w_hbm.at[b] for b in rb[:ndir]]
-        st.stream_step(spans_chunks, [sent.at[b] for b in sb[:ndir]],
-                       [x_hbm.at[b] for b in rb[:ndir]], dst, red)
+        st.stream_step(
+            spans_chunks,
+            [tuple(x.at[b] for x in sent) for b in sb[:ndir]],
+            [tuple(x.at[b] for x in xs) for b in rb[:ndir]], dst, red)
 
 
 def _ag_rounds(st, my, p, ndir, spans_chunks, o_hbm, x_blk=None):
@@ -511,43 +581,45 @@ def _ag_rounds(st, my, p, ndir, spans_chunks, o_hbm, x_blk=None):
         sb = [lax.rem(my - s + 2 * p, p), lax.rem(my + s, p)]
         rb = [lax.rem(my - s - 1 + 2 * p, p), lax.rem(my + s + 1, p)]
         if x_blk is not None and s == 0:
-            src = [x_blk] * ndir
+            src = [(x_blk,)] * ndir
         else:
-            src = [o_hbm.at[b] for b in sb[:ndir]]
+            src = [(o_hbm.at[b],) for b in sb[:ndir]]
         st.stream_step(spans_chunks, src, None,
                        [o_hbm.at[b] for b in rb[:ndir]], None)
 
 
-def _hbm_all_reduce_kernel(axis_name, p, op, spans_chunks, depth, ndir,
-                           credits, mesh_ctx, x_hbm, o_hbm, *scratch):
-    """x/o: (p, block_rows, 128) in HBM. ``o_hbm`` is the fold rounds'
-    working buffer: they write every block of it but ``my-1`` (``my+1``
-    on the ccw lane's rows), the gather rounds every block but ``my``,
-    so nothing of ``x_hbm`` is copied ahead of the rounds."""
+def _hbm_all_reduce_kernel(axis_name, p, op, k, spans_chunks, depth, ndir,
+                           credits, mesh_ctx, *refs):
+    """``k`` operands, then o: each (p, block_rows, 128) in HBM, then
+    the scratch. ``o_hbm`` is the fold rounds' working buffer: they
+    write every block of it but ``my-1`` (``my+1`` on the ccw lane's
+    rows), the gather rounds every block but ``my``, so nothing of the
+    operands is copied ahead of the rounds."""
+    xs, (o_hbm, *scratch) = refs[:k], refs[k:]
     my, left, right = _ring_neighbours(axis_name, p)
     st = _mk_streamer(p, ndir, depth, credits, left, right,
-                      scratch[:-1], mesh_ctx, axis_name)
+                      scratch, mesh_ctx, axis_name)
     st.enter()
-    _rs_rounds(st, my, p, ndir, spans_chunks, _reducer(op), x_hbm, o_hbm)
+    _rs_rounds(st, my, p, ndir, spans_chunks, _reducer(op), xs, o_hbm)
     _ag_rounds(st, my, p, ndir, spans_chunks, o_hbm)
     st.finish()
 
 
-def _hbm_reduce_scatter_kernel(axis_name, p, op, spans_chunks, depth,
-                               ndir, credits, mesh_ctx, x_hbm, w_hbm,
-                               o_hbm, *scratch):
+def _hbm_reduce_scatter_kernel(axis_name, p, op, k, spans_chunks, depth,
+                               ndir, credits, mesh_ctx, *refs):
     """The reduce-scatter phase of the allreduce ring alone — the
     per-axis primitive of the multi-axis mesh decomposition. Streams
     the same p-1 fold rounds over the chunk-credit slot schedule: the
-    operand ``x_hbm`` (p, block_rows, 128) is read where it lies, the
+    ``k`` operands (p, block_rows, 128) are read where they lie, the
     partials on their way round live in the working buffer ``w_hbm`` (of
     the same shape), and the last round, which folds block ``my``
     whole, stores straight into the (block_rows, 128) output."""
+    xs, (w_hbm, o_hbm, *scratch) = refs[:k], refs[k:]
     my, left, right = _ring_neighbours(axis_name, p)
     st = _mk_streamer(p, ndir, depth, credits, left, right,
-                      scratch[:-1], mesh_ctx, axis_name)
+                      scratch, mesh_ctx, axis_name)
     st.enter()
-    _rs_rounds(st, my, p, ndir, spans_chunks, _reducer(op), x_hbm, w_hbm,
+    _rs_rounds(st, my, p, ndir, spans_chunks, _reducer(op), xs, w_hbm,
                o_hbm)
     st.finish()
 
@@ -559,7 +631,7 @@ def _hbm_all_gather_kernel(axis_name, p, spans_chunks, depth, ndir,
     which no round reads or writes, runs under the rounds."""
     my, left, right = _ring_neighbours(axis_name, p)
     st = _mk_streamer(p, ndir, depth, credits, left, right,
-                      scratch[:-1], mesh_ctx, axis_name)
+                      scratch, mesh_ctx, axis_name)
     own = pltpu.make_async_copy(x_hbm, o_hbm.at[my], scratch[-1])
     own.start()
     st.enter()
@@ -610,10 +682,11 @@ def _resolve_ndir(num_devices: int, bidirectional) -> int:
 
 def _ring_call(kernel_fn, static, block_rows: int, dtype, cid: int,
                out_shape, interpret, credits, chunk_bytes, depth,
-               num_devices: int, bidirectional, mesh_ctx, operand):
+               num_devices: int, bidirectional, mesh_ctx, *operands):
     """The pallas_call every streaming ring shares: resolve the chunk
     geometry in rows, bind the static schedule into ``kernel_fn`` and
-    launch with all operands left in HBM."""
+    launch with all operands left in HBM. More than one operand is the
+    fold rounds' ``k``: one fold slot more for each beyond the first."""
     interpret, credits = _resolve_flags(interpret, credits)
     chunk = min(_cfg_chunk_rows(dtype, chunk_bytes), block_rows)
     d = _cfg_depth(depth)
@@ -627,17 +700,25 @@ def _ring_call(kernel_fn, static, block_rows: int, dtype, cid: int,
     return pl.pallas_call(
         kernel,
         out_shape=out_shape,
-        in_specs=[hbm],
+        in_specs=[hbm] * len(operands),
         out_specs=(hbm,) * len(out_shape) if multi else hbm,
-        scratch_shapes=_scratch_shapes(ndir, d, chunk, dtype),
+        scratch_shapes=_scratch_shapes(ndir, d, chunk, dtype,
+                                       len(operands) - 1),
         compiler_params=compiler_params(collective_id=cid,
                                         has_side_effects=True),
         interpret=interpret,
         name=kernel_name(kernel_fn),
-    )(operand)
+    )(*operands)
 
 
-def hbm_ring_all_reduce(x: jax.Array, axis_name: str, num_devices: int,
+def _operands(x) -> Tuple[jax.Array, ...]:
+    """The ring's operands as a tuple: ``x`` is one array, or a tuple
+    of ``k`` arrays of one shape and dtype whose fold by the ring's op
+    is this shard's contribution."""
+    return tuple(x) if isinstance(x, (tuple, list)) else (x,)
+
+
+def hbm_ring_all_reduce(x, axis_name: str, num_devices: int,
                         op: str = "sum", *,
                         chunk_bytes: Optional[int] = None,
                         depth: Optional[int] = None,
@@ -647,28 +728,36 @@ def hbm_ring_all_reduce(x: jax.Array, axis_name: str, num_devices: int,
     """Allreduce along ``axis_name`` via the chunked HBM-streaming ring
     (pipelined reduce-scatter + all-gather). Any shape/size: the shard
     is flattened and padded to ``p`` whole-tile blocks with the op
-    identity. ``mesh_ctx``: the surrounding mesh's full ordered
-    (axis, size) tuple when the ring is one phase of a multi-axis
-    decomposition — device ids walk that axis' row-major id line
-    instead of 0..p-1."""
+    identity. ``x`` may be a tuple of ``k`` same-shaped arrays: the
+    shard's contribution is their fold, which the fold rounds make
+    chunk by chunk as they read them (each padded like one; a caller
+    that minds ``k`` copies folds a ragged length first, as
+    ``ici_all_reduce`` does). ``mesh_ctx``: the surrounding mesh's full
+    ordered (axis, size) tuple when the ring is one phase of a
+    multi-axis decomposition — device ids walk that axis' row-major id
+    line instead of 0..p-1."""
     p = num_devices
+    xs = _operands(x)
     if p == 1:
         from .collectives import allreduce
-        return allreduce(x, axis_name, op)
-    shape = x.shape
+        return allreduce(_fold_unless_ring_does(xs, op), axis_name, op)
+    shape, dtype = xs[0].shape, xs[0].dtype
     n = int(np.prod(shape)) if shape else 1
-    rows = _tile_rows(-(-n // p), x.dtype)
+    rows = _tile_rows(-(-n // p), dtype)
     n_pad = p * rows * _LANES
-    flat = x.reshape(n)
-    if n_pad > n:
-        flat = jnp.pad(flat, (0, n_pad - n),
-                       constant_values=_pad_identity(x.dtype, op))
+
+    def blocks(a):
+        flat = a.reshape(n)
+        if n_pad > n:
+            flat = jnp.pad(flat, (0, n_pad - n),
+                           constant_values=_pad_identity(dtype, op))
+        return flat.reshape(p, rows, _LANES)
     out = _ring_call(
-        _hbm_all_reduce_kernel, (axis_name, p, op), rows, x.dtype,
+        _hbm_all_reduce_kernel, (axis_name, p, op, len(xs)), rows, dtype,
         _CID_ALLREDUCE,
-        jax.ShapeDtypeStruct((p, rows, _LANES), x.dtype),
+        jax.ShapeDtypeStruct((p, rows, _LANES), dtype),
         interpret, credits, chunk_bytes, depth, p, bidirectional,
-        mesh_ctx, flat.reshape(p, rows, _LANES))
+        mesh_ctx, *[blocks(a) for a in xs])
     out = out.reshape(n_pad)
     return (out[:n] if n_pad > n else out).reshape(shape)
 
@@ -710,7 +799,7 @@ def all_gather_wire_bytes(nelems: int, dtype, num_devices: int) -> int:
             * np.dtype(dtype).itemsize)
 
 
-def hbm_ring_reduce_scatter(x: jax.Array, axis_name: str,
+def hbm_ring_reduce_scatter(x, axis_name: str,
                             num_devices: int, op: str = "sum", *,
                             chunk_bytes: Optional[int] = None,
                             depth: Optional[int] = None,
@@ -719,26 +808,32 @@ def hbm_ring_reduce_scatter(x: jax.Array, axis_name: str,
                             interpret=None, mesh_ctx=None) -> jax.Array:
     """Reduce-scatter along ``axis_name`` via the chunked HBM-streaming
     ring (the RS phase of the allreduce kernel alone). ``x``: this
-    shard's full contribution [n]; returns block ``my`` of the folded
-    array, [ceil(n/p)] (tiled; the tail blocks carry op-identity pad
-    when p does not divide n)."""
+    shard's full contribution [n], or a tuple of ``k`` such arrays
+    whose fold it is (see ``hbm_ring_all_reduce``); returns block
+    ``my`` of the folded array, [ceil(n/p)] (tiled; the tail blocks
+    carry op-identity pad when p does not divide n)."""
     p = num_devices
+    xs = _operands(x)
     if p == 1:
-        return _xla_reduce_scatter(x, axis_name, p, op)
-    n = int(x.size)
-    flat = x.reshape(n)
+        return _xla_reduce_scatter(_fold_unless_ring_does(xs, op),
+                                   axis_name, p, op)
+    n, dtype = int(xs[0].size), xs[0].dtype
     nblk = -(-n // p)
-    ident = _pad_identity(x.dtype, op)
-    if nblk * p > n:
-        flat = jnp.pad(flat, (0, nblk * p - n), constant_values=ident)
-    rows = _tile_rows(nblk, x.dtype)
+    ident = _pad_identity(dtype, op)
+    rows = _tile_rows(nblk, dtype)
+
+    def blocks(a):
+        flat = a.reshape(n)
+        if nblk * p > n:
+            flat = jnp.pad(flat, (0, nblk * p - n), constant_values=ident)
+        return _as_blocks(flat, p, rows, ident)
     _, out = _ring_call(
-        _hbm_reduce_scatter_kernel, (axis_name, p, op), rows, x.dtype,
-        _CID_REDUCE_SCATTER,
-        (jax.ShapeDtypeStruct((p, rows, _LANES), x.dtype),
-         jax.ShapeDtypeStruct((rows, _LANES), x.dtype)),
+        _hbm_reduce_scatter_kernel, (axis_name, p, op, len(xs)), rows,
+        dtype, _CID_REDUCE_SCATTER,
+        (jax.ShapeDtypeStruct((p, rows, _LANES), dtype),
+         jax.ShapeDtypeStruct((rows, _LANES), dtype)),
         interpret, credits, chunk_bytes, depth, p, bidirectional,
-        mesh_ctx, _as_blocks(flat, p, rows, ident))
+        mesh_ctx, *[blocks(a) for a in xs])
     return _from_blocks(out[None], nblk)
 
 
@@ -900,21 +995,56 @@ def _multi_axis(mesh_ctx) -> bool:
     return bool(mesh_ctx) and len(mesh_ctx) > 1
 
 
-def ici_all_reduce(x: jax.Array, axis_name: str, num_devices: int,
+def ring_folds(tier: str, n: int, dtype, num_devices: int,
+               multi_axis: bool = False) -> bool:
+    """Whether a reduction of ``tier`` folds a shard's ``k`` flat
+    ``[n]`` operands inside its ring's fold rounds: the streaming ring
+    of a 1-D mesh, where each operand makes ``p`` whole-tile blocks as
+    it lies (a pad would be ``k`` copies). Everywhere else they are
+    folded first and the ring takes the one result. Asked by
+    ``ici_all_reduce`` / ``ici_reduce_scatter`` of the operands they
+    were given and by the fold channel's leader for what it counts
+    (coll/device.py ``dev_fold_in_ring``)."""
+    return (tier == "hbm" and not multi_axis
+            and n % (num_devices * _sublanes(dtype) * _LANES) == 0)
+
+
+def _fold_unless_ring_does(xs, op: str, tier: Optional[str] = None,
+                           p: int = 1, mesh_ctx=None):
+    """The dispatchers' operand: ``xs`` as they are where the ring of
+    ``tier`` folds them (``ring_folds``) or there is one; else their
+    slot reduction, the fold the channel traced in front of the ring
+    before the ring could (coll/device.py ``_slot_fold``)."""
+    if len(xs) == 1:
+        return xs[0]
+    if xs[0].ndim == 1 and ring_folds(tier, xs[0].size, xs[0].dtype, p,
+                                      _multi_axis(mesh_ctx)):
+        return xs
+    from ..coll.device import _slot_fold
+    return _slot_fold(xs, op)
+
+
+def ici_all_reduce(x, axis_name: str, num_devices: int,
                    op: str = "sum", interpret=None,
                    mesh_ctx=None) -> jax.Array:
     """Tier-dispatched device allreduce: the engine ``planned_tier``
     names (VMEM-resident flat ring, HBM-streaming chunked ring,
-    quantized wire) or the XLA lowering. The per-call fallback pvar
-    accounting lives in coll/device.py; direct shard_map users are
-    counted once per traced shape."""
+    quantized wire) or the XLA lowering. ``x`` may be a tuple of ``k``
+    same-shaped arrays whose fold is the shard's contribution (two
+    ranks a chip: their deposits): the streaming ring folds them in its
+    rounds (``ring_folds``), every other engine takes their slot
+    reduction. The per-call fallback pvar accounting lives in
+    coll/device.py; direct shard_map users are counted once per traced
+    shape."""
     from .collectives import allreduce
     p = num_devices
+    xs = _operands(x)
     if p == 1:
-        return allreduce(x, axis_name, op)
-    nbytes = x.size * x.dtype.itemsize
-    tier, reason = planned_tier("allreduce", nbytes, x.dtype, op, interpret,
-                                p, _multi_axis(mesh_ctx))
+        return allreduce(_fold_unless_ring_does(xs, op), axis_name, op)
+    nbytes = xs[0].size * xs[0].dtype.itemsize
+    tier, reason = planned_tier("allreduce", nbytes, xs[0].dtype, op,
+                                interpret, p, _multi_axis(mesh_ctx))
+    x = _fold_unless_ring_does(xs, op, tier, p, mesh_ctx)
     _trace_entry("allreduce", tier, nbytes, op=op)
     if tier == "quant":
         from . import pallas_quant
@@ -957,18 +1087,21 @@ def ici_all_gather(x: jax.Array, axis_name: str, num_devices: int,
     return lax.all_gather(x, axis_name, tiled=True)
 
 
-def ici_reduce_scatter(x: jax.Array, axis_name: str, num_devices: int,
+def ici_reduce_scatter(x, axis_name: str, num_devices: int,
                        op: str = "sum", interpret=None,
                        mesh_ctx=None) -> jax.Array:
     """Tier-dispatched device reduce-scatter (tiled): this shard's
     block of the axis-folded array, [ceil(n/p)], by the chunked HBM
-    engine (the one with a reduce-scatter entry) or the XLA lowering."""
+    engine (the one with a reduce-scatter entry) or the XLA lowering.
+    ``x`` may be a tuple of ``k`` arrays, as for ``ici_all_reduce``."""
     p = num_devices
+    xs = _operands(x)
     if p == 1:
-        return x.reshape(-1)
-    nbytes = x.size * x.dtype.itemsize
-    tier, reason = planned_tier("reduce_scatter_block", nbytes, x.dtype, op,
-                                interpret, p, _multi_axis(mesh_ctx))
+        return _fold_unless_ring_does(xs, op).reshape(-1)
+    nbytes = xs[0].size * xs[0].dtype.itemsize
+    tier, reason = planned_tier("reduce_scatter_block", nbytes, xs[0].dtype,
+                                op, interpret, p, _multi_axis(mesh_ctx))
+    x = _fold_unless_ring_does(xs, op, tier, p, mesh_ctx)
     _trace_entry("reduce_scatter", tier, nbytes, op=op)
     if tier == "hbm":
         return hbm_ring_reduce_scatter(x, axis_name, p, op,

@@ -226,7 +226,7 @@ class _QuantStreamer(_RingStreamer):
     def _wlen(self, sz: int) -> int:
         return wire_words(sz, self.block)
 
-    def issue(self, d, sb_off, off, sz, rb_off):
+    def issue(self, d, sb_off, off, sz, rb_off, red=None):
         slot = self.gc[d] % self.depth
         prev = self.pending_send.pop((d, slot), None)
         if prev is not None:

@@ -344,17 +344,19 @@ def default_tier_edges(monkeypatch):
     ("allreduce", 64 * MiB, "mv2t_hbm_all_reduce"),
     ("reduce_scatter_block", 4 * MiB, "mv2t_hbm_reduce_scatter")],
     ids=["allreduce_cell", "reduce_scatter_block"])
-def test_fused_fold_program_is_two_kernels_and_the_root_copy(
+def test_fused_fold_program_is_one_kernel_and_the_root_copy(
         mesh4, monkeypatch, default_tier_edges, coll, nbytes, ring):
-    """``osu4.allreduce_2level.64MiB.dev``'s one program since ISSUE 44,
-    as the fold channel's leader builds it (``extra=k``): two flat
+    """``osu4.allreduce_2level.64MiB.dev``'s one program (ISSUE 44), as
+    the fold channel's leader builds it (``extra=k``): two flat
     mesh-sharded operands, per chip two parameters of the deposit's
-    size as they lie. Compiled for the four chips it is
-    ``mv2t_slot_reduce``, then the ring kernel, between bitcasts, and
-    the one ROOT copy every four-chip program has: no stack, relayout
-    or fusion between a parameter and the fold kernel, nothing aliased
-    (the callers keep their buffers). The same for
-    reduce_scatter_block, at a small size."""
+    size as they lie. Compiled for the four chips it is, since ISSUE
+    49, the ring kernel alone between bitcasts, both parameters its
+    operands, and the one ROOT copy every four-chip program has: no
+    ``mv2t_slot_reduce`` in front of the ring and no buffer of the
+    deposit's size for a fold result (the kernel's outputs and the ROOT
+    copy are all the program makes), no stack, relayout or fusion between a parameter and
+    the kernel, nothing aliased (the callers keep their buffers). The
+    same for reduce_scatter_block, at a small size."""
     import re
 
     import jax
@@ -373,11 +375,21 @@ def test_fused_fold_program_is_two_kernels_and_the_root_copy(
         [[str(n)]] * 2, entry
     ops = [op for op, _ in entry if op not in (
         "parameter", "bitcast", "get-tuple-element", "tuple")]
-    assert ops == ["custom-call", "custom-call", "copy"], ops
+    assert ops == ["custom-call", "copy"], ops
     # a Pallas call's instruction carries its kernel's name
     calls = re.findall(r"%(\w+?)(?:\.\d+)? = [^=]*? custom-call\(",
                        text[text.index("ENTRY"):])
-    assert calls == ["mv2t_slot_reduce", ring], calls
+    assert calls == [ring], calls
+    assert "mv2t_slot_reduce" not in text
+    # what the program allocates of a deposit's size: the kernel's
+    # outputs (the allreduce's result; the reduce-scatter's working
+    # buffer) and nothing for a fold result
+    def elems(dims):
+        return int(np.prod([int(d) for d in dims])) if dims else 0
+    made = [op for op, dims in entry
+            if op not in ("parameter", "bitcast") and elems(dims) >= n]
+    assert made == (["custom-call", "copy"] if coll == "allreduce"
+                    else []), made      # the latter's are one tuple
     assert not [dims for _, dims in entry if dims[:1] == ["1"]], entry
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes == 2 * nbytes

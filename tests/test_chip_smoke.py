@@ -81,6 +81,7 @@ def test_fold_phase_under_the_interpreter(monkeypatch, capsys):
     stacked0 = mpit.pvar("dev_fold_stacked").read()
     operands0 = mpit.pvar("dev_fold_operands").read()
     fused0 = mpit.pvar("dev_fold_fused").read()
+    in_ring0 = mpit.pvar("dev_fold_in_ring").read()
     try:
         chip_smoke.fold_phase(seed=3, nbytes=16 * 1024)
     finally:
@@ -90,12 +91,16 @@ def test_fold_phase_under_the_interpreter(monkeypatch, capsys):
     assert out.count("bit-equal to numpy on 8 ranks over 4 chips") == 6
     # the deposits are device arrays on their chips: the reduce family
     # folds them as they lie (ISSUE 41), inside the mesh program, one
-    # launch a call (ISSUE 44); allgather alone still makes a planar
-    # copy a chip. The phase asserts the three itself, and says them
+    # launch a call (ISSUE 44), and at 16 KiB, past this test's VMEM
+    # edge, inside the ring kernel's fold rounds (ISSUE 49); allgather
+    # alone still makes a planar copy a chip. The phase asserts the four
+    # itself, and says them
     assert mpit.pvar("dev_fold_stacked").read() - stacked0 == 4 * 1
     assert mpit.pvar("dev_fold_operands").read() - operands0 == 4
     assert mpit.pvar("dev_fold_fused").read() - fused0 == 4
+    assert mpit.pvar("dev_fold_in_ring").read() - in_ring0 == 4
     assert "'dev_fold_fused': 4" in out
+    assert "'dev_fold_in_ring': 4" in out
 
 
 @pytest.mark.parametrize("chips", [1, 4])

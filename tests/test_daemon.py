@@ -316,11 +316,24 @@ def _slot_alltoall(comm):
     assert out.shape == (n,) and np.array_equal(out, want)
 
 
-@pytest.mark.parametrize("ranks,ndev,app", [
-    (4, 4, _mesh_allreduce), (8, 1, _slot_alltoall)],
-    ids=["mesh-allreduce", "slot-alltoall"])
+def _fold_allreduce(comm):
+    import jax
+    import numpy as np
+    x = jax.device_put(np.full(16384, float(comm.rank + 1), np.float32),
+                       comm.device_channel.device)
+    out = np.asarray(comm.allreduce(x))
+    assert out[0] == sum(range(1, comm.size + 1))
+
+
+@pytest.mark.parametrize("ranks,ndev,app,exports", [
+    (4, 4, _mesh_allreduce, True), (8, 1, _slot_alltoall, True),
+    # the fused program's interpreted slot reduction resists export on
+    # the CPU: its key is asked for and nothing is there to load
+    (8, 4, _fold_allreduce, False)],
+    ids=["mesh-allreduce", "slot-alltoall", "fold-allreduce"])
 def test_exec_cache_parent_artifact_is_never_offered(ddir, monkeypatch,
-                                                     ranks, ndev, app):
+                                                     ranks, ndev, app,
+                                                     exports):
     """A program's signature can change under an unchanged (name, n,
     dtype, op, root, extra): the mesh programs' operand became flat
     (ISSUE 29, key ``mv2t-exec-v1`` -> ``v2``), the slot channel's
@@ -331,7 +344,12 @@ def test_exec_cache_parent_artifact_is_never_offered(ddir, monkeypatch,
     channel's reduce_scatter_block became the ring kernel where it was
     XLA's psum_scatter (ISSUE 42, ``v3`` -> ``v4``; the parent's
     artifact is right and is not the program the call counts itself
-    as). An artifact a parent of any of these changes exported on this
+    as), the fold channel's fused program hands its ``k`` operands to
+    the ring, whose rounds fold them, where it held the slot-reduce
+    kernel and then the ring (ISSUE 49, ``v4`` -> ``v5``; the parent's
+    two-kernel artifact is right too, and is not what
+    ``dev_fold_in_ring`` says ran). An artifact a parent of any of these
+    changes exported on this
     machine is never asked for
     and never deserialized, whatever else of its key matches; the
     second job, which does load what the first exported, is still
@@ -353,16 +371,17 @@ def test_exec_cache_parent_artifact_is_never_offered(ddir, monkeypatch,
                         lambda b: offered.append(b) or load(b))
 
     run_ranks(ranks, app, device_mesh=mesh)
-    assert asked and all(k.startswith("mv2t-exec-v4|") for k in asked)
+    assert asked and all(k.startswith("mv2t-exec-v5|") for k in asked)
     poison = b"artifact of a parent's program"
     for k in set(asked):    # the parents' keys for the same signature
-        for old in ("mv2t-exec-v1|", "mv2t-exec-v2|", "mv2t-exec-v3|"):
+        for old in ("mv2t-exec-v1|", "mv2t-exec-v2|", "mv2t-exec-v3|",
+                    "mv2t-exec-v4|"):
             assert daemon.exec_cache_put(
-                k.replace("mv2t-exec-v4|", old, 1), poison, ddir)
+                k.replace("mv2t-exec-v5|", old, 1), poison, ddir)
     del asked[:]
     run_ranks(ranks, app, device_mesh=mesh)     # fresh channels ask again
-    assert asked and all(k.startswith("mv2t-exec-v4|") for k in asked)
-    assert offered and poison not in offered
+    assert asked and all(k.startswith("mv2t-exec-v5|") for k in asked)
+    assert bool(offered) == exports and poison not in offered
     _reload(MV2T_DAEMON_DIR=None, MV2T_ALLREDUCE_ALGO=None,
             MV2T_DEVICE_COLL_MIN_BYTES=None)
 

@@ -269,7 +269,8 @@ PHASE_ARGS = {"seq", "coll"}
 # what a site learns after its B, on the E alone
 ADDED = {"dev_dispatch": "built", "dev_collect": "parts",
          "dev_deliver": "relaid"}
-FOLD_ADDED = {"k", "chips", "stacked", "fused"}     # dev_chip_fold E's own
+FOLD_ADDED = {"k", "chips", "stacked", "fused",
+              "in_ring"}                           # dev_chip_fold E's own
 
 
 @pytest.mark.parametrize("channel", list(CHANNELS))
@@ -318,8 +319,10 @@ def test_the_chip_fold_span_says_what_level_1_copied(traced, resident,
     """The fold leader's ``dev_chip_fold`` E of an allreduce: two ranks
     a chip on four chips, and no planar copy where the deposits lie flat
     on their chips (ISSUE 41: they are the fold's operands; ISSUE 44:
-    inside the mesh program, ``fused``); a host deposit is still staged,
-    one copy a chip, and folded by a launch a chip."""
+    inside the mesh program, ``fused``; ISSUE 49: not ``in_ring`` where
+    the kernels do not run and the mesh collective is XLA's); a host
+    deposit is still staged, one copy a chip, and folded by a launch a
+    chip."""
     ranks = CHANNELS["fold"][0]
     lanes = {}
 
@@ -337,7 +340,7 @@ def test_the_chip_fold_span_says_what_level_1_copied(traced, resident,
             for rank, lane in lanes.items()}
     assert ends.pop(0) == [{"seq": 1, "coll": "allreduce", "k": 2,
                             "chips": 4, "stacked": stacked,
-                            "fused": resident}]
+                            "fused": resident, "in_ring": False}]
     assert not any(ends.values())       # the leader's span alone
 
 

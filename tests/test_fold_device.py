@@ -9,7 +9,10 @@ else. Level 1 of the reduce family takes a chip's deposits as its
 operands where they lie flat on the chip (ISSUE 41) and stages anything
 else; where every chip's lie so it rides in the level-2 mesh program,
 one launch a call (ISSUE 44: ``dev_fold_fused``), bit-equal to a launch
-a chip. No test here asserts a time."""
+a chip; where the streaming ring takes the call and the deposits make
+whole-tile ring blocks the ring's fold rounds read both deposits and no
+slot reduction runs (ISSUE 49: ``dev_fold_in_ring``). No test here
+asserts a time."""
 
 import jax
 import numpy as np
@@ -39,7 +42,8 @@ FOLDED = ("allreduce_sum", "allreduce_max", "reduce_scatter_block",
           "reduce")     # level 1 is _fold_chip
 LEVELS = ("coll_level_chip", "coll_level_ici")
 COUNTED = LEVELS + ("dev_fold_stacked", "dev_fold_operands",
-                    "dev_fold_fused", "dev_call_plan_hit",
+                    "dev_fold_fused", "dev_fold_in_ring",
+                    "dev_coll_tier_hbm", "dev_call_plan_hit",
                     "dev_call_plan_filed", "dev_deposit_as_is")
 
 
@@ -101,11 +105,13 @@ def _on_host(comm, x):
     return x.copy()     # a host bcast writes its buffer in place
 
 
-def _run(case, seed, calls=1, after=None, n=N, deposit=_on_own_chip):
+def _run(case, seed, calls=1, after=None, n=N, deposit=_on_own_chip,
+         mesh=None):
     """``calls`` calls of ``case`` on what ``deposit(comm, values)``
     makes of a rank's values (device-resident on its own chip unless
-    said); every rank's last result read back, the devices it lay on,
-    and what ``after(comm)`` returned."""
+    said), over the four chips in a line unless another ``mesh`` of
+    them is given; every rank's last result read back, the devices it
+    lay on, and what ``after(comm)`` returned."""
     data = _inputs(seed, n)
     got, homes, extra = [None] * RANKS, [None] * RANKS, [None] * RANKS
 
@@ -123,8 +129,8 @@ def _run(case, seed, calls=1, after=None, n=N, deposit=_on_own_chip):
         if after is not None:
             extra[comm.rank] = after(comm)
 
-    run_ranks(RANKS, app,
-              device_mesh=make_mesh((CHIPS,), ("x",), jax.devices()[:CHIPS]))
+    run_ranks(RANKS, app, device_mesh=mesh or make_mesh(
+        (CHIPS,), ("x",), jax.devices()[:CHIPS]))
     return data, got, homes, extra
 
 
@@ -149,11 +155,13 @@ def test_device_resident_deposits_match_the_plain_reference(case):
 @pytest.mark.parametrize("case", list(CASES))
 def test_levels_copies_and_plans_are_counted(case):
     """Both levels rise per rank per call; on device-resident deposits
-    the reduce family makes no planar copy and ``dev_fold_operands`` and
-    ``dev_fold_fused`` rise by one per leader call (the deposits went
-    over as they lay, into the one mesh program), allgather still copies
-    a chip's two; the first call of the signature files a plan and the
-    two after run on it."""
+    the reduce family makes no planar copy and ``dev_fold_operands``,
+    ``dev_fold_fused`` and ``dev_fold_in_ring`` rise by one per leader
+    call (the deposits went over as they lay, into the one mesh program,
+    and at ``N``, four whole-tile blocks on the streaming tier, into the
+    ring kernel), allgather still copies a chip's two; every call counts
+    the tier once a rank, as before; the first call of the signature
+    files a plan and the two after run on it."""
     calls = 3
     before = _reads()
     _run(case, seed=38, calls=calls)
@@ -161,7 +169,9 @@ def test_levels_copies_and_plans_are_counted(case):
     assert all(rose[lv] == RANKS * calls for lv in LEVELS), rose
     assert rose["dev_fold_stacked"] == CASES[case][2] * calls, rose
     assert rose["dev_fold_operands"] == rose["dev_fold_fused"] == \
-        (calls if case in FOLDED else 0), rose
+        rose["dev_fold_in_ring"] == (calls if case in FOLDED else 0), rose
+    assert rose["dev_coll_tier_hbm"] == \
+        (0 if case == "bcast" else RANKS * calls), rose
     assert rose["dev_call_plan_filed"] == RANKS, rose
     assert rose["dev_call_plan_hit"] == RANKS * (calls - 1), rose
     assert rose["dev_deposit_as_is"] == RANKS * calls, rose
@@ -184,7 +194,8 @@ def test_host_deposits_are_staged_and_match_the_plain_reference(case):
         assert np.count_nonzero(got[r] != want) == 0, (case, r)
     assert all(rose[lv] == RANKS * calls for lv in LEVELS), rose
     assert rose["dev_fold_stacked"] == HOST_STACKED[case] * calls, rose
-    assert rose["dev_fold_operands"] == rose["dev_fold_fused"] == 0, rose
+    assert rose["dev_fold_operands"] == rose["dev_fold_fused"] == \
+        rose["dev_fold_in_ring"] == 0, rose
     assert rose["dev_deposit_as_is"] == rose["dev_call_plan_filed"] == 0
 
 
@@ -337,8 +348,10 @@ def test_operand_form_is_bit_equal_to_the_stacked_form(op, n):
 
 def test_a_ragged_length_still_goes_in_as_it_lies():
     """``n % 128 != 0``: the deposits are still the program's operands
-    (no eager stack, and the one fused program); the program pads them
-    itself and the result agrees with ``numpy``."""
+    (no eager stack, and the one fused program); the program stacks,
+    pads and folds them itself in front of the ring (in the ring a pad
+    would be ``k`` copies: not ``dev_fold_in_ring``) and the result
+    agrees with ``numpy``."""
     before = _reads()
     data, got, homes, _ = _run("allreduce_sum", seed=9, n=1000)
     rose = {n: v - before[n] for n, v in _reads().items()}
@@ -347,7 +360,52 @@ def test_a_ragged_length_still_goes_in_as_it_lies():
         assert np.array_equal(got[r], want), r
         assert homes[r][0] == homes[r][1]
     assert (rose["dev_fold_stacked"], rose["dev_fold_operands"],
-            rose["dev_fold_fused"]) == (0, 1, 1)
+            rose["dev_fold_fused"], rose["dev_fold_in_ring"]) == (0, 1, 1, 0)
+
+
+# where level 1 stays in front of the level-2 collective: (elements a
+# rank, MV2T_DEV_TIER_VMEM_MAX, the four chips' mesh, dev_fold_fused a
+# call, the tier pvar counted once a rank a call)
+FOLDS_FIRST = {
+    # a ring block of 750 elements: the ring would pad each operand
+    "ragged": (3000, "8192", ((CHIPS,), ("x",)), 1, "dev_coll_tier_hbm"),
+    # N under the streaming tier's edge: a sum rides the flat VMEM ring
+    "under_the_hbm_tier": (N, "65536", ((CHIPS,), ("x",)), 1,
+                           "dev_coll_tier_vmem"),
+    # 2 x 2: the chips' folds go to the unfused multi-axis program
+    "multi_axis": (N, "8192", ((2, 2), ("x", "y")), 0, None),
+}
+
+
+@pytest.mark.parametrize("why", list(FOLDS_FIRST))
+@pytest.mark.parametrize("case", ["allreduce_sum", "reduce"])
+def test_level_1_outside_the_ring_is_not_counted_in_ring(monkeypatch, traced,
+                                                         case, why):
+    """``dev_fold_in_ring`` is the streaming ring of a 1-D mesh on
+    whole-tile blocks and nothing else: a ragged length, a message under
+    the ``hbm`` tier's edge and a multi-axis mesh fold first, as before
+    ISSUE 49 (``dev_fold_fused`` as then), the E says ``in_ring``
+    false, both levels still rise once a rank a call, and the result is
+    the plain reference's."""
+    n, vmem_max, (shape, axes), fused, tier = FOLDS_FIRST[why]
+    monkeypatch.setenv("MV2T_DEV_TIER_VMEM_MAX", vmem_max)
+    get_config().reload()
+    before = _reads()
+    tier0 = mpit.pvar(tier).read() if tier else 0
+    data, got, _, lanes = _run(
+        case, seed=2**31 + 49, after=_device_lane, n=n,
+        mesh=make_mesh(shape, axes, jax.devices()[:CHIPS]))
+    rose = {n: v - before[n] for n, v in _reads().items()}
+    for r, want in enumerate(_want(case, data)):
+        if want is not None:
+            assert np.count_nonzero(got[r] != want) == 0, (case, why, r)
+    assert all(rose[lv] == RANKS for lv in LEVELS), rose
+    assert (rose["dev_fold_fused"], rose["dev_fold_in_ring"]) == (fused, 0)
+    if tier:
+        assert mpit.pvar(tier).read() - tier0 == RANKS
+    (end,) = [a for _t, _l, n, ph, a in lanes[0]
+              if n == "dev_chip_fold" and ph == "E"]
+    assert (end["fused"], end["in_ring"]) == (bool(fused), False)
 
 
 @pytest.mark.parametrize("where", ["another_chip", "host"])
@@ -375,7 +433,7 @@ def test_one_deposit_off_its_chip_sends_the_call_down_the_unfused_arm(
         if homes[r] is not None:        # a host caller gets a host array
             assert homes[r][0] == homes[r][1], (case, r)
     assert (rose["dev_fold_stacked"], rose["dev_fold_operands"],
-            rose["dev_fold_fused"]) == (1, 0, 0)
+            rose["dev_fold_fused"], rose["dev_fold_in_ring"]) == (1, 0, 0, 0)
 
 
 def test_a_chip_answering_with_one_array_reaches_the_ring_as_it_is(
@@ -411,9 +469,9 @@ def _device_lane(comm):
 def test_chip_fold_span_lies_in_the_leaders_stage(traced, case):
     """``dev_chip_fold``: a B/E pair of the device lane on rank 0 only,
     inside ``dev_stage``, ``seq`` and ``coll`` on both, the E adding
-    ``k``, ``chips``, ``stacked`` and ``fused`` (the reduce family on
-    deposits that lie: level 1 went into the mesh program); the second
-    call says ``planned``."""
+    ``k``, ``chips``, ``stacked``, ``fused`` and ``in_ring`` (the reduce
+    family on deposits that lie: level 1 went into the mesh program, and
+    at ``N`` into its ring kernel); the second call says ``planned``."""
     name, _op, stacked = CASES[case]
     _, _, _, lanes = _run(case, seed=7, calls=2, after=_device_lane)
     for rank, lane in enumerate(lanes):
@@ -431,7 +489,8 @@ def test_chip_fold_span_lies_in_the_leaders_stage(traced, case):
             extra = {k: v for k, v in a.items() if k not in ("seq", "coll")}
             assert extra == ({} if ph == "B" else
                              {"k": K, "chips": CHIPS, "stacked": stacked,
-                              "fused": case in FOLDED})
+                              "fused": case in FOLDED,
+                              "in_ring": case in FOLDED})
         # nested: stage B, fold B, fold E, stage E, in that order
         order = [(n, ph) for _t, _l, n, ph, _a in lane
                  if n in ("dev_stage", "dev_chip_fold")]

@@ -283,34 +283,48 @@ def _ring_expect(coll, xv, p):
     return full.reshape(p, nblk)
 
 
-@pytest.mark.parametrize("coll", sorted(_RING_FNS))
-@pytest.mark.parametrize("p,bidir,shard,chunk_bytes", [
-    (2, None, 44 * ROW - 5, 4096),   # one lane (p = 2), ragged, 6 chunks
-    (4, False, 24 * ROW, 4096),      # one lane, 3 chunks a block
-    (4, True, 44 * ROW - 5, 4096),   # two lanes, ragged shard, uneven
-    (8, False, 37, 64),              # one tile a block (see the note above)
-    (8, True, 16 * ROW + 3, 4096),   # two lanes, ragged, one chunk each
-])
-def test_operand_is_left_as_it_was(coll, p, bidir, shard, chunk_bytes):
+_LEFT_AS_IT_WAS = [
+    # (coll, k operands, p, bidir, shard, chunk_bytes)
+    (coll, 1, *shape) for coll in sorted(_RING_FNS) for shape in [
+        (2, None, 44 * ROW - 5, 4096),   # one lane (p = 2), ragged, 6 chunks
+        (4, False, 24 * ROW, 4096),      # one lane, 3 chunks a block
+        (4, True, 44 * ROW - 5, 4096),   # two lanes, ragged shard, uneven
+        (8, False, 37, 64),              # one tile a block (see the note above)
+        (8, True, 16 * ROW + 3, 4096),   # two lanes, ragged, one chunk each
+    ]] + [
+    # the fold rounds on k operands (ISSUE 49): each only ever read
+    (coll, k, *shape) for coll in ("all_reduce", "reduce_scatter")
+    for k, shape in [
+        (2, (2, None, 44 * ROW - 5, 4096)),
+        (2, (4, True, 48 * ROW, 8192)),  # 24-row lanes, a short last chunk
+        (3, (4, False, 24 * ROW, 4096)),
+    ]]
+
+
+@pytest.mark.parametrize("coll,k,p,bidir,shard,chunk_bytes", _LEFT_AS_IT_WAS)
+def test_operand_is_left_as_it_was(coll, k, p, bidir, shard, chunk_bytes):
     """The kernels read the send buffer where it lies, so what the
     caller handed in is bit for bit what it was after the call, and the
-    answer is numpy's."""
+    answer is numpy's: of one operand, or of the ``k`` whose sum is the
+    shard's contribution."""
     comm = MeshComm(make_mesh((p,), ("x",), jax.devices()[:p]))
     # an odd shard leaves p not dividing the reduce-scatter's input
     n = shard if coll == "all_gather" else p * shard - (shard % 2)
-    xv = ((np.arange(p * n) * 7 + 3) % 11).astype(np.float32)
-    xj = jnp.asarray(xv)
+    xvs = [((np.arange(p * n) * 7 + 3 + 5 * i) % 11).astype(np.float32)
+           for i in range(k)]
+    xjs = [jnp.asarray(xv) for xv in xvs]
     fn = _RING_FNS[coll]
 
-    def body(s):
-        return fn(s, "x", p, chunk_bytes=chunk_bytes, bidirectional=bidir,
-                  interpret=True), s
+    def body(*s):
+        return fn(s if k > 1 else s[0], "x", p, chunk_bytes=chunk_bytes,
+                  bidirectional=bidir, interpret=True), s
 
-    out, seen = comm.run(body, xj, out_specs=(P("x"), P("x")))
-    np.testing.assert_array_equal(np.asarray(xj), xv)
-    np.testing.assert_array_equal(np.asarray(seen), xv)
+    out, seen = comm.run(body, *xjs, out_specs=(P("x"), (P("x"),) * k))
+    for xv, xj, sj in zip(xvs, xjs, seen):
+        np.testing.assert_array_equal(np.asarray(xj), xv)
+        np.testing.assert_array_equal(np.asarray(sj), xv)
     np.testing.assert_array_equal(np.asarray(out).reshape(p, -1),
-                                  _ring_expect(coll, xv, p))
+                                  _ring_expect(coll, sum(xvs), p))
 
 
 def _ring_order_fold(blocks, red):
@@ -361,6 +375,56 @@ def test_fold_order_is_the_rings(comm4, coll, op, dtype):
         np.testing.assert_array_equal(got.reshape(-1), exp)
 
 
+def _fold_then_ring(fn, red):
+    """``fn`` on the operands' fold, made in front of it, first operand
+    first: what the fold channel's program was until ISSUE 49."""
+    import functools
+    return lambda xs, *a, **kw: fn(functools.reduce(red, xs), *a, **kw)
+
+
+_ONE_LANE, _TWO_LANES = (False, 4096), (True, 8192)    # the latter leaves
+_K_OPERANDS = [                                        # a short last chunk
+    # (k, op, dtype, (bidirectional, chunk_bytes))
+    (2, "sum", "float32", _ONE_LANE), (2, "max", "float32", _TWO_LANES),
+    (2, "min", "bfloat16", _TWO_LANES), (2, "sum", "bfloat16", _ONE_LANE),
+    (3, "sum", "float32", _TWO_LANES), (3, "max", "bfloat16", _ONE_LANE),
+]
+
+
+@pytest.mark.parametrize("k,op,dtype,lanes", _K_OPERANDS)
+@pytest.mark.parametrize("coll", ["all_reduce", "reduce_scatter"])
+def test_k_operands_fold_in_the_rounds(comm4, coll, k, op, dtype, lanes):
+    """The fold rounds on ``k`` operands a shard (two ranks a chip:
+    their deposits): a chunk read from the operands is read from all
+    ``k`` and folded in VMEM, ``red(red(x0, x1, ...), arrival)``. Every
+    other element is a value whose sums depend on the order, the ones
+    between small whole numbers that any order sums exactly in both
+    types: the result is bit for bit fold-then-ring's on all of them and
+    the plain reference's on the whole numbers; one and two lanes,
+    16-row bfloat16 tiles, a short last chunk."""
+    blk = 48 * ROW      # 24-row lanes: 8192 B chunks leave 16 + 8 rows
+    rng = np.random.default_rng([49, k])
+    n = P4 * P4 * blk
+    xvs = [np.where(np.arange(n) % 2, rng.uniform(0.5, 1.5, n),
+                    rng.integers(-9, 9, n)).astype(np.float32)
+           for _ in range(k)]
+    xjs = [jnp.asarray(xv, dtype=dtype) for xv in xvs]
+    fn = _RING_FNS[coll]
+    kw = dict(chunk_bytes=lanes[1], bidirectional=lanes[0], interpret=True)
+    got = comm4.run(lambda *s: fn(s, "x", P4, op, **kw), *xjs,
+                    out_specs=P("x"))
+    apart = comm4.run(
+        lambda *s: _fold_then_ring(fn, pallas_ici._reducer(op))(
+            s, "x", P4, op, **kw), *xjs, out_specs=P("x"))
+    assert got.dtype == apart.dtype == xjs[0].dtype
+    assert np.asarray(got).tobytes() == np.asarray(apart).tobytes()
+    want = getattr(np, op)(np.stack(xvs).reshape(k * P4, -1), axis=0)
+    rows = np.asarray(got.astype(jnp.float32))
+    for row in (rows.reshape(P4, -1) if coll == "all_reduce"
+                else rows.reshape(1, -1)):
+        np.testing.assert_array_equal(row[::2], want[::2])
+
+
 def _sub_jaxprs(params):
     for v in params.values():
         for sub in (v if isinstance(v, (list, tuple)) else [v]):
@@ -369,12 +433,15 @@ def _sub_jaxprs(params):
                 yield sub
 
 
-def _eqns(jaxpr, name):
+def _all_eqns(jaxpr):
     for e in jaxpr.eqns:
-        if e.primitive.name == name:
-            yield e
+        yield e
         for sub in _sub_jaxprs(e.params):
-            yield from _eqns(sub, name)
+            yield from _all_eqns(sub)
+
+
+def _eqns(jaxpr, name):
+    return (e for e in _all_eqns(jaxpr) if e.primitive.name == name)
 
 
 def _local_dmas(coll, p, n, hbm_names, **kw):
@@ -436,6 +503,64 @@ def test_no_whole_operand_copy_in_front_of_the_rounds(coll, hbm):
     else:
         assert loads.count("o") == (rounds - 1 + rounds) * chunks
         assert stores.count("o") == 2 * rounds * chunks
+
+
+def _kernel_ops(coll, k, p, n, **kw):
+    """What the traced kernel of ``coll`` on ``k`` operands holds: its
+    primitives counted by name, and ``vpu``, those among them that
+    compute a whole chunk (the reducer's; loads and stores of VMEM are
+    ``get`` and ``swap``)."""
+    import collections
+    comm = MeshComm(make_mesh((p,), ("x",), jax.devices()[:p]))
+    traced = jax.make_jaxpr(lambda *xs: comm.run(
+        lambda *s: _RING_FNS[coll](s if k > 1 else s[0], "x", p,
+                                   interpret=True, **kw), *xs,
+        out_specs=P("x")))(*[jnp.zeros(p * n, jnp.float32)] * k)
+    (call,) = _eqns(traced.jaxpr, "pallas_call")
+    ops = collections.Counter()
+    for e in _all_eqns(call.params["jaxpr"]):
+        ops[e.primitive.name] += 1
+        if e.primitive.name not in ("get", "swap") and any(
+                len(getattr(v.aval, "shape", ())) >= 2 for v in e.outvars):
+            ops["vpu"] += 1
+    return ops
+
+
+# the parent of ISSUE 49 (commit c492591), p = 4, two lanes, 3 chunks a
+# lane, read off its traced kernels: three other cells run these
+_PARENT_OPS = {
+    "all_reduce": dict(dma_start=126, dma_wait=162, get=36, swap=18, vpu=18,
+                       semaphore_signal=40, semaphore_wait=39),
+    "reduce_scatter": dict(dma_start=72, dma_wait=90, get=36, swap=18,
+                           vpu=18, semaphore_signal=22, semaphore_wait=21),
+    "all_gather": dict(dma_start=55, dma_wait=73, get=0, swap=0, vpu=0,
+                       semaphore_signal=22, semaphore_wait=21),
+}
+
+
+@pytest.mark.parametrize("coll,k", [
+    ("all_reduce", 1), ("reduce_scatter", 1), ("all_gather", 1),
+    ("all_reduce", 2), ("reduce_scatter", 2), ("reduce_scatter", 3)])
+def test_one_operand_is_the_parents_kernel_and_k_add_a_load_a_chunk(coll, k):
+    """At ``k = 1`` the traced kernel holds the DMA starts and waits,
+    the VMEM reads and writes, the chunk-wide VPU ops and the semaphore
+    ops it held before the fold rounds took ``k`` operands, count for
+    count. Each further operand adds, a chunk of a fold round, one load
+    (a start and a wait), one VMEM read and one VPU op for the
+    accumulator, and in round 0 the same again for the send chunk,
+    which is read and written back once; not one store, remote DMA or
+    semaphore op more."""
+    chunks, rounds = 2 * 3, P4 - 1
+    n = 48 * ROW if coll == "all_gather" else P4 * 48 * ROW
+    ops = _kernel_ops(coll, k, P4, n, chunk_bytes=4096)
+    want = dict(_PARENT_OPS[coll])
+    more = (k - 1) * (rounds + 1) * chunks      # loads of the others
+    for name in ("dma_start", "dma_wait", "get", "vpu"):
+        want[name] += more
+    if k > 1:       # round 0's send chunks, read and written back folded
+        want["get"] += chunks
+        want["swap"] += chunks
+    assert {name: ops[name] for name in want} == want
 
 
 # ---------------------------------------------------------------------------
