@@ -22,6 +22,8 @@ stage, look the program up and call it, hand out); the 1:1 mesh channel,
 the one-device slot channel and the leaders-per-chip fold channel differ
 in two hooks, ``_stage`` (the program's key and operands out of what was
 deposited) and ``_hand_out`` (every rank's result out of the output).
+No leader waits for the device: a result is handed out at the enqueue
+and its caller waits for it.
 Which kernel a program holds is not decided here: the lowering asks the
 kernel modules' one tier rule (``ops/pallas_ici.planned_tier``, with its
 amendments for the op, the collective and the mesh inside it), and
@@ -234,8 +236,6 @@ class _ImportedProgram:
 #   dev_dispatch     rank 0: program-cache lookup + enqueue, in
 #                    ``_leader``'s own frame; its E says ``built`` when
 #                    the call made or loaded the program
-#   dev_device_wait  rank 0, slot channel, in its ``_hand_out``: the
-#                    leader's block_until_ready
 #   dev_collect      rank 0, in ``_hand_out``: one result per rank out of
 #                    the output; its
 #                    E says ``parts``, the arrays cut out of it by eager
@@ -340,8 +340,10 @@ _KEYED_ON_RESULT = ("allgather",)
 class _Gate:
     """The blocking collectives' one meeting point. Every rank counts
     itself in (``arrive``) and only the leader waits for the count; the
-    others wait to be let out (``leave``), which the leader does when
-    the results lie ready (``open``). They leave one at a time, in the
+    others wait to be let out (``leave``), which the leader does as
+    soon as the program is enqueued and every rank's result, an array
+    the device may still be computing, lies in ``rv.result`` (``open``):
+    no leader waits for the device. They leave one at a time, in the
     order they came, each letting the next go before it does anything
     else: first in, first out, so every rank's call lasts one period of
     the loop. Woken all at once, as a ``threading.Barrier`` wakes them,
@@ -350,13 +352,26 @@ class _Gate:
     rank of a call a millisecond slower than the period, differently
     from run to run (PERF.md, PR 34).
 
+    ``last_first`` turns the line round: the last to come leaves first.
+    It is for ranks that share one device (the slot channel), whose
+    callers all wait on one completion: the runtime hands a result to
+    the threads that wait for it last come, first served, so after a
+    first-in-first-out line the first rank in is the last to have its
+    result, the order turns over every call, and the slowest rank of a
+    call sits three slices above the period; let go last in, first out,
+    the ranks enter their wait in the reverse of the order they came
+    and come back in the order they came, the same every call
+    (PERF.md, PR 50). Ranks on devices of their own wait on completions
+    of their own and keep the first-in-first-out line.
+
     One ``threading.Lock`` a rank is its semaphore: taken at the start,
     released by whoever lets the rank go. ``abort`` breaks the gate for
     good, as ``threading.Barrier.abort`` did: whoever waits or comes
     later raises ``threading.BrokenBarrierError``."""
 
-    def __init__(self, size: int):
+    def __init__(self, size: int, last_first: bool = False):
         self.size = size
+        self.last_first = last_first
         self.broken = False
         self._lock = threading.Lock()
         self._arrived = 0
@@ -393,9 +408,12 @@ class _Gate:
                 raise threading.BrokenBarrierError
 
     def open(self) -> None:
-        """The leader lets the line go, its head first."""
+        """The leader lets the line go, its head first (its tail first
+        where the gate is ``last_first``)."""
         with self._lock:
             line, self._line = self._line, []
+            if self.last_first:
+                line.reverse()
             self._arrived = 0
             for rank, nxt in zip(line, line[1:] + [None]):
                 self._after[rank] = nxt
@@ -430,9 +448,9 @@ class _Rendezvous:
     MPI already requires every rank to issue collectives on a comm in the
     same order, so one in-flight collective per comm is the contract."""
 
-    def __init__(self, size: int):
+    def __init__(self, size: int, last_first: bool = False):
         self.size = size
-        self.gate = _Gate(size)
+        self.gate = _Gate(size, last_first)
         self.slots: List = [None] * size
         self.result: List = [None] * size
         self.error: Optional[BaseException] = None
@@ -1043,8 +1061,8 @@ class DeviceCollChannel:
             if tr is not None:
                 tr.record("device", span, "E", args)
         if mx is not None:
-            # the rank's time in rendezvous + leader, per tier; on the
-            # mesh channel that ends at the enqueue, not at the result
+            # the rank's time in rendezvous + leader, per tier: it ends
+            # at the enqueue, not at the result
             mx.rec_us(f"lat_dev_{tier}",
                       (_time.perf_counter() - t0) * 1e6)
         if self._draft is not None:     # decided on this call, and it ran
@@ -1496,7 +1514,12 @@ class HBMSlotChannel(DeviceCollChannel):
     slot segment — the device-side analog of the reference's slotted
     shared-memory collective segment (ch3_shmem_coll.c:527-528; see
     ops/pallas_hbm.py). Every rank deposits at the rendezvous and the
-    leader runs one program on what was deposited. Device arrays on the
+    leader runs one program on what was deposited and hands its output
+    out at the enqueue, as the mesh and fold leaders do: the ranks go
+    round under the kernel, and each waits for its own result where it
+    uses it (its ``block_until_ready``, ``_deliver``'s copy into a host
+    ``recvbuf``); a failure the runtime reports after the enqueue
+    reaches every rank from there. Device arrays on the
     slot device go in as they lie, ``R`` operands and no eager op; host
     buffers are stacked on the host and staged as one ``(R, n)``
     operand. Either way the program is:
@@ -1606,11 +1629,9 @@ class HBMSlotChannel(DeviceCollChannel):
         return (name, n, str(dtype), op, root, len(xs)), xs
 
     def _hand_out(self, name: str, out) -> List:
-        """The leader waits for the device, then shares the program's
-        one result or hands out its per-rank outputs."""
-        import jax
-        with self._phase("dev_device_wait"):
-            out = jax.block_until_ready(out)
+        """Shares the program's one result or hands out its per-rank
+        outputs as the enqueue returned them: no leader waits for the
+        device, every caller waits for its own result."""
         with self._phase("dev_collect") as ph:
             if ph is not None:
                 ph.args["parts"] = 0    # no eager op cuts anything out
@@ -2248,7 +2269,7 @@ def bind_universes(universes, mesh=None, axis=None) -> bool:
                  dict(mesh.shape),
                  [(d.id, getattr(d, "coords", None))
                   for d in mesh.devices.flat])
-    rv = _Rendezvous(n)
+    rv = _Rendezvous(n, last_first=slot_device is not None)
     for r, u in enumerate(universes):
         if slot_device is not None:
             ch = HBMSlotChannel(slot_device, rv, r, n)

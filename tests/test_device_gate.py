@@ -1,6 +1,7 @@
 """The blocking device collectives' meeting point (coll/device.py:_Gate):
 the leader alone waits for the ranks to arrive, the others wait in one
-line and leave it first in, first out; a broken gate raises under
+line and leave it first in, first out (last in, first out where the
+ranks share one device); a broken gate raises under
 whoever waits or comes later; and through the front door every rank of
 a call gets that call's result, call after call."""
 
@@ -45,8 +46,12 @@ def _in_line(gate, ranks, left, errors):
 @pytest.mark.parametrize("order", [
     (1, 2, 3, 4, 5, 6, 7), (7, 6, 5, 4, 3, 2, 1), (4, 1, 7, 2, 6, 3, 5),
     (2, 1), (1,)], ids=lambda o: "-".join(map(str, o)))
-def test_ranks_leave_in_the_order_they_came(order):
-    gate = _Gate(len(order) + 1)
+@pytest.mark.parametrize("last_first", [False, True],
+                         ids=["first_in_first_out", "last_first"])
+def test_ranks_leave_in_the_order_they_came(order, last_first):
+    """... or, through the slot channel's ``last_first`` gate (ISSUE
+    50), in the reverse of it; one at a time either way."""
+    gate = _Gate(len(order) + 1, last_first)
     left, errors = [], []
     threads = _in_line(gate, order, left, errors)
     assert left == []               # nobody leaves before the leader opens
@@ -54,8 +59,29 @@ def test_ranks_leave_in_the_order_they_came(order):
     gate.open()
     for t in threads:
         t.join(10)
-    assert left == list(order) and errors == []
+    assert left == list(order)[::-1 if last_first else 1] and errors == []
     assert gate.n_waiting == 0 and not gate.broken
+
+
+@pytest.mark.parametrize("binding,last_first", [
+    ("slot", True), ("mesh", False), ("fold", False)])
+def test_only_the_one_device_binding_lets_go_last_first(binding,
+                                                        last_first):
+    """Ranks that share one device wait on one completion, which the
+    runtime hands to its waiters last come, first served; ranks on
+    devices of their own do not: their gates stay first in, first
+    out."""
+    import jax
+    from mvapich2_tpu.parallel.mesh import make_mesh
+    ranks, ndev = {"slot": (4, 1), "mesh": (4, 4), "fold": (8, 4)}[binding]
+    seen = []
+
+    def app(comm):
+        seen.append(comm.device_channel.rv.gate.last_first)
+
+    run_ranks(ranks, app, device_mesh=make_mesh(
+        (ndev,), ("x",), jax.devices()[:ndev]))
+    assert seen == [last_first] * ranks
 
 
 def test_the_leader_waits_for_the_last_rank():

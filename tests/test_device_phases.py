@@ -1,11 +1,12 @@
 """Phase spans inside ``dev_<coll>`` (ISSUE 26): every blocking device
 collective carries a ``seq`` equal on every rank, and between its
 ``dev_<coll>`` B and E the rendezvous and the leader open ``dev_arrive``,
-``dev_stage``, ``dev_dispatch``, ``dev_device_wait`` (slot channel only),
-``dev_collect`` and ``dev_release``; ``dev_deliver`` follows the E; the
-fold channel's leader opens ``dev_chip_fold`` inside its ``dev_stage``. No
-test here asserts a time: only names, order, nesting, ``seq`` and that
-every span closes, on the error paths too.
+``dev_stage``, ``dev_dispatch``, ``dev_collect`` and ``dev_release``, the
+same list on the three channels (no leader waits for the device: ISSUE
+50); ``dev_deliver`` follows the E; the fold channel's leader opens
+``dev_chip_fold`` inside its ``dev_stage``. No test here asserts a time:
+only names, order, nesting, ``seq`` and that every span closes, on the
+error paths too.
 """
 
 import os
@@ -97,11 +98,9 @@ def _violations(lanes):
 @pytest.mark.parametrize("channel", list(CHANNELS))
 def test_phases_in_order_nested_one_seq(traced, channel):
     lanes = _run_two_collectives(channel)
-    waits = ["dev_device_wait"] if channel == "slot" else []
     for rank, lane in lanes.items():
         spans = _spans(lane)
-        inside = (["dev_arrive"]
-                  + (LEADER[:2] + waits + LEADER[2:] if rank == 0 else [])
+        inside = (["dev_arrive"] + (LEADER if rank == 0 else [])
                   + ["dev_release"])
         tops = [(name, seq, kids) for name, seq, depth, kids in spans
                 if depth == 0]
@@ -182,6 +181,135 @@ def test_error_paths_close_every_span(traced, fault):
     assert _violations(lanes) == []
 
 
+# -- no leader waits: a result is a future (ISSUE 50) ----------------------
+
+class _NotYet:
+    """What an enqueue returns, as far as the library may look at it:
+    flat, and not to be waited for by anyone but its caller."""
+
+    ndim = 1
+
+    def __init__(self):
+        self.waited = []        # the threads that asked
+
+    def block_until_ready(self):
+        self.waited.append(threading.get_ident())
+        raise RuntimeError("the runtime's own, after the enqueue")
+
+
+@pytest.mark.parametrize("coll,per_rank", [("allreduce", False),
+                                           ("alltoall", True)])
+def test_the_slot_leader_hands_out_what_the_enqueue_returned(coll,
+                                                             per_rank):
+    """The slot leader does not touch the program's output: a result
+    that raises on ``block_until_ready`` is still handed to every rank,
+    the one array shared or a tuple's element a rank, and what the
+    runtime reports after the enqueue reaches every rank from its own
+    wait on what it was handed, as on the mesh channel."""
+    ranks = 8
+    outs = ([_NotYet() for _ in range(ranks)] if per_rank
+            else [_NotYet()] * ranks)
+    got, raised, idents = [None] * ranks, [None] * ranks, [None] * ranks
+
+    def app(comm):
+        ch = comm.device_channel
+        assert type(ch).__name__ == "HBMSlotChannel"
+        if comm.rank == 0:      # the leader's program: an enqueue, no more
+            out = tuple(outs) if per_rank else outs[0]
+            ch._program = lambda *key: lambda *operands: out
+        comm.barrier()
+        x = jax.device_put(np.ones(ranks * 16, np.float32), ch.device)
+        got[comm.rank] = getattr(comm, coll)(x)     # returns: nobody waited
+        idents[comm.rank] = threading.get_ident()
+        comm.barrier()
+        assert not any(o.waited for o in outs)
+        comm.barrier()
+        try:
+            jax.block_until_ready(got[comm.rank])
+        except RuntimeError as e:
+            raised[comm.rank] = str(e)
+
+    run_ranks(ranks, app, device_mesh=_mesh("slot"))
+    assert all(g is o for g, o in zip(got, outs))
+    assert raised == ["the runtime's own, after the enqueue"] * ranks
+    if per_rank:                # each waited for its own, and once
+        assert [o.waited for o in outs] == [[i] for i in idents]
+    else:
+        assert sorted(outs[0].waited) == sorted(idents)
+
+
+@pytest.mark.parametrize("ranks", [4, 8])
+def test_a_failed_enqueue_raises_on_every_slot_rank_and_the_gate_holds(
+        traced, ranks):
+    """A failure at the enqueue (trace, compile, argument) is the
+    leader's: every rank raises "failed on the leader", every span
+    closes, and the next call on the same gate runs and is right."""
+    errors, sums, lanes = {}, {}, {}
+
+    def app(comm):
+        ch = comm.device_channel
+        if comm.rank == 0:
+            program = ch._program
+
+            def boom(*_a, **_k):
+                ch._program = program       # this call alone
+                raise ValueError("seeded enqueue failure")
+            ch._program = boom
+        comm.barrier()
+        x = jax.device_put(np.full(N, float(comm.rank + 1), np.float32),
+                           ch.device)
+        try:
+            comm.allreduce(x)
+        except RuntimeError as e:
+            errors[comm.rank] = (str(e), type(e.__cause__).__name__)
+        sums[comm.rank] = float(np.asarray(comm.allreduce(x))[0])
+        lanes[comm.rank] = _device_lane(comm)
+
+    run_ranks(ranks, app, device_mesh=_mesh("slot"))
+    assert errors == {r: ("device collective allreduce failed on the leader",
+                          "ValueError") for r in range(ranks)}
+    assert sums == {r: ranks * (ranks + 1) / 2 for r in range(ranks)}
+    kids = [k for name, _s, depth, k in _spans(lanes[0])
+            if name == "dev_allreduce"]
+    assert kids == [["dev_arrive", "dev_stage", "dev_dispatch",
+                     "dev_release"],
+                    ["dev_arrive"] + LEADER + ["dev_release"]]
+    for lane in lanes.values():
+        _spans(lane)
+    assert _violations(lanes) == []
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int32"])
+def test_eight_threads_waiting_on_one_shared_result_get_the_same_bits(
+        dtype):
+    """Eight rank threads wait at once on the one array the slot leader
+    shared at the enqueue: every one reads the reference's bits, and
+    its own send buffer as it was."""
+    ranks, n = 8, 1 << 16
+    rng = np.random.default_rng(50)
+    data = [rng.integers(-2 ** 20, 2 ** 20, n).astype(dtype)
+            for _ in range(ranks)]
+    want = np.sum(data, axis=0, dtype=dtype)
+    got, ids, kept = [None] * ranks, [None] * ranks, [None] * ranks
+    start = threading.Barrier(ranks)
+
+    def app(comm):
+        x = jax.device_put(data[comm.rank], comm.device_channel.device)
+        for _ in range(3):
+            out = comm.allreduce(x)
+        ids[comm.rank] = id(out)
+        start.wait()
+        got[comm.rank] = np.asarray(jax.block_until_ready(out))
+        kept[comm.rank] = np.asarray(x)
+
+    run_ranks(ranks, app, device_mesh=_mesh("slot"))
+    assert len(set(ids)) == 1               # the zero-copy share
+    for r in range(ranks):
+        assert got[r].dtype == want.dtype
+        assert got[r].tobytes() == want.tobytes()
+        assert kept[r].tobytes() == data[r].tobytes()
+
+
 @pytest.mark.parametrize("trace", [False, True])
 def test_trace_annotation_only_while_a_recorder_is_attached(
         monkeypatch, trace):
@@ -257,10 +385,10 @@ def test_phase_names_pass_the_events_lint():
     with open(path) as f:
         src = f.read()
     for name in ("dev_arrive", "dev_stage", "dev_chip_fold", "dev_dispatch",
-                 "dev_device_wait", "dev_collect", "dev_release",
-                 "dev_deliver"):
+                 "dev_collect", "dev_release", "dev_deliver"):
         assert f'self._phase("{name}")' in src
         assert conform.grammar_covers("device", name)
+    assert "dev_device_wait" not in src     # went with the wait (ISSUE 50)
 
 
 # -- what one event holds, and what recording it reads (ISSUE 36) --------

@@ -51,7 +51,7 @@ def _clean_env():
     _reload(MV2T_QUANT_COLL=None, MV2T_QUANT_BLOCK=None,
             MV2T_DEV_TIER_QUANT_MIN=None, MV2T_ICI_INTERPRET=None,
             MV2T_DEV_TIER_VMEM_MAX=None, MV2T_DEV_TIER_XLA_MIN=None,
-            MV2T_ICI_CHUNK_BYTES=None)
+            MV2T_ICI_CHUNK_BYTES=None, MV2T_DEVICE_COLL_MIN_BYTES=None)
 
 
 def _run_q(comm8, xv, p, wire="q8", **kw):
