@@ -37,7 +37,9 @@ Pallas ICI ring kernels) compared with numpy AND with the stock XLA
 lowering (``lax.psum`` / ``all_gather`` / ``all_to_all`` /
 ``psum_scatter``: allreduce at 4 KiB, 1 MiB and 64 MiB, allgather and
 alltoall at 16 MiB, allreduce max and reduce_scatter_block at 1 MiB, the
-last on the ring's fold rounds alone); then the fold
+last on the ring's fold rounds alone; bcast at 32 MiB from rank 0 and
+from rank 2 by the streaming chain, ``dev_coll_tier_hbm`` +1 a rank a
+call, the senders' buffers deleted after the calls); then the fold
 phase, ``run_ranks(8, app, device_mesh=<the four chips>)`` (two ranks a
 chip, ``DeviceFoldChannel``): allreduce sum and max, allgather,
 reduce_scatter_block, bcast and reduce at 1 MiB a rank on device-resident
@@ -380,6 +382,7 @@ def _stock(mesh, name: str, op: str, xs):
     """The stock XLA lowering of one collective over the same mesh, on
     the same data: [p] per-rank results as numpy."""
     import jax
+    import jax.numpy as jnp
     from jax import lax
     from jax.sharding import NamedSharding, PartitionSpec as P
     p = len(xs)
@@ -392,11 +395,19 @@ def _stock(mesh, name: str, op: str, xs):
             x.reshape(p, -1), "x", 0, 0).reshape(1, -1),
         ("reduce_scatter_block", "sum"): lambda x: lax.psum_scatter(
             x.reshape(-1), "x", tiled=True).reshape(1, -1),
+        # a bcast's ``op`` is its root: the one-hot psum the kernel replaced
+        ("bcast", op): lambda x: lax.psum(
+            jnp.where(lax.axis_index("x") == op, x, jnp.zeros_like(x)), "x"),
     }[(name, op)]
     g = jax.device_put(np.stack(xs), NamedSharding(mesh, P("x", None)))
     f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("x", None),),
                               out_specs=P("x", None), check_vma=False))
     return np.asarray(f(g))
+
+
+def _tag(op) -> str:
+    """A case's op, or a bcast's root, as the report prints it."""
+    return "" if op is None else str(op)
 
 
 def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
@@ -416,6 +427,9 @@ def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
         (3, "allreduce", "sum", 64 * MiB), (4, "allgather", None, 16 * MiB),
         (5, "alltoall", None, 16 * MiB), (6, "allreduce", "max", MiB),
         (7, "reduce_scatter_block", "sum", MiB),
+        # a bcast's op is its root: rank 0 and one in mid-ring, at a
+        # streaming size (the chain kernel, a program a root)
+        (8, "bcast", 0, 32 * MiB), (9, "bcast", 2, 32 * MiB),
     ]
     cases = [(t, n, o, max(nranks * 128 * 4, b // scale))
              for t, n, o, b in cases]
@@ -424,6 +438,7 @@ def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
     results = {t: [None] * nranks for t, *_ in cases}
     homes = [None] * nranks
     report = {}
+    bcast_counts = ("dev_coll_tier_hbm", "dev_bc_wire_bytes")
 
     def app(comm):
         ch = comm.device_channel
@@ -438,7 +453,11 @@ def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
                     "allgather": lambda: comm.allgather(x),
                     "alltoall": lambda: comm.alltoall(x),
                     "reduce_scatter_block":
-                        lambda: comm.reduce_scatter_block(x)}[name]
+                        lambda: comm.reduce_scatter_block(x),
+                    "bcast": lambda: comm.bcast(x, root=op)}[name]
+            if name == "bcast":
+                comm.barrier()
+                counted = {n: mpit.pvar(n).read() for n in bcast_counts}
             times = []
             for _ in range(1 + STEADY_CALLS):
                 comm.barrier()
@@ -447,10 +466,22 @@ def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
                 times.append(time.perf_counter() - t0)
                 assert out.sharding.device_set == {dev}, \
                     (name, comm.rank, out.sharding.device_set)
+            if name == "bcast":
+                # every call took the chain (the tier pvar, a rank a
+                # call) and counted the message once on the root's wire;
+                # the senders' buffers go, the results hold
+                comm.barrier()
+                rose = {n: mpit.pvar(n).read() - v
+                        for n, v in counted.items()}
+                calls = nranks * (1 + STEADY_CALLS)
+                assert rose == {"dev_coll_tier_hbm": calls,
+                                "dev_bc_wire_bytes": calls * nbytes}, rose
+                comm.barrier()
+                x.delete()
             results[t][comm.rank] = np.asarray(out)
             if comm.rank == 0:
                 report[t] = (times[0], statistics.median(times[1:]))
-                say(f"four chips: ran {name} {op or ''} {nbytes} B/rank "
+                say(f"four chips: ran {name} {_tag(op)} {nbytes} B/rank "
                     f"x {1 + STEADY_CALLS}")
 
     before = {n: mpit.pvar(n).read()
@@ -474,7 +505,8 @@ def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
                    [xs[s][r * c:(r + 1) * c] for s in range(nranks)])
                    for r in range(nranks)],
                "reduce_scatter_block": lambda: list(
-                   np.sum(xs, axis=0).reshape(nranks, c))}[name]()
+                   np.sum(xs, axis=0).reshape(nranks, c)),
+               "bcast": lambda: [xs[op]] * nranks}[name]()
         stock = _stock(mesh, name, op, xs)
         for r in range(nranks):
             got = results[t][r]
@@ -485,7 +517,7 @@ def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
                 raise AssertionError(f"{name} {op} {nbytes} B: rank {r} "
                                      f"differs from the stock lowering")
         first, steady = report[t]
-        say(f"four chips: {name} {op or '':<3} {nbytes:>9} B/rank  "
+        say(f"four chips: {name} {_tag(op):<3} {nbytes:>9} B/rank  "
             f"bit-equal to numpy and to the stock XLA lowering on "
             f"{nranks} ranks | first call {first:.3f} s (compile), steady "
             f"{steady * 1e3:.3f} ms/call (smoke timing)")

@@ -331,7 +331,8 @@ class _CallPlan:
 _WIRE = {"alltoall": ("dev_a2a_wire", "alltoall_wire_bytes"),
          "allgather": ("dev_ag_wire", "all_gather_wire_bytes"),
          "reduce_scatter_block": ("dev_rs_wire",
-                                  "reduce_scatter_wire_bytes")}
+                                  "reduce_scatter_wire_bytes"),
+         "bcast": ("dev_bc_wire", "bcast_wire_bytes")}
 # The collectives whose result, every rank's deposit, is what must fit
 # the engine: the tier rule is asked with the result's bytes.
 _KEYED_ON_RESULT = ("allgather",)
@@ -610,7 +611,7 @@ class DeviceCollChannel:
         if not daemon.exec_cache_enabled():
             return self._build(name, n, op, root, extra)
         from ..ops import _compat
-        ck = "|".join(("mv2t-exec-v5", self._chan_desc(), name,
+        ck = "|".join(("mv2t-exec-v6", self._chan_desc(), name,
                        f"n{n}", dtype_str, f"op:{op}", f"root:{root}",
                        f"x:{extra!r}", _compat.exec_fingerprint()))
         blob = daemon.exec_cache_get(ck)
@@ -627,7 +628,6 @@ class DeviceCollChannel:
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from .. import ops
         from ..parallel.mesh import shard_map
         axis, p = self.axis, self._mesh_extent()
 
@@ -642,7 +642,10 @@ class DeviceCollChannel:
             out_specs = P(None)             # replicated [n]
         elif name == "bcast":
             def f(x):
-                return ops.bcast(x, axis, root)
+                # tier dispatch: the streaming chain from the root's
+                # shard (no other shard is read), or the XLA lowering
+                from ..ops import pallas_ici
+                return pallas_ici.ici_bcast(x, axis, p, root)
             out_specs = P(None)
         elif name == "allgather":
             def f(x):

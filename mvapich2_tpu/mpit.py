@@ -483,6 +483,15 @@ pvar("dev_rs_wire_bytes", PVAR_CLASS_COUNTER, "device",
      "(reduce_scatter_wire_bytes; counted per call in coll/device.py "
      "_note_tier, the same number as wire_bytes on the call's "
      "dev_rs_wire trace instant)")
+pvar("dev_bc_wire_bytes", PVAR_CLASS_COUNTER, "device",
+     "bytes the HBM-streaming ring broadcast kernel (ops/pallas_ici) "
+     "sends over ICI from its root, summed per rank over the calls it "
+     "served on the 1:1 mesh channel: the payload once, tile padding "
+     "included, both lanes together; a forwarding chip sends less and "
+     "the root's own copy never reaches the wire, as the kernel module "
+     "reckons them (bcast_wire_bytes; counted per call in "
+     "coll/device.py _note_tier, the same number as wire_bytes on the "
+     "call's dev_bc_wire trace instant)")
 pvar("dev_coll_fallback_nbc", PVAR_CLASS_COUNTER, "device",
      "nonblocking collectives on a device-capable comm that could not "
      "route through the device tier (op/dtype/residency/size or the "

@@ -66,17 +66,18 @@ number of tiles with the op identity and reshape the HBM operands to
 
 Tier selection is one rule, ``planned_tier``, asked once by each
 dispatcher here (``ici_all_reduce`` / ``ici_all_gather`` /
-``ici_reduce_scatter``, which switch on its answer and nothing else) and
-by the MPI channel's per-call accounting (coll/device.py
-``_decide_tier``). It is data driven: coll/tuning.py's ``device_tier``
-maps shard bytes to vmem (pallas_ring) / hbm (here) / quant
+``ici_reduce_scatter`` / ``ici_bcast``, which switch on its answer and
+nothing else) and by the MPI channel's per-call accounting
+(coll/device.py ``_decide_tier``). It is data driven: coll/tuning.py's
+``device_tier`` maps shard bytes to vmem (pallas_ring) / hbm (here) / quant
 (pallas_quant — the block-scaled quantized wire above the hbm tier,
 gated by the MV2T_QUANT_COLL accuracy budget) / xla, with the
 boundaries re-measurable by ``bin/measure_crossover --device``; the
 rule then names the engine that can carry the call (the flat VMEM ring
 takes sums and gathers of a 1-D mesh only; a reduce-scatter, another
 op, a ring along one axis of a multi-axis mesh stream through the
-engine here). Every fallback to the XLA lowering is counted by the
+engine here; a broadcast has the streaming chain here and nothing
+below it). Every fallback to the XLA lowering is counted by the
 ``dev_coll_fallback_*`` pvar family — the 4 MiB cliff is no longer
 silent.
 
@@ -87,6 +88,7 @@ mesh-bound MPI channel (coll/device.py), which routes per-call.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -119,6 +121,7 @@ _CID_ALLREDUCE = 2
 _CID_ALLGATHER = 3
 _CID_SENDRECV = 4
 _CID_REDUCE_SCATTER = 5
+_CID_BCAST = 12
 
 _LANES = 128
 
@@ -263,6 +266,7 @@ class _RingStreamer:
         self.send_sem, self.recv_sem, self.cap_sem = \
             send_sem, recv_sem, cap_sem
         self.gc = [0] * ndir                   # global chunk counter / dir
+        self.rc = [0] * ndir                   # chunks ``take`` took / dir
         self.pending_send: Dict = {}           # (d, slot) -> remote handle
         self.pending_acc: Dict = {}
         self.pending_fold: Dict = {}           # (i, d, slot) -> fold load
@@ -322,6 +326,19 @@ class _RingStreamer:
             own = red(own, self.fold_buf[i, d, slot, :sz])
         return own
 
+    def _remote(self, d, slot, sz):
+        """Lane ``d``'s remote copy of ``sz`` rows through slot
+        ``slot``: this chip's send slot into the downstream
+        neighbour's recv slot of the same index."""
+        dst = self.right if d == 0 else self.left
+        return pltpu.make_async_remote_copy(
+            src_ref=self.send_buf.at[d, slot, pl.ds(0, sz)],
+            dst_ref=self.recv_buf.at[d, slot, pl.ds(0, sz)],
+            send_sem=self.send_sem.at[d, slot],
+            recv_sem=self.recv_sem.at[d, slot],
+            device_id=self._dev(dst),
+            device_id_type=pltpu.DeviceIdType.LOGICAL)
+
     def issue(self, d, src, off, sz, acc, red=None):
         """Front half of the chunk pipeline: load the send chunk (and,
         for the reduce phase, prefetch the local accumulator chunk),
@@ -365,14 +382,7 @@ class _RingStreamer:
         if acc is not None:
             self._load_others(d, slot, acc[1:], off, sz)
         self._take_credit(d)
-        dst = self.right if d == 0 else self.left
-        rdma = pltpu.make_async_remote_copy(
-            src_ref=self.send_buf.at[d, slot, pl.ds(0, sz)],
-            dst_ref=self.recv_buf.at[d, slot, pl.ds(0, sz)],
-            send_sem=self.send_sem.at[d, slot],
-            recv_sem=self.recv_sem.at[d, slot],
-            device_id=self._dev(dst),
-            device_id_type=pltpu.DeviceIdType.LOGICAL)
+        rdma = self._remote(d, slot, sz)
         rdma.start()
         self.pending_send[(d, slot)] = rdma
         self.gc[d] += 1
@@ -400,13 +410,33 @@ class _RingStreamer:
             st.start()
             self.pending_store[(d, slot)] = st
         else:
-            st = pltpu.make_async_copy(
-                self.recv_buf.at[d, slot, pl.ds(0, sz)],
-                dst.at[pl.ds(off, sz)],
-                self.st_sem.at[d, slot])
-            st.start()
-            st.wait()                  # slot must land before re-grant
-            self._grant(d)
+            self._store_arrival(d, slot, dst, off, sz)
+
+    def _store_arrival(self, d, slot, dst, off, sz):
+        """The arrival in recv slot ``(d, slot)`` stored verbatim into
+        rows [off, off+sz) of ``dst``; the slot is re-granted once the
+        store has landed."""
+        st = pltpu.make_async_copy(
+            self.recv_buf.at[d, slot, pl.ds(0, sz)],
+            dst.at[pl.ds(off, sz)],
+            self.st_sem.at[d, slot])
+        st.start()
+        st.wait()                  # slot must land before re-grant
+        self._grant(d)
+
+    def take(self, d, dst, off, sz):
+        """``drain``'s gather arm for a chip that need not have sent
+        what it receives: wait for the upstream neighbour's next chunk
+        on lane ``d`` and store it into rows [off, off+sz) of ``dst``.
+        The slot is the receive counter's, ``rc[d] % depth``: a chain's
+        root sends and never receives, a lane's last chip receives and
+        never sends, so ``issue``'s counter cannot serve both halves;
+        upstream's n-th send and this chip's n-th receive are the same
+        number, which is all the credit schedule needs."""
+        slot = self.rc[d] % self.depth
+        self._remote(d, slot, sz).wait_recv()
+        self._store_arrival(d, slot, dst, off, sz)
+        self.rc[d] += 1
 
     def _take_credit(self, d):                # device: hw-only
         """Consume one slot credit before the remote DMA — the sender
@@ -640,6 +670,120 @@ def _hbm_all_gather_kernel(axis_name, p, spans_chunks, depth, ndir,
     st.finish()
 
 
+def _chain(st, spans_chunks, src, dst, sends, skew):
+    """A pipelined chain, one chunk of each lane a step, as one chip
+    runs it. In step t lane ``d`` handles its chunk ``t - skew[d]``:
+    the upstream neighbour's chunk is taken into ``dst`` where one is
+    given (``take``) and, where ``sends[d]`` says this chip passes the
+    lane on, the same rows of ``src`` go downstream (``issue``). A chip
+    that forwards has ``src`` and ``dst`` the same buffer, so it passes
+    chunk j on as soon as j has landed, while upstream already sends
+    j + 1.
+
+    ``skew[d]`` is how many more hops from the root this chip lies
+    along lane ``d`` than along its nearer lane: a chunk takes a step a
+    hop, so the chunk of the farther lane that has reached this chip by
+    step t is that much older. Without it a chip would wait, in every
+    step, for a chunk that its neighbour can only send once it has this
+    step's chunk of the other lane from this very chip: two wire times
+    a step (the chip read exactly that: PERF.md section 6, PR 51).
+
+    The steps differ in nothing but their row offsets, so they are not
+    unrolled: the first are traced as they are (until every lane has
+    ``depth`` chunks behind it, so that every send slot has a DMA to
+    wait for), the whole groups of ``depth`` steps that follow, as long
+    as every lane's chunk is a full one, run as one ``fori_loop`` body
+    whose offsets are traced and whose slots are static, and what is
+    left (a lane's odd chunk, its short last one, the farther lane's
+    last ``skew`` chunks) is traced after. The traced program is a few
+    steps whatever the payload."""
+    depth = st.depth
+    lanes = [d for d, c in enumerate(spans_chunks) if c]
+    chunk = spans_chunks[lanes[0]][0][1]
+    order = sorted(lanes, key=lambda d: not sends[d])
+
+    def step(chunks):
+        for d in order:
+            if chunks[d] is not None:
+                if dst is not None:
+                    st.take(d, dst, *chunks[d])
+                if sends[d]:
+                    st.issue(d, (src,), *chunks[d], None)
+
+    def static(t):
+        step([c[t - skew[d]] if 0 <= t - skew[d] < len(c) else None
+              for d, c in enumerate(spans_chunks)])
+
+    def group(g, _):
+        for i in range(depth):
+            t = first + g * depth + i
+            step([(pl.multiple_of(c[0][0] + (t - skew[d]) * chunk,
+                                  math.gcd(c[0][0], chunk)), chunk)
+                  for d, c in enumerate(spans_chunks)])
+
+    # the steps in which every lane handles a full chunk that is not
+    # among its first ``depth``
+    first = max(skew[d] for d in lanes) + depth
+    groups = (min(skew[d] + sum(sz == chunk for _, sz in spans_chunks[d])
+                  for d in lanes) - first) // depth
+    steps = max(skew[d] + len(spans_chunks[d]) for d in lanes)
+    if groups < 2 or len(lanes) < len(spans_chunks):
+        first, groups = steps, 0
+    for t in range(first):
+        static(t)
+    if groups:
+        # the body leaves its own descriptors behind, which are its
+        # scope's; the ones from before it name the same slots
+        sent = dict(st.pending_send)
+        lax.fori_loop(0, groups, group, None)
+        st.pending_send = sent
+    for t in range(first + groups * depth, steps):
+        static(t)
+
+
+def _hbm_bcast_kernel(axis_name, p, root, spans_chunks, depth, ndir,
+                      credits, mesh_ctx, x_hbm, o_hbm, *scratch):
+    """x, o: (rows, 128) in HBM. Lane 0 carries the first half of the
+    rows clockwise from the root, lane 1 the second counter-clockwise.
+    One straight-line schedule for each distance from the root, chosen
+    once, each a few steps and a loop (``_chain``): the root only
+    sends, chunk after chunk from its operand (which it alone reads:
+    chunk by chunk for the wire, and whole by one HBM-to-HBM copy into
+    its own output that runs under the chain); every other chip takes
+    each chunk into its output and passes it on from there on the lanes
+    it is not the last chip of (a lane ends at the root's neighbour
+    against the lane's sense), the lane it lies farther along so many
+    chunks behind the other. Which lanes a chip sends and receives on,
+    and how far apart, is static inside a schedule, so the streamer's
+    slot and credit counters stay plain Python; nobody's operand but
+    the root's is touched."""
+    my, left, right = _ring_neighbours(axis_name, p)
+
+    def streamer():
+        return _mk_streamer(p, ndir, depth, credits, left, right,
+                            scratch, mesh_ctx, axis_name)
+    streamer().enter()      # the barrier and the credits: every chip's
+    dist = lax.rem(my - root + p, p)
+
+    @pl.when(dist == 0)
+    def _():
+        st = streamer()     # each schedule counts its own chunks
+        own = pltpu.make_async_copy(x_hbm, o_hbm, scratch[-1])
+        own.start()
+        st.pending_store["own"] = own   # drained with the stores
+        _chain(st, spans_chunks, x_hbm, None, [True] * ndir, [0] * ndir)
+        st.finish()
+
+    for k in range(1, p):
+        @pl.when(dist == k)
+        def _(k=k):
+            hops = [k, p - k][:ndir]        # from the root, along each lane
+            st = streamer()
+            _chain(st, spans_chunks, o_hbm, o_hbm,
+                   [h < p - 1 for h in hops], [h - min(hops) for h in hops])
+            st.finish()
+
+
 def _sendrecv_kernel(axis_name, p, src, dst, x_hbm, o_hbm, send_sem,
                      recv_sem):
     """Single remote-DMA point-to-point exchange: HBM to remote HBM, no
@@ -799,6 +943,46 @@ def all_gather_wire_bytes(nelems: int, dtype, num_devices: int) -> int:
             * np.dtype(dtype).itemsize)
 
 
+def hbm_ring_bcast(x: jax.Array, axis_name: str, num_devices: int,
+                   root: int, *, chunk_bytes: Optional[int] = None,
+                   depth: Optional[int] = None,
+                   bidirectional: Optional[bool] = None,
+                   credits: Optional[bool] = None,
+                   interpret=None, mesh_ctx=None) -> jax.Array:
+    """Broadcast along ``axis_name`` via the chunked HBM-streaming ring:
+    a pipelined chain from shard ``root`` (a trace-time constant), half
+    the rows each way round where the axis has more than 2 shards.
+    ``x``: this shard's block [m, ...]; the root's is the payload, the
+    others' are never read. Returns the root's block on every shard, bit
+    for bit. A length of whole tiles is handed to the kernel as it
+    lies."""
+    p = num_devices
+    if p == 1:
+        return x
+    shape = x.shape
+    m = int(np.prod(shape)) if shape else 1
+    rows = _tile_rows(m, x.dtype)
+    out = _ring_call(
+        _hbm_bcast_kernel, (axis_name, p, int(root) % p), rows, x.dtype,
+        _CID_BCAST,
+        jax.ShapeDtypeStruct((rows, _LANES), x.dtype),
+        interpret, credits, chunk_bytes, depth, p, bidirectional,
+        mesh_ctx, _as_blocks(x.reshape(m), 1, rows)[0])
+    return _from_blocks(out[None], m).reshape(shape)
+
+
+def bcast_wire_bytes(nelems: int, dtype, num_devices: int) -> int:
+    """Bytes the root sends over ICI in one run of ``hbm_ring_bcast`` on
+    a ``[nelems]`` payload: the payload rounded up to whole tiles, once,
+    both lanes' halves together. A chip that forwards sends the half of
+    each lane it is not the last chip of (as much as the root where it
+    is the last of neither, nothing of a lane it ends); every chip but
+    the root receives what the root sends. The root's own copy is an
+    HBM-to-HBM DMA that never reaches the wire."""
+    del num_devices     # the chain's length moves the time, not the bytes
+    return (_tile_rows(nelems, dtype) * _LANES * np.dtype(dtype).itemsize)
+
+
 def hbm_ring_reduce_scatter(x, axis_name: str,
                             num_devices: int, op: str = "sum", *,
                             chunk_bytes: Optional[int] = None,
@@ -917,9 +1101,9 @@ def planned_tier(name: str, shard_nbytes: int, dtype, op: Optional[str],
                  multi_axis: bool = False) -> Tuple[str, Optional[str]]:
     """(tier, fallback_reason) for one device collective call: the one
     rule. ``ici_all_reduce`` / ``ici_all_gather`` / ``ici_reduce_scatter``
-    lower what it says, and the channel's per-call accounting
-    (coll/device.py ``_decide_tier``) counts what it says, so the pvar a
-    call bumps is the kernel its program holds. ``name`` is the MPI
+    / ``ici_bcast`` lower what it says, and the channel's per-call
+    accounting (coll/device.py ``_decide_tier``) counts what it says, so
+    the pvar a call bumps is the kernel its program holds. ``name`` is the MPI
     collective whose lowering asks ('allreduce', 'reduce', 'allgather',
     'reduce_scatter_block', 'bcast'; 'alltoall' through
     pallas_alltoall.planned_a2a_tier); ``shard_nbytes`` what must fit
@@ -931,10 +1115,11 @@ def planned_tier(name: str, shard_nbytes: int, dtype, op: Optional[str],
     platform (not a TPU and not interpreting), dtype (op/dtype the
     kernels cannot reduce), shape (degenerate extent), size (past the
     measured XLA crossover). Two XLA takes are no fallback (reason
-    None): a collective with no ring kernel (bcast), and a multi-axis
-    mesh under the interpreter, whose remote-DMA discharge refuses more
-    than one named axis (the decomposition above the phase is the same,
-    which is what the CPU sweep pins).
+    None): a broadcast where it has no engine yet (the 'vmem' bin; a
+    multi-axis mesh), and a multi-axis mesh under the interpreter,
+    whose remote-DMA discharge refuses more than one named axis (the
+    decomposition above the phase is the same, which is what the CPU
+    sweep pins).
 
     Past the size bins (coll/tuning.device_tier) the answer is the
     engine that can carry the call, each a bit-exact move, never an XLA
@@ -944,9 +1129,9 @@ def planned_tier(name: str, shard_nbytes: int, dtype, op: Optional[str],
     only, so 'vmem' with another op, for a reduce-scatter (no flat
     kernel, and the chunked engine has no size floor: it pads) or on a
     multi-axis mesh (the VMEM and quant engines address devices 1-D)
-    is 'hbm' too."""
-    if name == "bcast":
-        return "xla", None
+    is 'hbm' too. A broadcast has the streaming chain and nothing else:
+    past the 'vmem' bin on a 1-D mesh it is 'hbm' (a 'quant' bin too:
+    it moves bits)."""
     if not _kernels_runnable(interpret):
         return "xla", "platform"
     if multi_axis and not on_tpu():
@@ -961,6 +1146,8 @@ def planned_tier(name: str, shard_nbytes: int, dtype, op: Optional[str],
     tier = device_tier(name, shard_nbytes)
     if tier == "xla":
         return "xla", "size"
+    if name == "bcast":
+        return ("xla" if tier == "vmem" or multi_axis else "hbm"), None
     if tier == "quant":
         from . import pallas_quant
         if multi_axis or not pallas_quant.quant_eligible(
@@ -1085,6 +1272,27 @@ def ici_all_gather(x: jax.Array, axis_name: str, num_devices: int,
     if reason is not None:
         note_fallback("allgather", reason, out_nbytes, x.dtype)
     return lax.all_gather(x, axis_name, tiled=True)
+
+
+def ici_bcast(x: jax.Array, axis_name: str, num_devices: int, root: int,
+              interpret=None, mesh_ctx=None) -> jax.Array:
+    """Tier-dispatched device broadcast from shard ``root`` (static):
+    the streaming chain where ``planned_tier`` names it, else the XLA
+    lowering (ops/collectives.bcast, a one-hot psum)."""
+    p = num_devices
+    if p == 1:
+        return x
+    nbytes = x.size * x.dtype.itemsize
+    tier, reason = planned_tier("bcast", nbytes, x.dtype, None, interpret,
+                                p, _multi_axis(mesh_ctx))
+    _trace_entry("bcast", tier, nbytes, root=root)
+    if tier == "hbm":
+        return hbm_ring_bcast(x, axis_name, p, root, interpret=interpret,
+                              mesh_ctx=mesh_ctx)
+    if reason is not None:
+        note_fallback("bcast", reason, nbytes, x.dtype)
+    from .collectives import bcast
+    return bcast(x, axis_name, root)
 
 
 def ici_reduce_scatter(x, axis_name: str, num_devices: int,

@@ -525,6 +525,58 @@ def test_reduce_scatter_program_at_its_own_size(mesh4, monkeypatch,
     assert nbytes <= mem.temp_size_in_bytes <= nbytes + 64 * KiB
 
 
+@pytest.mark.parametrize("nbytes,root", [(64 * MiB, 0), (8 * MiB, 2)],
+                         ids=["cell", "8MiB-root2"])
+def test_bcast_program_at_its_own_size(mesh4, monkeypatch,
+                                       default_tier_edges, nbytes, root):
+    """``osu4.bcast.64MiB.dev``'s program as the leader builds it
+    (ISSUE 51), at the cell's 33 554 432 bfloat16 a rank from rank 0
+    (lowered and compiled here in under 2 s: the chain's steps are a
+    loop's body, one schedule a role) and at an eighth of it from
+    another root: the streaming chain between bitcasts and the one ROOT
+    copy every four-chip program has. No ``all-reduce`` and no
+    ``select`` anywhere in the module (the parent's one-hot ``psum``
+    had both), nothing between the parameter and the kernel that could
+    read a non-root's deposit, a buffer in, a buffer out, no temporary,
+    nothing aliased (the root keeps its buffer)."""
+    import re
+
+    import jax
+    import ml_dtypes
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from mvapich2_tpu.coll.device import DeviceCollChannel, _Rendezvous
+    from mvapich2_tpu.ops import _compat, pallas_ici
+    monkeypatch.setattr(_compat, "on_tpu", lambda: True)
+    monkeypatch.setattr(pallas_ici, "on_tpu", lambda: True)
+    dt = np.dtype(ml_dtypes.bfloat16)
+    n = nbytes // dt.itemsize
+    assert pallas_ici.planned_tier("bcast", nbytes, dt, None,
+                                   num_devices=P4) == ("hbm", None)
+    # whole tiles: the root puts the message on the wire once
+    assert pallas_ici.bcast_wire_bytes(n, dt, P4) == nbytes
+    ch = DeviceCollChannel(mesh4, "x", _Rendezvous(P4), 0)
+    x = jax.ShapeDtypeStruct((P4 * n,), dt,
+                             sharding=NamedSharding(mesh4, P("x")))
+    compiled = ch._build("bcast", n, "sum", root).lower(x).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-reduce" not in text and "select" not in text
+    entry = _entry_ops(text)
+    ops = [op for op, _ in entry if op not in (
+        "parameter", "bitcast", "get-tuple-element", "tuple")]
+    assert ops == ["custom-call", "copy"], ops
+    calls = re.findall(r"%(\w+?)(?:\.\d+)? = [^=]*? custom-call\(",
+                       text[text.index("ENTRY"):])
+    assert calls == ["mv2t_hbm_bcast"], calls
+    assert not [dims for _, dims in entry if dims[:1] == ["1"]], entry
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes, mem.output_size_in_bytes,
+            mem.temp_size_in_bytes) == (nbytes, nbytes, 0)
+    assert mem.alias_size_in_bytes == 0
+    assert "input_output_alias" not in text
+
+
 _RING_SIZES = [(4 * KiB, "float32"), (1 * MiB, "float32"),
                (64 * MiB, "float32"), (1 * MiB, "bfloat16")]
 

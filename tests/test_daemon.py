@@ -348,7 +348,11 @@ def test_exec_cache_parent_artifact_is_never_offered(ddir, monkeypatch,
     the ring, whose rounds fold them, where it held the slot-reduce
     kernel and then the ring (ISSUE 49, ``v4`` -> ``v5``; the parent's
     two-kernel artifact is right too, and is not what
-    ``dev_fold_in_ring`` says ran). An artifact a parent of any of these
+    ``dev_fold_in_ring`` says ran), the mesh channel's bcast became the
+    streaming chain where it was a one-hot psum (ISSUE 51, ``v5`` ->
+    ``v6``; the parent's artifact gives the same bits from an
+    all-reduce, and is not what ``dev_coll_tier_hbm`` says ran). An
+    artifact a parent of any of these
     changes exported on this
     machine is never asked for
     and never deserialized, whatever else of its key matches; the
@@ -371,16 +375,16 @@ def test_exec_cache_parent_artifact_is_never_offered(ddir, monkeypatch,
                         lambda b: offered.append(b) or load(b))
 
     run_ranks(ranks, app, device_mesh=mesh)
-    assert asked and all(k.startswith("mv2t-exec-v5|") for k in asked)
+    assert asked and all(k.startswith("mv2t-exec-v6|") for k in asked)
     poison = b"artifact of a parent's program"
     for k in set(asked):    # the parents' keys for the same signature
         for old in ("mv2t-exec-v1|", "mv2t-exec-v2|", "mv2t-exec-v3|",
-                    "mv2t-exec-v4|"):
+                    "mv2t-exec-v4|", "mv2t-exec-v5|"):
             assert daemon.exec_cache_put(
-                k.replace("mv2t-exec-v5|", old, 1), poison, ddir)
+                k.replace("mv2t-exec-v6|", old, 1), poison, ddir)
     del asked[:]
     run_ranks(ranks, app, device_mesh=mesh)     # fresh channels ask again
-    assert asked and all(k.startswith("mv2t-exec-v5|") for k in asked)
+    assert asked and all(k.startswith("mv2t-exec-v6|") for k in asked)
     assert bool(offered) == exports and poison not in offered
     _reload(MV2T_DAEMON_DIR=None, MV2T_ALLREDUCE_ALGO=None,
             MV2T_DEVICE_COLL_MIN_BYTES=None)

@@ -228,3 +228,175 @@ def test_pallas_ring_nondivisible_pads(comm8):
                     x)
     expected = np.arange(40, dtype=np.float32).reshape(8, 5).sum(axis=0)
     np.testing.assert_allclose(np.asarray(out).reshape(8, 5)[0], expected)
+
+
+# ---------------------------------------------------------------------------
+# comm.bcast on the 1:1 mesh channel (ISSUE 51): the streaming chain
+# (``mv2t_hbm_bcast``, interpreted on four CPU devices) from a device
+# buffer, held to the plain reference; the call counts the tier its
+# program holds and what the root puts on the wire; the vmem bin keeps
+# XLA's lowering and counts no fallback; ibcast's segments take whatever
+# the one rule says of a segment's size.
+# ---------------------------------------------------------------------------
+
+P4 = 4
+_BCAST_WATCH = ("coll_level_ici", "dev_coll_tier_hbm", "dev_coll_tier_vmem",
+                "dev_bc_wire_bytes", "dev_call_plan_hit",
+                "dev_call_plan_filed", "dev_deposit_as_is")
+
+
+@pytest.fixture
+def interpreted_chain(monkeypatch):
+    """The ring kernels under the interpreter; the vmem bin up to 8 KiB,
+    no XLA crossover; the recorder on."""
+    from mvapich2_tpu.utils.config import get_config
+    for k, v in (("MV2T_ICI_INTERPRET", "1"), ("MV2T_TRACE", "1"),
+                 ("MV2T_DEV_TIER_VMEM_MAX", "8192"),
+                 ("MV2T_DEV_TIER_XLA_MIN", "-1")):
+        monkeypatch.setenv(k, v)
+    get_config().reload()
+    yield monkeypatch
+    monkeypatch.undo()
+    get_config().reload()
+
+
+def _fallback_reads():
+    from mvapich2_tpu import mpit
+    names = (mpit.pvar_get_info(i)["name"]
+             for i in range(mpit.pvar_get_num()))
+    return {n: mpit.pvar(n).read() for n in names
+            if n.startswith("dev_coll_fallback_")}
+
+
+def _bcast_inputs(n, dtype, seed):
+    """Other whole numbers on every rank: a rank handed its own buffer
+    back, or another non-root's, shows."""
+    return [np.random.default_rng([seed, r]).integers(
+        -1 << 20, 1 << 20, n).astype(np.float32).astype(dtype)
+        for r in range(P4)]
+
+
+def _drive_bcast(inputs, root, calls):
+    """Four ranks on four devices, each calling ``comm.bcast`` on its
+    own device-resident flat array ``calls`` times and deleting the
+    array it sent afterwards. Returns the last results on the host,
+    what the watched pvars and the fallback family rose by, and every
+    rank's ``device``-lane events."""
+    from mvapich2_tpu import mpit, run_ranks
+    before = {n: mpit.pvar(n).read() for n in _BCAST_WATCH}
+    fb0 = _fallback_reads()
+    got, lanes = [None] * P4, [None] * P4
+
+    def app(comm):
+        ch = comm.device_channel
+        assert type(ch).__name__ == "DeviceCollChannel"
+        x = jax.device_put(inputs[comm.rank], ch.device)
+        for _ in range(calls):
+            out = jax.block_until_ready(comm.bcast(x, root=root))
+        assert out.devices() == {ch.device} and out.ndim == 1
+        assert out is not x     # the root's result is a copy of its own
+        comm.barrier()
+        x.delete()              # the senders' buffers go; the result holds
+        got[comm.rank] = np.asarray(out)
+        lanes[comm.rank] = [e for e in comm.u.engine.tracer.events
+                            if e[1] == "device"]
+
+    run_ranks(P4, app, device_mesh=make_mesh((P4,), ("x",),
+                                             jax.devices()[:P4]))
+    rose = {n: mpit.pvar(n).read() - before[n] for n in _BCAST_WATCH}
+    fb = {n: v - fb0[n] for n, v in _fallback_reads().items() if v != fb0[n]}
+    return got, rose, fb, lanes
+
+
+def _bits_equal(got, want):
+    for r, (g, w) in enumerate(zip(got, want)):
+        assert g.dtype == w.dtype and g.shape == w.shape, (r, g.shape)
+        bits = np.dtype(f"u{w.dtype.itemsize}")
+        assert np.array_equal(g.view(bits), w.view(bits)), r
+
+
+@pytest.mark.parametrize("root", [0, 2])
+def test_comm_bcast_streams_and_counts_itself(interpreted_chain, root):
+    import ml_dtypes
+    from plain_reference import bcast as reference
+    from mvapich2_tpu.ops import pallas_ici
+    dt = np.dtype(ml_dtypes.bfloat16)
+    n = 5 * 2048                    # 20 KiB: the streaming bin, 5 tiles
+    inputs = _bcast_inputs(n, dt, 5100 + root)
+    got, rose, fb, lanes = _drive_bcast(inputs, root, calls=3)
+    _bits_equal(got, reference(inputs, root))
+    # the tier the program holds, a rank a call; call 1 decides and
+    # files, calls 2 and 3 run its plan and count as it did
+    assert rose["coll_level_ici"] == rose["dev_coll_tier_hbm"] == P4 * 3
+    assert rose["dev_coll_tier_vmem"] == 0 and fb == {}
+    assert rose["dev_call_plan_filed"] == P4
+    assert rose["dev_call_plan_hit"] == P4 * 2
+    assert rose["dev_deposit_as_is"] == P4 * 3
+    wire = pallas_ici.bcast_wire_bytes(n, dt, P4)
+    assert wire == n * 2 and rose["dev_bc_wire_bytes"] == P4 * 3 * wire
+    for lane in lanes:
+        wires = [(a["seq"], a["coll"], a["wire_bytes"])
+                 for _t, _l, name, ph, a in lane
+                 if name == "dev_bc_wire" and ph == "i"]
+        assert wires == [(s, "bcast", wire) for s in (1, 2, 3)]
+        begun = [(a["seq"], a["tier"], a["planned"], a["as_is"])
+                 for _t, _l, name, ph, a in lane
+                 if name == "dev_bcast" and ph == "B"]
+        assert begun == [(1, "hbm", False, True), (2, "hbm", True, True),
+                         (3, "hbm", True, True)]
+    # the lowering asked the same rule, once for the one signature
+    lowered = [a for lane in lanes for _t, _l, name, _ph, a in lane
+               if name == "ici_bcast"]
+    assert lowered and all(a["tier"] == "hbm" and a["root"] == root
+                           for a in lowered)
+
+
+def test_comm_bcast_in_the_vmem_bin_is_xla_and_no_fallback(
+        interpreted_chain):
+    from plain_reference import bcast as reference
+    inputs = _bcast_inputs(1024, np.dtype(np.float32), 5103)   # 4 KiB
+    got, rose, fb, lanes = _drive_bcast(inputs, 1, calls=2)
+    _bits_equal(got, reference(inputs, 1))
+    assert rose["coll_level_ici"] == P4 * 2 and fb == {}
+    assert rose["dev_coll_tier_hbm"] == rose["dev_coll_tier_vmem"] == 0
+    assert rose["dev_bc_wire_bytes"] == 0
+    assert rose["dev_call_plan_filed"] == rose["dev_call_plan_hit"] == P4
+    for lane in lanes:
+        assert [a["tier"] for _t, _l, name, ph, a in lane
+                if name == "dev_bcast" and ph == "B"] == ["xla", "xla"]
+        assert not [1 for _t, _l, name, _ph, _a in lane
+                    if name == "dev_bc_wire"]
+
+
+@pytest.mark.parametrize("seg_bytes,tier", [(12288, "hbm"), (4096, "xla")])
+def test_ibcast_segments_take_the_rule_of_their_own_size(interpreted_chain,
+                                                         seg_bytes, tier):
+    """``ibcast`` of 24 KiB cut into segments either side of the 8 KiB
+    edge: two of 12 KiB, each the chain, or six of 4 KiB, each XLA's
+    lowering; the bits are the root's either way. (The buffer is the
+    host's: a jax array cannot be written at ``wait()``, so ``ibcast``
+    never took one, on any path.)"""
+    from mvapich2_tpu import run_ranks
+    from mvapich2_tpu.utils.config import get_config
+    interpreted_chain.setenv("MV2T_DEVICE_COLL_MIN_BYTES", "1")
+    interpreted_chain.setenv("MV2T_DEVICE_NBC_SEG_BYTES", str(seg_bytes))
+    get_config().reload()
+    n, root = 6144, 3
+    inputs = _bcast_inputs(n, np.dtype(np.float32), 5104)
+    routed, tiers = [], []
+
+    def app(comm):
+        buf = inputs[comm.rank].copy()
+        req = comm.ibcast(buf, root=root)
+        routed.append(getattr(req, "device_nbc", False))
+        req.wait()
+        np.testing.assert_array_equal(buf, inputs[root])
+        comm.barrier()      # whichever rank's poll launched a segment
+        tiers.extend(a["tier"] for _t, lane, name, _ph, a
+                     in comm.u.engine.tracer.events
+                     if lane == "device" and name == "ici_bcast")
+
+    run_ranks(P4, app, device_mesh=make_mesh((P4,), ("x",),
+                                             jax.devices()[:P4]))
+    assert routed and all(routed)
+    assert tiers and set(tiers) == {tier}
