@@ -186,13 +186,16 @@ class DevicePass(LintPass):
         out: List[Finding] = []
         dev_mods = [m for m in modules if _is_device_module(m)]
         configs = self._budget_configs(modules)
+        drains_of: Dict[str, Dict[str, Set[str]]] = {}
+        for mod in dev_mods:
+            self._harvest_drains(mod, drains_of.setdefault(mod.path, {}))
         for mod in dev_mods:
             parks: Dict[str, dict] = {}
-            drains: Dict[str, Set[str]] = {}
+            drains = drains_of[mod.path]
             self._check_unbound(mod, out)
             self._harvest_parks_and_flow(mod, parks, out)
-            self._harvest_drains(mod, drains)
-            self._check_containers(mod, parks, drains, out)
+            self._check_containers(
+                mod, parks, self._with_imported(mod, drains_of), out)
             self._check_dead_pending(mod, parks, drains, out)
             self._check_semaphores(mod, out)
             self._check_vmem_budget(mod, configs, out)
@@ -415,6 +418,25 @@ class DevicePass(LintPass):
                 and it.func.attr in ("items", "values"):
             return terminal_name(it.func.value)
         return None
+
+    @staticmethod
+    def _with_imported(mod: SourceModule,
+                       drains_of: Dict[str, Dict[str, Set[str]]]
+                       ) -> Dict[str, Set[str]]:
+        """``mod``'s drains and those of the scanned device modules
+        beside it that it imports names from: a streamer subclassed
+        from a sibling module (ops/pallas_alltoall's, from
+        ops/pallas_ici) parks into maps its base class drains."""
+        merged = {c: set(k) for c, k in drains_of[mod.path].items()}
+        here = os.path.dirname(mod.path)
+        for node in ast.walk(mod.tree):
+            if not (isinstance(node, ast.ImportFrom) and node.module):
+                continue
+            sibling = os.path.join(
+                here, node.module.rsplit(".", 1)[-1] + ".py")
+            for c, kinds in drains_of.get(sibling, {}).items():
+                merged.setdefault(c, set()).update(kinds)
+        return merged
 
     # -- container adequacy ---------------------------------------------
     def _check_containers(self, mod: SourceModule, parks: Dict[str, dict],

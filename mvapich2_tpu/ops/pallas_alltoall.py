@@ -12,8 +12,10 @@ slot/credit streaming engine as ops/pallas_ici.py:
     and receives block ``(my-s)%p`` from the opposite one, so each
     receiver has exactly one writer per step and the whole step is a
     fixed permutation (no ring rotation of partials — alltoall payloads
-    are distinct, nothing folds). The local block short-circuits as one
-    HBM-to-HBM DMA before the wire steps.
+    are distinct, nothing folds). The local block short-circuits as
+    HBM-to-HBM DMAs of a chunk's rows, one started with each chunk of
+    the wire steps, so that they run under them, all waited for at the
+    kernel's end.
   * **Slot discipline** — chunks stream through the same
     double-buffered VMEM slots, addressed by a per-lane *global* chunk
     counter that keeps counting across steps (slot = gc % depth): the
@@ -114,6 +116,32 @@ class _A2AStreamer(_RingStreamer):
         # one lane's set_step clobber the other's routing
         self.step_dst = [None] * self.ndir
         self.step_up = [None] * self.ndir
+        self.own = None         # the local block's copy: set_own
+
+    def set_own(self, my, chunks, sem):
+        """The local block ``x[my] -> o[my]`` is ``chunks``, static
+        (row offset, rows), still to be copied, all on DMA semaphore
+        ``sem`` (no wave touches it)."""
+        self.own = (my, list(chunks), sem)
+
+    def copy_own_piece(self, x_hbm, o_hbm):
+        """Start the next piece of the local block's copy, HBM to HBM,
+        no wire; False when none was left. Nothing waits for it here:
+        it is parked with the stores and drained by ``finish()``, so it
+        runs under the wave's chunks. No wave writes ``o[my]`` (drains store
+        into ``o[step_up]``) and ``x`` is only read, so the order is
+        free. A piece a chunk step and not the block in one DMA: that
+        one, started in front of the first wave, held the wave's own
+        loads back for as long as it ran (PERF.md §6, PR 52)."""
+        my, chunks, sem = self.own
+        if not chunks:
+            return False
+        off, sz = chunks.pop(0)
+        cp = pltpu.make_async_copy(x_hbm.at[my, pl.ds(off, sz)],
+                                   o_hbm.at[my, pl.ds(off, sz)], sem)
+        cp.start()
+        self.pending_store["own", off] = cp
+        return True
 
     def set_step(self, d, dst, upstream):
         """Lane ``d`` now sends to ``dst`` and is written by
@@ -234,7 +262,8 @@ def _lane_steps(p: int, ndir: int) -> List[List[int]]:
 
 def _a2a_wave(st, x_hbm, o_hbm, lanes):
     """One permutation step across the active lanes: grant the step's
-    credits, pipeline issue-chunk-c / drain-chunk-(c-1) per lane, then
+    credits, pipeline issue-chunk-c / drain-chunk-(c-1) per lane (and
+    start a piece of the local block's copy beside each), then
     fence. ``lanes``: (d, dst, upstream, chunks) with chunks the static
     (row offset, rows) list the step moves."""
     for d, dst, up, _ch in lanes:
@@ -243,6 +272,7 @@ def _a2a_wave(st, x_hbm, o_hbm, lanes):
     cmax = max(len(ch) for _d, _t, _u, ch in lanes)
     slots = {d: [None] * len(ch) for d, _t, _u, ch in lanes}
     for c in range(cmax + 1):
+        st.copy_own_piece(x_hbm, o_hbm)
         for d, _t, _u, chunks in lanes:
             if c < len(chunks):
                 slots[d][c] = st.issue_a2a(d, x_hbm, *chunks[c])
@@ -263,10 +293,14 @@ def _hbm_alltoall_kernel(axis_name, p, step_rows, chunk, depth, ndir,
     for shard j — output the same shape with block j received from
     shard j. ``step_rows[s]`` is the static row count permutation step
     ``s`` moves (s=0: the local block); uniform alltoall moves whole
-    blocks, the v-variant the step-wide maximum. The chunk schedule is
-    globally uniform, so the whole program is symmetric — every shard's
-    k-th outgoing handle pairs with its k-th arrival and the peer
-    indices stay traced arithmetic."""
+    blocks, the v-variant the step-wide maximum. The local block's
+    copy runs under the waves: a piece of a chunk's rows is started
+    with each chunk step (the uniform block is out with the first
+    wave's), and the waits are in ``st.finish()``, with the streamer's
+    stores. The chunk schedule is globally uniform, so the whole
+    program is symmetric — every shard's k-th outgoing handle pairs
+    with its k-th arrival and the peer indices stay traced
+    arithmetic."""
     my = lax.axis_index(axis_name)
     st = _mk_a2a_streamer(p, ndir, depth, credits, scratch[:-1])
 
@@ -274,10 +308,9 @@ def _hbm_alltoall_kernel(axis_name, p, step_rows, chunk, depth, ndir,
     # the first writer differs per lane: barrier with all peers
     _entry_barrier([lax.rem(my + s, p) for s in range(1, p)])
 
-    # local block: one HBM-to-HBM DMA, no wire
-    if step_rows[0] > 0:
-        _copy(x_hbm.at[my, pl.ds(0, step_rows[0])],
-              o_hbm.at[my, pl.ds(0, step_rows[0])], scratch[-1])
+    # local block: HBM-to-HBM DMAs, no wire, started a piece a chunk
+    # step under the waves and waited for in finish()
+    st.set_own(my, _chunks(0, step_rows[0], chunk), scratch[-1])
 
     steps = _lane_steps(p, ndir)
     for q in range(max(len(ls) for ls in steps)):
@@ -292,6 +325,8 @@ def _hbm_alltoall_kernel(axis_name, p, step_rows, chunk, depth, ndir,
                           _chunks(0, step_rows[s], chunk)))
         if lanes:
             _a2a_wave(st, x_hbm, o_hbm, lanes)
+    while st.copy_own_piece(x_hbm, o_hbm):
+        pass                # what the waves had no chunk step left for
     st.finish()
 
 

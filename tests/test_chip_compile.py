@@ -673,6 +673,87 @@ def test_remote_sendrecv_compiles(mesh4):
     assert "tpu_custom_call" in text
 
 
+# -- the ROOT copy behind a communicating kernel (ISSUE 52, ROADMAP A3(e)) --
+
+def _root_copy_probe(kind, x):
+    """The least ``pallas_call`` of each kind: one whole-buffer copy of
+    the operand into the output. ``remote``: to the right neighbour, one
+    remote DMA; ``barrier``: a local DMA behind a signal and a wait on
+    the barrier semaphore; ``local``: the same local DMA, nothing else;
+    ``remote_aliased``: ``remote`` with the operand aliased to the
+    output."""
+    import jax
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from mvapich2_tpu.ops._compat import compiler_params
+
+    def kernel(x_hbm, o_hbm, sem, recv_sem):
+        right = (lax.rem(lax.axis_index("x") + 1, P4),)
+        if kind == "barrier":
+            barrier = pltpu.get_barrier_semaphore()
+            pltpu.semaphore_signal(
+                barrier, inc=1, device_id=right,
+                device_id_type=pltpu.DeviceIdType.MESH)
+            pltpu.semaphore_wait(barrier, 1)
+        if kind.startswith("remote"):
+            cp = pltpu.make_async_remote_copy(
+                src_ref=x_hbm, dst_ref=o_hbm, send_sem=sem,
+                recv_sem=recv_sem, device_id=right,
+                device_id_type=pltpu.DeviceIdType.MESH)
+        else:
+            cp = pltpu.make_async_copy(x_hbm, o_hbm, sem)
+        cp.start()
+        cp.wait()
+
+    # a collective id goes with the barrier semaphore and only with it
+    ids = dict(collective_id=13) if kind == "barrier" else {}
+    return pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())] * 2,
+        compiler_params=compiler_params(has_side_effects=True, **ids),
+        input_output_aliases={0: 0} if kind == "remote_aliased" else {},
+        name=f"probe_{kind}")(x)
+
+
+@pytest.mark.parametrize("kind,ops", [
+    ("remote", ["parameter", "custom-call", "copy"]),
+    ("barrier", ["parameter", "custom-call", "copy"]),
+    ("local", ["parameter", "custom-call"]),
+    ("remote_aliased", ["parameter", "copy-start", "copy-done",
+                        "custom-call", "copy"]),
+])
+def test_a_communicating_kernel_is_followed_by_a_root_copy(mesh4, kind, ops):
+    """What puts the ROOT ``copy`` behind every kernel of the four-chip
+    cells (ROADMAP A3(e)), pinned so that nobody asks again: under the
+    four-chip ``shard_map`` a ``pallas_call`` that holds one remote DMA,
+    or only touches the barrier semaphore, compiles to ``custom-call``
+    + ROOT ``copy`` of its whole result (8 192 x 128 float32 here); the
+    same local copy with neither is ROOT itself. ``input_output_aliases``
+    does not remove the copy: it adds a ``copy-start`` / ``copy-done``
+    of the operand in front."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    rows = 8192
+    sm = jax.shard_map(functools.partial(_root_copy_probe, kind),
+                       mesh=mesh4, in_specs=(P("x", None),),
+                       out_specs=P("x", None), check_vma=False)
+    x = jax.ShapeDtypeStruct((P4 * rows, 128), np.dtype("float32"),
+                             sharding=NamedSharding(mesh4, P("x", None)))
+    text = jax.jit(sm).lower(x).compile().as_text()
+    entry = _entry_ops(text)
+    assert [op for op, _dims in entry] == ops
+    assert all(dims == [str(rows), "128"] for op, dims in entry
+               if op in ("custom-call", "copy", "copy-done"))
+    root = [line for line in text[text.index("ENTRY"):].splitlines()
+            if line.lstrip().startswith("ROOT")]
+    assert len(root) == 1
+    assert ("custom-call(" in root[0]) == (kind == "local")
+    assert (f" copy(%probe_{kind}" in root[0]) == (kind != "local")
+
+
 # -- what the chip's compiler still refuses (ISSUE 22 stop rule) --------
 # These kernels are off chip_smoke's path. On a TPU they raise the
 # compiler's error — no XLA fallback covers them — and each case below

@@ -448,6 +448,61 @@ def test_device_pass_committed_kernels_clean():
     assert DevicePass().run(mods) == []
 
 
+_BASE_STREAMER = """
+from jax.experimental.pallas import tpu as pltpu
+
+
+class Streamer:
+    def __init__(self):
+        self.pending_store = {}
+
+    def store(self, src, dst, sem, key):
+        st = pltpu.make_async_copy(src, dst, sem)
+        st.start()
+        self.pending_store[key] = st
+
+    def drain_stores(self):
+        for key, h in list(self.pending_store.items()):
+            h.wait()
+            del self.pending_store[key]
+"""
+
+_CHILD_KERNEL = """
+from jax.experimental.pallas import tpu as pltpu
+{imports}
+
+def kernel(st, x_hbm, o_hbm, sem):
+    own = pltpu.make_async_copy(x_hbm, o_hbm, sem)
+    own.start()
+    st.pending_store["own"] = own
+    st.drain_stores()
+"""
+
+
+@pytest.mark.parametrize("imports,findings", [
+    ("from .base_streamer import Streamer", 0),
+    ("from pkg.ops.base_streamer import Streamer", 0),
+    ("from .another import Streamer", 1),
+    ("", 1)])
+def test_device_pass_counts_a_drain_the_base_class_module_holds(
+        tmp_path, imports, findings):
+    """A module that parks a started copy into a map of a streamer it
+    imports from a sibling device module (ops/pallas_alltoall's own
+    block, into ``_RingStreamer.pending_store``) is held to that
+    module's drains too; with no such import, or one of a module that
+    was not scanned beside it, the park is still a finding."""
+    from mvapich2_tpu.analysis.device import DevicePass
+    (tmp_path / "base_streamer.py").write_text(_BASE_STREAMER)
+    (tmp_path / "child.py").write_text(
+        _CHILD_KERNEL.format(imports=imports))
+    mods, errs = core.scan_paths([str(tmp_path)])
+    assert not errs and len(mods) == 2
+    fs = DevicePass(profiles=[]).run(mods)
+    assert len(fs) == findings, [f.msg for f in fs]
+    assert all("never drained" in f.msg and f.path.endswith("child.py")
+               for f in fs)
+
+
 def test_device_pass_catches_seed_violation_classes(tmp_path):
     """Mutation check with teeth: re-introduce the exact classes fixed
     in this PR's seed run and prove the pass catches each one."""

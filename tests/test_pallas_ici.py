@@ -444,12 +444,21 @@ def _eqns(jaxpr, name):
     return (e for e in _all_eqns(jaxpr) if e.primitive.name == name)
 
 
+def _dma(e):
+    """``(source, source is indexed, destination, destination is
+    indexed, semaphore, device id)`` of a ``dma_start`` / ``dma_wait``
+    equation; the device id is ``None`` for a local DMA."""
+    from jax import tree_util
+    (src, src_tf, dst, dst_tf, sem, _sem_tf, _ssem, _ssem_tf,
+     device) = tree_util.tree_unflatten(e.params["tree"], e.invars)
+    return src, bool(src_tf), dst, bool(dst_tf), sem, device
+
+
 def _local_dmas(coll, p, n, hbm_names, **kw):
     """Every local DMA the traced kernel of ``coll`` starts, as
     ``(source, source is whole, destination, destination is whole)``:
     the HBM operands by the names given (kernel argument order), any
     scratch buffer as ``vmem``; whole = no index on the ref."""
-    from jax import tree_util
     comm = MeshComm(make_mesh((p,), ("x",), jax.devices()[:p]))
     traced = jax.make_jaxpr(lambda x: comm.run(
         lambda s: _RING_FNS[coll](s, "x", p, interpret=True, **kw), x,
@@ -460,8 +469,7 @@ def _local_dmas(coll, p, n, hbm_names, **kw):
     names = dict(zip(kernel.invars, hbm_names))
     dmas = []
     for e in _eqns(kernel, "dma_start"):
-        (src, src_tf, dst, dst_tf, _sem, _sem_tf, _ssem, _ssem_tf,
-         device) = tree_util.tree_unflatten(e.params["tree"], e.invars)
+        src, src_tf, dst, dst_tf, _sem, device = _dma(e)
         if device is None:
             dmas.append((names.get(src, "vmem"), not src_tf,
                          names.get(dst, "vmem"), not dst_tf))
@@ -561,6 +569,143 @@ def test_one_operand_is_the_parents_kernel_and_k_add_a_load_a_chunk(coll, k):
         want["get"] += chunks
         want["swap"] += chunks
     assert {name: ops[name] for name in want} == want
+
+
+# ---------------------------------------------------------------------------
+# alltoall(v): the own block's copy runs under the waves (ISSUE 52)
+# ---------------------------------------------------------------------------
+
+def _ragged_counts(p, own=True):
+    """A skewed count matrix: no two pairs alike, past two shards a
+    permutation step that nobody has anything for, the own blocks
+    non-empty or all empty."""
+    counts = [[(17 * ROW + 37 * r + 1211 * j) % (23 * ROW) + 1
+               for j in range(p)] for r in range(p)]
+    for r in range(p):
+        counts[r][r] = 9 * ROW + 5 + r if own else 0
+        if p > 2:
+            counts[r][(r + p - 1) % p] = 0
+    return counts
+
+
+_A2A_CASES = {
+    # name: (p, bidirectional, elements a pair or the count matrix)
+    "p2": (2, None, 24 * ROW),               # one lane, one wave, 3 chunks
+    "p4": (4, True, 24 * ROW),               # two lanes, two waves
+    "p4_one_lane": (4, False, 20 * ROW - 7),     # ragged tiles, three waves
+    "p8": (8, True, 24 * ROW),               # two lanes, four waves
+    "v4": (4, True, _ragged_counts(4)),
+    "v2": (2, None, _ragged_counts(2)),
+    "v4_no_own": (4, True, _ragged_counts(4, own=False)),
+}
+
+
+def _a2a_fn(p, bidir, what):
+    """``(per-shard function, elements a shard takes, elements a shard
+    returns)`` of one case: ``hbm_alltoall`` on ``what`` elements a
+    pair, ``hbm_alltoallv`` on the count matrix ``what``."""
+    from mvapich2_tpu.ops import pallas_alltoall
+    kw = dict(chunk_bytes=4096, bidirectional=bidir, interpret=True)
+    if isinstance(what, int):
+        return (lambda s: pallas_alltoall.hbm_alltoall(s, "x", p, **kw),
+                p * what, p * what)
+    _sd, _rd, in_len, out_len = pallas_alltoall.packed_displs(what)
+    return (lambda s: pallas_alltoall.hbm_alltoallv(s, "x", p, what, **kw),
+            in_len, out_len)
+
+
+def _a2a_dma_order(p, bidir, what):
+    """The traced alltoall(v) kernel's DMAs in program order, as
+    ``(primitive, kind, semaphore)``: ``own`` is a local copy from the
+    HBM input to the HBM output, ``remote`` one with a device id."""
+    comm = MeshComm(make_mesh((p,), ("x",), jax.devices()[:p]))
+    fn, in_len, _out = _a2a_fn(p, bidir, what)
+    traced = jax.make_jaxpr(lambda x: comm.run(fn, x, out_specs=P("x")))(
+        jnp.zeros(p * in_len, jnp.float32))
+    (call,) = _eqns(traced.jaxpr, "pallas_call")
+    assert not call.params["input_output_aliases"]
+    kernel = call.params["jaxpr"]
+    x_hbm, o_hbm = kernel.invars[:2]
+    order = []
+    for e in _all_eqns(kernel):
+        if e.primitive.name not in ("dma_start", "dma_wait"):
+            continue
+        src, _src_tf, dst, _dst_tf, sem, device = _dma(e)
+        kind = ("remote" if device is not None else
+                "own" if (src, dst) == (x_hbm, o_hbm) else "local")
+        order.append((e.primitive.name, kind, sem))
+    return order, kernel.invars[-1]
+
+
+@pytest.mark.parametrize("case", sorted(_A2A_CASES))
+def test_alltoall_own_block_copy_runs_under_the_waves(case):
+    """Read off the ``pallas_call``'s jaxpr: the local HBM-to-HBM DMAs,
+    ``x[my] -> o[my]`` a chunk's rows at a time, are as many as the own
+    block has chunks, each started in front of the remote DMAs of a
+    chunk step of its own (the first in front of the first remote DMA;
+    never two with no remote DMA between them while a wave has chunks
+    left), and nothing waits on their semaphore, the kernel's last
+    scratch, which no wave touches, until the last remote DMA has been
+    started; where nobody keeps anything for itself there is no such
+    DMA."""
+    p, bidir, what = _A2A_CASES[case]
+    order, own_sem = _a2a_dma_order(p, bidir, what)
+    starts = [i for i, (name, kind, _s) in enumerate(order)
+              if (name, kind) == ("dma_start", "own")]
+    on_own_sem = [i for i, (_n, _k, sem) in enumerate(order)
+                  if sem is own_sem]
+    remote = [i for i, (name, kind, _s) in enumerate(order)
+              if (name, kind) == ("dma_start", "remote")]
+    assert len(remote) > 2, "several chunks a wave"
+    if case.endswith("no_own"):
+        assert not starts and not on_own_sem
+        return
+    own_elems = what if isinstance(what, int) else max(
+        row[r] for r, row in enumerate(what))
+    pieces = -(-pallas_ici._tile_rows(own_elems, np.float32)
+               // pallas_ici._cfg_chunk_rows(np.float32, 4096))
+    assert len(starts) == pieces > 1
+    waits = [i for i in on_own_sem if i not in starts]
+    assert len(waits) == pieces
+    assert all(order[i][:2] == ("dma_wait", "own") for i in waits)
+    assert all(order[i][2] is own_sem for i in starts)
+    assert starts[0] < remote[0], "no piece in front of the waves"
+    for a, b in zip(starts, starts[1:]):
+        assert b > remote[-1] or any(a < r < b for r in remote), \
+            "two pieces started in one chunk step"
+    assert min(waits) > remote[-1], \
+        "a piece is waited for before the waves end"
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_A2A_CASES))
+def test_alltoall_is_the_plain_reference_and_leaves_its_operand(case, dtype):
+    """The same cases run (the interpreter's DMAs are asynchronous too:
+    a copy that raced a wave would show): every shard holds, bit for
+    bit, what ``tests/plain_reference.py`` says, and the send buffer is
+    what it was, the own block the copy reads too."""
+    import plain_reference as ref
+    p, bidir, what = _A2A_CASES[case]
+    comm = MeshComm(make_mesh((p,), ("x",), jax.devices()[:p]))
+    fn, in_len, out_len = _a2a_fn(p, bidir, what)
+    # whole numbers under 256: bfloat16 holds each exactly
+    xv = ((np.arange(p * in_len) * 7 + 3) % 251).astype(np.float32)
+    xj = jnp.asarray(xv, dtype=dtype)
+    out, seen = comm.run(lambda s: (fn(s), s), xj,
+                         out_specs=(P("x"), P("x")))
+    assert out.dtype == xj.dtype
+    for kept in (xj, seen):
+        np.testing.assert_array_equal(
+            np.asarray(kept.astype(jnp.float32)), xv)
+    got = np.asarray(out.astype(jnp.float32)).reshape(p, out_len)
+    inputs = list(xv.reshape(p, in_len))
+    if isinstance(what, int):
+        want = ref.alltoall(inputs)
+    else:
+        want = ref.alltoallv([x[:sum(row)] for x, row in zip(inputs, what)],
+                             what)
+    for r in range(p):
+        np.testing.assert_array_equal(got[r, :want[r].size], want[r])
 
 
 # ---------------------------------------------------------------------------
