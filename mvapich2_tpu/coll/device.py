@@ -104,7 +104,12 @@ def _maybe_start_jax_profile() -> None:
             import atexit
 
             import jax
-            jax.profiler.start_trace(out_dir)
+            # the runtime's events and the ``dev_<coll>`` annotations are
+            # what trace/xprof.py reads; jax's Python tracer, on by
+            # default, would trace every rank thread frame by frame
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(out_dir, profiler_options=opts)
             atexit.register(_stop_jax_profile)
             log.info("jax.profiler trace started -> %s "
                      "(MV2T_JAX_PROFILE)", out_dir)
@@ -244,7 +249,9 @@ class _ImportedProgram:
 #                    channels, always), k*ndev for the fold channel's
 #                    reduce_scatter_block
 #   dev_release      rank 0: opening the gate; the others: their wait
-#                    in line, from their arrival to being let go
+#                    in line, from their arrival to being let go; their
+#                    E says ``turn``, the rank's place in the line the
+#                    leader let go (0 left first)
 #   dev_deliver      every rank, after dev_<coll> E: _deliver; its E says
 #                    ``relaid``, 1 when _deliver issued a reshape (0
 #                    for every program here: their results are flat)
@@ -378,6 +385,7 @@ class _Gate:
         self._arrived = 0
         self._line: List[int] = []      # the waiting ranks, as they came
         self._after: List[Optional[int]] = [None] * size
+        self._turn = [0] * size         # each rank's place in the line let go
         self._sems = [threading.Lock() for _ in range(size)]
         for sem in self._sems:
             sem.acquire()
@@ -416,21 +424,27 @@ class _Gate:
             if self.last_first:
                 line.reverse()
             self._arrived = 0
-            for rank, nxt in zip(line, line[1:] + [None]):
+            for turn, (rank, nxt) in enumerate(
+                    zip(line, line[1:] + [None])):
                 self._after[rank] = nxt
+                self._turn[rank] = turn
         if line:
             self._sems[line[0]].release()
         if self.broken:
             raise threading.BrokenBarrierError
 
-    def leave(self, rank: int) -> None:
-        """Wait to be let go, and let the next in line go."""
+    def leave(self, rank: int) -> int:
+        """Wait to be let go, and let the next in line go. Returns the
+        rank's place in the line the leader let go (0 left first): the
+        order the gate chose, for whoever traces it."""
         self._sems[rank].acquire()
+        turn = self._turn[rank]
         nxt, self._after[rank] = self._after[rank], None
         if nxt is not None:
             self._sems[nxt].release()
         if self.broken:
             raise threading.BrokenBarrierError
+        return turn
 
     def abort(self) -> None:
         with self._lock:
@@ -818,12 +832,14 @@ class DeviceCollChannel:
             except BaseException as e:   # noqa: BLE001 — must release peers
                 rv.error = e
                 rv.result = [None] * self.size
-        with self._phase("dev_release"):
+        with self._phase("dev_release") as ph:
             try:
                 if leader:
                     rv.gate.open()
-                else:
+                elif ph is None:
                     rv.gate.leave(self.rank)
+                else:       # its place in the line, on the span's E
+                    ph.args["turn"] = rv.gate.leave(self.rank)
             except threading.BrokenBarrierError:
                 rv.slots[self.rank] = None
                 raise RuntimeError(
@@ -1029,7 +1045,9 @@ class DeviceCollChannel:
         traced nor metered (``metrics.LIVE``).
         While a recorder is attached the call also lies on the jax
         profiler's host plane as a TraceAnnotation of the same name, so
-        an MV2T_JAX_PROFILE trace shows it beside the device's ops."""
+        an MV2T_JAX_PROFILE trace shows it beside the device's ops; it
+        says ``seq`` and ``rank``, by which trace/xprof.py ties the
+        runtime's events on this thread's line to the recorder's spans."""
         global _profiler
         tier = self._tier = self._note_tier(comm, name, local, op)
         for lv in self._level_pvars:    # the hierarchy levels it rides
@@ -1051,7 +1069,8 @@ class DeviceCollChannel:
                                      else local).nbytes),
                        "seq": self._seq, "coll": name, "as_is": as_is,
                        "planned": self._plan is not None})
-            note = _profiler.TraceAnnotation(span, seq=self._seq)
+            note = _profiler.TraceAnnotation(span, seq=self._seq,
+                                             rank=self.rank)
         _maybe_start_jax_profile()
         mx = _metrics.LIVE
         if mx is not None:

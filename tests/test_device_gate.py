@@ -24,14 +24,17 @@ def _until(cond, what, timeout=10.0):
         time.sleep(0.001)
 
 
-def _in_line(gate, ranks, left, errors):
+def _in_line(gate, ranks, left, errors, turns=None):
     """Start one waiting thread a rank, each only after the one before
-    it stands in the line, so the line's order is ``ranks``."""
+    it stands in the line, so the line's order is ``ranks``; ``turns``
+    takes what ``leave`` told each rank."""
     def waiter(rank):
         try:
             gate.arrive(rank, False)
-            gate.leave(rank)
+            turn = gate.leave(rank)
             left.append(rank)
+            if turns is not None:
+                turns[rank] = turn
         except threading.BrokenBarrierError:
             errors.append(rank)
     threads = []
@@ -50,16 +53,18 @@ def _in_line(gate, ranks, left, errors):
                          ids=["first_in_first_out", "last_first"])
 def test_ranks_leave_in_the_order_they_came(order, last_first):
     """... or, through the slot channel's ``last_first`` gate (ISSUE
-    50), in the reverse of it; one at a time either way."""
+    50), in the reverse of it; one at a time either way, and ``leave``
+    tells each rank its place in the line let go (ISSUE 53)."""
     gate = _Gate(len(order) + 1, last_first)
-    left, errors = [], []
-    threads = _in_line(gate, order, left, errors)
+    left, errors, turns = [], [], {}
+    threads = _in_line(gate, order, left, errors, turns)
     assert left == []               # nobody leaves before the leader opens
     gate.arrive(0, True)            # the last to arrive: does not wait
     gate.open()
     for t in threads:
         t.join(10)
     assert left == list(order)[::-1 if last_first else 1] and errors == []
+    assert turns == {rank: turn for turn, rank in enumerate(left)}
     assert gate.n_waiting == 0 and not gate.broken
 
 

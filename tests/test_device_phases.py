@@ -314,7 +314,9 @@ def test_eight_threads_waiting_on_one_shared_result_get_the_same_bits(
 def test_trace_annotation_only_while_a_recorder_is_attached(
         monkeypatch, trace):
     """Untraced: no recorder, no phase object, no TraceAnnotation.
-    Traced: one annotation per collective, named like the span."""
+    Traced: one annotation per collective, named like the span, that
+    says the call's ``seq`` and the rank whose thread it is
+    (trace/xprof.py ties a line of the profile to its rank by it)."""
     import mvapich2_tpu.coll.device as devmod
     if trace:
         monkeypatch.setenv("MV2T_TRACE", "1")
@@ -343,11 +345,53 @@ def test_trace_annotation_only_while_a_recorder_is_attached(
         monkeypatch.undo()
         get_config().reload()
     if trace:
-        assert sorted(made, key=str) == [("dev_allreduce", {"seq": 1})] * 4
+        assert sorted(made, key=str) == [
+            ("dev_allreduce", {"seq": 1, "rank": r}) for r in range(4)]
         assert all(isinstance(p, devmod._Phase) for p in phases)
     else:
         assert made == []
         assert phases == [None] * 4     # the shared no-op was entered
+
+
+@pytest.mark.parametrize("channel", ["mesh", "slot"])
+def test_release_says_the_turn_the_gate_chose(traced, channel):
+    """Every rank but the leader leaves the gate with its place in the
+    line the leader let go on its ``dev_release`` E (``turn``; rank 0,
+    which opens the gate, says none): 0..size-2 once each a ``seq``, in
+    the order the ranks came on the mesh channel's first-in-first-out
+    gate and in its reverse on the slot channel's last-first one. The
+    ranks come 30 ms apart, the leader first, so the order they came in
+    is theirs by rank."""
+    import time
+    ranks = CHANNELS[channel][0]
+    lanes = {}
+
+    def app(comm):
+        x = np.ones(N, np.float32)
+        comm.allreduce(x)               # builds the program
+        comm.barrier()
+        time.sleep(0.03 * comm.rank)
+        comm.allreduce(x)
+        lanes[comm.rank] = _device_lane(comm)
+
+    run_ranks(ranks, app, device_mesh=_mesh(channel))
+    turns = {}                          # seq -> {rank: turn}
+    for rank, lane in lanes.items():
+        for _t, _layer, name, ph, args in lane:
+            if name == "dev_release" and ph == "E":
+                assert ("turn" in args) == (rank != 0), (rank, args)
+                if rank:
+                    turns.setdefault(args["seq"], {})[rank] = args["turn"]
+            elif name == "dev_release":
+                assert "turn" not in args       # the B is as it was
+    assert sorted(turns) == [1, 2]
+    for got in turns.values():
+        assert sorted(got.values()) == list(range(ranks - 1))
+    came = list(range(1, ranks))
+    if channel == "slot":
+        came.reverse()
+    assert [r for r, _turn in sorted(turns[2].items(),
+                                     key=lambda kv: kv[1])] == came
 
 
 def test_summarize_counts_nested_spans_once_and_lists_the_phases():
@@ -406,8 +450,9 @@ def test_the_ring_holds_the_same_tuple_and_args(traced, channel):
     """Every event is ``(time.monotonic, lane, name, ph, args)``; the
     ``dev_<coll>`` B says tier, op, bytes, seq, coll, as_is and planned,
     its E and every phase event seq and coll, and a phase's E besides them
-    what its site added after the B, which the B in the ring never
-    gains. ``us`` (and the E's ``tier``) are gone: the two stamps say
+    what its site added after the B (``dev_release``'s, on every rank
+    but the leader, the ``turn`` the gate gave it), which the B in the
+    ring never gains. ``us`` (and the E's ``tier``) are gone: the two stamps say
     it."""
     lanes = _run_two_collectives(channel)
     for rank, lane in lanes.items():
@@ -427,6 +472,8 @@ def test_the_ring_holds_the_same_tuple_and_args(traced, channel):
                 assert set(args) == PHASE_ARGS | {ADDED[name]}, ev
             elif ph == "E" and name == "dev_chip_fold":
                 assert set(args) == PHASE_ARGS | FOLD_ADDED, ev
+            elif ph == "E" and name == "dev_release" and rank:
+                assert set(args) == PHASE_ARGS | {"turn"}, ev
             else:
                 assert set(args) == PHASE_ARGS, ev
         first = next(e[4] for e in lane if e[2] == "dev_allreduce")
