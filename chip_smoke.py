@@ -410,6 +410,24 @@ def _tag(op) -> str:
     return "" if op is None else str(op)
 
 
+def _ring_steps_note(name: str, op, nbytes: int, p: int) -> str:
+    """How the streaming ring kernel under a float32 call of ``nbytes``
+    a shard is written (``pallas_ici.ring_steps``: chunk steps traced,
+    chunk steps its loops stand for); nothing for a collective with no
+    such kernel or where the one tier rule sends the call elsewhere."""
+    from mvapich2_tpu.ops.pallas_ici import planned_tier, ring_steps
+    coll = {"allreduce": "allreduce", "reduce": "allreduce",
+            "reduce_scatter_block": "reduce_scatter",
+            "allgather": "allgather", "bcast": "bcast"}.get(name)
+    if coll is None or planned_tier(
+            name, nbytes * (p if name == "allgather" else 1), np.float32,
+            op if isinstance(op, str) else None, num_devices=p)[0] != "hbm":
+        return ""
+    steps = ring_steps(coll, nbytes // 4, np.float32, p)
+    return (f" | ring steps {steps['steps_traced']} traced, "
+            f"{steps['steps_looped']} looped")
+
+
 def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
     """``scale`` divides the element counts (CPU rehearsal only)."""
     import jax
@@ -520,7 +538,8 @@ def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
         say(f"four chips: {name} {_tag(op):<3} {nbytes:>9} B/rank  "
             f"bit-equal to numpy and to the stock XLA lowering on "
             f"{nranks} ranks | first call {first:.3f} s (compile), steady "
-            f"{steady * 1e3:.3f} ms/call (smoke timing)")
+            f"{steady * 1e3:.3f} ms/call (smoke timing)"
+            + _ring_steps_note(name, op, nbytes, nranks))
     rose = {n: mpit.pvar(n).read() - v for n, v in before.items()}
     fb = {n: v - fb0[n] for n, v in fallback_pvars().items()}
     say(f"proof: {rose}; fallbacks {fb}")
@@ -537,11 +556,13 @@ def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
 
 
 def fold_phase(seed: int, nranks: int = 8, ndev: int = 4,
-               nbytes: int = MiB) -> None:
+               nbytes: int = 32 * MiB) -> None:
     """Two ranks a chip: ``run_ranks(8, app, device_mesh=<four chips>)``
     binds ``DeviceFoldChannel``; its five supported collectives once
-    each on device-resident buffers, against numpy (``nbytes`` a rank;
-    a CPU rehearsal passes a smaller one)."""
+    each on device-resident buffers, against numpy (``nbytes`` a rank:
+    a streaming size for all four reductions, at which the ring folds
+    both deposits in rounds long enough to loop; a CPU rehearsal passes
+    a smaller one)."""
     import jax
 
     from mvapich2_tpu import mpit, run_ranks
@@ -567,6 +588,7 @@ def fold_phase(seed: int, nranks: int = 8, ndev: int = 4,
             "reduce": [total if r == root else None for r in range(nranks)]}
     results = {name: [None] * nranks for name in names}
     homes = [None] * nranks
+    took = {}
 
     def app(comm):
         ch = comm.device_channel
@@ -583,10 +605,13 @@ def fold_phase(seed: int, nranks: int = 8, ndev: int = 4,
                  "reduce": lambda: comm.reduce(x, root=root)}
         for name in names:
             comm.barrier()
+            t0 = time.perf_counter()
             out = calls[name]()
             if out is None:         # reduce, off the root
                 continue
             out = jax.block_until_ready(out)
+            if comm.rank == root:
+                took[name] = time.perf_counter() - t0
             assert out.sharding.device_set == {dev}, \
                 (name, comm.rank, out.sharding.device_set)
             results[name][comm.rank] = np.asarray(out)
@@ -619,8 +644,14 @@ def fold_phase(seed: int, nranks: int = 8, ndev: int = 4,
                     (want is not None and not np.array_equal(got, want)):
                 raise AssertionError(f"fold {name}: rank {r} differs from "
                                      f"the numpy reference")
+        coll, _, op = name.partition(" ")
         say(f"fold: {name:<20} {nbytes:>8} B/rank  bit-equal to numpy on "
-            f"{nranks} ranks over {ndev} chips")
+            f"{nranks} ranks over {ndev} chips | first call "
+            f"{took[name]:.3f} s (compile)"
+            # the reductions' program is the ring over the chips (a
+            # gather or a broadcast moves the chips' stacked deposits)
+            + (_ring_steps_note(coll, op or "sum", nbytes, ndev)
+               if coll.startswith(("allreduce", "reduce")) else ""))
     rose = {n_: mpit.pvar(n_).read() - v for n_, v in before.items()}
     fb = {n_: v - fb0[n_] for n_, v in fallback_pvars().items()}
     say(f"proof (fold): {rose}; fallbacks {fb}")

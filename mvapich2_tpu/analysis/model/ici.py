@@ -94,7 +94,11 @@ _FREE = -1     # slot occupant sentinel: never written
 def _program(C: int, dirs):
     """The serialized per-rank instruction stream of stream_step:
     issue c on every direction, then drain c-1; trailing drains of the
-    last chunk close the wave."""
+    last chunk close the wave. Since ISSUE 54 the kernel writes a long
+    round as its first ``D`` steps, one loop over groups of ``D`` steps
+    and a tail (pallas_ici ``_looped_steps``); the chip executes the
+    same instructions in this same order on the same slots, so the
+    stream modelled here is still the one that runs."""
     prog = []
     for c in range(C):
         for d in dirs:

@@ -59,8 +59,11 @@ Layout (what Mosaic's tiling demands): data moves as ``(rows, 128)``
 tiles with ``rows`` a multiple of the dtype's sublane tile (8 for
 4-byte types, 16 for 2-byte, 32 for 1-byte); slot, direction and ring
 block indices sit on leading, untiled dimensions, so every slice of a
-tiled dimension is a static, tile-aligned range and the only traced
-indices are leading ones. Wrappers pad each ring block to a whole
+tiled dimension is a tile-aligned range: static in a step that is
+written out, and in a loop's body (a long round's or chain's chunk
+steps are a ``fori_loop``, ``_looped_steps``) a traced row offset that
+is a marked multiple of the tile, of a static size; every other traced
+index is a leading one. Wrappers pad each ring block to a whole
 number of tiles with the op identity and reshape the HBM operands to
 ``(p, block_rows, 128)`` before the ``pallas_call``.
 
@@ -272,6 +275,12 @@ class _RingStreamer:
         self.pending_fold: Dict = {}           # (i, d, slot) -> fold load
         self.pending_store: Dict = {}
 
+    def pending(self):
+        """The tables of DMA descriptors started and not yet waited
+        for, each keyed by the slot its DMA occupies."""
+        return (self.pending_send, self.pending_acc, self.pending_fold,
+                self.pending_store)
+
     def _dev(self, idx):
         # logical device id of ring index ``idx``: the identity on a
         # 1-D mesh; on a multi-axis torus the ring runs along ONE axis,
@@ -476,22 +485,107 @@ class _RingStreamer:
         result is stored, in whatever unit ``issue``/``drain`` take
         (tuples of HBM block refs and a block ref here; the quantized
         streamer still passes flat element offsets) — this loop only
-        hands them through."""
-        ndir = self.ndir
-        cmax = max(len(c) for c in spans_chunks)
-        live: List[List[Optional[int]]] = [[None] * len(spans_chunks[d])
-                                           for d in range(ndir)]
-        for c in range(cmax + 1):
+        hands them through.
+
+        The steps differ in nothing but their offsets, so they are not
+        unrolled (``_looped_steps``): the first ``depth`` are traced as
+        they are, after which every slot of every lane holds a send, a
+        store and the loads to wait for; the whole groups of ``depth``
+        steps that follow, as long as every lane issues and drains full
+        chunks, are one ``fori_loop`` body whose offsets are traced and
+        whose slots are static; a lane's short last chunk, the odd
+        steps and the last drain are traced after. The chip runs the
+        same DMAs in the same order on the same slots; the traced
+        program is a few steps a round whatever the payload. A round
+        too short for two groups (``_step_plan``) is traced whole."""
+        ndir, depth = self.ndir, self.depth
+        base = list(self.gc)        # a chunk's slot: (base + c) % depth
+        first, groups, steps = plan = _step_plan(spans_chunks, depth, lag=1)
+
+        def step(c, now, before):
+            # ``c``: the step, or what it is modulo ``depth``
             for d in range(ndir):
-                if c < len(spans_chunks[d]):
-                    off, sz = spans_chunks[d][c]
-                    live[d][c] = self.issue(
-                        d, src[d], off, sz, acc[d] if acc else None, red)
+                if now[d] is not None:
+                    self.issue(d, src[d], *now[d],
+                               acc[d] if acc else None, red)
             for d in range(ndir):
-                if 1 <= c and c - 1 < len(spans_chunks[d]):
-                    off, sz = spans_chunks[d][c - 1]
-                    self.drain(d, live[d][c - 1], dst[d], off, sz, red)
+                if before[d] is not None:
+                    self.drain(d, (base[d] + c - 1) % depth, dst[d],
+                               *before[d], red)
+
+        def static(c):
+            def at(j):
+                return [chunks[j] if 0 <= j < len(chunks) else None
+                        for chunks in spans_chunks]
+            step(c, at(c), at(c - 1))
+
+        def looped(g, i):
+            # step first + g * depth + i: full chunks on every lane
+            chunk = spans_chunks[0][0][1]
+            turn = g * (depth * chunk)
+
+            def at(j):
+                return [(pl.multiple_of(turn + (lo + j * chunk),
+                                        math.gcd(lo, chunk)), chunk)
+                        for (lo, _), *_ in spans_chunks]
+            step(first + i, at(first + i), at(first + i - 1))
+
+        _looped_steps(self, plan, static, looped)
         self.drain_stores()
+
+
+def _step_plan(spans_chunks, depth: int, skew=None,
+               lag: int = 0) -> Tuple[int, int, int]:
+    """``(first, groups, steps)`` of a run of pipeline steps in which
+    lane ``d`` handles its chunk ``t - skew[d]`` in step ``t`` (and is
+    done with it ``lag`` steps later): the ``first`` steps are traced as
+    they are, until every lane has ``depth`` chunks behind it; the
+    ``groups`` whole groups of ``depth`` steps that follow, in each of
+    which every lane's chunk is a full one, are a loop's body; the
+    rest of the ``steps`` is traced after. Decided from the shapes
+    alone: fewer than two groups, or a lane with no chunk, and nothing
+    is looped (``first`` is ``steps``)."""
+    lanes = [d for d, c in enumerate(spans_chunks) if c]
+    skew = skew or [0] * len(spans_chunks)
+    chunk = spans_chunks[lanes[0]][0][1]
+    first = max(skew[d] for d in lanes) + depth
+    groups = (min(skew[d] + sum(sz == chunk for _, sz in spans_chunks[d])
+                  for d in lanes) - first) // depth
+    steps = max(skew[d] + len(spans_chunks[d]) for d in lanes) + lag
+    if groups < 2 or len(lanes) < len(spans_chunks):
+        first, groups = steps, 0
+    return first, groups, steps
+
+
+def _looped_steps(st, plan, static, looped) -> None:
+    """Trace the steps of ``plan`` (``_step_plan``) on streamer ``st``:
+    ``static(t)`` traces step ``t`` as it is, ``looped(g, i)`` step
+    ``first + g * depth + i`` with ``g`` the loop's traced counter.
+    The body's first waits are for descriptors made in front of the
+    loop (same slot, same shape, same semaphore: a wait needs no more);
+    the ones it leaves behind are its scope's, so after the loop the
+    streamer holds again those from before it, which name the same
+    slots, and its chunk counters say what the chip has counted."""
+    first, groups, steps = plan
+    depth = st.depth
+    for t in range(first):
+        static(t)
+    if groups:
+        held = [dict(m) for m in st.pending()]
+        counted = [list(n) for n in (st.gc, st.rc)]
+
+        def group(g, _):
+            for i in range(depth):
+                looped(g, i)
+
+        lax.fori_loop(0, groups, group, None)
+        for m, was in zip(st.pending(), held):
+            m.clear()
+            m.update(was)
+        for n, was in zip((st.gc, st.rc), counted):
+            n[:] = [a + (b - a) * groups for a, b in zip(was, n)]
+    for t in range(first + groups * depth, steps):
+        static(t)
 
 
 def _mk_streamer(p, ndir, depth, credits, left, right, scratch,
@@ -689,18 +783,20 @@ def _chain(st, spans_chunks, src, dst, sends, skew):
     a step (the chip read exactly that: PERF.md section 6, PR 51).
 
     The steps differ in nothing but their row offsets, so they are not
-    unrolled: the first are traced as they are (until every lane has
-    ``depth`` chunks behind it, so that every send slot has a DMA to
-    wait for), the whole groups of ``depth`` steps that follow, as long
-    as every lane's chunk is a full one, run as one ``fori_loop`` body
-    whose offsets are traced and whose slots are static, and what is
-    left (a lane's odd chunk, its short last one, the farther lane's
-    last ``skew`` chunks) is traced after. The traced program is a few
-    steps whatever the payload."""
+    unrolled (``_looped_steps``, as a ring's round): the first are
+    traced as they are (until every lane has ``depth`` chunks behind
+    it, so that every send slot has a DMA to wait for), the whole
+    groups of ``depth`` steps that follow, as long as every lane's
+    chunk is a full one, run as one ``fori_loop`` body whose offsets
+    are traced and whose slots are static, and what is left (a lane's
+    odd chunk, its short last one, the farther lane's last ``skew``
+    chunks) is traced after. The traced program is a few steps whatever
+    the payload."""
     depth = st.depth
     lanes = [d for d, c in enumerate(spans_chunks) if c]
     chunk = spans_chunks[lanes[0]][0][1]
     order = sorted(lanes, key=lambda d: not sends[d])
+    first, _groups, _steps = plan = _step_plan(spans_chunks, depth, skew)
 
     def step(chunks):
         for d in order:
@@ -714,31 +810,13 @@ def _chain(st, spans_chunks, src, dst, sends, skew):
         step([c[t - skew[d]] if 0 <= t - skew[d] < len(c) else None
               for d, c in enumerate(spans_chunks)])
 
-    def group(g, _):
-        for i in range(depth):
-            t = first + g * depth + i
-            step([(pl.multiple_of(c[0][0] + (t - skew[d]) * chunk,
-                                  math.gcd(c[0][0], chunk)), chunk)
-                  for d, c in enumerate(spans_chunks)])
+    def looped(g, i):
+        t = first + g * depth + i
+        step([(pl.multiple_of(c[0][0] + (t - skew[d]) * chunk,
+                              math.gcd(c[0][0], chunk)), chunk)
+              for d, c in enumerate(spans_chunks)])
 
-    # the steps in which every lane handles a full chunk that is not
-    # among its first ``depth``
-    first = max(skew[d] for d in lanes) + depth
-    groups = (min(skew[d] + sum(sz == chunk for _, sz in spans_chunks[d])
-                  for d in lanes) - first) // depth
-    steps = max(skew[d] + len(spans_chunks[d]) for d in lanes)
-    if groups < 2 or len(lanes) < len(spans_chunks):
-        first, groups = steps, 0
-    for t in range(first):
-        static(t)
-    if groups:
-        # the body leaves its own descriptors behind, which are its
-        # scope's; the ones from before it name the same slots
-        sent = dict(st.pending_send)
-        lax.fori_loop(0, groups, group, None)
-        st.pending_send = sent
-    for t in range(first + groups * depth, steps):
-        static(t)
+    _looped_steps(st, plan, static, looped)
 
 
 def _hbm_bcast_kernel(axis_name, p, root, spans_chunks, depth, ndir,
@@ -765,22 +843,17 @@ def _hbm_bcast_kernel(axis_name, p, root, spans_chunks, depth, ndir,
     streamer().enter()      # the barrier and the credits: every chip's
     dist = lax.rem(my - root + p, p)
 
-    @pl.when(dist == 0)
-    def _():
-        st = streamer()     # each schedule counts its own chunks
-        own = pltpu.make_async_copy(x_hbm, o_hbm, scratch[-1])
-        own.start()
-        st.pending_store["own"] = own   # drained with the stores
-        _chain(st, spans_chunks, x_hbm, None, [True] * ndir, [0] * ndir)
-        st.finish()
-
-    for k in range(1, p):
+    for k, sends, skew in _bcast_roles(p, ndir):
         @pl.when(dist == k)
-        def _(k=k):
-            hops = [k, p - k][:ndir]        # from the root, along each lane
-            st = streamer()
-            _chain(st, spans_chunks, o_hbm, o_hbm,
-                   [h < p - 1 for h in hops], [h - min(hops) for h in hops])
+        def _(k=k, sends=sends, skew=skew):
+            st = streamer()     # each schedule counts its own chunks
+            if k == 0:
+                own = pltpu.make_async_copy(x_hbm, o_hbm, scratch[-1])
+                own.start()
+                st.pending_store["own"] = own   # drained with the stores
+                _chain(st, spans_chunks, x_hbm, None, sends, skew)
+            else:
+                _chain(st, spans_chunks, o_hbm, o_hbm, sends, skew)
             st.finish()
 
 
@@ -824,6 +897,56 @@ def _resolve_ndir(num_devices: int, bidirectional) -> int:
     return 2 if (bidirectional and num_devices > 2) else 1
 
 
+def _ring_geometry(block_rows: int, dtype, chunk_bytes, depth,
+                   num_devices: int, bidirectional):
+    """``(chunk rows, depth, lanes, each lane's chunks)`` of a
+    streaming ring over blocks of ``block_rows`` rows: what the cvars
+    say where the caller says nothing."""
+    chunk = min(_cfg_chunk_rows(dtype, chunk_bytes), block_rows)
+    ndir = _resolve_ndir(num_devices, bidirectional)
+    return chunk, _cfg_depth(depth), ndir, [
+        _chunks(lo, hi, chunk) for lo, hi in
+        _block_spans(block_rows, ndir, _sublanes(dtype))]
+
+
+def _bcast_roles(p: int, ndir: int):
+    """``(distance from the root, lanes the chip sends on, each lane's
+    skew)`` of every schedule of the broadcast chain: the root's, then
+    one for each chip after it (``_hbm_bcast_kernel``)."""
+    yield 0, [True] * ndir, [0] * ndir
+    for k in range(1, p):
+        hops = [k, p - k][:ndir]            # from the root, along each lane
+        yield k, [h < p - 1 for h in hops], [h - min(hops) for h in hops]
+
+
+def ring_steps(coll: str, nelems: int, dtype, num_devices: int, *,
+               chunk_bytes: Optional[int] = None,
+               depth: Optional[int] = None,
+               bidirectional: Optional[bool] = None) -> Dict[str, int]:
+    """How the streaming kernel ``hbm_ring_<coll>`` ('allreduce',
+    'reduce_scatter', 'allgather', 'bcast') is written for a shard of
+    ``nelems`` elements (its ring blocks are the shard for a gather or
+    a broadcast, a ``p``-th of it for a reduction): ``steps_traced``,
+    the chunk steps its trace holds, and ``steps_looped``, the chunk
+    steps its loops stand for, each summed over the kernel's rounds
+    (over the chain's schedules for a broadcast). A kernel whose rounds
+    are too short to loop (``_step_plan``) reads 0 looped."""
+    p = num_devices
+    block = nelems if coll in ("allgather", "bcast") else -(-nelems // p)
+    _, d, ndir, spans_chunks = _ring_geometry(
+        _tile_rows(block, dtype), dtype, chunk_bytes, depth, p,
+        bidirectional)
+    if coll == "bcast":
+        plans = [_step_plan(spans_chunks, d, skew)
+                 for _, _, skew in _bcast_roles(p, ndir)]
+    else:
+        rounds = (p - 1) * (2 if coll == "allreduce" else 1)
+        plans = [_step_plan(spans_chunks, d, lag=1)] * rounds
+    return {"steps_traced": sum(steps - groups * d + (d if groups else 0)
+                                for _, groups, steps in plans),
+            "steps_looped": sum(groups * d for _, groups, _ in plans)}
+
+
 def _ring_call(kernel_fn, static, block_rows: int, dtype, cid: int,
                out_shape, interpret, credits, chunk_bytes, depth,
                num_devices: int, bidirectional, mesh_ctx, *operands):
@@ -832,11 +955,8 @@ def _ring_call(kernel_fn, static, block_rows: int, dtype, cid: int,
     launch with all operands left in HBM. More than one operand is the
     fold rounds' ``k``: one fold slot more for each beyond the first."""
     interpret, credits = _resolve_flags(interpret, credits)
-    chunk = min(_cfg_chunk_rows(dtype, chunk_bytes), block_rows)
-    d = _cfg_depth(depth)
-    ndir = _resolve_ndir(num_devices, bidirectional)
-    spans_chunks = [_chunks(lo, hi, chunk) for lo, hi in
-                    _block_spans(block_rows, ndir, _sublanes(dtype))]
+    chunk, d, ndir, spans_chunks = _ring_geometry(
+        block_rows, dtype, chunk_bytes, depth, num_devices, bidirectional)
     kernel = functools.partial(kernel_fn, *static, spans_chunks, d, ndir,
                                credits, mesh_ctx)
     multi = isinstance(out_shape, tuple)
@@ -1159,18 +1279,23 @@ def planned_tier(name: str, shard_nbytes: int, dtype, op: Optional[str],
     return tier, None
 
 
-def _trace_entry(coll: str, tier: str, nbytes: int, op=None,
+def _trace_entry(coll: str, tier: str, nbytes: int, op=None, ring=None,
                  **extra) -> None:
     """Drop a 'device'-lane instant at an ICI entry point. These
     wrappers execute at TRACE time (once per compiled signature, not
     per call — programs are cached), so the instant records which tier
-    a signature LOWERED to; the per-call span lives one level up in
+    a signature LOWERED to and, of one that lowered to the streaming
+    ring (``ring``: the shard's ``(nelems, dtype, num_devices)``), how
+    many of its chunk steps are written out and how many its loops
+    stand for (``ring_steps``); the per-call span lives one level up in
     coll/device.py. One recorder lookup, nothing when untraced."""
     try:
         from ..runtime.universe import current_universe
         u = current_universe()
         rec = u.engine.tracer if u is not None else None
         if rec is not None:
+            if ring is not None and tier == "hbm":
+                extra.update(ring_steps(coll, *ring))
             rec.record("device", f"ici_{coll}", "i", tier=tier,
                        bytes=int(nbytes), op=op, **extra)
     except Exception:   # tracing must never kill a lowering
@@ -1232,7 +1357,8 @@ def ici_all_reduce(x, axis_name: str, num_devices: int,
     tier, reason = planned_tier("allreduce", nbytes, xs[0].dtype, op,
                                 interpret, p, _multi_axis(mesh_ctx))
     x = _fold_unless_ring_does(xs, op, tier, p, mesh_ctx)
-    _trace_entry("allreduce", tier, nbytes, op=op)
+    _trace_entry("allreduce", tier, nbytes, op=op,
+                 ring=(xs[0].size, xs[0].dtype, p))
     if tier == "quant":
         from . import pallas_quant
         return pallas_quant.quant_ring_all_reduce(x, axis_name, p, op,
@@ -1261,7 +1387,7 @@ def ici_all_gather(x: jax.Array, axis_name: str, num_devices: int,
     out_nbytes = x.size * x.dtype.itemsize * p
     tier, reason = planned_tier("allgather", out_nbytes, x.dtype, None,
                                 interpret, p, _multi_axis(mesh_ctx))
-    _trace_entry("allgather", tier, out_nbytes)
+    _trace_entry("allgather", tier, out_nbytes, ring=(x.size, x.dtype, p))
     if tier == "vmem":
         from . import pallas_ring
         return pallas_ring.ring_all_gather(x, axis_name, p,
@@ -1285,7 +1411,8 @@ def ici_bcast(x: jax.Array, axis_name: str, num_devices: int, root: int,
     nbytes = x.size * x.dtype.itemsize
     tier, reason = planned_tier("bcast", nbytes, x.dtype, None, interpret,
                                 p, _multi_axis(mesh_ctx))
-    _trace_entry("bcast", tier, nbytes, root=root)
+    _trace_entry("bcast", tier, nbytes, root=root,
+                 ring=(x.size, x.dtype, p))
     if tier == "hbm":
         return hbm_ring_bcast(x, axis_name, p, root, interpret=interpret,
                               mesh_ctx=mesh_ctx)
@@ -1310,7 +1437,8 @@ def ici_reduce_scatter(x, axis_name: str, num_devices: int,
     tier, reason = planned_tier("reduce_scatter_block", nbytes, xs[0].dtype,
                                 op, interpret, p, _multi_axis(mesh_ctx))
     x = _fold_unless_ring_does(xs, op, tier, p, mesh_ctx)
-    _trace_entry("reduce_scatter", tier, nbytes, op=op)
+    _trace_entry("reduce_scatter", tier, nbytes, op=op,
+                 ring=(xs[0].size, xs[0].dtype, p))
     if tier == "hbm":
         return hbm_ring_reduce_scatter(x, axis_name, p, op,
                                        interpret=interpret,

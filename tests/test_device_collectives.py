@@ -349,6 +349,10 @@ def test_comm_bcast_streams_and_counts_itself(interpreted_chain, root):
                if name == "ici_bcast"]
     assert lowered and all(a["tier"] == "hbm" and a["root"] == root
                            for a in lowered)
+    # and says how the chain it lowered to is written (ISSUE 54)
+    steps = pallas_ici.ring_steps("bcast", n, dt, P4)
+    assert steps["steps_traced"] > 0
+    assert all({k: a[k] for k in steps} == steps for a in lowered)
 
 
 def test_comm_bcast_in_the_vmem_bin_is_xla_and_no_fallback(

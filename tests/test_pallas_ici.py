@@ -267,6 +267,9 @@ _RING_FNS = {
     "reduce_scatter": pallas_ici.hbm_ring_reduce_scatter,
     "all_gather": pallas_ici.hbm_ring_all_gather,
 }
+# as ``pallas_ici.ring_steps`` and the ``ici_<coll>`` instants name them
+_STEP_NAMES = {"all_reduce": "allreduce", "all_gather": "allgather",
+               "reduce_scatter": "reduce_scatter"}
 
 
 def _ring_expect(coll, xv, p):
@@ -444,6 +447,18 @@ def _eqns(jaxpr, name):
     return (e for e in _all_eqns(jaxpr) if e.primitive.name == name)
 
 
+def _run_eqns(jaxpr, times=1):
+    """Every equation as often as the chip runs it: one inside a loop
+    (the ``scan`` a ``fori_loop`` of a known trip count is) counts once
+    a trip. Since ISSUE 54 a long ring round is a few steps and a loop,
+    so what a kernel does in all is read off a step at a time."""
+    for e in jaxpr.eqns:
+        yield from [e] * times
+        trips = e.params["length"] if e.primitive.name == "scan" else 1
+        for sub in _sub_jaxprs(e.params):
+            yield from _run_eqns(sub, times * trips)
+
+
 def _dma(e):
     """``(source, source is indexed, destination, destination is
     indexed, semaphore, device id)`` of a ``dma_start`` / ``dma_wait``
@@ -468,9 +483,15 @@ def _local_dmas(coll, p, n, hbm_names, **kw):
     kernel = call.params["jaxpr"]
     names = dict(zip(kernel.invars, hbm_names))
     dmas = []
-    for e in _eqns(kernel, "dma_start"):
+    loop = {}       # a loop body's name for the kernel's operand
+    for e in _run_eqns(kernel):
+        if e.primitive.name == "scan":
+            loop.update(zip(e.params["jaxpr"].jaxpr.invars, e.invars))
+        if e.primitive.name != "dma_start":
+            continue
         src, src_tf, dst, dst_tf, _sem, device = _dma(e)
         if device is None:
+            src, dst = loop.get(src, src), loop.get(dst, dst)
             dmas.append((names.get(src, "vmem"), not src_tf,
                          names.get(dst, "vmem"), not dst_tf))
     return dmas
@@ -479,16 +500,21 @@ def _local_dmas(coll, p, n, hbm_names, **kw):
 @pytest.mark.parametrize("coll,hbm", [
     ("all_reduce", ("x", "o")), ("reduce_scatter", ("x", "w", "o")),
     ("all_gather", ("x", "o"))])
-def test_no_whole_operand_copy_in_front_of_the_rounds(coll, hbm):
+@pytest.mark.parametrize("lane", [3, 8], ids=["unrolled", "looped"])
+def test_no_whole_operand_copy_in_front_of_the_rounds(coll, hbm, lane):
     """Read off the ``pallas_call``'s jaxpr (p = 4, two lanes, 3 chunks
-    a lane): no DMA moves a whole operand into a whole working or
-    output buffer, none writes the operand, and HBM meets HBM only in
-    the all-gather's copy of the shard into its own block. The fold
-    rounds load every accumulator chunk and round 0's send chunks from
-    the operand; the reduce-scatter's last fold stores into its
-    output."""
-    chunks, rounds = 2 * 3, P4 - 1
-    n = 48 * ROW if coll == "all_gather" else P4 * 48 * ROW
+    a lane, every step written out, and 8, a round a loop: each DMA
+    counted as often as it runs): no DMA moves a whole operand into a
+    whole working or output buffer, none writes the operand, and HBM
+    meets HBM only in the all-gather's copy of the shard into its own
+    block. The fold rounds load every accumulator chunk and round 0's
+    send chunks from the operand; the reduce-scatter's last fold stores
+    into its output."""
+    chunks, rounds = 2 * lane, P4 - 1
+    n = 16 * lane * ROW * (1 if coll == "all_gather" else P4)
+    assert bool(pallas_ici.ring_steps(
+        _STEP_NAMES[coll], n, np.float32, P4,
+        chunk_bytes=4096)["steps_looped"]) == (lane == 8)
     dmas = _local_dmas(coll, P4, n, hbm, chunk_bytes=4096)
     assert not [d for d in dmas if d[2] == "x"], "the operand is written"
     hbm2hbm = [d for d in dmas if d[0] != "vmem" and d[2] != "vmem"]
@@ -513,20 +539,29 @@ def test_no_whole_operand_copy_in_front_of_the_rounds(coll, hbm):
         assert stores.count("o") == 2 * rounds * chunks
 
 
-def _kernel_ops(coll, k, p, n, **kw):
-    """What the traced kernel of ``coll`` on ``k`` operands holds: its
-    primitives counted by name, and ``vpu``, those among them that
-    compute a whole chunk (the reducer's; loads and stores of VMEM are
-    ``get`` and ``swap``)."""
-    import collections
+def _traced_kernel(fn, n, dtype, k=1, p=P4):
+    """The ``pallas_call``'s kernel jaxpr of ``fn(shards)`` on ``k``
+    shards of ``[n]`` a chip: traced on shapes, nothing runs."""
     comm = MeshComm(make_mesh((p,), ("x",), jax.devices()[:p]))
     traced = jax.make_jaxpr(lambda *xs: comm.run(
-        lambda *s: _RING_FNS[coll](s if k > 1 else s[0], "x", p,
-                                   interpret=True, **kw), *xs,
-        out_specs=P("x")))(*[jnp.zeros(p * n, jnp.float32)] * k)
+        lambda *s: fn(s), *xs, out_specs=P("x")))(
+        *[jax.ShapeDtypeStruct((p * n,), dtype)] * k)
     (call,) = _eqns(traced.jaxpr, "pallas_call")
+    return call.params["jaxpr"]
+
+
+def _kernel_ops(coll, k, p, n, **kw):
+    """What the traced kernel of ``coll`` on ``k`` operands holds: its
+    primitives counted by name, each as often as it runs, and ``vpu``,
+    those among them that compute a whole chunk (the reducer's; loads
+    and stores of VMEM are ``get`` and ``swap``)."""
+    import collections
+    kernel = _traced_kernel(
+        lambda s: _RING_FNS[coll](s if k > 1 else s[0], "x", p,
+                                  interpret=True, **kw),
+        n, jnp.float32, k, p)
     ops = collections.Counter()
-    for e in _all_eqns(call.params["jaxpr"]):
+    for e in _run_eqns(kernel):
         ops[e.primitive.name] += 1
         if e.primitive.name not in ("get", "swap") and any(
                 len(getattr(v.aval, "shape", ())) >= 2 for v in e.outvars):
@@ -544,6 +579,47 @@ _PARENT_OPS = {
     "all_gather": dict(dma_start=55, dma_wait=73, get=0, swap=0, vpu=0,
                        semaphore_signal=22, semaphore_wait=21),
 }
+
+
+def _unrolled_stream_step(self, spans_chunks, src, acc, dst, red):
+    """``_RingStreamer.stream_step`` as it was until ISSUE 54, every
+    chunk step written out: the reference schedule."""
+    ndir = self.ndir
+    cmax = max(len(c) for c in spans_chunks)
+    live = [[None] * len(spans_chunks[d]) for d in range(ndir)]
+    for c in range(cmax + 1):
+        for d in range(ndir):
+            if c < len(spans_chunks[d]):
+                off, sz = spans_chunks[d][c]
+                live[d][c] = self.issue(
+                    d, src[d], off, sz, acc[d] if acc else None, red)
+        for d in range(ndir):
+            if 1 <= c and c - 1 < len(spans_chunks[d]):
+                off, sz = spans_chunks[d][c - 1]
+                self.drain(d, live[d][c - 1], dst[d], off, sz, red)
+    self.drain_stores()
+
+
+@pytest.mark.parametrize("coll,k", [
+    ("all_reduce", 1), ("all_reduce", 2), ("reduce_scatter", 2),
+    ("all_gather", 1)])
+def test_a_looped_kernel_runs_what_the_unrolled_one_holds(monkeypatch,
+                                                          coll, k):
+    """The counts below, restated for rounds that loop (8 chunks a
+    lane): each primitive as often as the chip runs it is what the
+    kernel with every step written out holds: the same DMA starts and
+    waits, VMEM reads and writes, chunk-wide VPU ops and semaphore ops;
+    the loops add scalar arithmetic and nothing else."""
+    n = 128 * ROW if coll == "all_gather" else P4 * 128 * ROW
+    names = ("dma_start", "dma_wait", "get", "swap", "vpu",
+             "semaphore_signal", "semaphore_wait")
+    looped = _kernel_ops(coll, k, P4, n, chunk_bytes=4096)
+    assert looped["scan"] == (P4 - 1) * (2 if coll == "all_reduce" else 1)
+    monkeypatch.setattr(pallas_ici._RingStreamer, "stream_step",
+                        _unrolled_stream_step)
+    unrolled = _kernel_ops(coll, k, P4, n, chunk_bytes=4096)
+    assert not unrolled["scan"]
+    assert {m: looped[m] for m in names} == {m: unrolled[m] for m in names}
 
 
 @pytest.mark.parametrize("coll,k", [
