@@ -24,7 +24,15 @@ plain numpy reference computed on the host from the same ``--seed``:
      buffers, each deletes its own array once the call has returned,
      and what each received is still bit-equal to what the other sent,
      on its own device (``dev_pt2pt_send`` +2, no fallback).
-  5. *Proof the chip did the work.* ``coll_level_chip`` rose by exactly
+  5. *Derived communicators.* ``run_ranks(8, app, device_mesh=True)``
+     once more: a ``dup`` of the world, its 2 x 4 rows and its columns
+     by ``comm.split`` (both rows, and all four columns, calling at
+     once) and the world with its keys reversed; on each the six
+     blocking collectives on device arrays at 4 MiB a rank, bit-equal
+     to ``tests/plain_reference.py``'s derived forms, every result on
+     the chip, ``dev_coll_derived`` +1 a rank a call and no
+     ``dev_coll_fallback_*``.
+  6. *Proof the chip did the work.* ``coll_level_chip`` rose by exactly
      the device collectives issued, ``dev_coll_fallback_host_dtype`` by
      exactly the port's float64 latency statistics (x64 is off, so they
      are turned away and counted), every other ``dev_coll_fallback_*``
@@ -39,7 +47,10 @@ lowering (``lax.psum`` / ``all_gather`` / ``all_to_all`` /
 alltoall at 16 MiB, allreduce max and reduce_scatter_block at 1 MiB, the
 last on the ring's fold rounds alone; bcast at 32 MiB from rank 0 and
 from rank 2 by the streaming chain, ``dev_coll_tier_hbm`` +1 a rank a
-call, the senders' buffers deleted after the calls); then the fold
+call, the senders' buffers deleted after the calls; and the 64 MiB
+allreduce once more on a ``dup`` of the world, whose channel runs the
+world's programs: the ring kernel, ``dev_coll_tier_hbm`` and
+``dev_coll_derived`` +1 a rank, nothing lowered again); then the fold
 phase, ``run_ranks(8, app, device_mesh=<the four chips>)`` (two ranks a
 chip, ``DeviceFoldChannel``): allreduce sum and max, allgather,
 reduce_scatter_block, bcast and reduce at 1 MiB a rank on device-resident
@@ -47,7 +58,8 @@ buffers, once each, compared with numpy; level 1 of the four reductions
 has to ride in the mesh program (``dev_fold_fused`` +4), and inside the
 ring kernel's fold rounds wherever the streaming ring takes the call
 (``dev_fold_in_ring``: at 1 MiB the max and the reduce_scatter_block; a
-sum of that size rides the flat VMEM ring behind the slot reduction);
+sum of that size rides the flat VMEM ring behind the slot reduction),
+and the allreduce once more on a ``dup``;
 then the
 point-to-point lane between two ranks on two chips (``dev_pt2pt_d2d``
 +2).
@@ -253,6 +265,99 @@ def library_door(seed: int, nranks: int = NRANKS, big: int = 16 * MiB,
     return len(phases) * (1 + STEADY_CALLS)
 
 
+def derived_comms(seed: int, nranks: int = NRANKS, nbytes: int = 4 * MiB,
+                  device_mesh=True, at_once: bool = True) -> int:
+    """Communicators derived from the bound world (ISSUE 55): a ``dup``,
+    the 2 x ``nranks / 2`` rows and the columns by ``comm.split`` (the
+    groups of a split calling at once), and the world with its keys
+    reversed; on each the six blocking collectives once on device
+    arrays, against ``tests/plain_reference.py``'s derived forms. Every
+    rank is a member of all four, so the step adds its return value, the
+    device collectives issued per rank, to ``coll_level_chip``.
+    ``at_once=False`` lets the groups of a split reduce one after the
+    other: the CPU rehearsal's, whose Pallas interpreter keeps one
+    shared memory a process."""
+    import jax
+
+    from mvapich2_tpu import mpit, run_ranks
+    sys.path.insert(0, os.path.join(REPO, "tests"))
+    import plain_reference as ref
+
+    half, n, root = nranks // 2, nbytes // 4, 1
+    world = list(range(nranks))
+    made = {     # name -> (the call on the world, the partition it makes)
+        "dup": (lambda c: c.dup(), [world]),
+        "rows": (lambda c: c.split(c.rank // half, c.rank % half),
+                 [world[:half], world[half:]]),
+        "columns": (lambda c: c.split(c.rank % half, c.rank // half),
+                    [[j, j + half] for j in range(half)]),
+        "reversed": (lambda c: c.split(0, -c.rank), [world[::-1]])}
+    colls = {    # name -> (the call, the reference of one group, computes)
+        "allreduce": (lambda c, x: c.allreduce(x), ref.allreduce, True),
+        "reduce": (lambda c, x: c.reduce(x, root=root),
+                   lambda xs: ref.reduce(xs, root), True),
+        "bcast": (lambda c, x: c.bcast(x, root=root),
+                  lambda xs: ref.bcast(xs, root), False),
+        "allgather": (lambda c, x: c.allgather(x), ref.allgather, False),
+        "alltoall": (lambda c, x: c.alltoall(x), ref.alltoall, False),
+        "reduce_scatter_block": (lambda c, x: c.reduce_scatter_block(x),
+                                 ref.reduce_scatter_block, True)}
+    xs = [rank_data(seed, 400, r, n) for r in range(nranks)]
+    results = {(m, c): [None] * nranks for m in made for c in colls}
+    took = {}
+
+    def app(comm):
+        dev = comm.device_channel.device
+        x = jax.device_put(xs[comm.rank], dev)
+        for m, (make, groups) in made.items():
+            t0 = time.perf_counter()
+            sub = make(comm)
+            if comm.rank == 0:
+                took[m] = time.perf_counter() - t0
+            ch = sub.device_channel
+            assert type(ch).__name__ == "HBMSlotChannel" and ch.derived \
+                and ch.device == dev, (m, type(ch).__name__)
+            mine = next(i for i, g in enumerate(groups) if comm.rank in g)
+            for c, (call, _ref, computes) in colls.items():
+                turns = [None] if at_once or not computes \
+                    else range(len(groups))
+                for turn in turns:
+                    if turn in (None, mine):
+                        out = call(sub, x)
+                        if out is not None:     # reduce, off the root
+                            out = jax.block_until_ready(out)
+                            assert out.devices() == {dev}, (m, c)
+                            results[m, c][comm.rank] = np.asarray(out)
+                    comm.barrier()
+            sub.free()
+
+    names = ("coll_level_chip", "dev_coll_derived")
+    before = {n_: mpit.pvar(n_).read() for n_ in names}
+    fb0 = fallback_pvars()
+    run_ranks(nranks, app, device_mesh=device_mesh, timeout=900.0)
+    for m, (_make, groups) in made.items():
+        for c, (_call, reference, _computes) in colls.items():
+            want = ref.on_groups(reference, xs, groups)
+            for r in range(nranks):
+                got = results[m, c][r]
+                if (got is None) != (want[r] is None) or (
+                        got is not None and not np.array_equal(got, want[r])):
+                    raise AssertionError(
+                        f"derived comms: {c} on {m}: rank {r} differs "
+                        f"from the plain reference")
+        say(f"derived comms: {m:<9} ({len(groups)} group(s) of "
+            f"{len(groups[0])}) made in {took[m] * 1e3:.1f} ms; six "
+            f"collectives at {nbytes} B/rank bit-equal to the plain "
+            f"reference, results on the chip")
+    calls = len(made) * len(colls)
+    rose = {n_: int(mpit.pvar(n_).read() - v) for n_, v in before.items()}
+    fb = {n_: v - fb0[n_] for n_, v in fallback_pvars().items()}
+    say(f"proof (derived comms): {rose}; fallbacks {fb}")
+    assert rose == {n_: nranks * calls for n_ in names}, rose
+    assert fb and not any(fb.values()), fb
+    return calls
+
+
 def pt2pt_lane(seed: int, device_mesh=True, nbytes: int = MiB,
                d2d: int = 0) -> None:
     """The device point-to-point lane through the library door: two
@@ -362,6 +467,7 @@ def one_chip(seed: int) -> None:
     calls = library_door(seed)
     osu_calls, osu_stats = launcher_door()
     calls += osu_calls
+    calls += derived_comms(seed)
     pt2pt_lane(seed)            # no collective of the device path in it
     rose = mpit.pvar("coll_level_chip").read() - chip0
     fb = {n: v - fb0[n] for n, v in fallback_pvars().items()}
@@ -501,7 +607,33 @@ def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
                 report[t] = (times[0], statistics.median(times[1:]))
                 say(f"four chips: ran {name} {_tag(op)} {nbytes} B/rank "
                     f"x {1 + STEADY_CALLS}")
+        # a dup of the world (ISSUE 55): a channel of its own over the
+        # same mesh, the world's programs. The largest allreduce once
+        # more, on it: the ring kernel (the tier pvar), on the derived
+        # channel, with nothing lowered again
+        t, _name, _op, nbytes = DUP_CASE
+        x = jax.device_put(data[t][comm.rank], dev)
+        dup = comm.dup()
+        assert type(dup.device_channel) is type(ch) \
+            and dup.device_channel.derived
+        comm.barrier()
+        counted = {n: mpit.pvar(n).read() for n in dup_counts}
+        comm.barrier()
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(dup.allreduce(x))
+        first = time.perf_counter() - t0
+        assert out.sharding.device_set == {dev}
+        comm.barrier()
+        rose = {n: mpit.pvar(n).read() - v for n, v in counted.items()}
+        assert rose == dict.fromkeys(dup_counts, nranks), rose
+        dup_results[comm.rank] = np.asarray(out)
+        if comm.rank == 0:
+            report["dup"] = first
+        dup.free()
 
+    DUP_CASE = cases[2]         # allreduce sum at the largest size
+    dup_counts = ("dev_coll_tier_hbm", "dev_coll_derived", "coll_level_ici")
+    dup_results = [None] * nranks
     before = {n: mpit.pvar(n).read()
               for n in ("dev_coll_tier_vmem", "dev_coll_tier_hbm",
                         "coll_level_ici", "dev_rs_wire_bytes")}
@@ -540,11 +672,21 @@ def four_chips(seed: int, nranks: int = 4, scale: int = 1) -> None:
             f"{nranks} ranks | first call {first:.3f} s (compile), steady "
             f"{steady * 1e3:.3f} ms/call (smoke timing)"
             + _ring_steps_note(name, op, nbytes, nranks))
+    total = np.sum(data[DUP_CASE[0]], axis=0)
+    for r in range(nranks):
+        if not np.array_equal(dup_results[r], total):
+            raise AssertionError(f"allreduce on a dup of the world: rank {r} "
+                                 f"differs from the numpy reference")
+    say(f"four chips: allreduce sum {DUP_CASE[3]:>9} B/rank on comm.dup(): "
+        f"bit-equal to numpy, the ring kernel on the derived channel | "
+        f"first call {report['dup'] * 1e3:.3f} ms (the world's program: "
+        f"smoke timing)")
     rose = {n: mpit.pvar(n).read() - v for n, v in before.items()}
     fb = {n: v - fb0[n] for n, v in fallback_pvars().items()}
     say(f"proof: {rose}; fallbacks {fb}")
     assert rose["dev_coll_tier_vmem"] > 0 and rose["dev_coll_tier_hbm"] > 0
-    assert rose["coll_level_ici"] == nranks * len(cases) * (1 + STEADY_CALLS)
+    assert rose["coll_level_ici"] == \
+        nranks * (len(cases) * (1 + STEADY_CALLS) + 1)
     # the reduce-scatter's calls took the ring kernel, which counts what
     # it sends (three quarters of the send buffer at 1 MiB: whole tiles)
     from mvapich2_tpu.ops.pallas_ici import reduce_scatter_wire_bytes
@@ -617,7 +759,17 @@ def fold_phase(seed: int, nranks: int = 8, ndev: int = 4,
             results[name][comm.rank] = np.asarray(out)
             if comm.rank == 0:
                 say(f"fold: ran {name} {nbytes} B/rank")
+        # a dup of the world (ISSUE 55): the fold channel once more over
+        # the same mesh, the world's fused program
+        dup = comm.dup()
+        assert type(dup.device_channel) is type(ch) \
+            and dup.device_channel.derived
+        out = jax.block_until_ready(dup.allreduce(x))
+        assert out.sharding.device_set == {dev}
+        on_dup[comm.rank] = np.asarray(out)
+        dup.free()
 
+    on_dup = [None] * nranks
     levels = ("coll_level_chip", "coll_level_ici")
     # level 1 of the four reductions rides in the mesh program, one
     # launch a call, and in the ring kernel itself where the one tier
@@ -630,8 +782,13 @@ def fold_phase(seed: int, nranks: int = 8, ndev: int = 4,
         for coll, op in (("allreduce", "sum"), ("allreduce", "max"),
                          ("reduce_scatter_block", "sum"), ("reduce", "sum")))
     assert in_ring >= 2, in_ring    # the max and the reduce-scatter stream
-    folds = {"dev_fold_fused": 4, "dev_fold_in_ring": in_ring,
-             "dev_fold_operands": 4, "dev_fold_stacked": ndev}
+    # the allreduce on the dup folds as the world's first one did
+    dup_in_ring = int(ring_folds(planned_tier(
+        "allreduce", nbytes, np.float32, "sum", num_devices=ndev)[0],
+        n, np.float32, ndev))
+    folds = {"dev_fold_fused": 5, "dev_fold_in_ring": in_ring + dup_in_ring,
+             "dev_fold_operands": 5, "dev_fold_stacked": ndev,
+             "dev_coll_derived": nranks}
     before = {n_: mpit.pvar(n_).read() for n_ in levels + tuple(folds)}
     fb0 = fallback_pvars()
     run_ranks(nranks, app, device_mesh=mesh, timeout=900.0)
@@ -652,10 +809,16 @@ def fold_phase(seed: int, nranks: int = 8, ndev: int = 4,
             # gather or a broadcast moves the chips' stacked deposits)
             + (_ring_steps_note(coll, op or "sum", nbytes, ndev)
                if coll.startswith(("allreduce", "reduce")) else ""))
+    for r in range(nranks):
+        if not np.array_equal(on_dup[r], total):
+            raise AssertionError(f"fold allreduce on a dup of the world: "
+                                 f"rank {r} differs from the numpy reference")
+    say(f"fold: allreduce on comm.dup()  {nbytes:>8} B/rank  bit-equal to "
+        f"numpy on the derived channel")
     rose = {n_: mpit.pvar(n_).read() - v for n_, v in before.items()}
     fb = {n_: v - fb0[n_] for n_, v in fallback_pvars().items()}
     say(f"proof (fold): {rose}; fallbacks {fb}")
-    assert all(rose[lv] == nranks * len(names) for lv in levels), rose
+    assert all(rose[lv] == nranks * (len(names) + 1) for lv in levels), rose
     assert all(rose[n_] == v for n_, v in folds.items()), rose
     assert fb and not any(fb.values()), fb
 

@@ -29,6 +29,13 @@ kernel modules' one tier rule (``ops/pallas_ici.planned_tier``, with its
 amendments for the op, the collective and the mesh inside it), and
 ``_decide_tier`` asks the same rule for what the call counts.
 
+A communicator derived from a bound one (dup, split, create, the
+topology constructors) is bound too, where the parent's channel has a
+channel for the new group (``derive``, asked by ``bind_derived``): its
+members share a rendezvous of their own, which the world's keeps; where
+it has none the communicator keeps the host arm, and a device array
+handed to it is counted (``note_host_comm``).
+
 The rendezvous requires all bound ranks to share one process (rank threads
 — the virtual-pod harness, ``mpirun --vpod``) or one jax.distributed
 runtime; process-mode ranks without either keep the host path (the install
@@ -40,7 +47,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -76,6 +83,7 @@ from ..utils import is_device_array  # noqa: E402 — shared predicate
 _DEPOSIT_AS_IS = mpit.pvar("dev_deposit_as_is")
 _PLAN_HIT = mpit.pvar("dev_call_plan_hit")
 _PLAN_FILED = mpit.pvar("dev_call_plan_filed")
+_DERIVED = mpit.pvar("dev_coll_derived")
 _config = get_config()
 
 # -- MV2T_JAX_PROFILE: hardware-profiler bracket ------------------------
@@ -463,7 +471,8 @@ class _Rendezvous:
     MPI already requires every rank to issue collectives on a comm in the
     same order, so one in-flight collective per comm is the contract."""
 
-    def __init__(self, size: int, last_first: bool = False):
+    def __init__(self, size: int, last_first: bool = False,
+                 root: Optional["_Rendezvous"] = None, key=None):
         self.size = size
         self.gate = _Gate(size, last_first)
         self.slots: List = [None] * size
@@ -475,6 +484,47 @@ class _Rendezvous:
         self.nb_lock = threading.Lock()
         self.nb_calls: Dict[int, dict] = {}
         self.nb_failed = False
+        # the rendezvous ``bind_universes`` made is the root of those
+        # of the communicators derived from its own (``derive``): it
+        # keeps them, under ``_reg_lock``, by ``key`` = (context id,
+        # the group's world ranks), each with the members still holding
+        # it. The colours of one split share a context id, so the id
+        # alone is no key
+        self.root = root or self
+        self.key = key
+        self._reg_lock = threading.Lock()
+        self._derived: Dict[tuple, list] = {}   # key -> [rendezvous, holders]
+
+    def derive(self, key: tuple) -> "_Rendezvous":
+        """The one rendezvous of the derived communicator ``key``, for
+        a member that binds it: the first to come makes it, with this
+        one's gate order, the rest find it; each holds it until its
+        ``release``."""
+        with self._reg_lock:
+            held = self._derived.get(key)
+            if held is None:
+                held = self._derived[key] = [
+                    _Rendezvous(len(key[1]), self.gate.last_first, self,
+                                key), 0]
+            held[1] += 1
+            return held[0]
+
+    def release(self, rv: "_Rendezvous") -> None:
+        """A member freed its communicator; the last one out takes the
+        entry with it."""
+        with self._reg_lock:
+            held = self._derived.get(rv.key)
+            if held is not None and held[0] is rv:
+                held[1] -= 1
+                if held[1] <= 0:
+                    del self._derived[rv.key]
+
+    def live(self, world_rank: Optional[int] = None) -> List["_Rendezvous"]:
+        """The derived rendezvous held now (those ``world_rank`` is a
+        member of, if given)."""
+        with self._reg_lock:
+            return [rv for (_ctx, world), (rv, _n) in self._derived.items()
+                    if world_rank is None or world_rank in world]
 
     def abort(self) -> None:
         """Break the gate so peers blocked in a device collective see
@@ -513,8 +563,9 @@ class DeviceCollChannel:
                                   "reduce_scatter_block", "alltoallv")
     # what _phase reads, set per call by _run: the rank's recorder while
     # its blocking collective is traced, its ``seq`` (the blocking
-    # collectives this rank has begun), and the one args dict every
-    # phase event of the call carries, ``seq`` and ``coll``
+    # collectives this rank has begun on this channel), and the one args
+    # dict every phase event of the call carries, ``seq``, ``coll`` and
+    # ``ctx``
     _tr = None
     _seq = 0
     _args: Optional[dict] = None
@@ -556,6 +607,12 @@ class DeviceCollChannel:
         self._plans: Dict[tuple, _CallPlan] = {}
         self._level_pvars = tuple(mpit.pvar(f"coll_level_{lv}")
                                   for lv in self.LEVELS)
+        # whose channel this is: its communicator's ``ctx_coll`` (set by
+        # ``install_device_coll``), its ranks' world ranks, and whether
+        # ``derive`` made it (``_adopt`` then sets the last two)
+        self.ctx: Optional[int] = None
+        self.world: Tuple[int, ...] = tuple(range(self.size))
+        self.derived = False
 
     @property
     def multi_axis(self) -> bool:
@@ -577,7 +634,62 @@ class DeviceCollChannel:
         return self.size
 
     def abort(self) -> None:
+        """This rank is dying: break its rendezvous here and every live
+        one derived from the same root that it is a member of, so that
+        whoever waits for it in a row communicator's collective raises
+        as on the world's, and no other group is touched."""
         self.rv.abort()
+        for rv in self.rv.root.live(self.world[self.rank]):
+            rv.abort()
+
+    # -- channels of derived communicators -------------------------------
+    def derive(self, members: Sequence[int], ctx: int
+               ) -> Optional["DeviceCollChannel"]:
+        """This rank's channel for a communicator derived from the one
+        this channel is bound to, or None where the host arm has to
+        carry it (counted: dev_coll_fallback_host_comm). ``members``
+        are the new group's ranks in this communicator, in the new
+        rank order; ``ctx`` is the new context id.
+
+        A group that is this communicator's whole group in its order
+        (MPI_Comm_dup; a split with one colour and keys in rank order;
+        cart_create without reorder) gets a channel over the same mesh
+        and axis with a rendezvous of its own: it runs this channel's
+        programs. A ring over some chips of the mesh, or in another
+        order, has no program here yet."""
+        if tuple(members) != tuple(range(self.size)):
+            return None
+        return self._adopt(self._twin(self._derived_rv(members, ctx)))
+
+    def _twin(self, rv: _Rendezvous) -> "DeviceCollChannel":
+        """A channel like this one, of the same rank, on ``rv``."""
+        return DeviceCollChannel(self.mesh, self.axes, rv, self.rank)
+
+    def _derived_rv(self, members: Sequence[int], ctx: int) -> _Rendezvous:
+        """The rendezvous the members of the derived communicator share:
+        kept by the root's, found by (context id, world ranks)."""
+        return self.rv.root.derive(
+            (ctx, tuple(self.world[m] for m in members)))
+
+    def _adopt(self, child: "DeviceCollChannel") -> "DeviceCollChannel":
+        """``child`` is the channel of a communicator derived from this
+        one's: it counts its calls (dev_coll_derived, beside the level
+        pvars), knows its ranks' world ranks, and where it is of this
+        channel's size its programs are this channel's (same
+        ``_chan_desc``, same keys: nothing is traced or compiled
+        again)."""
+        child.derived = True
+        child.world = child.rv.key[1]
+        child._level_pvars += (_DERIVED,)
+        if child.size == self.size:
+            child._programs = self._programs
+        return child
+
+    def release(self) -> None:
+        """``Comm.free``: a derived channel lets go of its rendezvous
+        (the last member out removes it from the root's keeping)."""
+        if self.derived:
+            self.rv.root.release(self.rv)
 
     # -- jitted program cache (per mesh, keyed by op signature) ----------
     def _program(self, name: str, n: int, dtype_str: str, op: str,
@@ -977,7 +1089,7 @@ class DeviceCollChannel:
                 # give the call
                 tr.record("device", plan.wire[0], "i",  # mv2tlint: ignore[events]
                           {"coll": name, "seq": self._seq + 1,
-                           "wire_bytes": plan.wire[1]})
+                           "ctx": self.ctx, "wire_bytes": plan.wire[1]})
         return plan.tier
 
     def _decide_tier(self, plan: _CallPlan, name: str, local,
@@ -1038,16 +1150,21 @@ class DeviceCollChannel:
         phase spans inside it (``_phase``), and the MV2T_JAX_PROFILE
         bracket for hardware runs. Every span of one collective carries
         its ``seq``: this rank's count of blocking collectives on the
-        channel, equal on every rank because MPI orders collectives;
-        the B also says ``as_is``, ``_as_local``'s word that ``local`` is
-        the caller's own array object. The span's length is its two
+        channel, equal on every rank because MPI orders collectives,
+        and its ``ctx``, the communicator's ``ctx_coll``: every channel
+        counts from 1, so a call is ``(ctx, seq)``.
+        The B also says ``derived`` (the channel is a derived
+        communicator's) and ``as_is``, ``_as_local``'s word that
+        ``local`` is the caller's own array object. The span's length
+        is its two
         stamps' difference; no clock is read for a call that is neither
         traced nor metered (``metrics.LIVE``).
         While a recorder is attached the call also lies on the jax
         profiler's host plane as a TraceAnnotation of the same name, so
         an MV2T_JAX_PROFILE trace shows it beside the device's ops; it
-        says ``seq`` and ``rank``, by which trace/xprof.py ties the
-        runtime's events on this thread's line to the recorder's spans."""
+        says ``seq``, ``ctx``, ``derived`` and ``rank``, by which
+        trace/xprof.py ties the runtime's events on this thread's line
+        to the recorder's spans."""
         global _profiler
         tier = self._tier = self._note_tier(comm, name, local, op)
         for lv in self._level_pvars:    # the hierarchy levels it rides
@@ -1061,16 +1178,19 @@ class DeviceCollChannel:
                 _profiler = jax.profiler
             span = f"dev_{name}"
             # the one dict the call's E and every phase event carry
-            args = self._args = {"seq": self._seq, "coll": name}
+            args = self._args = {"seq": self._seq, "coll": name,
+                                 "ctx": self.ctx}
             tr.record("device", span, "B",
                       {"tier": tier, "op": op,
                        "bytes": int((local.data
                                      if isinstance(local, _VDeposit)
                                      else local).nbytes),
                        "seq": self._seq, "coll": name, "as_is": as_is,
-                       "planned": self._plan is not None})
+                       "planned": self._plan is not None,
+                       "ctx": self.ctx, "derived": self.derived})
             note = _profiler.TraceAnnotation(span, seq=self._seq,
-                                             rank=self.rank)
+                                             rank=self.rank, ctx=self.ctx,
+                                             derived=self.derived)
         _maybe_start_jax_profile()
         mx = _metrics.LIVE
         if mx is not None:
@@ -1275,8 +1395,10 @@ class DeviceCollChannel:
         ``plan=True`` is the MPI_*_init pre-warm: run the same routing
         gates, then build the program signatures through the exec-cache
         seam instead of launching (returns True/False)."""
-        if self.mesh is None:
-            return None      # slot channel keeps the host schedule
+        if self.mesh is None or self.derived:
+            # the slot channel keeps the host schedule, and so does a
+            # derived communicator's channel for now
+            return None
         opn, op_sel, root = None, None, 0
         rcounts = rdispls = None
         if name == "allreduce":
@@ -1584,6 +1706,19 @@ class HBMSlotChannel(DeviceCollChannel):
     def _chan_desc(self) -> str:
         return f"slot{self.size}x{self.device.platform}"
 
+    def derive(self, members: Sequence[int], ctx: int
+               ) -> Optional["HBMSlotChannel"]:
+        """Any two or more of the chip's ranks, in any order, are a slot
+        channel of their own on the same device: the rows and columns
+        of a pencil, a reversed key, ``cart_sub``, MPI_Comm_create over
+        a subset. A group of one gets none: the host entry of a
+        one-rank communicator copies nothing."""
+        if len(members) < 2:
+            return None
+        return self._adopt(
+            HBMSlotChannel(self.device, self._derived_rv(members, ctx),
+                           list(members).index(self.rank), len(members)))
+
     def _build(self, name: str, n: int, op: str, root: int, extra=None):
         """One jitted ``f(*xs)`` per signature. ``xs`` is what the
         leader had: the ``R`` deposited ``(n,)`` arrays, or one staged
@@ -1739,6 +1874,10 @@ class DeviceFoldChannel(DeviceCollChannel):
 
     def _mesh_extent(self) -> int:
         return self.ndev
+
+    def _twin(self, rv: _Rendezvous) -> "DeviceFoldChannel":
+        return DeviceFoldChannel(self.mesh, self.axes, rv, self.rank,
+                                 self.size)
 
     def _chan_desc(self) -> str:
         return f"fold{self.size}r{self.ndev}d_{super()._chan_desc()}"
@@ -2080,6 +2219,7 @@ def install_device_coll(comm, channel: DeviceCollChannel) -> None:
         install_coll_ops(comm)
     host = dict(comm.coll_fns)
     comm.device_channel = channel
+    channel.ctx = comm.ctx_coll
     sz = comm.size
 
     # per-coll (bytes-on-the-wire, op-position, recv-count) metadata; the
@@ -2176,6 +2316,50 @@ def install_device_coll(comm, channel: DeviceCollChannel) -> None:
             return host_a2av(comm_, sendbuf, scounts, sdispls, recvbuf,
                              rcounts, rdispls, datatype)
         comm.coll_fns["alltoallv"] = a2av_entry
+
+
+def bind_derived(parent, new) -> None:
+    """``new`` was just derived from ``parent`` (MPI_Comm_dup, _create,
+    _create_group, _split: ``core/comm.py``, once the context id is
+    agreed), this rank is a member and ``parent`` is device-bound: ask
+    its channel for a channel of the new group (``derive``) and, if it
+    has one, bind it.
+    The ``dev_comm_derive`` span lies around the asking; its E says
+    which class was bound (``none``: the communicator keeps the host
+    arm, and a device array handed to it is counted) and whether it
+    runs on the parent's mesh with the parent's programs."""
+    parent_ch = parent.device_channel
+    tr = getattr(parent.u.engine, "tracer", None)
+    if tr is not None:
+        args = {"parent_ctx": parent.ctx_coll, "ctx": new.ctx_coll,
+                "size": new.size}
+        tr.record("device", "dev_comm_derive", "B", args)
+    try:
+        ch = parent_ch.derive(
+            new.group.translate_ranks(range(new.size), parent.group),
+            new.context_id)
+        if ch is not None:
+            install_device_coll(new, ch)
+    finally:
+        if tr is not None:
+            bound = new.device_channel
+            tr.record("device", "dev_comm_derive", "E", {
+                **args, "channel": type(bound).__name__ if bound else "none",
+                "same_mesh": bound is not None
+                and bound._chan_desc() == parent_ch._chan_desc()})
+
+
+def note_host_comm(comm) -> None:
+    """A device array was handed to a collective of a communicator with
+    no device channel, in a universe whose world has one: it takes the
+    host arm and comes back as numpy. Counted
+    (dev_coll_fallback_host_comm) and, traced, noted as
+    ``_note_turned_away`` notes a dtype."""
+    mpit.pvar("dev_coll_fallback_host_comm").inc()
+    tr = getattr(comm.u.engine, "tracer", None)
+    if tr is not None:
+        tr.record("channel", "dev_coll_fallback", "i", reason="host_comm",
+                  ctx=comm.ctx_coll, size=comm.size)
 
 
 def build_nonblocking_request(comm, name: str, *a):

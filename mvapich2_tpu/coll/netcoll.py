@@ -114,9 +114,11 @@ def _state(comm) -> Optional[_Net2State]:
         ngroups = math.ceil(comm.size / 64)
         if 1 < ngroups < comm.size:
             color = comm.rank % ngroups
-            intra = comm.split(color, key=comm.rank)
+            # host buffers of the host schedule: no device channel
+            intra = comm.split(color, key=comm.rank, _bind=False)
             is_leader = intra is not None and intra.rank == 0
-            leaders = comm.split(0 if is_leader else None, key=comm.rank)
+            leaders = comm.split(0 if is_leader else None, key=comm.rank,
+                                 _bind=False)
             if intra is not None and (not is_leader or leaders is not None):
                 st = _Net2State(ngroups, intra, leaders, is_leader)
     except Exception as e:   # degrade, never desync: every rank that

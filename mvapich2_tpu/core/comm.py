@@ -84,7 +84,11 @@ class Comm:
         # device-mesh binding (ICI channel): set by parallel/mesh layer when
         # this comm maps onto a jax Mesh axis
         self.mesh_axis = None
-        # ICI collective channel (coll/device.py install_device_coll)
+        # this rank's device-collective channel (coll/device.py
+        # install_device_coll): set by bind_universes on COMM_WORLD, and
+        # by dup / create / create_group / split (_bind_derived) on a
+        # communicator derived from a bound one, where the parent's
+        # channel has one for the new group
         self.device_channel = None
         # revoke-packet routing + failure unwind need ctx -> comm
         universe.comms_by_ctx[context_id] = self
@@ -471,6 +475,11 @@ class Comm:
                 MPI_ERR_COMM, "device-array recvbuf requires a mesh-bound "
                 "communicator (see coll/device.py)")
         if _is_device(sendbuf):
+            if self.u.device is not None:
+                # the world is bound and this communicator is not (a
+                # sub-mesh, shrink's, build_2level's): counted
+                from ..coll.device import note_host_comm
+                note_host_comm(self)
             sendbuf = np.asarray(sendbuf)
         return sendbuf, recvbuf
 
@@ -850,7 +859,7 @@ class Comm:
         self.attrs.copy_all(self, new.attrs)
         new.errhandler = self.errhandler
         new.topo = self.topo
-        return new
+        return self._bind_derived(new)
 
     def create(self, group: Group) -> Optional["Comm"]:
         """MPI_Comm_create: collective over self; returns None for
@@ -875,7 +884,8 @@ class Comm:
             # (MPICH likewise frees the id on non-members immediately)
             self.u.release_context_id(ctx)
             return None
-        return Comm(self.u, group, ctx, self.name + "_create", self)
+        return self._bind_derived(
+            Comm(self.u, group, ctx, self.name + "_create", self))
 
     def create_group(self, group: Group, tag: int = 0) -> Optional["Comm"]:
         """MPI_Comm_create_group: collective only over ``group``'s members
@@ -893,8 +903,9 @@ class Comm:
         m = group.size
         if m == 1:
             # single-member: no agreement (see alloc_context_local)
-            return Comm(self.u, group, self.u.alloc_context_local(),
-                        self.name + "_create_group", self)
+            return self._bind_derived(
+                Comm(self.u, group, self.u.alloc_context_local(),
+                     self.name + "_create_group", self))
         parent_of = {g: self.group.rank_of_world(group.world_of_rank(g))
                      for g in range(m)}
         # AND-combine the members' availability masks (the same
@@ -939,7 +950,8 @@ class Comm:
                 break
             import time
             time.sleep(0.0002)
-        return Comm(self.u, group, ctx, self.name + "_create_group", self)
+        return self._bind_derived(
+            Comm(self.u, group, ctx, self.name + "_create_group", self))
 
     def _plane_gather(self, payload: np.ndarray) -> Optional[np.ndarray]:
         """Allgather one small fixed-size record from every member
@@ -984,7 +996,11 @@ class Comm:
             return None
         return table
 
-    def split(self, color: int, key: int = 0) -> Optional["Comm"]:
+    def split(self, color: int, key: int = 0,
+              _bind: bool = True) -> Optional["Comm"]:
+        """MPI_Comm_split. ``_bind`` is for the library's own splits
+        (``build_2level``, the net2 bridge): their communicators carry
+        the host algorithms' host buffers and get no device channel."""
         self._check()
         my_color = int(color) if color is not None else UNDEFINED
         mine = np.array([my_color, key, self.u.world_rank],
@@ -998,9 +1014,10 @@ class Comm:
             # single-member: no agreement (see alloc_context_local)
             if my_color == UNDEFINED:
                 return None
-            return Comm(self.u, Group([self.u.world_rank]),
-                        self.u.alloc_context_local(),
-                        f"{self.name}_split", self)
+            return self._bind_derived(
+                Comm(self.u, Group([self.u.world_rank]),
+                     self.u.alloc_context_local(),
+                     f"{self.name}_split", self), _bind)
         allv = None
         ctx = -1
         agree_key = (self.context_id, 0)
@@ -1043,8 +1060,20 @@ class Comm:
             if c == my_color:
                 members.append((k, r, wr))   # sort by key, then comm rank
         members.sort()
-        return Comm(self.u, Group([wr for _, _, wr in members]), ctx,
-                    f"{self.name}_split", self)
+        return self._bind_derived(
+            Comm(self.u, Group([wr for _, _, wr in members]), ctx,
+                 f"{self.name}_split", self), _bind)
+
+    def _bind_derived(self, new: "Comm", bind: bool = True) -> "Comm":
+        """``new`` was derived from this communicator, its context id is
+        agreed and this rank is a member: where this one is device-bound
+        the parent's channel is asked for a channel of the new group
+        (coll/device.py bind_derived), so that a device array handed to
+        ``new``'s collectives stays on the device."""
+        if bind and self.device_channel is not None:
+            from ..coll.device import bind_derived
+            bind_derived(self, new)
+        return new
 
     def split_type_shared(self, key: int = 0) -> "Comm":
         """MPI_Comm_split_type(COMM_TYPE_SHARED): ranks on my node."""
@@ -1063,6 +1092,9 @@ class Comm:
             return
         self.attrs.delete_all(self)
         self.u.comms_by_ctx.pop(self.context_id, None)
+        if self.device_channel is not None:
+            # a derived communicator's rendezvous goes with its last member
+            self.device_channel.release()
         # return a mask-allocated context id to the availability pool
         # (MPIR-style reuse: dup/free loops must never exhaust the
         # 2048-comm budget — comm/ctxalloc.c, comm/ctxsplit.c)
@@ -1091,9 +1123,10 @@ class Comm:
         if self._twolevel_ready:
             return self._shmem_comm, self._leader_comm
         node_of_me = self.u.node_ids[self.u.world_rank]
-        shmem = self.split(node_of_me, self.rank)
+        shmem = self.split(node_of_me, self.rank, _bind=False)
         am_leader = shmem.rank == 0
-        leader = self.split(0 if am_leader else None, self.rank)
+        leader = self.split(0 if am_leader else None, self.rank,
+                            _bind=False)
         self._shmem_comm = shmem
         self._leader_comm = leader if am_leader else None
         self._twolevel_ready = True

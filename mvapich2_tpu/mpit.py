@@ -433,6 +433,15 @@ pvar("dev_coll_fallback_host_dtype", PVAR_CLASS_COUNTER, "device",
      "(coll/device.py _select_transport). The transport-level sibling "
      "of dev_coll_fallback_dtype, which counts XLA takes at the kernel "
      "tier")
+pvar("dev_coll_fallback_host_comm", PVAR_CLASS_COUNTER, "device",
+     "collective calls, per rank, that handed a device array to a "
+     "communicator with no device channel in a device-bound universe "
+     "and took the host arm, the array read back and a numpy result "
+     "returned (core/comm.py _stage_if_unbound): a proper sub-group "
+     "of a 1:1 mesh or fold communicator, MPIX_Comm_shrink's, and a "
+     "communicator split by build_2level. A communicator derived "
+     "from a device-bound one is otherwise bound (coll/device.py "
+     "bind_derived) and counts dev_coll_derived")
 pvar("dev_coll_fallback_shape", PVAR_CLASS_COUNTER, "device",
      "device collectives routed to the XLA lowering because of a "
      "degenerate buffer extent")
@@ -578,6 +587,13 @@ pvar("dev_mesh_reordered", PVAR_CLASS_COUNTER, "device",
      "neighbours (a 2x2 given as ids 0, 1, 2, 3 is walked 0, 1, 3, 2); "
      "devices without coords, and meshes given in ring order, do not "
      "count")
+pvar("dev_coll_derived", PVAR_CLASS_COUNTER, "device",
+     "blocking device collective calls, per rank, that ran on the "
+     "channel of a derived communicator (MPI_Comm_dup, _split, "
+     "_create, _create_group and the topology constructors over "
+     "them, of a device-bound communicator: coll/device.py "
+     "bind_derived), counted in _run beside the level pvars, which "
+     "rise as on the world's channel")
 pvar("coll_level_chip", PVAR_CLASS_COUNTER, "device",
      "collective calls that exercised the chip level of the three-"
      "level hierarchy: an HBM slot fold among co-resident ranks (the "

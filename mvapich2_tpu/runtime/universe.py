@@ -106,7 +106,9 @@ class Universe:
     @property
     def device(self):
         """The device this rank is bound to (its COMM_WORLD's device
-        channel: run_ranks(..., device_mesh=...), --vpod), or None."""
+        channel: run_ranks(..., device_mesh=...), --vpod), or None. The
+        channel of a communicator derived from COMM_WORLD lives on the
+        same device."""
         ch = getattr(self.comm_world, "device_channel", None)
         return ch.device if ch is not None else None
 
@@ -548,7 +550,9 @@ def run_ranks(nranks: int, fn: Callable, *args,
             # wake peers stuck waiting on us
             ch = getattr(universes[r].comm_world, "device_channel", None)
             if ch is not None:
-                ch.abort()   # break the device-collective rendezvous
+                # break the device-collective rendezvous, and those of
+                # the derived communicators this rank is a member of
+                ch.abort()
             for u in universes:
                 u.engine.wakeup()
         finally:

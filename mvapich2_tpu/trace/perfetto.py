@@ -124,18 +124,21 @@ def _profile_rows(out: List[Dict[str, Any]], dumps: List[Dict[str, Any]],
     for rank, mine in sorted(calls.items()):
         out.append({"name": "thread_name", "ph": "M", "pid": rank,
                     "tid": _RUNTIME_TID, "args": {"name": "runtime"}})
-        for seq, call in mine.items():
+        for call in mine.values():
+            said = {"seq": call.seq}    # and whose call it is, if said
+            if call.ctx is not None:
+                said["ctx"] = call.ctx
             row(call.name, "runtime", rank, _RUNTIME_TID, call.begin,
-                call.end, seq=seq)
+                call.end, **said)
             for s, t in call.launch:
-                row("launch", "runtime", rank, _RUNTIME_TID, s, t, seq=seq)
+                row("launch", "runtime", rank, _RUNTIME_TID, s, t, **said)
             for name, s, t in call.execute:
-                row(name, "runtime", rank, _RUNTIME_TID, s, t, seq=seq)
+                row(name, "runtime", rank, _RUNTIME_TID, s, t, **said)
             for s, t in call.wait:
-                row("wait", "runtime", rank, _RUNTIME_TID, s, t, seq=seq)
+                row("wait", "runtime", rank, _RUNTIME_TID, s, t, **said)
             for dev, _flow, _enqueued, s, t in call.done:
-                row("done", "runtime", rank, _RUNTIME_TID, s, t, seq=seq,
-                    device=dev)
+                row("done", "runtime", rank, _RUNTIME_TID, s, t,
+                    device=dev, **said)
 
     ops_of = {o: xprof.device_ops(profile, o)
               for o in xprof.device_ordinals(profile)}

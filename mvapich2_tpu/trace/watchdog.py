@@ -190,6 +190,13 @@ def _device_report(u) -> list:
         gate = rv.gate
         lines.append(f"  rendezvous: {gate.n_waiting}/{rv.size} ranks "
                      f"waiting, broken={gate.broken}")
+        # the derived communicators this rank is a member of: who waits
+        # at which gate, by context id
+        for sub in rv.live(ch.world[ch.rank]):
+            ctx, world = sub.key
+            lines.append(f"  derived rendezvous ctx {ctx} (world ranks "
+                         f"{list(world)}): {sub.gate.n_waiting}/{sub.size} "
+                         f"ranks waiting, broken={sub.gate.broken}")
     try:
         pvs = []
         for name in ("dev_coll_tier_vmem", "dev_coll_tier_hbm",
@@ -197,6 +204,7 @@ def _device_report(u) -> list:
                      "dev_coll_quant_bytes_saved",
                      "dev_coll_fallback_size", "dev_coll_fallback_dtype",
                      "dev_coll_fallback_host_dtype",
+                     "dev_coll_fallback_host_comm", "dev_coll_derived",
                      "dev_coll_fallback_shape",
                      "dev_coll_fallback_platform"):
             v = mpit.pvar(name).read()

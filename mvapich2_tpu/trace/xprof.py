@@ -2,8 +2,13 @@
 
 While a recorder is attached every rank's every device collective lies
 on the profiler's host plane as a ``TraceAnnotation`` ``dev_<coll>``
-that says ``seq`` and ``rank`` (``coll/device.py:_run``), so each rank
-thread's line of ``/host:CPU`` is keyed like the recorder's spans. On
+that says ``seq``, ``ctx`` and ``rank`` (``coll/device.py:_run``), so
+each rank thread's line of ``/host:CPU`` is keyed like the recorder's
+spans. ``seq`` is a channel's own count and every communicator's
+channel counts from 1: a call is ``(ctx, seq)``, ``ctx`` the
+communicator's ``ctx_coll`` (``None`` in a trace of a tree before the
+stat), and a job that uses two communicators ties each call to its own
+spans. On
 those same lines the runtime writes its own events, stamped in C++
 outside the interpreter lock and on the annotation's clock: the jitted
 call from entry to return, the executable's steps inside it, and the
@@ -30,7 +35,7 @@ HOST_PLANE = "/host:CPU"
 DEVICE_PLANE = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"    # one event a program run: all its ops
-ANNOTATION = "dev_"         # ``_run``'s annotation: dev_<coll>, seq, rank
+ANNOTATION = "dev_"         # ``_run``'s annotation: dev_<coll>, seq, ctx, rank
 
 # The runtime's events read, by kind: a name that holds one of the
 # kind's entries is of that kind. Filled from what
@@ -70,6 +75,7 @@ _DEVICE = "device_ordinal"
 
 
 Span = Tuple[float, float]
+Key = Tuple[Optional[int], int]     # a call: (ctx, seq)
 
 
 def _produced(stats: dict):
@@ -88,8 +94,8 @@ class RankLine(NamedTuple):
     name: str                               # the thread's
     # by start: (kind of RUNTIME_EVENTS or None, name, begin, end, stats)
     events: List[Tuple[Optional[str], str, float, float, dict]]
-    calls: List[Tuple[int, float, float]]   # (seq, begin, end), by begin
-    names: Dict[int, str]                   # seq -> its annotation's name
+    calls: List[Tuple[Key, float, float]]   # ((ctx, seq), begin, end), by begin
+    names: Dict[Key, str]                   # (ctx, seq) -> its annotation's name
     # an outer launch event's start -> the programs it enqueued that
     # the trace saw done (``Call.done``'s tuples)
     programs: Dict[float, List[Tuple[int, object, float, float, float]]]
@@ -110,12 +116,13 @@ class Call(NamedTuple):
     # end); the flow id is also its run's on the device plane
     # (``device_programs``)
     done: List[Tuple[int, object, float, float, float]]
+    ctx: Optional[int] = None               # the communicator's ctx_coll
 
 
 class Tie(NamedTuple):
     """``offset_s`` + a recorder stamp = the trace's axis. ``spread_s``:
     how far the middle pair lies above the least; ``pairs``: how many
-    ``(rank, seq)`` both sides held."""
+    ``(rank, ctx, seq)`` both sides held."""
     offset_s: float
     spread_s: float
     pairs: int
@@ -221,9 +228,10 @@ def rank_lines(profile) -> Dict[int, RankLine]:
                 kind = kind_of(name)
                 if kind is None and name.startswith(ANNOTATION) \
                         and "seq" in stats and "rank" in stats:
-                    rank, seq = int(stats["rank"]), int(stats["seq"])
-                    calls.append((seq,) + span)
-                    names[seq] = name
+                    rank = int(stats["rank"])
+                    key = (_ctx_of(stats), int(stats["seq"]))
+                    calls.append((key,) + span)
+                    names[key] = name
                     continue
                 if _FLOW_OUT in stats or _FLOW_IN in stats:
                     flows.add(number, kind, span, stats)
@@ -246,21 +254,27 @@ def rank_lines(profile) -> Dict[int, RankLine]:
     return out
 
 
-def _stamps_of(events) -> Dict[int, float]:
-    """``seq -> stamp`` of one rank's recorder ``dev_<coll>`` B events
-    (tuples of the ring, or lists of a dump)."""
+def _ctx_of(said: dict) -> Optional[int]:
+    """The ``ctx`` an annotation's stats or a span's args say."""
+    ctx = said.get("ctx")
+    return None if ctx is None else int(ctx)
+
+
+def _stamps_of(events) -> Dict[Key, float]:
+    """``(ctx, seq) -> stamp`` of one rank's recorder ``dev_<coll>`` B
+    events (tuples of the ring, or lists of a dump)."""
     out = {}
     for t, layer, name, ph, args in events:
         if layer == "device" and ph == "B" and args and "seq" in args \
                 and name == ANNOTATION + str(args.get("coll")):
-            out[args["seq"]] = t
+            out[_ctx_of(args), args["seq"]] = t
     return out
 
 
 def tie(profile, events_by_rank: Dict[int, Sequence],
         lines: Optional[Dict[int, RankLine]] = None) -> Optional[Tie]:
     """Seconds to add to a recorder stamp to land on the trace's axis.
-    Per ``(rank, seq)`` both sides hold: the annotation's start less the
+    Per ``(rank, ctx, seq)`` both sides hold: the annotation's start less the
     recorder's ``dev_<coll>`` B stamp. The annotation is entered a few
     lines after the stamp in the same thread, so every difference is the
     offset plus what those lines took: the least is the offset. ``None``
@@ -269,8 +283,8 @@ def tie(profile, events_by_rank: Dict[int, Sequence],
     diffs = []
     for rank, line in lines.items():
         stamps = _stamps_of(events_by_rank.get(rank, ()))
-        diffs += [begin - stamps[seq] for seq, begin, _end in line.calls
-                  if seq in stamps]
+        diffs += [begin - stamps[key] for key, begin, _end in line.calls
+                  if key in stamps]
     if not diffs:
         return None
     least = min(diffs)
@@ -279,9 +293,12 @@ def tie(profile, events_by_rank: Dict[int, Sequence],
 
 def runtime_events(profile, rank: int,
                    lines: Optional[Dict[int, RankLine]] = None
-                   ) -> Dict[int, Call]:
-    """``seq -> Call`` of one rank: on its line, from the start of
-    annotation ``seq`` to the start of the next annotation (the last
+                   ) -> Dict[object, Call]:
+    """One rank's calls: ``seq -> Call`` where its line holds one
+    communicator's calls (every cell of the benchmark, whose readers
+    ask by ``seq``), ``(ctx, seq) -> Call`` where it holds those of
+    more than one. On its line, from the start of
+    an annotation to the start of the next (the last
     call's stretch runs to the line's end), the launch events that lie
     in no other launch event, whatever else lies inside them (the
     execute steps), the completion of each program they enqueued, and
@@ -293,12 +310,14 @@ def runtime_events(profile, rank: int,
     line = lines.get(rank)
     if line is None:
         return {}
-    out: Dict[int, Call] = {}
+    out: Dict[object, Call] = {}
     events, at = line.events, 0
-    for k, (seq, begin, end) in enumerate(line.calls):
+    by_seq = len({ctx for (ctx, _seq), _b, _e in line.calls}) <= 1
+    for k, (key, begin, end) in enumerate(line.calls):
         until = line.calls[k + 1][1] if k + 1 < len(line.calls) \
             else float("inf")
-        call = Call(seq, line.names[seq], begin, end, [], [], [], [])
+        ctx, seq = key
+        call = Call(seq, line.names[key], begin, end, [], [], [], [], ctx)
         pending: Dict[object, float] = {}   # flow id -> the wait's start
         while at < len(events) and events[at][2] < begin:
             at += 1
@@ -320,7 +339,7 @@ def runtime_events(profile, rank: int,
                     call.wait.append((s, t))
             elif call.launch and t <= call.launch[-1][1]:
                 call.execute.append((name, s, t))
-        out[seq] = call
+        out[seq if by_seq else key] = call
     return out
 
 
@@ -353,10 +372,11 @@ def device_ops(profile, ordinal: int) -> List[Tuple[str, float, float]]:
     return sorted(out, key=lambda op: op[1])
 
 
-def result_seen(calls: Dict[int, Dict[int, Call]], seq: int
+def result_seen(calls: Dict[int, Dict[object, Call]], seq
                 ) -> Optional[float]:
     """When call ``seq``'s result was first seen (``calls`` is ``{rank:
-    runtime_events(...)}``): the earliest end, over every rank's line,
+    runtime_events(...)}``, ``seq`` a key of theirs): the earliest end,
+    over every rank's line,
     of the call's wait (its last, where a call waits for several
     arrays); on a client that writes no wait on a waiting thread's line
     (the TPU's), the earliest start of the completion events of the

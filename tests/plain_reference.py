@@ -74,6 +74,27 @@ def reduce_scatter_block(inputs: Sequence[np.ndarray], op: str = "sum"
     return [total[r * c:(r + 1) * c] for r in range(p)]
 
 
+def on_groups(collective, inputs: Sequence[np.ndarray],
+              groups: Sequence[Sequence[int]], *args, **kw
+              ) -> List[Optional[np.ndarray]]:
+    """The derived forms: ``collective`` (one of the six above) called
+    at once on every communicator of a partition of the world.
+    ``inputs`` is every world rank's buffer; ``groups`` the partition,
+    each group its members' world ranks in the order of their ranks in
+    the new communicator (what ``MPI_Comm_split``'s keys, a group's
+    order or a ``dup`` give). A group's result is the world's reference
+    applied to that group's inputs in group order, so a ``root`` counts
+    within the group; a world rank in no group gets ``None``, as does a
+    rank its group's collective hands nothing. Knows nothing of
+    communicators, context ids or channels."""
+    out: List[Optional[np.ndarray]] = [None] * len(inputs)
+    for group in groups:
+        got = collective([inputs[w] for w in group], *args, **kw)
+        for rank, w in enumerate(group):
+            out[w] = got[rank]
+    return out
+
+
 def sendrecv(inputs: Sequence[np.ndarray],
              pairs: Sequence[Tuple[Optional[int], Optional[int]]]
              ) -> List[Optional[np.ndarray]]:

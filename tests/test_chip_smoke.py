@@ -95,12 +95,33 @@ def test_fold_phase_under_the_interpreter(monkeypatch, capsys):
     # edge, inside the ring kernel's fold rounds (ISSUE 49); allgather
     # alone still makes a planar copy a chip. The phase asserts the four
     # itself, and says them
+    # ... and the allreduce once more on a dup of the world (ISSUE 55)
+    # folds as the world's did
     assert mpit.pvar("dev_fold_stacked").read() - stacked0 == 4 * 1
-    assert mpit.pvar("dev_fold_operands").read() - operands0 == 4
-    assert mpit.pvar("dev_fold_fused").read() - fused0 == 4
-    assert mpit.pvar("dev_fold_in_ring").read() - in_ring0 == 4
-    assert "'dev_fold_fused': 4" in out
-    assert "'dev_fold_in_ring': 4" in out
+    assert mpit.pvar("dev_fold_operands").read() - operands0 == 5
+    assert mpit.pvar("dev_fold_fused").read() - fused0 == 5
+    assert mpit.pvar("dev_fold_in_ring").read() - in_ring0 == 5
+    assert "'dev_fold_fused': 5" in out
+    assert "'dev_fold_in_ring': 5" in out
+    assert "'dev_coll_derived': 8" in out
+    assert "allreduce on comm.dup()" in out
+
+
+def test_derived_comms_step_on_one_device(capsys):
+    """The one-chip run's step: eight ranks on one device, a dup, the
+    rows, the columns and the reversed world, six collectives each (a
+    split's groups reduce in turn here: the interpreter)."""
+    from mvapich2_tpu.parallel.mesh import make_mesh
+    derived0 = mpit.pvar("dev_coll_derived").read()
+    calls = chip_smoke.derived_comms(
+        seed=11, nbytes=8 * 512 * 4, at_once=False,
+        device_mesh=make_mesh((1,), ("x",), jax.devices()[:1]))
+    assert calls == 4 * 6
+    assert mpit.pvar("dev_coll_derived").read() - derived0 == \
+        chip_smoke.NRANKS * calls
+    out = capsys.readouterr().out
+    assert out.count("bit-equal to the plain reference") == 4
+    assert "columns   (4 group(s) of 2)" in out
 
 
 @pytest.mark.parametrize("chips", [1, 4])

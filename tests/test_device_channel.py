@@ -310,7 +310,7 @@ def test_device_dispatch_spans_and_phases(monkeypatch):
     args = bs[0][4]
     assert args["tier"] in tiers
     assert args["op"] == "sum" and args["bytes"] > 0
-    assert es[0][4] == {"seq": 1, "coll": "allreduce"}
+    assert es[0][4] == {"seq": 1, "coll": "allreduce", "ctx": 1}   # the world's ctx_coll
     for r, events in device_lane.items():
         names = [e[2] for e in events if e[3] == "B"]
         assert names[0] == "dev_allreduce"

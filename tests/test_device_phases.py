@@ -315,8 +315,9 @@ def test_trace_annotation_only_while_a_recorder_is_attached(
         monkeypatch, trace):
     """Untraced: no recorder, no phase object, no TraceAnnotation.
     Traced: one annotation per collective, named like the span, that
-    says the call's ``seq`` and the rank whose thread it is
-    (trace/xprof.py ties a line of the profile to its rank by it)."""
+    says the call's ``seq``, its communicator's ``ctx`` (the world's
+    ``ctx_coll`` here, and not ``derived``) and the rank whose thread it
+    is (trace/xprof.py ties a line of the profile to its rank by it)."""
     import mvapich2_tpu.coll.device as devmod
     if trace:
         monkeypatch.setenv("MV2T_TRACE", "1")
@@ -346,7 +347,8 @@ def test_trace_annotation_only_while_a_recorder_is_attached(
         get_config().reload()
     if trace:
         assert sorted(made, key=str) == [
-            ("dev_allreduce", {"seq": 1, "rank": r}) for r in range(4)]
+            ("dev_allreduce", {"seq": 1, "rank": r, "ctx": 1,
+                               "derived": False}) for r in range(4)]
         assert all(isinstance(p, devmod._Phase) for p in phases)
     else:
         assert made == []
@@ -437,7 +439,7 @@ def test_phase_names_pass_the_events_lint():
 
 # -- what one event holds, and what recording it reads (ISSUE 36) --------
 
-PHASE_ARGS = {"seq", "coll"}
+PHASE_ARGS = {"seq", "coll", "ctx"}
 # what a site learns after its B, on the E alone
 ADDED = {"dev_dispatch": "built", "dev_collect": "parts",
          "dev_deliver": "relaid"}
@@ -466,7 +468,8 @@ def test_the_ring_holds_the_same_tuple_and_args(traced, channel):
             assert ph in ("B", "E")
             if name in ("dev_allreduce", "dev_bcast"):
                 assert set(args) == (PHASE_ARGS | (
-                    {"tier", "op", "bytes", "as_is", "planned"} if ph == "B"
+                    {"tier", "op", "bytes", "as_is", "planned", "derived"}
+                    if ph == "B"
                     else set())), ev
             elif ph == "E" and name in ADDED:
                 assert set(args) == PHASE_ARGS | {ADDED[name]}, ev
@@ -513,7 +516,7 @@ def test_the_chip_fold_span_says_what_level_1_copied(traced, resident,
     ends = {rank: [a for _t, _l, name, ph, a in lane
                    if name == "dev_chip_fold" and ph == "E"]
             for rank, lane in lanes.items()}
-    assert ends.pop(0) == [{"seq": 1, "coll": "allreduce", "k": 2,
+    assert ends.pop(0) == [{"seq": 1, "coll": "allreduce", "ctx": 1, "k": 2,
                             "chips": 4, "stacked": stacked,
                             "fused": resident, "in_ring": False}]
     assert not any(ends.values())       # the leader's span alone

@@ -487,7 +487,8 @@ def test_chip_fold_span_lies_in_the_leaders_stage(traced, case):
         assert [ph for ph, _a in folds] == ["B", "E"] * 2
         for i, (ph, a) in enumerate(folds):
             assert (a["seq"], a["coll"]) == (i // 2 + 1, name)
-            extra = {k: v for k, v in a.items() if k not in ("seq", "coll")}
+            extra = {k: v for k, v in a.items()
+                     if k not in ("seq", "coll", "ctx")}
             assert extra == ({} if ph == "B" else
                              {"k": K, "chips": CHIPS, "stacked": stacked,
                               "fused": case in FOLDED,

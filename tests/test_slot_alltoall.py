@@ -248,6 +248,6 @@ def test_spans_say_the_eager_ops_behind_a_result(traced, channel, coll,
         assert [(a["seq"], a["relaid"]) for a in ends(rank, "dev_deliver")] \
             == [(s, relaid) for s in range(1, CALLS + 1)], rank
         # the Bs carry what they always carried
-        assert all(set(a) == {"seq", "coll"} for _t, _l, nam, ph, a
+        assert all(set(a) == {"seq", "coll", "ctx"} for _t, _l, nam, ph, a
                    in lanes[rank]
                    if ph == "B" and nam in ("dev_collect", "dev_deliver"))

@@ -131,11 +131,13 @@ def recorder(rank, lag_us, base_s=5000.0):
 def test_rank_lines_by_what_the_annotation_says(shuffled):
     lines = xprof.rank_lines(synthetic(shuffled))
     assert sorted(lines) == [0, 1]      # no rank stat, no annotation: out
-    assert [c[0] for c in lines[0].calls] == [1, 2, 3]
+    # a call is (ctx, seq); these annotations say no ctx
+    assert [c[0] for c in lines[0].calls] == [(None, 1), (None, 2), (None, 3)]
     assert lines[0].calls[0][1:] == pytest.approx((100e-6, 500e-6))
     assert lines[1].calls[2][1:] == pytest.approx((2110e-6, 2490e-6))
-    assert lines[0].names == {1: "dev_allreduce", 2: "dev_allreduce",
-                              3: "dev_allreduce"}
+    assert lines[0].names == {(None, 1): "dev_allreduce",
+                              (None, 2): "dev_allreduce",
+                              (None, 3): "dev_allreduce"}
 
 
 def test_tie_is_the_least_difference_and_says_the_spread():
@@ -234,13 +236,13 @@ def _bracketed(merged, ranks, spread_us):
     within ``spread_us``; returns how many calls were looked at."""
     looked = 0
     for rank in ranks:
-        stamps = {(e["args"]["seq"], e["ph"]): e["ts"]
+        stamps = {((e["args"].get("ctx"), e["args"]["seq"]), e["ph"]): e["ts"]
                   for e in merged["traceEvents"]
                   if e.get("pid") == rank and e.get("cat") == "device"
                   and e["name"].startswith("dev_") and e.get("args")
                   and e["name"] == "dev_" + str(e["args"].get("coll"))}
         for row in _rows(merged, rank, perfetto._RUNTIME_TID):
-            seq = row["args"]["seq"]
+            seq = (row["args"].get("ctx"), row["args"]["seq"])
             if not row["name"].startswith("dev_") \
                     or (seq, "B") not in stamps or (seq, "E") not in stamps:
                 continue
@@ -288,6 +290,53 @@ def test_the_merge_holds_runtime_lanes_and_device_rows_on_one_clock():
     assert perfetto.merge(dumps, NS(planes=[]))["metadata"] == {"tie": None}
 
 
+def test_two_communicators_calls_tie_by_ctx_and_seq():
+    """Every channel counts its calls from 1: a rank that used the
+    world and a communicator split from it holds two calls ``seq`` 1.
+    Each ties to its own recorder spans by ``(ctx, seq)``: joined by
+    ``seq`` alone, one of the two would pair with the other's stamp, a
+    millisecond off."""
+    line = ([ev("dev_allreduce", 100, 400, seq=1, rank=0, ctx=3, derived=0)]
+            + launch(200, 100, 1)
+            + [ev("dev_alltoall", 1100, 400, seq=1, rank=0, ctx=9,
+                  derived=1)] + launch(1200, 100, 2)
+            + [ev("dev_allreduce", 2100, 400, seq=2, rank=0, ctx=3,
+                  derived=0)] + launch(2200, 100, 3))
+    prof = NS(planes=[NS(name="/host:CPU",
+                         lines=[NS(name="python", events=line)])])
+    base, lags = 5000.0, {(3, 1): 7, (9, 1): 3, (3, 2): 5}
+    starts = {(3, 1): 100, (9, 1): 1100, (3, 2): 2100}
+    events = []
+    for (ctx, seq), lag in lags.items():
+        coll = "alltoall" if ctx == 9 else "allreduce"
+        a = {"seq": seq, "coll": coll, "ctx": ctx, "derived": ctx == 9}
+        at = base + starts[ctx, seq] * 1e-6
+        events.append([at - lag * 1e-6, "device", f"dev_{coll}", "B", a])
+        events.append([at + 405e-6, "device", f"dev_{coll}", "E", a])
+    events.sort(key=lambda e: e[0])
+    lines = xprof.rank_lines(prof)
+    assert [c[0] for c in lines[0].calls] == [(3, 1), (9, 1), (3, 2)]
+    tied = xprof.tie(prof, {0: events}, lines)
+    assert tied.pairs == 3
+    assert tied.offset_s == pytest.approx(-base + 3e-6, abs=1e-9)
+    assert tied.spread_s == pytest.approx(2e-6, abs=1e-9)   # 0, 2, 4 us above
+    mine = xprof.runtime_events(prof, 0, lines)
+    assert sorted(mine) == [(3, 1), (3, 2), (9, 1)]
+    assert mine[9, 1].name == "dev_alltoall" and mine[9, 1].ctx == 9
+    assert mine[9, 1].seq == 1 and mine[3, 2].seq == 2
+    assert mine[9, 1].launch == [pytest.approx((1200e-6, 1300e-6))]
+    assert mine[3, 1].launch == [pytest.approx((200e-6, 300e-6))]
+    # the merged timeline says whose call a row is, and every call's
+    # recorder stamps lie round its own annotation
+    merged = perfetto.merge([{"rank": 0, "events": events}], prof)
+    rows = [e for e in _rows(merged, 0, perfetto._RUNTIME_TID)
+            if e["name"].startswith("dev_")]
+    assert [(e["args"]["ctx"], e["args"]["seq"]) for e in rows] \
+        == [(3, 1), (9, 1), (3, 2)]
+    assert _bracketed(merged, (0,), merged["metadata"]["tie"]["spread_us"]) \
+        == 3
+
+
 @pytest.fixture
 def traced(monkeypatch):
     monkeypatch.setenv("MV2T_TRACE", "1")
@@ -306,8 +355,11 @@ def test_a_real_cpu_profile_of_a_two_rank_allreduce(traced, tmp_path):
     calls together)."""
     calls = 6
     spans = {}
+    world_ctx = []
 
     def app(comm):
+        if comm.rank == 0:
+            world_ctx.append(comm.ctx_coll)
         x = jax.device_put(np.ones(1 << 22, np.float32),
                            comm.device_channel.device)
         jax.block_until_ready(comm.allreduce(x))     # builds the program
@@ -330,8 +382,10 @@ def test_a_real_cpu_profile_of_a_two_rank_allreduce(traced, tmp_path):
     prof = xprof.load(str(tmp_path))
     lines = xprof.rank_lines(prof)
     assert sorted(lines) == [0, 1]
-    for rank in (0, 1):
-        assert [c[0] for c in lines[rank].calls] == list(range(2, calls + 2))
+    for rank in (0, 1):     # every call on the world's channel, by its ctx
+        assert {c[0][0] for c in lines[rank].calls} == {world_ctx[0]}
+        assert [c[0][1] for c in lines[rank].calls] \
+            == list(range(2, calls + 2))
     tied = xprof.tie(prof, spans, lines)
     assert tied.pairs == 2 * calls and 0 <= tied.spread_s < 1e-3
     mine = xprof.runtime_events(prof, 0, lines)
@@ -348,6 +402,50 @@ def test_a_real_cpu_profile_of_a_two_rank_allreduce(traced, tmp_path):
               for w in c.wait]
     assert waited and all(b < e for b, e in waited)
     assert xprof.device_ordinals(prof) == []        # the CPU has no plane
+
+
+def test_a_real_cpu_profile_of_two_communicators(traced, tmp_path):
+    """What this jax writes of a job that calls on the world and on a
+    dup of it in turn: both channels count from 1, every annotation says
+    its ``ctx``, and every call ties to the recorder's span of its own
+    communicator."""
+    calls = 3
+    spans, ctxs = {}, {}
+
+    def app(comm):
+        x = jax.device_put(np.ones(1 << 16, np.float32),
+                           comm.device_channel.device)
+        dup = comm.dup()
+        ctxs[comm.rank] = (comm.ctx_coll, dup.ctx_coll)
+        comm.barrier()
+        if comm.rank == 0:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        comm.barrier()
+        try:
+            for _ in range(calls):
+                jax.block_until_ready(comm.allreduce(x))
+                jax.block_until_ready(dup.allreduce(x))
+            comm.barrier()
+        finally:
+            if comm.rank == 0:
+                jax.profiler.stop_trace()
+        spans[comm.rank] = list(comm.u.engine.tracer.events)
+
+    run_ranks(2, app, device_mesh=True)
+    prof = xprof.load(str(tmp_path))
+    lines = xprof.rank_lines(prof)
+    world, dup = ctxs[0]
+    assert world != dup and ctxs[1] == ctxs[0]
+    want = [(c, k) for k in range(1, calls + 1) for c in (world, dup)]
+    for rank in (0, 1):
+        assert [c[0] for c in lines[rank].calls] == want
+    tied = xprof.tie(prof, spans, lines)
+    assert tied.pairs == 2 * 2 * calls and 0 <= tied.spread_s < 1e-3
+    mine = xprof.runtime_events(prof, 0, lines)
+    assert sorted(mine) == sorted(want)
+    assert {c.ctx for c in mine.values()} == {world, dup}
 
 
 def test_mpitrace_merges_a_jax_profile_of_a_two_rank_run(tmp_path):
